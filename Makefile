@@ -1,6 +1,11 @@
 # Top-level convenience targets (the reference's Makefile/CI entrypoints
 # role — see tests/ and native/ for the real work).
 
+# The drills below run at CPU sizes: they ask for the CPU themselves.
+# Nothing in tools/ or bench.py picks it by default — those run on
+# whatever device JAX finds (`make bench`, `make chip-smoke`).
+CPU := JAX_PLATFORMS=cpu
+
 all: native
 
 native:
@@ -8,15 +13,15 @@ native:
 
 test: native check
 	$(MAKE) -C native test
-	python -m pytest tests/ -q
-	python tools/wire_report.py
-	python tools/memory_report.py
-	python tools/loadgen.py
-	python tools/dr_drill.py
+	$(CPU) python -m pytest tests/ -q
+	$(CPU) python tools/wire_report.py
+	$(CPU) python tools/memory_report.py
+	$(CPU) python tools/loadgen.py
+	$(CPU) python tools/dr_drill.py
 	$(MAKE) kernels
 
 test-fast: check
-	python -m pytest tests/ -q -x --ignore=tests/test_dist.py
+	$(CPU) python -m pytest tests/ -q -x --ignore=tests/test_dist.py
 
 check:
 	python -m tools.graftcheck
@@ -24,66 +29,70 @@ check:
 bench:
 	python bench.py
 
+# one process, one chip; `python chip_smoke.py --chips 4` on a 4-chip host
+chip-smoke:
+	python chip_smoke.py
+
 bench-trend:
 	python tools/bench_table.py --trend
 
 efficiency:
-	python tools/efficiency_report.py
+	$(CPU) python tools/efficiency_report.py
 
 wire:
-	python tools/wire_report.py
+	$(CPU) python tools/wire_report.py
 
 # PR-20 capacity ledger: reconciled pool books on a checkpointed fit
 # AND a generation-lane serving run, then the synthetic OOM squeeze
 memory:
-	python tools/memory_report.py
+	$(CPU) python tools/memory_report.py
 
 dryrun:
-	python __graft_entry__.py
+	$(CPU) python __graft_entry__.py
 
 dist-test:
 	python tools/launch.py -n 2 python tests/dist/dist_sync_kvstore.py
 
 chaos:
-	python -m pytest tests/ -q -m chaos
+	$(CPU) python -m pytest tests/ -q -m chaos
 
 trace:
-	python tools/trace_fit.py
+	$(CPU) python tools/trace_fit.py
 
 watchdog:
-	python tools/watchdog_fit.py
+	$(CPU) python tools/watchdog_fit.py
 
 elastic:
-	python tools/elastic_fit.py
+	$(CPU) python tools/elastic_fit.py
 
 dr:
-	python tools/dr_drill.py
+	$(CPU) python tools/dr_drill.py
 
 continuous:
-	python tools/continuous_fit.py
+	$(CPU) python tools/continuous_fit.py
 
 serve:
-	python tools/serve.py --smoke
+	$(CPU) python tools/serve.py --smoke
 
 generate:
-	python tools/generate_demo.py
+	$(CPU) python tools/generate_demo.py
 
 slo:
-	python tools/slo_report.py
+	$(CPU) python tools/slo_report.py
 
 fairness:
-	python tools/loadgen.py
+	$(CPU) python tools/loadgen.py
 
 # fused-kernel tier (PR-19): full parity grid (exit nonzero on any
 # mismatch), then the BENCH_KERNELS=1 lane (which re-gates on the quick
 # grid and measures the optimizer-tree CPU win)
 kernels:
-	python -m mxnet_tpu.ops.fused.parity
-	BENCH_KERNELS=1 python bench.py
+	$(CPU) python -m mxnet_tpu.ops.fused.parity
+	$(CPU) BENCH_KERNELS=1 python bench.py
 
 clean:
 	$(MAKE) -C native clean
 
-.PHONY: all native test test-fast check bench bench-trend efficiency \
+.PHONY: all native test test-fast check bench chip-smoke bench-trend efficiency \
 	wire memory dryrun dist-test chaos trace watchdog elastic dr continuous \
 	serve generate slo fairness kernels clean

@@ -106,6 +106,7 @@ def get_devices(args):
 
 def fit(args, network, data_loader, **kwargs):
     """Train ``network`` on data from ``data_loader(args, kv)``."""
+    mx.compile_cache.enable()
     kv = mx.kvstore.create(args.kv_store)
 
     logging.basicConfig(level=logging.INFO,

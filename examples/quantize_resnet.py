@@ -11,7 +11,7 @@ Two modes:
 * ``--benchmark``: ResNet-50 at ImageNet shape on the current device —
   int8 vs bf16 vs fp32 inference throughput (synthetic weights;
   throughput does not depend on weight values), one JSON line per
-  dtype.  Run on the chip for the BENCH_TABLE.md int8 row.
+  dtype.  Run on the chip for ``tools/bench_table.py``'s int8 row.
 
     python examples/quantize_resnet.py            # accuracy gate
     python examples/quantize_resnet.py --benchmark --tpus 1
@@ -186,7 +186,7 @@ def benchmark(batch=128, image=224, log=True):
                               "value": round(rows[tag], 1),
                               "unit": "img/s", "batch": batch}),
                   flush=True)
-    # bf16 via the model's dtype knob (fp rows in BENCH_TABLE use this)
+    # bf16 via the model's dtype knob (bench_table's fp rows use this)
     bsym = resnet.get_symbol(num_classes=1000, num_layers=50,
                              image_shape=(3, image, image), layout="NHWC",
                              dtype="bfloat16")

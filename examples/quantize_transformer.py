@@ -14,7 +14,7 @@ Two modes (mirror of ``examples/quantize_resnet.py``):
   on the current device — int8(out=bf16, quantized from the bf16
   graph so the unquantized attention path is identical in both rows)
   vs bf16 vs fp32 inference tokens/s, one JSON line per dtype.  Run on
-  the chip for the BENCH_TABLE.md int8 LM row.
+  the chip for ``tools/bench_table.py``'s int8 LM row.
 
     python examples/quantize_transformer.py             # accuracy gate
     python examples/quantize_transformer.py --benchmark --tpus 1

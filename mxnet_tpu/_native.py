@@ -4,8 +4,8 @@ The reference loads ``libmxnet.so`` through ctypes (``python/mxnet/base.py``:
 ``_LIB``/``check_call``); this is the same pattern for the TPU build's native
 core (engine, storage, profiler, recordio — see ``native/include/mxtpu/c_api.h``).
 The library is built on demand with ``make`` the first time it's needed and
-cached; every consumer has a pure-Python fallback so the framework degrades
-gracefully when no C++ toolchain exists.
+cached; every consumer has a pure-Python fallback, and a build that fails
+says so (a warning, and :func:`status`).
 """
 
 from __future__ import annotations
@@ -15,8 +15,9 @@ import ctypes
 import os
 import subprocess
 import threading
+import warnings
 
-__all__ = ["lib", "available", "RecordLoader", "DecodeLoader",
+__all__ = ["lib", "available", "status", "RecordLoader", "DecodeLoader",
            "buf_to_bytes"]
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
@@ -26,6 +27,7 @@ _SO_PATH = os.path.join(_NATIVE_DIR, "build", "libmxtpu.so")
 _lock = threading.Lock()
 _lib = None
 _tried = False
+_status = "not loaded"
 
 
 def _configure(lib):
@@ -90,41 +92,66 @@ def _configure(lib):
 
 
 def _build():
+    """``make`` the library; returns None on success, else why not."""
     try:
         subprocess.run(["make", "-s", "-j4"], cwd=_NATIVE_DIR, check=True,
                        capture_output=True, timeout=300)
-        return True
-    except Exception:
-        return False
+    except subprocess.CalledProcessError as exc:
+        tail = (exc.stderr or b"").decode("utf-8", "replace").strip()
+        return "make failed: %s" % tail[-300:]
+    except (OSError, subprocess.TimeoutExpired) as exc:
+        return "make did not run: %r" % (exc,)
+    return None
+
+
+def _load():
+    """(configured CDLL, None), or (None, why it cannot be loaded)."""
+    try:
+        return _configure(ctypes.CDLL(_SO_PATH)), None
+    except (OSError, AttributeError) as exc:
+        return None, "cannot load %s: %s" % (_SO_PATH, exc)
 
 
 def lib():
     """Return the configured CDLL, building it if needed; None on failure.
 
-    Disable entirely with MXTPU_NO_NATIVE=1 (forces pure-Python fallbacks —
-    the analog of the reference's NaiveEngine debug switch at the build level).
+    A failed build or load is not silent: it warns once with the reason
+    and :func:`status` keeps it.  Disable entirely with MXTPU_NO_NATIVE=1
+    (forces pure-Python fallbacks — the analog of the reference's
+    NaiveEngine debug switch at the build level).
     """
-    global _lib, _tried
+    global _lib, _tried, _status
     with _lock:
         if _tried:
             return _lib
         _tried = True
         if os.environ.get("MXTPU_NO_NATIVE"):
+            _status = "disabled by MXTPU_NO_NATIVE"
             return None
-        if not os.path.exists(_SO_PATH) and os.path.isdir(_NATIVE_DIR):
-            _build()
-        if os.path.exists(_SO_PATH):
-            try:
-                _lib = _configure(ctypes.CDLL(_SO_PATH))
-            except (OSError, AttributeError):
+        found = os.path.exists(_SO_PATH)
+        why = None if found else _build()
+        if why is None:
+            _lib, why = _load()
+            if _lib is None and found:
                 # stale .so missing newer symbols: rebuild once, then retry
-                _lib = None
-                if _build():
-                    try:
-                        _lib = _configure(ctypes.CDLL(_SO_PATH))
-                    except (OSError, AttributeError):
-                        _lib = None
+                found = False
+                why = _build()
+                if why is None:
+                    _lib, why = _load()
+        if _lib is None:
+            _status = "unavailable, pure-Python fallbacks in use (%s)" % why
+            warnings.warn("native runtime " + _status)
+        else:
+            _status = "loaded %s (%s)" % (
+                _SO_PATH, "found built" if found
+                else "built now from native/src")
         return _lib
+
+
+def status():
+    """One line on which native runtime this process got, and why."""
+    lib()
+    return _status
 
 
 def available():

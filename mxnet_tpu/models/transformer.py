@@ -27,7 +27,7 @@ def get_symbol(num_classes=32000, seq_len=1024, num_embed=512, num_heads=8,
     LM head (ShardedTrainer sums all loss-op outputs).  On a mesh with
     an ``expert`` axis the experts shard over it; on one chip the same
     graph runs dense (routing + capacity + dispatch still execute —
-    the single-chip MoE bench row in BENCH_TABLE.md)."""
+    ``tools/bench_table.py``'s single-chip MoE row)."""
     if ffn not in ("dense", "moe"):
         raise ValueError("ffn must be 'dense' or 'moe', got %r" % (ffn,))
     aux_losses = []

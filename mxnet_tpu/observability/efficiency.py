@@ -54,6 +54,7 @@ import os
 import threading
 import time as _time
 
+from ..base import MXNetError
 from . import metrics as _metrics
 
 __all__ = [
@@ -89,19 +90,19 @@ PEAK_FLOPS_TABLE = (
     ("a100", 312e12),
 )
 
-#: Denominator when the device kind matches nothing (the CPU smoke
-#: backend) — an arbitrary but *stable* 1 TFLOP/s so MFU stays a
-#: comparable diagnostic across runs rather than a meaningless 0/0.
-DEFAULT_PEAK_FLOPS = 1e12
-
 _KIND_CACHE = {"v": None}
 
 
 def peak_flops(device_kind=None):
-    """Peak FLOP/s for one device.  ``MXNET_TPU_DEVICE_PEAK_FLOPS``
-    (raw FLOP/s, e.g. ``197e12``) overrides; otherwise the
-    :data:`PEAK_FLOPS_TABLE` row matching ``device_kind`` (default: the
-    first visible device's kind), else :data:`DEFAULT_PEAK_FLOPS`."""
+    """Peak FLOP/s for one device, or None on the CPU.
+
+    ``MXNET_TPU_DEVICE_PEAK_FLOPS`` (raw FLOP/s, e.g. ``197e12``)
+    overrides; otherwise the :data:`PEAK_FLOPS_TABLE` row matching
+    ``device_kind`` (default: the first visible device's kind).  The
+    CPU has no row and no utilization: it gives None, and callers
+    record no MFU there.  Any other kind the table does not know is an
+    error, not a default — a utilization against a made-up peak reads
+    like a measurement."""
     env = os.environ.get("MXNET_TPU_DEVICE_PEAK_FLOPS")
     if env:
         try:
@@ -111,18 +112,20 @@ def peak_flops(device_kind=None):
     if device_kind is None:
         device_kind = _KIND_CACHE["v"]
         if device_kind is None:
-            try:
-                import jax
+            import jax
 
-                device_kind = jax.devices()[0].device_kind
-            except Exception:
-                device_kind = ""
+            device_kind = jax.devices()[0].device_kind
             _KIND_CACHE["v"] = device_kind
     kind = str(device_kind).lower()
+    if kind == "cpu":
+        return None
     for sub, flops in PEAK_FLOPS_TABLE:
         if sub in kind:
             return flops
-    return DEFAULT_PEAK_FLOPS
+    raise MXNetError(
+        "no peak FLOP/s known for device kind %r: add it to "
+        "PEAK_FLOPS_TABLE with its source, or set "
+        "MXNET_TPU_DEVICE_PEAK_FLOPS" % (device_kind,))
 
 
 # ----------------------------------------------------------------------
@@ -182,7 +185,8 @@ def _mfu_fams():
                     "model_flops_utilization",
                     "Model FLOPs utilization: achieved model FLOP/s over "
                     "the device peak (peak_flops(); "
-                    "MXNET_TPU_DEVICE_PEAK_FLOPS override)"),
+                    "MXNET_TPU_DEVICE_PEAK_FLOPS override); never set "
+                    "on the CPU, which has no peak"),
             }
             _LAZY["mfu"] = f
         return f
@@ -345,7 +349,7 @@ def record_step_rate(steps, seconds, peak=None):
     achieved = mfps * steps / seconds
     fams["rate"].set(achieved)
     pk = peak if peak else peak_flops()
-    if pk > 0:
+    if pk:
         fams["mfu"].set(achieved / pk)
 
 

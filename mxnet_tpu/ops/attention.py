@@ -294,6 +294,11 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q=1024, block_k=2048,
     B, H, T, D = q.shape
     Tk = k.shape[2]
     block_q = min(block_q, max(8, T))
+    if Tk > block_k and Tk % block_k:
+        # a ragged key tail adds a second [bq, bk] mask to the causal
+        # one; at block_k=2048 the v5e compiler refuses that kernel
+        # (17.98 MiB of 16 MiB scoped VMEM at T=2176), at 1024 it fits
+        block_k = min(block_k, 1024)
     block_k = min(block_k, max(8, Tk))
     # ragged shapes: pad to block multiples.  Padded q rows are sliced off
     # the output; padded key columns are masked inside the kernel (kv_len).
@@ -315,11 +320,8 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q=1024, block_k=2048,
         block_k=block_k, n_k=n_k, kv_len=Tk)
     kwargs = {}
     if not interpret:
-        params_cls = getattr(pltpu, "CompilerParams",
-                             getattr(pltpu, "TPUCompilerParams", None))
-        if params_cls is not None:
-            kwargs["compiler_params"] = params_cls(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"))
     out_shape = [jax.ShapeDtypeStruct((B * H, Tp, D), q.dtype)]
     out_specs = [pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))]
     if return_lse:
@@ -560,11 +562,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale,
 
     kwargs = {}
     if not interpret:
-        params_cls = getattr(pltpu, "CompilerParams",
-                             getattr(pltpu, "TPUCompilerParams", None))
-        if params_cls is not None:
-            kwargs["compiler_params"] = params_cls(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     dkdv_kernel = functools.partial(
         _flash_bwd_dkdv_kernel, sm_scale=sm_scale, causal=causal,
@@ -850,8 +849,26 @@ def _multi_head_attention(attrs, data, qkv_weight, out_weight,
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
         out = fn(q, k, v)
     else:
-        out = flash_attention(q, k, v, causal=attrs["causal"],
-                              interpret=attrs.get("interpret", False))
+        attn = functools.partial(flash_attention, causal=attrs["causal"],
+                                 interpret=attrs.get("interpret", False))
+        if mesh is not None and mesh.size > 1:
+            # GSPMD cannot partition a Mosaic kernel: each device runs
+            # the kernel on its own batch rows and heads
+            from jax import shard_map
+            from jax.sharding import PartitionSpec
+
+            def split(names, dim):
+                for cand in names:
+                    if cand in mesh.axis_names \
+                            and dim % mesh.shape[cand] == 0:
+                        return cand
+                return None
+
+            spec = PartitionSpec(split(("data", "batch"), B),
+                                 split(("model",), H), None, None)
+            attn = shard_map(attn, mesh=mesh, in_specs=(spec,) * 3,
+                             out_specs=spec, check_vma=False)
+        out = attn(q, k, v)
     out = out.transpose(0, 2, 1, 3).reshape(B, T, C)
     out = jnp.einsum("btc,fc->btf", out, out_weight)
     if out_bias is not None:

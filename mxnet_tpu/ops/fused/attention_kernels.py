@@ -19,9 +19,11 @@ Two generation-lane hot paths from ISSUE 19:
   PR-14 decode-parity contract survives kernel replacement.
 
 Both run under ``interpret=True`` off-TPU, which is how the parity
-harness pins them on CPU.  ``backends=("tpu",)`` keeps CPU *dispatch*
-on stock by default (CPU interpret is an emulation, not a win);
-``MXNET_TPU_OPS_FUSED_OVERRIDE`` forces them anywhere.
+harness pins them on CPU.  The prefill variant is eligible on ``"tpu"``
+only (CPU interpret is an emulation, not a win); the paged-decode
+kernel is eligible nowhere — the chip's compiler refuses it, see its
+registration below.  ``MXNET_TPU_OPS_FUSED_OVERRIDE`` forces either
+anywhere.
 """
 
 from __future__ import annotations
@@ -35,12 +37,9 @@ from jax import lax
 from .. import attention as _att
 from ..registry import register_variant
 from .parity import register_parity
+from .rowgrid import interpret as _interpret
 
 __all__ = ["fused_prefill_attention", "fused_paged_decode_attention"]
-
-
-def _interpret():
-    return jax.default_backend() != "tpu"
 
 
 # ----------------------------------------------------------------------
@@ -121,8 +120,7 @@ def fused_paged_decode_attention(q, k_step, v_step, k_pages, v_pages,
     paged_decode_attention` — same signature, bitwise-equal output.
 
     The gather scratch holds ``[B, max_blocks * blk, H, D]`` per side,
-    which bounds batch x context by VMEM; the serving shapes the
-    generation lane dispatches today fit with room to spare.
+    which bounds batch x context by VMEM.
     """
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -169,8 +167,14 @@ def fused_paged_decode_attention(q, k_step, v_step, k_pages, v_pages,
       v_pages)
 
 
+# Eligible on no backend: the v5e compiler refuses this kernel at every
+# shape (``tpu.matmul: Up to 1 batch dim supported`` for the batched
+# score, and the ``[B, max_blocks * blk, H, D]`` x2 gather scratch cannot
+# fit VMEM at LM width).  It stays registered so the interpret-mode
+# parity grid keeps pinning the block-table gather until the decode
+# kernel is redesigned (ROADMAP S1/D3); an override still forces it.
 register_variant("paged_decode_attention", "fused",
-                 fused_paged_decode_attention, backends=("tpu",),
+                 fused_paged_decode_attention, backends=(),
                  parity="bitwise")
 
 

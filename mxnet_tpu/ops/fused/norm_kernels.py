@@ -14,9 +14,10 @@ would otherwise schedule as separate HLOs:
 
 All three replay stock's op sequence exactly, so they are ``bitwise``
 class; the parity harness holds them to byte equality on the CPU
-interpret path.  Whole-array single-program kernels: the epilogue
-tensors the LM dispatches fit VMEM; a blocked row grid is the TPU-scale
-follow-up and changes nothing about the contract.
+interpret path.  Each runs over a row-block grid (:mod:`.rowgrid`): the
+rows are independent, so tiling changes no bit, and a block is sized to
+the chip's scoped VMEM rather than to the array
+(``tests/test_chip_compile.py`` compiles them at the LM's widths).
 """
 
 from __future__ import annotations
@@ -30,15 +31,12 @@ from jax import nn as jnn
 
 from ..registry import register_variant
 from .parity import register_parity
+from .rowgrid import row_call
 
 __all__ = ["fused_layer_norm_op", "fused_lm_layer_norm",
            "fused_lm_gelu_bias"]
 
 _LN_EPS = 1e-5   # transformer.py's _LN_EPS; asserted equal in parity
-
-
-def _interpret():
-    return jax.default_backend() != "tpu"
 
 
 def _ln_op_kernel(x_ref, g_ref, b_ref, o_ref, *, eps):
@@ -51,22 +49,31 @@ def _ln_op_kernel(x_ref, g_ref, b_ref, o_ref, *, eps):
                   + b_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
-def fused_layer_norm_op(attrs, data, gamma, beta):
-    """Op-convention variant of the ``LayerNorm`` registry op."""
-    import jax.experimental.pallas as pl
+def fused_layer_norm_op(attrs, data, gamma, beta, block_rows=None):
+    """Op-convention variant of the ``LayerNorm`` registry op.
+
+    Differentiable: the training graph reaches this op under
+    ``jax.grad`` and ``pallas_call`` has no transpose rule, so the
+    backward is stock's own VJP on the saved inputs."""
+    from .. import attention as _att
 
     axis = attrs["axis"]
-    if axis not in (-1, data.ndim - 1):
-        # non-minor axis: the registry op's generality, stock's job
-        from .. import attention as _att
-
+    if axis not in (-1, data.ndim - 1) or data.dtype == jnp.float16:
+        # a non-minor axis is the registry op's generality, and the
+        # chip has no fp16 vector loads (Mosaic: "Invalid vector type
+        # for load"): both are stock's job
         return _att._layer_norm(attrs, data, gamma, beta)
     kernel = functools.partial(_ln_op_kernel, eps=attrs["eps"])
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(data.shape, data.dtype),
-        interpret=_interpret(),
-    )(data, gamma, beta)
+    stock = functools.partial(_att._layer_norm, attrs)
+
+    def primal(data, gamma, beta):
+        return row_call(kernel, [data.dtype], [data], [gamma, beta],
+                        block_rows=block_rows)[0]
+
+    ln = jax.custom_vjp(primal)
+    ln.defvjp(lambda *args: (primal(*args), args),
+              lambda args, g: jax.vjp(stock, *args)[1](g))
+    return ln(data, gamma, beta)
 
 
 register_variant("LayerNorm", "fused", fused_layer_norm_op,
@@ -82,16 +89,11 @@ def _lm_ln_kernel(x_ref, g_ref, b_ref, o_ref, *, eps):
     o_ref[...] = y * g_ref[...] + b_ref[...]
 
 
-def fused_lm_layer_norm(x, gamma, beta):
+def fused_lm_layer_norm(x, gamma, beta, block_rows=None):
     """Plain-convention twin of ``transformer._lm_ln`` (fp32 LM path)."""
-    import jax.experimental.pallas as pl
-
     kernel = functools.partial(_lm_ln_kernel, eps=_LN_EPS)
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=_interpret(),
-    )(x, gamma, beta)
+    return row_call(kernel, [x.dtype], [x], [gamma, beta],
+                    block_rows=block_rows)[0]
 
 
 register_variant("lm_layer_norm", "fused", fused_lm_layer_norm,
@@ -102,15 +104,10 @@ def _gelu_bias_kernel(h_ref, b_ref, o_ref):
     o_ref[...] = jnn.gelu(h_ref[...] + b_ref[...])
 
 
-def fused_lm_gelu_bias(h, bias):
+def fused_lm_gelu_bias(h, bias, block_rows=None):
     """FFN epilogue ``gelu(h + bias)`` in one pass (``_lm_ffn``)."""
-    import jax.experimental.pallas as pl
-
-    return pl.pallas_call(
-        _gelu_bias_kernel,
-        out_shape=jax.ShapeDtypeStruct(h.shape, h.dtype),
-        interpret=_interpret(),
-    )(h, bias)
+    return row_call(_gelu_bias_kernel, [h.dtype], [h], [bias],
+                    whole_rows=False, block_rows=block_rows)[0]
 
 
 register_variant("lm_gelu_bias", "fused", fused_lm_gelu_bias,
@@ -133,7 +130,7 @@ def _ln_op_case(case):
 
     from .. import attention as _att
 
-    dtype, shape = case
+    dtype, shape, block_rows = case
     rng = np.random.default_rng(_seed(case))
     c = shape[-1]
     data = jnp.asarray(rng.standard_normal(shape), jnp.float32) \
@@ -142,17 +139,21 @@ def _ln_op_case(case):
     beta = jnp.asarray(rng.standard_normal((c,)), jnp.float32)
     attrs = {"axis": -1, "eps": 1e-5}
     stock = functools.partial(_att._layer_norm, attrs)
-    fused = functools.partial(fused_layer_norm_op, attrs)
+    fused = functools.partial(fused_layer_norm_op, attrs,
+                              block_rows=block_rows)
     return stock, fused, (data, gamma, beta)
 
 
 register_parity(
     "LayerNorm", "fused", _ln_op_case,
     grid=(
-        ("float32", (4, 7, 33)),         # ragged minor dim
-        ("float32", (2, 128)),
-        ("bfloat16", (3, 5, 64)),
-        ("float16", (2, 9, 17)),
+        # (dtype, shape, block_rows): None = one block, as derived
+        ("float32", (4, 7, 33), None),   # ragged minor dim
+        ("float32", (2, 128), None),
+        ("bfloat16", (3, 5, 64), None),
+        ("float16", (2, 9, 17), None),
+        ("float32", (4, 7, 33), 8),      # 28 rows: 3 blocks + ragged 4
+        ("bfloat16", (5, 16, 64), 16),   # 80 rows: 5 whole blocks
     ))
 
 
@@ -164,18 +165,20 @@ def _lm_ln_case(case):
 
         return _t._lm_ln_stock(x, gamma, beta)
 
-    shape = case
+    shape, block_rows = case
     rng = np.random.default_rng(_seed(case))
     c = shape[-1]
     x = jnp.asarray(rng.standard_normal(shape), jnp.float32)
     gamma = jnp.asarray(rng.standard_normal((c,)), jnp.float32)
     beta = jnp.asarray(rng.standard_normal((c,)), jnp.float32)
-    return stock, fused_lm_layer_norm, (x, gamma, beta)
+    fused = functools.partial(fused_lm_layer_norm, block_rows=block_rows)
+    return stock, fused, (x, gamma, beta)
 
 
 register_parity(
     "lm_layer_norm", "fused", _lm_ln_case,
-    grid=((2, 16, 32), (1, 1, 32), (3, 21, 33)))
+    grid=(((2, 16, 32), None), ((1, 1, 32), None), ((3, 21, 33), None),
+          ((3, 21, 33), 8)))             # 63 rows: 7 blocks + ragged 7
 
 
 def _gelu_case(case):
@@ -186,14 +189,16 @@ def _gelu_case(case):
 
         return _t._lm_gelu_bias_stock(h, bias)
 
-    shape = case
+    shape, block_rows = case
     rng = np.random.default_rng(_seed(case))
     f = shape[-1]
     h = jnp.asarray(rng.standard_normal(shape), jnp.float32)
     bias = jnp.asarray(rng.standard_normal((f,)), jnp.float32)
-    return stock, fused_lm_gelu_bias, (h, bias)
+    fused = functools.partial(fused_lm_gelu_bias, block_rows=block_rows)
+    return stock, fused, (h, bias)
 
 
 register_parity(
     "lm_gelu_bias", "fused", _gelu_case,
-    grid=((2, 16, 128), (1, 1, 64), (3, 17, 65)))
+    grid=(((2, 16, 128), None), ((1, 1, 64), None), ((3, 17, 65), None),
+          ((3, 17, 65), 8)))             # 51 rows: 6 blocks + ragged 3

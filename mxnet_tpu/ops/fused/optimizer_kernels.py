@@ -2,7 +2,8 @@
 
 ``sgd_mom_update``/``fused`` folds the whole momentum update — grad
 rescale, clip, weight decay, momentum, parameter add — into one Pallas
-pass: two reads, two writes per element, no intermediate HLO buffers.
+pass over a row-block grid (:mod:`.rowgrid`): three reads, two writes
+per element, no intermediate HLO buffers.
 Op convention (dispatched through ``Op.apply``), ``bitwise`` class: the
 kernel replays ``ops/tensor.py``'s ``_prep_grad`` + ``_sgd_mom_update``
 spelling op for op.
@@ -17,17 +18,13 @@ from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
 
 from ..registry import register_variant
 from .parity import register_parity
+from .rowgrid import row_call
 
 __all__ = ["fused_sgd_mom_update"]
-
-
-def _interpret():
-    return jax.default_backend() != "tpu"
 
 
 def _sgd_mom_kernel(w_ref, g_ref, m_ref, ow_ref, om_ref, *,
@@ -41,20 +38,14 @@ def _sgd_mom_kernel(w_ref, g_ref, m_ref, ow_ref, om_ref, *,
     om_ref[...] = new_mom
 
 
-def fused_sgd_mom_update(attrs, w, g, mom):
+def fused_sgd_mom_update(attrs, w, g, mom, block_rows=None):
     """Op-convention variant of ``sgd_mom_update`` → (weight, mom)."""
-    import jax.experimental.pallas as pl
-
     kernel = functools.partial(
         _sgd_mom_kernel, lr=attrs["lr"], wd=attrs["wd"],
         momentum=attrs["momentum"], rescale=attrs["rescale_grad"],
         clip=attrs.get("clip_gradient"))
-    return pl.pallas_call(
-        kernel,
-        out_shape=(jax.ShapeDtypeStruct(w.shape, w.dtype),
-                   jax.ShapeDtypeStruct(mom.shape, mom.dtype)),
-        interpret=_interpret(),
-    )(w, g, mom)
+    return tuple(row_call(kernel, [w.dtype, mom.dtype], [w, g, mom],
+                          whole_rows=False, block_rows=block_rows))
 
 
 register_variant("sgd_mom_update", "fused", fused_sgd_mom_update,
@@ -91,7 +82,7 @@ def _sgd_mom_case(case):
 
     from .. import tensor as _tensor
 
-    shape, lr, wd, momentum, rescale, clip = case
+    shape, lr, wd, momentum, rescale, clip, block_rows = case
     rng = np.random.default_rng(_seed(case))
     w = jnp.asarray(rng.standard_normal(shape), jnp.float32)
     g = jnp.asarray(rng.standard_normal(shape), jnp.float32)
@@ -99,17 +90,20 @@ def _sgd_mom_case(case):
     attrs = {"lr": lr, "wd": wd, "momentum": momentum,
              "rescale_grad": rescale, "clip_gradient": clip}
     stock = functools.partial(_tensor._sgd_mom_update, attrs)
-    fused = functools.partial(fused_sgd_mom_update, attrs)
+    fused = functools.partial(fused_sgd_mom_update, attrs,
+                              block_rows=block_rows)
     return stock, fused, (w, g, mom)
 
 
 register_parity(
     "sgd_mom_update", "fused", _sgd_mom_case,
     grid=(
-        ((1031,), 0.1, 0.0, 0.9, 1.0, -1.0),     # ragged 1-D, no clip
-        ((17, 33), 0.01, 1e-4, 0.9, 1.0, -1.0),  # ragged 2-D, wd on
-        ((64, 8), 0.05, 1e-4, 0.99, 0.5, 0.25),  # rescale + clip
-        ((3, 5, 7), 0.1, 0.0, 0.0, 1.0, 1.0),    # momentum 0, clip on
+        # last field is block_rows: None = one block, as derived
+        ((1031,), 0.1, 0.0, 0.9, 1.0, -1.0, None),     # ragged 1-D
+        ((17, 33), 0.01, 1e-4, 0.9, 1.0, -1.0, None),  # ragged 2-D, wd
+        ((64, 8), 0.05, 1e-4, 0.99, 0.5, 0.25, None),  # rescale + clip
+        ((3, 5, 7), 0.1, 0.0, 0.0, 1.0, 1.0, None),    # mom 0, clip on
+        ((17, 33), 0.01, 1e-4, 0.9, 1.0, -1.0, 8),     # 2 blocks + 1 row
     ))
 
 

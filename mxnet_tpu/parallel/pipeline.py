@@ -15,21 +15,11 @@ grads flow stage-to-stage without hand-written scheduling.
 from __future__ import annotations
 
 import functools
+import inspect as _inspect
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
-
-import inspect as _inspect
-
-# replication checking kw was renamed check_rep -> check_vma in jax 0.8
-_CHECK_KW = ("check_vma" if "check_vma"
-             in _inspect.signature(shard_map).parameters else "check_rep")
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..base import MXNetError
@@ -99,7 +89,7 @@ def pipeline_apply(stage_fn, stacked_params, x, *, mesh: Mesh,
     # other mesh axes (e.g. data) stay unmapped: this helper owns only pipe
     return shard_map(
         per_device, mesh=mesh, in_specs=in_specs, out_specs=P(),
-        **{_CHECK_KW: False})(stacked_params, x)
+        check_vma=False)(stacked_params, x)
 
 
 def _takes_stage_idx(stage_fn):
@@ -266,7 +256,7 @@ def pipeline_train_1f1b(stage_fn, loss_fn, stacked_params, x, target, *,
     dspec = P(batch_axis) if batch_axis is not None else P()
     return shard_map(
         per_device, mesh=mesh, in_specs=(pspec, dspec, dspec),
-        out_specs=(P(), pspec), **{_CHECK_KW: False})(
+        out_specs=(P(), pspec), check_vma=False)(
             stacked_params, x, target)
 
 

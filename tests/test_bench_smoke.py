@@ -13,7 +13,7 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _run_bench(extra_env):
-    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_INNER="1",
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                BENCH_STEPS="2", BENCH_BATCH="2", **extra_env)
     out = subprocess.run([sys.executable, os.path.join(_REPO, "bench.py")],
                          env=env, capture_output=True, text=True,
@@ -39,14 +39,30 @@ def test_bench_json_contract(pipeline):
     # drops no spans, but the keys must always be present
     assert rec["chaos_fired_total"] == 0
     assert rec["spans_dropped_total"] == 0
-    # additive provenance keys: schema revision + the commit measured
+    # additive provenance keys: schema revision + the commit measured,
+    # and the device the row was measured on
     assert rec["schema_version"] >= 3
     assert isinstance(rec["git_sha"], str) and rec["git_sha"]
+    assert rec["platform"] == "cpu" and rec["device_kind"]
+    assert rec["device_count"] >= 1
     # pipeline_steps only appears when the pipelined path actually ran
     if pipeline > 1:
         assert rec["pipeline_steps"] == pipeline
     else:
         assert "pipeline_steps" not in rec
+
+
+def test_bench_failure_is_a_failure():
+    """No guard: a run that fails exits non-zero and prints no row —
+    neither an error row under exit 0 nor a stored number."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_STEPS="1",
+               BENCH_BATCH="2", BENCH_LAYOUT="no-such-layout")
+    out = subprocess.run([sys.executable, os.path.join(_REPO, "bench.py")],
+                         env=env, capture_output=True, text=True,
+                         timeout=240, cwd=_REPO)
+    assert out.returncode != 0
+    assert out.stdout.strip() == ""
+    assert "no-such-layout" in out.stderr
 
 
 def test_bench_serving_keys():

@@ -1,0 +1,259 @@
+"""AOT compiles for a described TPU v5e — what interpret mode cannot see.
+
+The chip's compiler is installed here and compiles for a chip that is
+described, not attached (``/opt/skills/guides/on-chip-measurement`` §2.3).
+Every Pallas kernel on ``chip_smoke.py``'s path is compiled at the
+smoke's widths, and every fused variant that is eligible on ``"tpu"`` at
+its widest parity-grid shape: scoped-VMEM overflows, unsupported vector
+types and Mosaic kernels that GSPMD cannot partition are refused HERE,
+at no chip time, while they pass every interpret-mode test.  Nothing
+runs, so these say nothing about results or speed; a compile that passes
+is not a chip run.
+
+Code that asks ``jax.default_backend()`` sees the CPU under such a
+compile, so the tests steer it (monkeypatch) — the program has no option
+for that.  The persistent compilation cache is off around them: an entry
+written by such a compile cannot be read back without a chip.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+import mxnet_tpu  # noqa: F401  (registers ops and variants)
+from mxnet_tpu.ops import attention as att
+from mxnet_tpu.ops import registry
+from mxnet_tpu.ops.fused import attention_kernels, norm_kernels
+from mxnet_tpu.ops.fused import optimizer_kernels, parity
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip("cannot describe a v5e topology here: %s" % exc)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Make platform-sniffing code take its TPU branch: real kernels,
+    not interpret mode, and the variants eligible on ``"tpu"``."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    registry.reset_fused_dispatch()
+    yield
+    registry.reset_fused_dispatch()
+
+
+def _compile(fn, args, sharding):
+    """Compile ``fn`` for the described chip from shapes alone."""
+    def struct(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    return jax.jit(fn).lower(*jax.tree_util.tree_map(struct, args)).compile()
+
+
+def _s(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# ----------------------------------------------------------------------
+# flash attention, forward and backward
+
+
+def _flash_fwd(q, k, v):
+    return att._flash_fwd_pallas(q, k, v, True, 0.125, return_lse=True)
+
+
+def _flash_bwd(q, k, v, o, lse, do):
+    return att._flash_bwd_pallas(q, k, v, o, lse, do, True, 0.125)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("shape", [
+    (8, 16, 2048, 64),      # the LM train step's attention
+    (1, 16, 32768, 64),     # the long-context configuration
+    (4, 8, 2176, 64),       # ragged T: refused at block_k=2048 before PR 21
+], ids=["T2048", "T32768", "T2176-ragged"])
+def test_flash_kernels_compile(topo, on_tpu, shape, direction):
+    one = SingleDeviceSharding(topo.devices[0])
+    x, lse = _s(shape, BF16), _s(shape[:3], F32)
+    if direction == "fwd":
+        _compile(_flash_fwd, (x, x, x), one)
+    else:
+        _compile(_flash_bwd, (x, x, x, x, lse, x), one)
+
+
+# ----------------------------------------------------------------------
+# the fused variants at the smoke's widths
+
+_LN_ATTRS = {"axis": -1, "eps": 1e-5}
+_SGD_ATTRS = {"lr": 0.1, "wd": 1e-4, "momentum": 0.9, "rescale_grad": 1.0,
+              "clip_gradient": -1.0}
+
+
+def _ln_fwd_bwd(x, g, b):
+    # the train step differentiates this op: compile both directions
+    def loss(x, g, b):
+        out = norm_kernels.fused_layer_norm_op(_LN_ATTRS, x, g, b)
+        return out.astype(F32).sum(), out
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(x, g, b)
+
+
+_AT_SMOKE_WIDTH = {
+    # train_lm: [B, T, C] bf16 activations, fp32 scale and shift
+    "LayerNorm-8x2048x1024-bf16": (
+        _ln_fwd_bwd,
+        (_s((8, 2048, 1024), BF16), _s((1024,), F32), _s((1024,), F32))),
+    # serve_lm prefill (one prompt of 1536) and decode (bucket 4)
+    "lm_layer_norm-prefill": (
+        norm_kernels.fused_lm_layer_norm,
+        (_s((1, 1536, 1024), F32), _s((1024,), F32), _s((1024,), F32))),
+    "lm_layer_norm-decode": (
+        norm_kernels.fused_lm_layer_norm,
+        (_s((4, 1, 1024), F32), _s((1024,), F32), _s((1024,), F32))),
+    "lm_gelu_bias-prefill": (
+        norm_kernels.fused_lm_gelu_bias,
+        (_s((1, 1536, 4096), F32), _s((4096,), F32))),
+    "lm_gelu_bias-decode": (
+        norm_kernels.fused_lm_gelu_bias,
+        (_s((4, 1, 4096), F32), _s((4096,), F32))),
+    "stable_causal_attention-prefill": (
+        attention_kernels.fused_prefill_attention,
+        (_s((1, 16, 1536, 64), F32),) * 3),
+    # the widest shapes refused before the row grid (ISSUE 21's table)
+    "lm_layer_norm-8x2048x1024": (
+        norm_kernels.fused_lm_layer_norm,
+        (_s((8, 2048, 1024), F32), _s((1024,), F32), _s((1024,), F32))),
+    "lm_gelu_bias-8x2048x4096": (
+        norm_kernels.fused_lm_gelu_bias,
+        (_s((8, 2048, 4096), F32), _s((4096,), F32))),
+    # the eager optimizer step of Module.fit, per parameter: the FFN
+    # weight, the vocab bias (1-D), a conv weight (3-wide minor dim)
+    "sgd_mom_update-1024x4096": (
+        lambda w, g, m: optimizer_kernels.fused_sgd_mom_update(
+            _SGD_ATTRS, w, g, m), (_s((1024, 4096), F32),) * 3),
+    "sgd_mom_update-32000": (
+        lambda w, g, m: optimizer_kernels.fused_sgd_mom_update(
+            _SGD_ATTRS, w, g, m), (_s((32000,), F32),) * 3),
+    "sgd_mom_update-512x512x3x3": (
+        lambda w, g, m: optimizer_kernels.fused_sgd_mom_update(
+            _SGD_ATTRS, w, g, m), (_s((512, 512, 3, 3), F32),) * 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_AT_SMOKE_WIDTH))
+def test_fused_variants_compile_at_smoke_width(topo, on_tpu, case):
+    fn, args = _AT_SMOKE_WIDTH[case]
+    compiled = _compile(fn, args, SingleDeviceSharding(topo.devices[0]))
+    assert "tpu_custom_call" in compiled.as_text(), \
+        "%s compiled without its kernel" % case
+
+
+# ----------------------------------------------------------------------
+# the rule: nothing is eligible on "tpu" that this compile refuses
+
+
+def _tpu_variants():
+    return sorted(
+        (op, name) for op, variants in registry.FUSED_VARIANTS.items()
+        for name, var in variants.items() if "tpu" in var.backends)
+
+
+def _widest(reg):
+    """The parity-grid case with the most elements in its arguments."""
+    def size(case):
+        return sum(int(np.prod(x.shape)) for x in
+                   jax.tree_util.tree_leaves(reg.builder(case)[2]))
+
+    return max(reg.grid, key=size)
+
+
+@pytest.mark.parametrize("op,variant", _tpu_variants(),
+                         ids=["%s:%s" % k for k in _tpu_variants()])
+def test_every_tpu_variant_compiles_at_widest_parity_shape(
+        topo, on_tpu, op, variant):
+    reg = parity._PARITY[(op, variant)]
+    _, fused, args = reg.builder(_widest(reg))[:3]
+    _compile(fused, args, SingleDeviceSharding(topo.devices[0]))
+
+
+def test_paged_decode_variant_is_withdrawn_from_tpu():
+    """The compiler refuses this kernel at every shape (``tpu.matmul``:
+    one batch dim at most), so it is eligible nowhere; an override still
+    reaches it and the interpret-mode parity grid still pins it."""
+    var = registry.FUSED_VARIANTS["paged_decode_attention"]["fused"]
+    assert var.backends == ()
+    assert ("paged_decode_attention", "fused") in parity._PARITY
+
+
+# ----------------------------------------------------------------------
+# four chips: a Mosaic kernel under a mesh must sit in shard_map
+
+
+def _lower_step(trainer):
+    """Lower a ShardedTrainer's fused step from shapes alone (no array
+    can be placed on a described device)."""
+    from mxnet_tpu.parallel import default_mesh
+
+    trainer.step_fn()
+    pshard, _, ashard, dshard = trainer._step_shardings()
+    params = {n: jax.ShapeDtypeStruct(
+        tuple(trainer.arg_shapes[n]), trainer._param_dtype(n),
+        sharding=pshard[n]) for n in trainer.param_names}
+    aux = {n: jax.ShapeDtypeStruct(
+        tuple(s), trainer.aux_dtypes.get(n, "float32"), sharding=ashard[n])
+        for n, s in trainer.aux_shapes.items()}
+    batch = {n: jax.ShapeDtypeStruct(
+        tuple(trainer.arg_shapes[n]), trainer.arg_dtypes.get(n, "float32"),
+        sharding=dshard[n]) for n in trainer._input_names}
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                               sharding=NamedSharding(trainer.mesh, P()))
+    with default_mesh(trainer.mesh):
+        return trainer._jit_step_raw.lower(
+            params, trainer.opt_state_struct(), aux, batch, key)
+
+
+def test_sharded_lm_step_compiles(topo, on_tpu):
+    """``chip_smoke.py --chips 4`` in small: an LM step on a data=2 x
+    model=2 mesh of four described chips, long enough (T=1024) to take
+    the flash kernels.  GSPMD refuses to partition a Mosaic kernel
+    ("wrap the call in a shard_map"), which no CPU run can show."""
+    from mxnet_tpu.models import transformer
+    from mxnet_tpu.parallel.trainer import ShardedTrainer
+
+    batch, seq, vocab = 4, 1024, 512
+    sym = transformer.get_symbol(
+        num_classes=vocab, seq_len=seq, num_embed=128, num_heads=2,
+        num_layers=1, dtype="bfloat16")
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    trainer = ShardedTrainer(
+        sym, mesh, data_shapes={"data": (batch, seq)},
+        label_shapes={"softmax_label": (batch, seq)},
+        type_dict={"data": "int32"}, learning_rate=1e-3, momentum=0.9,
+        rescale_grad=1.0 / (batch * seq))
+    text = _lower_step(trainer).compile().as_text()
+    # flash forward + its two backward passes + three LayerNorms
+    assert text.count("tpu_custom_call") >= 6
+    assert not registry.fused_fallbacks()

@@ -15,8 +15,7 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # backend does not implement multiprocess computations, so under a forced
 # CPU platform each worker fails after its full launch-retry budget —
 # minutes of guaranteed failure per test.  Skip up front instead.
-_PLAT = (os.environ.get("MXNET_TPU_PLATFORM")
-         or os.environ.get("JAX_PLATFORMS") or "").strip().lower()
+_PLAT = os.environ.get("JAX_PLATFORMS", "").strip().lower()
 pytestmark = pytest.mark.skipif(
     _PLAT == "cpu",
     reason="cross-process collectives are not implemented on the XLA "

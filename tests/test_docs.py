@@ -95,7 +95,7 @@ def test_how_to_and_architecture_trees_complete():
         ("how_to", "model_parallel_lstm.md"): ["ctx_group", "ShardedTrainer"],
         ("how_to", "visualize_graph.md"): ["plot_network", "print_summary"],
         ("how_to", "faq.md"): ["BucketingModule", "bf16"],
-        ("how_to", "perf.md"): ["BENCH_TABLE", "PERF.md"],
+        ("how_to", "perf.md"): ["chip_smoke.py", "PERF.md"],
         ("how_to", "index.md"): ["new_op.md", "faq.md"],
         ("architecture", "index.md"): ["overview.md", "note_engine.md"],
         ("architecture", "overview.md"): ["Layer map", "C ABI"],

@@ -38,7 +38,7 @@ def _run_example(name, call, func="run", timeout=900):
         "print('STATS ' + json.dumps({k: float(v) for k, v in stats.items()}))\n"
         % (_REPO, os.path.join(_REPO, "examples", name), func, call)
     )
-    env = dict(os.environ, MXNET_TPU_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env, timeout=timeout, cwd=_REPO)
     assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-2000:])
@@ -235,8 +235,7 @@ def test_benchmark_sweep_driver():
 
     with tempfile.TemporaryDirectory() as d:
         out = os.path.join(d, "sweep.csv")
-        env = dict(os.environ, MXNET_TPU_PLATFORM="cpu",
-                   JAX_PLATFORMS="cpu")
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         r = subprocess.run(
             [sys.executable,
              os.path.join(_REPO, "examples", "image_classification",

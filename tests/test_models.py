@@ -179,7 +179,7 @@ def test_benchmark_score_device_loop_smoke():
          "--network", "alexnet", "--batch-size", "2", "--num-batches", "3",
          "--device-loop"],
         capture_output=True, text=True, timeout=600,
-        env=dict(os.environ, JAX_PLATFORMS="cpu", MXNET_TPU_PLATFORM="cpu"))
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert r.returncode == 0, r.stdout + r.stderr
     line = [l for l in r.stderr.splitlines() + r.stdout.splitlines()
             if "images/sec" in l]

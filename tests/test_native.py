@@ -380,7 +380,7 @@ def test_c_api_trains_lenet(tmp_path):
 
     binary = os.path.join(repo, "native", "build", "train_capi_test")
     prior = os.environ.get("PYTHONPATH")
-    env = dict(os.environ, JAX_PLATFORMS="cpu", MXNET_TPU_PLATFORM="cpu",
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=repo + ((os.pathsep + prior) if prior else ""))
     r = subprocess.run([binary, str(tmp_path / "img.idx"),
                         str(tmp_path / "lab.idx"), "3", "32"],
@@ -423,7 +423,7 @@ def test_cpp_frontend_trains_lenet(tmp_path):
 
     binary = os.path.join(repo, "native", "build", "train_lenet")
     prior = os.environ.get("PYTHONPATH")
-    env = dict(os.environ, JAX_PLATFORMS="cpu", MXNET_TPU_PLATFORM="cpu",
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=repo + ((os.pathsep + prior) if prior else ""))
     prefix = str(tmp_path / "cppmodel")
     r = subprocess.run([binary, str(tmp_path / "img.idx"),
@@ -477,7 +477,7 @@ def test_cpp_frontend_bucketing():
 
     binary = os.path.join(repo, "native", "build", "train_bucketing")
     prior = os.environ.get("PYTHONPATH")
-    env = dict(os.environ, JAX_PLATFORMS="cpu", MXNET_TPU_PLATFORM="cpu",
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=repo + ((os.pathsep + prior) if prior else ""))
     r = subprocess.run([binary, "6", "32"], capture_output=True,
                        text=True, env=env, timeout=900)
@@ -522,7 +522,7 @@ def test_perl_frontend_trains_lenet(tmp_path):
     build = tmp_path / "AI-MXNetTPU"
     shutil.copytree(pkg, build)
     env = dict(os.environ, MXTPU_NATIVE=os.path.join(repo, "native"),
-               JAX_PLATFORMS="cpu", MXNET_TPU_PLATFORM="cpu",
+               JAX_PLATFORMS="cpu",
                PYTHONPATH=repo + ((os.pathsep + os.environ["PYTHONPATH"])
                                   if os.environ.get("PYTHONPATH") else ""))
     r = subprocess.run([perl, "Makefile.PL"], cwd=build, env=env,
@@ -575,7 +575,7 @@ def test_c_api_imperative_autograd(tmp_path):
                         "PYTHON=%s" % _sys.executable],
                        capture_output=True, text=True)
     assert r.returncode == 0, r.stderr
-    env = dict(os.environ, JAX_PLATFORMS="cpu", MXNET_TPU_PLATFORM="cpu",
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=repo + ((os.pathsep + os.environ["PYTHONPATH"])
                                   if os.environ.get("PYTHONPATH") else ""))
     r = subprocess.run(
@@ -617,7 +617,7 @@ def test_generated_cpp_ops_compile_and_run():
                         "build/gen_ops_test", "PYTHON=%s" % _sys.executable],
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stderr
-    env = dict(os.environ, JAX_PLATFORMS="cpu", MXNET_TPU_PLATFORM="cpu",
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=repo + ((os.pathsep + os.environ["PYTHONPATH"])
                                   if os.environ.get("PYTHONPATH") else ""))
     r = subprocess.run(

@@ -71,7 +71,7 @@ def test_im2rec_roundtrip(tmp_path):
     prefix = str(tmp_path / "ds")
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     tool = os.path.join(repo, "tools", "im2rec.py")
-    env = dict(os.environ, JAX_PLATFORMS="cpu", MXNET_TPU_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     subprocess.run([sys.executable, tool, prefix, str(root), "--list",
                     "--recursive"], check=True, env=env)
     assert os.path.exists(prefix + ".lst")

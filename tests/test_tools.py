@@ -28,13 +28,11 @@ def test_parse_log(tmp_path):
 
 
 def test_bandwidth_smoke():
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["JAX_PLATFORMS"] = "cpu"
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
     r = subprocess.run(
         [sys.executable, os.path.join(_REPO, "tools", "bandwidth.py"),
-         "--size-mb", "4", "--repeat", "3", "--platform", "cpu"],
+         "--size-mb", "4", "--repeat", "3"],
         capture_output=True, text=True, timeout=240, env=env, cwd=_REPO)
     assert r.returncode == 0, r.stdout + r.stderr
     assert "h2d:" in r.stdout and "all-reduce" in r.stdout

@@ -48,19 +48,12 @@ def main():
     parser.add_argument("--dist", action="store_true",
                         help="measure cross-process allreduce (use with "
                              "tools/launch.py)")
-    parser.add_argument("--platform", type=str, default=None,
-                        help="force a jax platform (plugin envs ignore "
-                             "JAX_PLATFORMS; this uses jax.config)")
     parser.add_argument("--wire", action="store_true",
                         help="also run an in-process 2-shard kvstore "
                              "loop and print the wire-bandwidth books "
                              "(observability.wire.wire_report)")
     args = parser.parse_args()
 
-    if args.platform:
-        import jax
-
-        jax.config.update("jax_platforms", args.platform)
     import mxnet_tpu as mx  # noqa: F401  (bootstraps jax.distributed)
     import jax
     import jax.numpy as jnp
@@ -90,16 +83,14 @@ def main():
         mesh = Mesh(np.array(devs), ("x",))
         sharded = jax.device_put(host, NamedSharding(mesh, P("x")))
 
-        psum = (jax.jit(
+        psum = jax.jit(
             jax.shard_map(lambda x: jax.lax.psum(x, "x"), mesh=mesh,
                           in_specs=P("x"), out_specs=P("x")))
-            if hasattr(jax, "shard_map") else None)
-        if psum is not None:
-            t = _time(lambda: psum(sharded).block_until_ready(), args.repeat)
-            # ring all-reduce moves 2*(n-1)/n of the data per device
-            algo = 2 * (len(devs) - 1) / len(devs) * gb
-            print("all-reduce (%d dev): %8.2f ms   %6.2f GB/s algo-bw"
-                  % (len(devs), t * 1e3, algo / t))
+        t = _time(lambda: psum(sharded).block_until_ready(), args.repeat)
+        # ring all-reduce moves 2*(n-1)/n of the data per device
+        algo = 2 * (len(devs) - 1) / len(devs) * gb
+        print("all-reduce (%d dev): %8.2f ms   %6.2f GB/s algo-bw"
+              % (len(devs), t * 1e3, algo / t))
 
         ag = jax.jit(lambda x: x, out_shardings=NamedSharding(mesh, P()))
         t = _time(lambda: ag(sharded).block_until_ready(), args.repeat)

@@ -2,7 +2,8 @@
 stream fit -> mid-fit kill -> bitwise resume -> checkpoint -> gate ->
 hot-swap under live traffic -> seeded regression -> automatic rollback.
 
-Drives all three tentpole pieces on the CPU backend and asserts the
+Drives all three tentpole pieces on whatever device JAX finds (the
+``make`` target asks for ``JAX_PLATFORMS=cpu``) and asserts the
 acceptance contract:
 
 1. **Bitwise mid-epoch resume**: a ``StreamDataIter`` fit killed in the
@@ -36,7 +37,6 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("MXNET_TPU_METRICS", "1")
 
 B, D, C = 8, 6, 8

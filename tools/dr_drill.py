@@ -3,8 +3,8 @@ mid-fit, cold-restart at a different PS shard count from the latest
 durable snapshot, and continue training bitwise-equal to a run that was
 never interrupted.
 
-The drill drives the PR-18 durability subsystem end to end on the CPU
-backend:
+The drill drives the PR-18 durability subsystem end to end, on whatever
+device JAX finds (the ``make`` target asks for ``JAX_PLATFORMS=cpu``):
 
 1. a reference ``ShardedTrainer.fit(kvstore=)`` run on a 2-shard PS
    trains 2 epochs uninterrupted and records the final parameters;
@@ -38,7 +38,6 @@ import tempfile
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("MXNET_TPU_METRICS", "1")
 # tmpfs-friendly: the drill measures protocol correctness, not disk
 os.environ.setdefault("MXNET_TPU_SNAPSHOT_FSYNC", "0")

@@ -3,7 +3,8 @@ compute-efficiency books — per-cache HLO cost analysis (FLOPs, bytes,
 arithmetic intensity, memory footprint), the model-FLOPs/MFU summary,
 and the goodput ledger.
 
-Drives the efficiency accounting plane end to end on the CPU backend: a
+Drives the efficiency accounting plane end to end on whatever device
+JAX finds (the ``make`` target asks for ``JAX_PLATFORMS=cpu``): a
 pipelined ``ShardedTrainer.fit`` records compile cost analysis for
 every jit cache (``trainer_compile_flops{cache}``), derives
 ``trainer_step_model_flops`` / ``model_flops_utilization`` from the
@@ -23,7 +24,6 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("MXNET_TPU_METRICS", "1")
 
 

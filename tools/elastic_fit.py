@@ -2,7 +2,8 @@
 the watchdog→autoscaler loop, with parity checked against a run that
 never resized.
 
-Drives the elastic-scale plane end to end on the CPU backend:
+Drives the elastic-scale plane end to end, on whatever device JAX
+finds (the ``make`` target asks for ``JAX_PLATFORMS=cpu``):
 
 1. a reference ``ShardedTrainer.fit(kvstore=)`` run against a *fixed*
    2-shard server group records the final parameters;
@@ -32,7 +33,6 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("MXNET_TPU_METRICS", "1")
 
 B, D = 8, 6

@@ -1,5 +1,8 @@
 """``make generate`` / ``python tools/generate_demo.py``: the
-autoregressive generation lane, end to end on CPU in a few seconds.
+autoregressive generation lane, end to end; a few seconds with
+``JAX_PLATFORMS=cpu``, which the ``make`` target asks for (the bitwise
+check below is a CPU contract; on a chip ``chip_smoke.py`` holds the
+same lane to a tolerance).
 
 Builds a tiny randomly-initialized transformer LM, registers it on a
 :class:`~mxnet_tpu.serving.GenerationScheduler` (paged KV cache,
@@ -13,7 +16,7 @@ verifies the contracts the round-14 issue names:
 - steady-state generation compiled nothing after warmup;
 - concurrent prompts share decode steps (iteration-level batching).
 
-Exits non-zero on any miss.  No checkpoint, no accelerator.
+Exits non-zero on any miss.  No checkpoint.
 """
 
 import json
@@ -25,7 +28,6 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("MXNET_TPU_METRICS", "1")
 
 import numpy as np  # noqa: E402

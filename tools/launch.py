@@ -89,7 +89,6 @@ def launch_servers(args, coordinator=None):
         env = dict(os.environ)
         # servers are host-side: never let one grab (or hang on) a chip
         env["JAX_PLATFORMS"] = "cpu"
-        env["MXNET_TPU_PLATFORM"] = "cpu"
         env["MXNET_TPU_SERVER_PORT"] = "0"
         env["MXNET_TPU_SERVER_ADDR_FILE"] = addr_file
         env["MXNET_TPU_SERVER_ID"] = str(shard)
@@ -174,6 +173,17 @@ def launch_servers(args, coordinator=None):
 
 
 def launch_local(args, cmd):
+    if args.platform != "cpu" and args.num_workers > 1:
+        # a chip belongs to one process: N workers that all ask for the
+        # host's accelerator would fight over the same chips (the second
+        # fails or hangs), and this launcher does not confine a worker
+        # to a chip of its own
+        raise SystemExit(
+            "launch.py: --platform %s with -n %d on one host: a chip "
+            "belongs to one process, and local workers are not confined "
+            "to a chip each.  Run ONE worker (one process drives every "
+            "chip of its host through the mesh), or --launcher ssh with "
+            "one worker per host." % (args.platform, args.num_workers))
     coordinator = "127.0.0.1:%d" % _free_port()
     server_procs, server_env = ([], {})
     if args.num_servers > 0:
@@ -185,10 +195,8 @@ def launch_local(args, cmd):
         env["MXNET_TPU_NUM_PROCS"] = str(args.num_workers)
         env["MXNET_TPU_PROC_ID"] = str(i)
         # each local worker gets its own CPU "chip" (the one-host simulated
-        # cluster of tests/nightly); --platform overrides, e.g. for a real
-        # one-process-per-host TPU launch
+        # cluster of tests/nightly)
         env["JAX_PLATFORMS"] = args.platform
-        env["MXNET_TPU_PLATFORM"] = args.platform  # wins over site-hook presets
         env.setdefault("MXNET_TPU_TRACE_TRACK", "worker%d" % i)
         env.update(server_env)
         metrics_base = getattr(args, "metrics_port_base", 0) or 0
@@ -275,7 +283,7 @@ def launch_ssh(args, cmd):
                 slot = i * replicas + j
                 host = hosts[slot % len(hosts)]
                 port = args.server_port_base + slot
-                env = ("MXNET_TPU_PLATFORM=cpu JAX_PLATFORMS=cpu "
+                env = ("JAX_PLATFORMS=cpu "
                        "MXNET_TPU_SERVER_PORT=%d MXNET_TPU_SERVER_ID=%d "
                        "MXNET_TPU_NUM_SERVERS=%d MXNET_TPU_PS_HOST=%s "
                        "MXNET_TPU_TRACE_TRACK=server%d:%s"
@@ -302,7 +310,7 @@ def launch_ssh(args, cmd):
             slot = args.num_servers * replicas + k
             host = hosts[slot % len(hosts)]
             port = args.server_port_base + slot
-            env = ("MXNET_TPU_PLATFORM=cpu JAX_PLATFORMS=cpu "
+            env = ("JAX_PLATFORMS=cpu "
                    "MXNET_TPU_SERVER_PORT=%d MXNET_TPU_SERVER_ID=%d "
                    "MXNET_TPU_NUM_SERVERS=%d MXNET_TPU_PS_HOST=%s "
                    "MXNET_TPU_TRACE_TRACK=server%d:spare"
@@ -380,7 +388,9 @@ def main():
     parser.add_argument("-H", "--hostfile", type=str, default=None)
     parser.add_argument("--port", type=int, default=None)
     parser.add_argument("--platform", type=str, default="cpu",
-                        help="JAX platform for local workers")
+                        help="JAX platform for local workers; anything "
+                             "but cpu is refused with more than one "
+                             "worker (a chip belongs to one process)")
     parser.add_argument("--tag-output", action="store_true",
                         help="prefix every relayed line with [worker-N] "
                              "(mpirun-style) for per-rank attribution")

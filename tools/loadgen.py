@@ -3,7 +3,8 @@ robustness drill.
 
 A self-contained synthetic load generator proving the PR-16 fairness
 contract the repo's way — drive the real stack, assert on the real
-metrics, exit non-zero on any miss.  Four acts, a few seconds on CPU:
+metrics, exit non-zero on any miss.  Four acts, a few seconds with
+``JAX_PLATFORMS=cpu`` (what the ``make`` target asks for):
 
 1. **Fairness under heavy-tailed skew.**  Three tenants hammer one
    numpy-backed replica group — ``bulk`` sends ~8× the load of
@@ -40,7 +41,6 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("MXNET_TPU_METRICS", "1")
 
 import numpy as np                                    # noqa: E402

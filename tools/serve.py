@@ -19,7 +19,9 @@ v1 HTTP front-end (``mxnet_tpu/serving/``):
 tiny in-memory MLP, serves it on a 2-replica group, drives the HTTP
 API end to end — predict, models listing, readiness — kills one
 replica mid-run to prove the failover path, and exits non-zero on any
-miss.  No checkpoint, no accelerator, a few seconds on CPU.
+miss.  No checkpoint; a few seconds with ``JAX_PLATFORMS=cpu``, which
+the ``make`` target asks for.  Without it the server runs on whatever
+device JAX finds.
 """
 
 import argparse
@@ -31,7 +33,6 @@ import urllib.request
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("MXNET_TPU_METRICS", "1")
 
 
@@ -220,6 +221,9 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="self-contained end-to-end smoke, then exit")
     args = ap.parse_args(argv)
+    from mxnet_tpu import compile_cache
+
+    compile_cache.enable()
     if args.smoke:
         return smoke()
     return serve(args)

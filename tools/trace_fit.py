@@ -1,7 +1,8 @@
 """``make trace``: run a short pipelined fit with tracing on and
 validate the emitted chrome://tracing JSON.
 
-Drives the full observability path end to end on the CPU backend: a
+Drives the full observability path end to end on whatever device JAX
+finds (the ``make`` target asks for ``JAX_PLATFORMS=cpu``): a
 5-step ``ShardedTrainer.fit`` (pipeline_steps=2, so the prefetch feeder
 and engine IO lane are load-bearing) under ``profiler_set_state('run')``,
 then ``dump_profile()`` and a JSON re-load of the merged trace.  Exits
@@ -18,8 +19,6 @@ import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def main():

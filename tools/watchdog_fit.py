@@ -1,8 +1,9 @@
 """``make watchdog``: run a short instrumented fit, print the step-time
 attribution table, and evaluate the default SLO watchdog rules.
 
-Drives the performance-observability plane end to end on the CPU
-backend: a pipelined ``ShardedTrainer.fit`` fills the attribution
+Drives the performance-observability plane end to end on whatever
+device JAX finds (the ``make`` target asks for ``JAX_PLATFORMS=cpu``):
+a pipelined ``ShardedTrainer.fit`` fills the attribution
 histograms (``trainer_step_phase_seconds``) and compile-accounting
 counters, then the attribution books are checked against the wall-clock
 step histogram — phases + the ``unattributed`` residual must reconcile
@@ -21,7 +22,6 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("MXNET_TPU_METRICS", "1")
 
 

@@ -27,7 +27,6 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("MXNET_TPU_METRICS", "1")
 os.environ["MXNET_TPU_KV_REPL_SYNC"] = "1"
 os.environ.setdefault("MXNET_TPU_PS_SECRET", "wire-report")
