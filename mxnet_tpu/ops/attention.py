@@ -84,7 +84,10 @@ def _attention_fwd_ref(q, k, v, causal, sm_scale, return_lse=False):
 # exact zeros to the p·v contraction), makes every op here stable across
 # both the query-length axis and key-dim padding.  Prefill, full
 # forward, and paged decode all route through these two helpers so the
-# three paths cannot drift.
+# three paths cannot drift.  (jaxlib 0.9: the scores and the softmax are
+# still stable; XLA:CPU now groups the adds of the p.v dot by the key
+# count, so the gate — tests/test_generation.py — holds the three paths
+# to the last few float32 bits, no longer to all of them.)
 
 
 def _stable_scores(q, k):

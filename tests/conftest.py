@@ -2,7 +2,10 @@
 sharding paths are exercised without TPU hardware (SURVEY.md §4: the
 reference's 'multiple ctx on one box' strategy)."""
 
+import json
 import os
+import subprocess
+import sys
 
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
@@ -65,23 +68,73 @@ def _bound_compiler_state():
     NOTE: this alone did NOT stop the XLA:CPU backend-compiler segfault
     seen around the ~300th test when the heavy example gates compiled
     in-process — that needed true subprocess isolation (see
-    test_examples_round3.py).  Kept as hygiene: it caps live-executable
+    ``_run_example``).  Kept as hygiene: it caps live-executable
     memory across the rest of the suite at a small recompile cost."""
     yield
     jax.clear_caches()
+
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def load_example(name):
     """Import an examples/ script as a module (shared by the example-gate
     tests; registered in sys.modules so dataclass/pickle paths work)."""
     import importlib.util
-    import sys
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    path = os.path.join(repo, "examples", name)
+    path = os.path.join(_REPO, "examples", name)
     spec = importlib.util.spec_from_file_location(
         "example_" + os.path.splitext(os.path.basename(name))[0], path)
     mod = importlib.util.module_from_spec(spec)
     sys.modules[spec.name] = mod
     spec.loader.exec_module(mod)
     return mod
+
+
+def _run_example(name, call, timeout, func="run"):
+    """Run ``examples/<name>``'s ``func(<call>)`` in a FRESH subprocess
+    and return the stats it reports.
+
+    Why a subprocess: one pytest process compiling every example's
+    graphs on top of the rest of the suite eventually segfaults
+    XLA:CPU's backend compiler (observed deterministically around the
+    ~300th test; ``jax.clear_caches()`` does not help — the leak is in
+    global compiler state).  Isolation also keeps the examples honest:
+    each must work from a cold start, like a user run.
+
+    ``timeout`` (seconds) is the gate's own budget, written beside it:
+    about three times what the gate takes under the tier-1 command and
+    never over 600 (``test_docs.py`` holds every file to that), so a
+    gate that hangs fails as itself and the rest of the suite still
+    runs.  The gates live in ``test_example_gates_*.py``, dealt so the
+    files take about the same time: tier-1 (``-n 6 --dist loadfile``)
+    hands a whole file to one worker, and a worker its next file when
+    two of its tests are left.  So there are as many files as workers
+    (eight files ran 725 s against 537-588 s for six: two workers walked
+    two each), each with three gates or more (a file of two takes the
+    next file with it), the long gates first.  The child keeps XLA's default thread pools: a gate is
+    mostly one thread (``autoencoder``: 189 s alone on eight cores with
+    247 s of CPU time, 215 s held to two cores, 270 s beside five other
+    workers; ``cnn_text_classification``: 55, 59 and 69 s), so there is
+    nothing to bound.
+    """
+    code = (
+        "import sys, json\n"
+        "sys.path.insert(0, %r)\n"
+        "import importlib.util\n"
+        "spec = importlib.util.spec_from_file_location('ex', %r)\n"
+        "mod = importlib.util.module_from_spec(spec)\n"
+        "sys.modules['ex'] = mod\n"
+        "spec.loader.exec_module(mod)\n"
+        "stats = mod.%s(%s)\n"
+        "stats.pop('image', None)\n"
+        "print('STATS ' + json.dumps({k: float(v) for k, v in stats.items()}))\n"
+        % (_REPO, os.path.join(_REPO, "examples", name), func, call)
+    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, timeout=timeout, cwd=_REPO)
+    assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-2000:])
+    line = [l for l in r.stdout.splitlines() if l.startswith("STATS ")]
+    assert line, r.stdout
+    return json.loads(line[-1][6:])

@@ -55,7 +55,8 @@ def test_generated_docs_in_sync():
     (the gen_cpp_ops-style drift gate)."""
     r = subprocess.run(
         [sys.executable, os.path.join(_REPO, "tools", "gen_docs.py"),
-         "--check"], capture_output=True, text=True, cwd=_REPO)
+         "--check"], capture_output=True, text=True, cwd=_REPO,
+        timeout=120)
     assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-2000:])
 
 
@@ -135,3 +136,41 @@ def test_docs_relative_links_resolve():
                 if not os.path.exists(resolved):
                     bad.append((os.path.relpath(path, _REPO), target))
     assert not bad, bad
+
+
+def test_suite_fits_its_clock():
+    """Tier-1 runs ``-n 6 --dist loadfile`` inside 1470 s: a file is the
+    unit of distribution and one hung child must not eat the clock.  So
+    every ``subprocess.run(`` / ``_run_example(`` under tests/ passes a
+    ``timeout``, no ``timeout=<number>`` anywhere under tests/ (a call's
+    or a helper's default) is over 600 s, and no file holds more than
+    eight ``_run_example`` gates."""
+    import ast
+    import re
+
+    bad = []
+    for root, _dirs, files in os.walk(os.path.join(_REPO, "tests")):
+        for fname in sorted(f for f in files if f.endswith(".py")):
+            path = os.path.join(root, fname)
+            rel = os.path.relpath(path, _REPO)
+            src = open(path, encoding="utf-8").read()
+            bad += ["%s: timeout=%s is over 600 s" % (rel, n)
+                    for n in re.findall(r"timeout=(\d+)", src)
+                    if int(n) > 600]
+            tree = ast.parse(src)
+            bad += ["%s:%d %s( passes no timeout"
+                    % (rel, c.lineno, ast.unparse(c.func))
+                    for c in ast.walk(tree) if isinstance(c, ast.Call)
+                    and ast.unparse(c.func) in ("subprocess.run",
+                                                "_run_example")
+                    and not any(kw.arg == "timeout" for kw in c.keywords)]
+            gates = [f.name for f in ast.walk(tree)
+                     if isinstance(f, ast.FunctionDef)
+                     and f.name.startswith("test_")
+                     and any(isinstance(c, ast.Call)
+                             and ast.unparse(c.func) == "_run_example"
+                             for c in ast.walk(f))]
+            if len(gates) > 8:
+                bad.append("%s holds %d _run_example gates, over eight"
+                           % (rel, len(gates)))
+    assert not bad, "\n".join(bad)

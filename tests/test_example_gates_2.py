@@ -1,0 +1,62 @@
+"""Example gates, file 2 of 6.  ``conftest._run_example`` says what a
+gate is, why it runs in a fresh subprocess and what the ``timeout``
+beside it is.  The gates are dealt by measured time, not by theme:
+tier-1 hands a whole file to one worker, so the files are kept about
+equal, the long gates first."""
+
+import os
+import subprocess
+import sys
+
+from conftest import _REPO, _run_example
+
+
+def test_dec_clustering_example():
+    """DEC recipe (AE pretrain -> k-means centroid init -> KL(P||Q)
+    refinement): the learned embedding clusters data whose raw Euclidean
+    structure is swamped by nuisance variance, and refinement improves
+    on its own k-means init.  The example's own 45 pretraining epochs
+    stay: they read 0.743 against the 0.7 bar, 30 read 0.722 and 20
+    read 0.710 (PR 24)."""
+    stats = _run_example("dec_clustering.py", "log=False", timeout=600)
+    assert stats["dec_acc"] > stats["raw_acc"] + 0.3, stats
+    assert stats["dec_acc"] >= stats["init_acc"] - 0.02, stats
+    assert stats["dec_acc"] > 0.7, stats
+
+
+def test_speech_demo_example():
+    """Kaldi-pipeline acoustic model (reference example/speech-demo):
+    features written as REAL Kaldi binary ark/scp (pure-numpy reader —
+    the reference needs a compiled Kaldi), round-tripped, trained
+    through an LSTM acoustic model, posteriors written back to ark and
+    verified; frame accuracy >= 0.9."""
+    stats = _run_example("speech_demo.py", "epochs=6, log=False",
+                         timeout=90)
+    assert stats["frame_acc"] >= 0.9, stats
+
+
+def test_benchmark_sweep_driver():
+    """Multi-worker throughput sweep driver (reference benchmark.py): runs
+    train_imagenet over 1 and 2 local workers through tools/launch.py
+    --tag-output, attributes Speedometer lines per rank, writes the CSV.
+    Scaling efficiency itself is not gated — the box has one core."""
+    import csv as _csv
+    import tempfile
+
+    with tempfile.TemporaryDirectory() as d:
+        out = os.path.join(d, "sweep.csv")
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        r = subprocess.run(
+            [sys.executable,
+             os.path.join(_REPO, "examples", "image_classification",
+                          "benchmark.py"),
+             "--networks", "mlp", "--worker-counts", "1,2",
+             "--num-examples", "512", "--batch-size", "64",
+             "--disp-batches", "2", "--output", out],
+            capture_output=True, text=True, env=env, timeout=120,
+            cwd=_REPO)
+        assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-2000:])
+        with open(out) as f:
+            rows = list(_csv.DictReader(f))
+        assert [int(x["workers"]) for x in rows] == [1, 2]
+        assert all(float(x["samples_per_sec"]) > 0 for x in rows)

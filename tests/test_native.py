@@ -303,7 +303,7 @@ def test_c_predict_api(tmp_path):
     # interpreter to the one running this test (venv-safe)
     r = subprocess.run(["make", "-C", os.path.join(repo, "native"),
                         "predict", "PYTHON=%s" % _sys.executable],
-                       capture_output=True, text=True)
+                       capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr
 
     # train-ish model: fixed params, deterministic outputs
@@ -364,7 +364,7 @@ def test_c_api_trains_lenet(tmp_path):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run(["make", "-C", os.path.join(repo, "native"),
                         "capi", "PYTHON=%s" % _sys.executable],
-                       capture_output=True, text=True)
+                       capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr
 
     # synthetic MNIST: class c = bright 10x10 block in grid cell c + noise
@@ -408,7 +408,7 @@ def test_cpp_frontend_trains_lenet(tmp_path):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run(["make", "-C", os.path.join(repo, "native"),
                         "cpp_train", "PYTHON=%s" % _sys.executable],
-                       capture_output=True, text=True)
+                       capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr
 
     rng = np.random.RandomState(5)
@@ -472,7 +472,7 @@ def test_cpp_frontend_bucketing():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run(["make", "-C", os.path.join(repo, "native"),
                         "cpp_train", "PYTHON=%s" % _sys.executable],
-                       capture_output=True, text=True)
+                       capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr
 
     binary = os.path.join(repo, "native", "build", "train_bucketing")
@@ -480,7 +480,7 @@ def test_cpp_frontend_bucketing():
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=repo + ((os.pathsep + prior) if prior else ""))
     r = subprocess.run([binary, "6", "32"], capture_output=True,
-                       text=True, env=env, timeout=900)
+                       text=True, env=env, timeout=120)
     assert r.returncode == 0, (r.stdout, r.stderr)
     line = [l for l in r.stdout.splitlines()
             if l.startswith("CPP_BUCKETING")]
@@ -507,13 +507,14 @@ def test_perl_frontend_trains_lenet(tmp_path):
         pytest.skip("perl/make unavailable")
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     probe = subprocess.run(
-        [perl, "-MExtUtils::MakeMaker", "-e", "1"], capture_output=True)
+        [perl, "-MExtUtils::MakeMaker", "-e", "1"], capture_output=True,
+        timeout=60)
     if probe.returncode != 0:
         pytest.skip("ExtUtils::MakeMaker unavailable")
 
     r = subprocess.run(["make", "-C", os.path.join(repo, "native"),
                         "capi", "PYTHON=%s" % _sys.executable],
-                       capture_output=True, text=True)
+                       capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr
 
     # MakeMaker writes its build tree next to the sources: build from a
@@ -573,7 +574,7 @@ def test_c_api_imperative_autograd(tmp_path):
     r = subprocess.run(["make", "-C", os.path.join(repo, "native"),
                         "build/imperative_capi_test",
                         "PYTHON=%s" % _sys.executable],
-                       capture_output=True, text=True)
+                       capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=repo + ((os.pathsep + os.environ["PYTHONPATH"])

@@ -73,10 +73,11 @@ def test_im2rec_roundtrip(tmp_path):
     tool = os.path.join(repo, "tools", "im2rec.py")
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     subprocess.run([sys.executable, tool, prefix, str(root), "--list",
-                    "--recursive"], check=True, env=env)
+                    "--recursive"], check=True, env=env, timeout=120)
     assert os.path.exists(prefix + ".lst")
     subprocess.run([sys.executable, tool, prefix + ".lst", str(root),
-                    "--encoding", ".npy"], check=True, env=env)
+                    "--encoding", ".npy"], check=True, env=env,
+                   timeout=120)
     assert os.path.exists(prefix + ".rec") and os.path.exists(prefix + ".idx")
 
     from mxnet_tpu import recordio

@@ -261,12 +261,16 @@ def _blobs(rng, n=400, d=16, k=4):
 
 
 def _mlp(k=4):
-    net = mx.sym.FullyConnected(mx.sym.Variable("data"), num_hidden=32,
-                                name="fc1")
-    net = mx.sym.Activation(net, act_type="relu")
-    return mx.sym.SoftmaxOutput(
-        mx.sym.FullyConnected(net, num_hidden=k, name="fc2"),
-        name="softmax")
+    # a fresh name scope: the anonymous Activation is ``activation0``
+    # whatever this process built before (the auto-name counter is
+    # process-wide, and the QAT observers are named after their nodes)
+    with mx.name.NameManager():
+        net = mx.sym.FullyConnected(mx.sym.Variable("data"),
+                                    num_hidden=32, name="fc1")
+        net = mx.sym.Activation(net, act_type="relu")
+        return mx.sym.SoftmaxOutput(
+            mx.sym.FullyConnected(net, num_hidden=k, name="fc2"),
+            name="softmax")
 
 
 def test_qat_pipeline_mlp():

@@ -20,7 +20,7 @@ def test_parse_log(tmp_path):
     r = subprocess.run(
         [sys.executable, os.path.join(_REPO, "tools", "parse_log.py"),
          str(log), "--metric", "accuracy", "--format", "csv"],
-        capture_output=True, text=True, check=True)
+        capture_output=True, text=True, check=True, timeout=60)
     lines = r.stdout.strip().splitlines()
     assert lines[0] == "epoch,train,val,samples_per_sec,time_s"
     assert lines[1].startswith("0,0.55,0.52,99.5,12.3")

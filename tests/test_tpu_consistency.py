@@ -23,7 +23,7 @@ def test_cpu_vs_tpu_consistency_sweep():
     r = subprocess.run(
         [sys.executable,
          os.path.join(_REPO, "tests", "tpu", "consistency_on_chip.py")],
-        capture_output=True, text=True, timeout=900, env=env, cwd=_REPO)
+        capture_output=True, text=True, timeout=600, env=env, cwd=_REPO)
     if "SKIP_NO_TPU" in r.stdout:
         pytest.skip("no TPU attached: %s" % r.stdout.strip())
     assert r.returncode == 0, (r.stdout[-3000:], r.stderr[-3000:])

@@ -408,7 +408,7 @@ def render(infer_rows, train_rows, chip, lm_row=None, int8_rows=None,
                 "%.2f×" % (i8 / bf16) if (i8 and bf16) else "—"),
             "",
             "Accuracy: the PTQ pipeline is gated end-to-end in",
-            "`tests/test_examples_round3.py::test_quantize_resnet_example`",
+            "`tests/test_example_gates_*.py::test_quantize_resnet_example`",
             "(int8 top-1 within a point of fp32 on the trained gate",
             "model).  Capture: `examples/quantize_resnet.py --benchmark`.",
         ]
@@ -443,7 +443,7 @@ def render(infer_rows, train_rows, chip, lm_row=None, int8_rows=None,
             "fused op).  FFN int8 regresses at these shapes — the",
             "decomposition is in docs/PERF.md \"int8 on the",
             "transformer\".  Accuracy gated in",
-            "`tests/test_examples_round3.py::`",
+            "`tests/test_example_gates_*.py::`",
             "`test_quantize_transformer_example`.  Capture:",
             "`examples/quantize_transformer.py --benchmark --batch 32`.",
         ]
