@@ -236,7 +236,8 @@ def lm_prefill(params, tokens, cfg, int8_head=False):
 
     Returns ``(logits [B, T, V], k [L, B, T, H, D], v [L, B, T, H, D])``
     — K/V in cache page layout, ready for ``PagedKVCache.write_prefill``
-    (per sequence: ``k[:, b, :length]``).  This is also the lane's
+    (per sequence: ``k[:, b]`` with its real length; the write drops
+    the pad positions on the device).  This is also the lane's
     "naive" full forward: the parity gate compares its row ``t`` logits
     against decode step ``t``.
     """
@@ -265,23 +266,34 @@ def lm_decode_step(params, tokens, positions, k_pages, v_pages,
     """One decode step for a batch of sequences through the paged cache.
 
     ``tokens``/``positions`` int32 ``[B]`` (position = context_len - 1);
-    ``k_pages``/``v_pages`` ``[L, num_blocks, block_size, H, D]``;
+    ``k_pages``/``v_pages`` ``[L, num_blocks, block_size, H * D]`` as
+    :class:`~mxnet_tpu.ops.kv_cache.PagedKVCache` holds them (``[L,
+    num_blocks, block_size, H, D]`` is read the same);
     ``block_tables`` int32 ``[B, max_blocks]``; ``context_lens`` int32
     ``[B]`` counting the current token.  Returns ``(logits [B, V],
-    k_step [L, B, H, D], v_step [L, B, H, D])`` — the caller writes
-    ``k_step``/``v_step`` into the pool only after the dispatch
-    succeeds, so chaos retries cannot corrupt other sequences' blocks.
+    k_step [L, B, H, D], v_step [L, B, H, D])``.  The pool is read as
+    of before the step and not written here: the caller hands
+    ``k_step``/``v_step``, still on the device, to
+    ``PagedKVCache.write_tokens`` after the dispatch succeeded, so a
+    dropped or retried dispatch cannot touch any sequence's blocks.
     """
     x = (params["embed_weight"][tokens]
          + params["pos_embed_weight"][0][positions])[:, None, :]
     x = x.astype(jnp.float32)
+    # every layer gathers from the whole pool through tables offset to
+    # its blocks: a per-layer slice ``k_pages[i]`` is materialised on a
+    # TPU, a copy of the layer's pool a layer a step
+    heads, num_blocks = cfg["num_heads"], k_pages.shape[1]
+    pool = (-1, k_pages.shape[2], heads, cfg["num_embed"] // heads)
+    k_pool, v_pool = k_pages.reshape(pool), v_pages.reshape(pool)
     ks, vs = [], []
     for i in range(cfg["num_layers"]):
         h = _lm_ln(x, params["l%d_ln1_gamma" % i], params["l%d_ln1_beta" % i])
         q, k, v = _lm_qkv(h, params["l%d_attn_qkv_weight" % i], cfg)
         k1, v1 = k[:, :, 0], v[:, :, 0]      # [B, H, D]
-        a = paged_decode_attention(q[:, :, 0], k1, v1, k_pages[i],
-                                   v_pages[i], block_tables, context_lens)
+        a = paged_decode_attention(
+            q[:, :, 0], k1, v1, k_pool, v_pool,
+            block_tables + i * num_blocks, context_lens)
         b, heads, d = a.shape
         a = a.reshape(b, 1, heads * d)
         x = x + jnp.einsum("btc,fc->btf", a,

@@ -136,12 +136,13 @@ def paged_decode_attention(q, k_step, v_step, k_pages, v_pages,
     """One decode step's attention, K/V gathered through the block table.
 
     - ``q`` / ``k_step`` / ``v_step``: ``[B, H, D]`` — this step's
-      single query per sequence and its freshly projected K/V (written
-      back to the pool by the caller *after* the step succeeds, so a
+      single query per sequence and its freshly projected K/V (the
+      caller scatters them into the pool on the device, with
+      ``PagedKVCache.write_tokens``, *after* the step succeeded, so a
       retried dispatch never leaves half-written pages).
     - ``k_pages`` / ``v_pages``: ``[num_blocks, block_size, H, D]`` —
       one layer's slice of the shared :class:`~mxnet_tpu.ops.kv_cache.
-      PagedKVCache` pool.
+      PagedKVCache` pool (device-resident), as of before this step.
     - ``block_tables``: ``int32 [B, max_blocks]`` — per-sequence page
       lists, zero-padded (pad rows are masked off below).
     - ``context_lens``: ``int32 [B]`` — valid tokens per sequence,
@@ -170,13 +171,19 @@ def _paged_decode_attention_stock(q, k_step, v_step, k_pages, v_pages,
     if sm_scale is None:
         sm_scale = 1.0 / float(q.shape[-1]) ** 0.5
     bsz, max_blocks = block_tables.shape
-    blk = k_pages.shape[1]
-    heads, dim = k_pages.shape[2], k_pages.shape[3]
+    num_blocks, blk = k_pages.shape[:2]
+    heads, dim = q.shape[1], q.shape[2]
     kmax = max_blocks * blk
     rows = jnp.arange(bsz)
     positions = context_lens - 1
-    k = k_pages[block_tables].reshape(bsz, kmax, heads, dim)
-    v = v_pages[block_tables].reshape(bsz, kmax, heads, dim)
+    # gather whole blocks as rows of H*D: that is how the cache stores
+    # them, so the reshape undoes the caller's and the gather reads the
+    # pool where it lies (a gather over [.., H, D] re-lays the layer's
+    # whole pool on a TPU first: its D = 64 is half a lane tile)
+    k = k_pages.reshape(num_blocks, blk, heads * dim)[block_tables]
+    v = v_pages.reshape(num_blocks, blk, heads * dim)[block_tables]
+    k = k.reshape(bsz, kmax, heads, dim)
+    v = v.reshape(bsz, kmax, heads, dim)
     k = k.at[rows, positions].set(k_step)
     v = v.at[rows, positions].set(v_step)
     k = k.transpose(0, 2, 1, 3)            # [B, H, Kmax, D]

@@ -12,13 +12,31 @@ through the serving admission machinery rather than an OOM.
 
 Layout: one pair of pools per cache, shaped
 
-    ``k_pages / v_pages : [num_layers, num_blocks, block_size, heads, dim]``
+    ``k_pages / v_pages : [num_layers, num_blocks, block_size, heads * dim]``
 
-so a decode step can ship the *whole* pool to the device plus per-batch
-``int32`` block tables, and :func:`~mxnet_tpu.ops.attention.
-paged_decode_attention` gathers K/V rows through the table inside the
-jitted step — the pool shape is static, so decode dispatches never
-recompile as sequences come and go.
+A token's K (or V) of one layer is one row of ``heads * dim`` values, a
+block is ``block_size`` such rows lying together.  Heads and head
+dimension are merged because the device lays an array out by its shape
+alone: with a trailing ``[heads, dim]`` and ``dim`` = 64, half a lane
+tile, a TPU keeps the *block* axis innermost, and every gather through
+a block table and every write of a token then re-lays a whole layer or
+the whole pool first (compiled for a v5e, PR 25: a pool-sized temporary
+and four pool-sized copies per write).  Rows of ``heads * dim`` lie as
+they are indexed, so a block is gathered, and a token's row written,
+where it lies.
+
+**The pools live on the device** (``jax.Array``); the allocator, the
+block tables, the lengths and the gauges live on the host.  A decode
+step hands the device its per-batch ``int32`` block tables (a few KB)
+and :func:`~mxnet_tpu.ops.attention.paged_decode_attention` gathers K/V
+rows through the table inside the jitted step — the pool shape is
+static, so decode dispatches never recompile as sequences come and go.
+Writes (:meth:`PagedKVCache.write_prefill`, :meth:`PagedKVCache.
+write_tokens`) take the K/V a dispatch produced *as device arrays* and
+scatter them into the pools in one jitted call that **donates** both
+pools and re-binds them to its outputs: the pool is updated in place,
+never exists twice, and no K/V byte visits the host.  The host computes
+only the target slots, from the tables it owns.
 
 The cache is **backend state**: ``serving.generation.LMBackend`` owns
 one, the ``ModelRegistry`` swap machinery replaces cache and weights
@@ -36,6 +54,8 @@ import os
 import threading
 import weakref
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from .. import chaos
@@ -43,8 +63,8 @@ from ..base import MXNetError
 from ..observability import memory as _memory
 from ..observability import metrics as _metrics
 
-__all__ = ["CacheExhaustedError", "PagedKVCache", "default_block_size",
-           "default_num_blocks"]
+__all__ = ["CacheExhaustedError", "CachePoolLostError", "PagedKVCache",
+           "default_block_size", "default_num_blocks"]
 
 
 class CacheExhaustedError(MXNetError):
@@ -56,6 +76,16 @@ class CacheExhaustedError(MXNetError):
     """
 
     http_status = 429
+
+
+class CachePoolLostError(MXNetError):
+    """A pool write failed after its buffers were donated.
+
+    The consumed buffers cannot be written again, so the cache has
+    already replaced them with a zeroed pool: the pages of every live
+    sequence are gone and their owner must fail them (the generation
+    lane does), exactly as if the backend had been swapped.
+    """
 
 
 def default_block_size():
@@ -99,14 +129,39 @@ _M_SESS_BLOCKS = _metrics.histogram(
     buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256))
 
 
+def _scatter_pages(k_pages, v_pages, k, v, blocks, offsets):
+    """``k``/``v`` ``[L, N, ...]`` (``heads * dim`` values a row) into
+    slot ``(blocks[n], offsets[n])`` of every layer.  A slot whose block
+    id is out of range (the pad rows of a decode bucket, the pad
+    positions of a prefill bucket) is dropped: it writes nowhere."""
+    layers, count = k.shape[:2]
+    # the layer is an index like block and offset, so that the indexed
+    # axes are the pool's leading ones and a row is written where it
+    # lies; sliced (``[:, blocks, offsets]``) the pool is transposed to
+    # bring the layer axis inside, and back: two copies of it
+    at = (jnp.arange(layers)[:, None], blocks[None, :], offsets[None, :])
+
+    def put(pages, rows):
+        rows = rows.reshape(layers, count, -1).astype(pages.dtype)
+        return pages.at[at].set(rows, mode="drop")
+
+    return put(k_pages, k), put(v_pages, v)
+
+
+# one program per N (a prefill or decode bucket); the pools are donated,
+# so the outputs alias them and the write is in place
+_write_pages = jax.jit(_scatter_pages, donate_argnums=(0, 1))
+
+
 class PagedKVCache(object):
     """Free-list block allocator + per-sequence block tables + the pools.
 
     Thread-safe: the generation lane allocates/frees from its loop
     thread while the front-end frees on client disconnect.  All index
-    math is host-side numpy; the pools are plain ``np.ndarray`` so the
-    dispatch path hands them to jit as-is (XLA:CPU aliases the buffer,
-    device backends stage them once per step).
+    math is host-side numpy; the pools ``k_pages``/``v_pages`` are
+    device arrays that stay on the device between dispatches and are
+    donated to, and re-bound from, every write (on XLA:CPU a device
+    array is host memory, so there is no second path).
     """
 
     def __init__(self, num_layers, num_heads, head_dim, block_size=None,
@@ -121,10 +176,10 @@ class PagedKVCache(object):
         self.num_heads = int(num_heads)
         self.head_dim = int(head_dim)
         self.model = model
-        shape = (self.num_layers, self.num_blocks, self.block_size,
-                 self.num_heads, self.head_dim)
-        self.k_pages = np.zeros(shape, dtype=dtype)
-        self.v_pages = np.zeros(shape, dtype=dtype)
+        self._shape = (self.num_layers, self.num_blocks, self.block_size,
+                       self.num_heads * self.head_dim)
+        self._dtype = np.dtype(dtype)
+        self._zero_pools()
         self._lock = threading.Lock()
         self._free = list(range(self.num_blocks - 1, -1, -1))
         self._tables = {}      # seq_id -> [block ids]
@@ -137,15 +192,24 @@ class PagedKVCache(object):
         self._allocs = _M_ALLOCS.labels(model)
         self._frees = _M_FREES.labels(model)
         self._sess_blocks = _M_SESS_BLOCKS.labels(model)
-        # book the host-resident page pools into the memory ledger;
-        # the finalizer releases the row when the cache (hot-swap,
-        # backend teardown) is collected
+        # book the device-resident page pools into the memory ledger
+        # (live jax arrays: the "all" row the reconcile gate sums); the
+        # finalizer releases the row when the cache (hot-swap, backend
+        # teardown) is collected
         self._ledger_key = id(self)
-        _memory.tag("kv_cache", self._ledger_key,
-                    self.k_pages.nbytes + self.v_pages.nbytes,
-                    device="host")
+        _memory.tag("kv_cache", self._ledger_key, self.pool_bytes)
         weakref.finalize(self, _memory.untag, "kv_cache",
                          self._ledger_key)
+
+    def _zero_pools(self):
+        # drop the old pair first: two pools never exist at once
+        self.k_pages = self.v_pages = None
+        self.k_pages = jnp.zeros(self._shape, self._dtype)
+        self.v_pages = jnp.zeros(self._shape, self._dtype)
+
+    @property
+    def pool_bytes(self):
+        return 2 * int(np.prod(self._shape)) * self._dtype.itemsize
 
     # -- allocation --------------------------------------------------
 
@@ -240,40 +304,86 @@ class PagedKVCache(object):
 
     # -- writes ------------------------------------------------------
 
-    def write_prefill(self, seq_id, k, v):
-        """Store prompt K/V: ``k``/``v`` shaped ``[L, T, heads, dim]``.
+    def _write_locked(self, k, v, blocks, offsets):
+        """Scatter into the donated pools and re-bind them; returns the
+        host bytes handed to the device (the slot indices)."""
+        try:
+            self.k_pages, self.v_pages = _write_pages(
+                self.k_pages, self.v_pages, k, v, blocks, offsets)
+        except Exception as exc:
+            if not (self.k_pages.is_deleted()
+                    or self.v_pages.is_deleted()):
+                raise       # refused before donation: pool untouched
+            self._zero_pools()
+            self._lengths = dict.fromkeys(self._lengths, 0)
+            raise CachePoolLostError(
+                "kv cache %r: a pool write failed after donation (%s: "
+                "%s); the pool was rebuilt zeroed and every live "
+                "sequence lost its pages"
+                % (self.model, type(exc).__name__, exc)) from exc
+        return blocks.nbytes + offsets.nbytes
 
-        Requires a prior :meth:`allocate` covering ``T`` tokens.  Writes
-        happen only after a successful prefill dispatch, so a retried
-        (chaos-dropped) dispatch never leaves half-written pages.
+    def write_prefill(self, seq_id, k, v, length):
+        """Store prompt K/V: ``k``/``v`` device arrays ``[L, T, heads *
+        dim]`` (or ``[L, T, heads, dim]``) as the prefill dispatch
+        produced them, ``T`` its bucket; positions ``< length`` are
+        written, the bucket's pad positions are dropped.
+
+        Requires a prior :meth:`allocate` covering ``length`` tokens.
+        Call it only after the prefill dispatch succeeded: it targets
+        the sequence's own reserved slots and nothing else, so a retried
+        write stores the same values again.  Returns the host bytes
+        handed to the device (two ``int32[T]`` index vectors).
         """
-        k = np.asarray(k)
-        num = k.shape[1]
+        bucket, length = int(k.shape[1]), int(length)
         with self._lock:
+            blocks = np.full(bucket, self.num_blocks, dtype=np.int32)
+            offsets = np.zeros(bucket, dtype=np.int32)
             table = self._tables.get(seq_id)
-            if table is None or len(table) < self._blocks_for(num):
+            if (table is None or length > bucket
+                    or len(table) < self._blocks_for(length)):
                 raise MXNetError(
-                    "write_prefill(%r, %d tokens) exceeds allocation"
-                    % (seq_id, num))
-            for t in range(num):
-                blk, off = table[t // self.block_size], t % self.block_size
-                self.k_pages[:, blk, off] = k[:, t]
-                self.v_pages[:, blk, off] = np.asarray(v)[:, t]
-            self._lengths[seq_id] = max(self._lengths.get(seq_id, 0), num)
-
-    def write_token(self, seq_id, pos, k, v):
-        """Store one decoded token's K/V: ``k``/``v`` ``[L, heads, dim]``."""
-        with self._lock:
-            table = self._tables.get(seq_id)
-            if table is None or pos >= len(table) * self.block_size:
-                raise MXNetError(
-                    "write_token(%r, pos=%d) exceeds allocation"
-                    % (seq_id, pos))
-            blk, off = table[pos // self.block_size], pos % self.block_size
-            self.k_pages[:, blk, off] = np.asarray(k)
-            self.v_pages[:, blk, off] = np.asarray(v)
+                    "write_prefill(%r, %d tokens of a bucket of %d) "
+                    "exceeds allocation" % (seq_id, length, bucket))
+            positions = np.arange(length)
+            blocks[:length] = np.asarray(table, dtype=np.int32)[
+                positions // self.block_size]
+            offsets[:length] = positions % self.block_size
+            staged = self._write_locked(k, v, blocks, offsets)
             self._lengths[seq_id] = max(self._lengths.get(seq_id, 0),
-                                        pos + 1)
+                                        length)
+        return staged
+
+    def write_tokens(self, seq_ids, positions, k, v):
+        """Store one decode step's K/V for the whole batch in one call:
+        ``k``/``v`` device arrays ``[L, B, heads * dim]`` (or ``[L, B,
+        heads, dim]``) as the decode dispatch produced them, row ``i``
+        belonging to ``seq_ids[i]`` at token position ``positions[i]``.
+        Rows beyond ``len(seq_ids)`` are the bucket's pad rows and write
+        nowhere.  Returns the host bytes handed to the device (two
+        ``int32[B]`` index vectors)."""
+        bucket = int(k.shape[1])
+        if len(seq_ids) > bucket or len(seq_ids) != len(positions):
+            raise MXNetError(
+                "write_tokens: %d sequences, %d positions, %d rows"
+                % (len(seq_ids), len(positions), bucket))
+        with self._lock:
+            blocks = np.full(bucket, self.num_blocks, dtype=np.int32)
+            offsets = np.zeros(bucket, dtype=np.int32)
+            for i, (seq_id, pos) in enumerate(zip(seq_ids, positions)):
+                table, pos = self._tables.get(seq_id), int(pos)
+                if (table is None
+                        or not 0 <= pos < len(table) * self.block_size):
+                    raise MXNetError(
+                        "write_tokens(%r, pos=%d) exceeds allocation"
+                        % (seq_id, pos))
+                blocks[i] = table[pos // self.block_size]
+                offsets[i] = pos % self.block_size
+            staged = self._write_locked(k, v, blocks, offsets)
+            for seq_id, pos in zip(seq_ids, positions):
+                self._lengths[seq_id] = max(self._lengths[seq_id],
+                                            int(pos) + 1)
+        return staged
 
     # -- introspection ----------------------------------------------
 
@@ -290,5 +400,4 @@ class PagedKVCache(object):
                                      if used else 0.0,
                     "sequences": len(self._tables),
                     "block_size": self.block_size,
-                    "pool_bytes": self.k_pages.nbytes
-                                  + self.v_pages.nbytes}
+                    "pool_bytes": self.pool_bytes}

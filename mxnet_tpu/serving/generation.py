@@ -27,9 +27,16 @@ scheduler was already styled after:
   :class:`~mxnet_tpu.ops.kv_cache.PagedKVCache`; exhaustion sheds the
   new request with the typed 429
   :class:`~mxnet_tpu.ops.kv_cache.CacheExhaustedError` through the
-  stock admission accounting.  Cache writes happen only AFTER a decode
-  dispatch succeeds, so a chaos-retried step can never corrupt another
-  sequence's blocks.
+  stock admission accounting.  Weights and pools stay on the device
+  between dispatches: a call hands over token ids, positions, block
+  tables and lengths and gets logits back
+  (``generation_host_to_device_bytes_total`` /
+  ``generation_device_to_host_bytes_total`` are the evidence).  The
+  pool write follows the dispatch that produced its K/V and targets
+  only the calling sequences' own reserved slots, so a chaos-dropped or
+  retried step can never corrupt another sequence's blocks; a write
+  that fails after the pool was donated fails the lane's live
+  sequences on a rebuilt, zeroed pool.
 - **Cache is backend state.**  ``ModelRegistry.swap`` replaces backend
   and cache together (the registry machinery is untouched); the loop
   notices the swap under ``dispatch_lock`` and transparently
@@ -67,7 +74,8 @@ from ..observability import memory as _memory
 from ..observability import metrics as _metrics
 from ..observability import tracing as _tracing
 from ..observability.events import emit as _emit_event
-from ..ops.kv_cache import CacheExhaustedError, PagedKVCache
+from ..ops.kv_cache import (CacheExhaustedError, CachePoolLostError,
+                            PagedKVCache)
 from . import admission as _admission
 from . import tenancy as _tenancy
 from .registry import Backend, ModelRegistry
@@ -107,6 +115,27 @@ def default_max_new_tokens():
 
 
 _DONE = object()
+
+# what crosses between host and device per generation call: the
+# residency contract's witness.  A decode call reads a few KB in and
+# ``B x V x 4`` out; a numpy array slipping back into a call (weights,
+# a pool) shows here at once.
+_M_H2D = _metrics.counter(
+    "generation_host_to_device_bytes_total",
+    "Host bytes handed to the device by generation calls (token ids, "
+    "positions, block tables, lengths, cache slot indices; any numpy "
+    "array among weights or pools), by model and phase",
+    ["model", "phase"])
+_M_D2H = _metrics.counter(
+    "generation_device_to_host_bytes_total",
+    "Device bytes generation calls copied back to the host (logits), "
+    "by model and phase", ["model", "phase"])
+
+
+def _host_nbytes(arrays):
+    """Bytes of the numpy arrays among ``arrays``: what a jitted call
+    given them has to stage on the device."""
+    return sum(a.nbytes for a in arrays if isinstance(a, _np.ndarray))
 
 
 class GenerationRequest(object):
@@ -211,6 +240,14 @@ class GenerationRequest(object):
         return list(self.generated)
 
 
+def _page_rows(kv):
+    """K/V of one dispatch, ``[L, 1, T, H, D]`` (prefill) or ``[L, B, H,
+    D]`` (decode), as the cache's rows: ``[L, N, H * D]``.  Done inside
+    the dispatch, so that it emits them as they will be written (a
+    ``[.., H, 64]`` output gets a device layout the write re-lays)."""
+    return kv.reshape(kv.shape[0], -1, kv.shape[-2] * kv.shape[-1])
+
+
 class LMBackend(Backend):
     """Generative serving backend: transformer params + paged KV cache
     + shape-keyed jit caches for prefill and decode.
@@ -221,6 +258,18 @@ class LMBackend(Backend):
     cache lives HERE, a hot swap replaces weights and KV state as one
     unit.
 
+    **Everything a call reads lives on the device between calls.**  The
+    constructor places the weight tree once (``self.params`` stays a
+    dict under the same names, its leaves device arrays) and the cache
+    keeps its pools there; :meth:`prefill` and :meth:`decode` hand over
+    token ids, positions, block tables and lengths and copy back only
+    logits.  The K/V they return are device arrays for
+    :meth:`~mxnet_tpu.ops.kv_cache.PagedKVCache.write_prefill` /
+    :meth:`~mxnet_tpu.ops.kv_cache.PagedKVCache.write_tokens`.  During
+    a hot swap's brownout two backends are alive, so two resident sets
+    (weights + pool each) are on the device at once: 2 x 3.76 GB =
+    7.5 GB for GPT-2 medium with a 680-block pool, of 16 GB.
+
     ``int8_head=True`` opts into the
     :func:`~mxnet_tpu.contrib.quantization.quantize_weight_int8` vocab
     head for decode logits (storage/bandwidth win on the model's
@@ -230,10 +279,12 @@ class LMBackend(Backend):
 
     def __init__(self, params, cfg, block_size=None, num_blocks=None,
                  int8_head=False, model="lm"):
+        import jax
+
         self.cfg = dict(cfg)
         self.int8_head = bool(int8_head)
-        self.params = _tfm.quantize_lm_head(params) if int8_head \
-            else dict(params)
+        self.params = jax.device_put(
+            _tfm.quantize_lm_head(params) if int8_head else dict(params))
         self.input_shapes = {"data": (self.cfg["seq_len"],)}
         self.cache = PagedKVCache(
             num_layers=self.cfg["num_layers"],
@@ -247,6 +298,9 @@ class LMBackend(Backend):
                                     // self.cache.block_size)
         self._jits = {}
         self._jit_lock = threading.Lock()
+        self._moved = {(phase, way): fam.labels(model, phase)
+                       for phase in ("prefill", "decode")
+                       for way, fam in (("h2d", _M_H2D), ("d2h", _M_D2H))}
         # book the weight tree into the memory ledger (serving-lane
         # analogue of the trainer's params seam); keyed by backend so a
         # hot-swap replaces the old backend's row when it is collected
@@ -265,21 +319,37 @@ class LMBackend(Backend):
                 self._jits[key] = fn
         return fn, cold
 
+    def moved(self, phase, h2d=0, d2h=0):
+        """Book bytes a ``phase`` (``prefill`` / ``decode``) call moved
+        between host and device."""
+        self._moved[phase, "h2d"].inc(h2d)
+        self._moved[phase, "d2h"].inc(d2h)
+
     # -- Backend protocol (full forward; also the naive baseline) ----
 
     def infer(self, batch):
         """Full-sequence forward (no cache) — the classifier-lane
         protocol, and the bench's naive re-prefill baseline."""
         tokens = _np.asarray(batch["data"], dtype=_np.int32)
-        fn, cold = self._jit(("infer",) + tokens.shape, self._build_prefill)
-        logits, _, _ = fn(self.params, tokens)
-        return [_np.asarray(logits)], cold
+        fn, cold = self._jit(("infer",) + tokens.shape, self._build_infer)
+        return [_np.asarray(fn(self.params, tokens))], cold
+
+    def _build_infer(self):
+        cfg = self.cfg
+
+        def run(params, tokens):
+            return _tfm.lm_prefill(params, tokens, cfg)[0]
+        return run
 
     def _build_prefill(self):
         cfg = self.cfg
 
-        def run(params, tokens):
-            return _tfm.lm_prefill(params, tokens, cfg)
+        def run(params, tokens, length):
+            # ``length`` is traced: one program per bucket, whatever the
+            # prompt's real length, and only row ``length - 1`` of the
+            # logits leaves the device
+            logits, k, v = _tfm.lm_prefill(params, tokens[None], cfg)
+            return logits[0, length - 1], _page_rows(k), _page_rows(v)
         return run
 
     def _build_decode(self):
@@ -287,38 +357,50 @@ class LMBackend(Backend):
 
         def run(params, tokens, positions, k_pages, v_pages,
                 block_tables, context_lens):
-            return _tfm.lm_decode_step(
+            logits, k, v = _tfm.lm_decode_step(
                 params, tokens, positions, k_pages, v_pages,
                 block_tables, context_lens, cfg, int8_head=int8)
+            return logits, _page_rows(k), _page_rows(v)
         return run
 
     # -- generation entry points -------------------------------------
 
     def prefill(self, tokens, length):
         """One prompt (``tokens`` int32 ``[T_bucket]`` padded, ``length``
-        real) → ``(last_logits [V], k [L, length, H, D], v)``; ``cold``
-        reports the jit-cache miss for compile accounting."""
-        tokens = _np.asarray(tokens, dtype=_np.int32)[None]
+        real) → ``(last_logits [V], k [L, T_bucket, H * D], v, cold)``.
+        The logits are a host copy; ``k``/``v`` are device arrays over
+        the whole bucket, for ``cache.write_prefill(seq, k, v, length)``
+        (which drops the pad positions); ``cold`` reports the jit-cache
+        miss for compile accounting."""
+        tokens = _np.asarray(tokens, dtype=_np.int32)
+        args = (tokens, _np.asarray(length, dtype=_np.int32))
         fn, cold = self._jit(("prefill",) + tokens.shape,
                              self._build_prefill)
-        logits, k, v = fn(self.params, tokens)
-        k = _np.asarray(k)[:, 0, :length]
-        v = _np.asarray(v)[:, 0, :length]
-        return _np.asarray(logits)[0, length - 1], k, v, cold
+        logits, k, v = fn(self.params, *args)
+        # a copy in ordinary host memory: np.asarray of a device array
+        # is a view of the runtime's transfer buffer
+        logits = _np.array(logits)
+        self.moved("prefill", _host_nbytes((*self.params.values(), *args)),
+                   logits.nbytes)
+        return logits, k, v, cold
 
     def decode(self, tokens, positions, block_tables, context_lens):
         """One decode step over a padded batch.  Returns ``(logits
-        [B, V], k_step [L, B, H, D], v_step, cold)`` — the caller writes
-        K/V back into the cache after the step succeeds."""
+        [B, V], k_step [L, B, H * D], v_step, cold)``: the logits a host
+        copy, ``k_step``/``v_step`` device arrays the caller hands to
+        ``cache.write_tokens`` after the step succeeded.  The pool is
+        read as of before the step and not written here."""
+        args = (_np.asarray(tokens, dtype=_np.int32),
+                _np.asarray(positions, dtype=_np.int32),
+                self.cache.k_pages, self.cache.v_pages,
+                _np.asarray(block_tables, dtype=_np.int32),
+                _np.asarray(context_lens, dtype=_np.int32))
         fn, cold = self._jit(("decode", len(tokens)), self._build_decode)
-        logits, k, v = fn(
-            self.params,
-            _np.asarray(tokens, dtype=_np.int32),
-            _np.asarray(positions, dtype=_np.int32),
-            self.cache.k_pages, self.cache.v_pages,
-            _np.asarray(block_tables, dtype=_np.int32),
-            _np.asarray(context_lens, dtype=_np.int32))
-        return (_np.asarray(logits), _np.asarray(k), _np.asarray(v), cold)
+        logits, k, v = fn(self.params, *args)
+        logits = _np.array(logits)
+        self.moved("decode", _host_nbytes((*self.params.values(), *args)),
+                   logits.nbytes)
+        return logits, k, v, cold
 
     def describe(self):
         d = Backend.describe(self)
@@ -521,28 +603,34 @@ class GenerationScheduler(object):
         return self.registry.swap(name, backend)
 
     def warmup(self, name):
-        """Pre-compile every prefill bucket (B=1) and decode bucket so
-        steady-state generation never compiles.  Returns cold count."""
+        """Pre-compile every prefill bucket (B=1) and decode bucket,
+        with the pool write that follows each, so steady-state
+        generation never compiles.  Returns cold count."""
         lane = self._lane(name)
         entry = lane.entry
         cold_n = 0
         with entry.dispatch_lock:
             backend = entry.backend
-            for t in self._prefill_buckets[name]:
-                _, _, _, cold = backend.prefill(
-                    _np.zeros(t, dtype=_np.int32), 1)
-                cold_n += bool(cold)
-            for b in entry.buckets:
-                sid = "__warm%d" % b
-                backend.cache.allocate(sid, 1)
-                tables = _np.stack(
-                    [backend.cache.block_table(
-                        sid, backend.max_blocks_per_seq)] * b)
-                _, _, _, cold = backend.decode(
-                    _np.zeros(b, _np.int32), _np.zeros(b, _np.int32),
-                    tables, _np.ones(b, _np.int32))
-                backend.cache.free(sid)
-                cold_n += bool(cold)
+            cache = backend.cache
+            sid = "__warm"
+            cache.allocate(sid, 1)
+            try:
+                for t in self._prefill_buckets[name]:
+                    _, k, v, cold = backend.prefill(
+                        _np.zeros(t, dtype=_np.int32), 1)
+                    cache.write_prefill(sid, k, v, 1)
+                    cold_n += bool(cold)
+                for b in entry.buckets:
+                    tables = _np.stack(
+                        [cache.block_table(
+                            sid, backend.max_blocks_per_seq)] * b)
+                    _, k, v, cold = backend.decode(
+                        _np.zeros(b, _np.int32), _np.zeros(b, _np.int32),
+                        tables, _np.ones(b, _np.int32))
+                    cache.write_tokens([sid], [0], k, v)
+                    cold_n += bool(cold)
+            finally:
+                cache.free(sid)
         if cold_n and _metrics.metrics_enabled():
             lane.m_compiles.inc(cold_n)
         return cold_n
@@ -824,8 +912,16 @@ class GenerationScheduler(object):
         logits, k, v, cold = out
         if cold and _metrics.metrics_enabled():
             lane.m_compiles.inc()
-        # cache writes only after the dispatch succeeded
-        backend.cache.write_prefill(seq_id, k, v)
+        # the pool write follows the dispatch that succeeded and targets
+        # only this sequence's own reserved slots
+        try:
+            backend.moved("prefill", h2d=backend.cache.write_prefill(
+                seq_id, k, v, t))
+        except Exception as exc:
+            backend.cache.free(seq_id)
+            if isinstance(exc, CachePoolLostError):
+                self._fail_live(lane, exc)
+            raise
         seq = _Sequence(req, seq_id, backend)
         seq.length = t
         if resume is None:
@@ -842,6 +938,13 @@ class GenerationScheduler(object):
         lane.active.append(seq)
         if _metrics.metrics_enabled():
             lane.m_prefill.observe(time.monotonic() - t0, req.trace)
+
+    @staticmethod
+    def _fail_live(lane, error):
+        """Fail every live sequence of ``lane`` (their pages are gone);
+        the next ``_retire`` frees their blocks."""
+        for seq in lane.active:
+            seq.req._fail(error)
 
     def _decode_step(self, name, lane, backend):
         """ONE iteration-level decode step over every live sequence,
@@ -898,6 +1001,22 @@ class GenerationScheduler(object):
                 seq.req._fail(err)
             return
         logits, k_step, v_step, cold = out
+        # the step succeeded for the whole batch: NOW write its K/V, one
+        # donated device write into the live rows' own slots (the
+        # bucket's pad rows write nowhere) — a dropped or retried
+        # dispatch above never touched the pool
+        try:
+            backend.moved("decode", h2d=backend.cache.write_tokens(
+                [s.seq_id for s in live], positions[:n], k_step, v_step))
+        except Exception as exc:   # noqa: BLE001 - fault path
+            # the step's K/V are lost (and after CachePoolLostError the
+            # whole pool, rebuilt zeroed): no live sequence can go on
+            if _metrics.metrics_enabled():
+                lane.m_errors.inc()
+            self._fail_live(lane, exc if isinstance(exc, MXNetError) else
+                            MXNetError("kv cache write failed: %s" % exc))
+            return
+        tokens_out = logits[:n].argmax(axis=1)
         now = time.monotonic()
         lane.steps += 1
         lane.rows += n
@@ -908,14 +1027,9 @@ class GenerationScheduler(object):
             lane.m_occ.set(n / float(bucket))
             if cold:
                 lane.m_compiles.inc()
-        # the step succeeded for the whole batch: NOW write K/V — a
-        # retried/failed dispatch above never touched the pool, so no
-        # other sequence's blocks can be corrupted by a fault here
         for i, seq in enumerate(live):
-            backend.cache.write_token(seq.seq_id, seq.length,
-                                      k_step[:, i], v_step[:, i])
             seq.length += 1
-            tok = int(_np.argmax(logits[i]))
+            tok = int(tokens_out[i])
             seq.req._push(tok)
             seq.last_token = tok
             seq.new_tokens += 1

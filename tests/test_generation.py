@@ -29,7 +29,9 @@ from mxnet_tpu import chaos, serving
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.contrib.quantization import quantize_weight_int8
 from mxnet_tpu.models import transformer as tfm
-from mxnet_tpu.ops.kv_cache import CacheExhaustedError, PagedKVCache
+from mxnet_tpu.ops import kv_cache
+from mxnet_tpu.ops.kv_cache import (CacheExhaustedError, CachePoolLostError,
+                                    PagedKVCache)
 
 VOCAB, SEQ_LEN, EMBED, HEADS, LAYERS = 64, 48, 16, 2, 2
 
@@ -103,7 +105,7 @@ def test_decode_equals_full_forward_to_the_last_bits(lm):
     assert _row_ulps(pref_logits, ref_logits[0]) <= 8, \
         "prefill logits differ from full forward"
     be.cache.allocate("s", prompt.size + steps)
-    be.cache.write_prefill("s", k, v)
+    be.cache.write_prefill("s", k, v, prompt.size)
     generated = [int(np.argmax(pref_logits))]
     length = int(prompt.size)
     for t in range(1, steps):
@@ -114,7 +116,7 @@ def test_decode_equals_full_forward_to_the_last_bits(lm):
             tables, np.array([length + 1], np.int32))
         assert _row_ulps(logits[0], ref_logits[t]) <= 8, \
             "decode step %d logits differ from full forward" % t
-        be.cache.write_token("s", length, ks[:, 0], vs[:, 0])
+        be.cache.write_tokens(["s"], [length], ks, vs)
         length += 1
         generated.append(int(np.argmax(logits[0])))
     assert generated == toks[len(prompt):]
@@ -177,14 +179,13 @@ def test_int8_head_decode(lm):
     fp = _backend(lm)
     logits, k, v, _ = fp.prefill(np.pad(prompt, (0, 8 - 4)), 4)
     fp.cache.allocate("s", 10)
-    fp.cache.write_prefill("s", k, v)
+    fp.cache.write_prefill("s", k, v, 4)
     tables = fp.cache.block_table("s", fp.max_blocks_per_seq)[None]
     ref, _, _, _ = fp.decode(np.array([out[0]], np.int32),
                              np.array([4], np.int32), tables,
                              np.array([5], np.int32))
-    q8 = be.cache  # int8 backend: replay the same step
-    be.cache.allocate("s", 10)
-    be.cache.write_prefill("s", k, v)
+    be.cache.allocate("s", 10)     # int8 backend: replay the same step
+    be.cache.write_prefill("s", k, v, 4)
     tables8 = be.cache.block_table("s", be.max_blocks_per_seq)[None]
     got, _, _, _ = be.decode(np.array([out[0]], np.int32),
                              np.array([4], np.int32), tables8,
@@ -192,6 +193,184 @@ def test_int8_head_decode(lm):
     scale = float(be.params["pred_scale"])
     # error budget: weight rounding (scale/2) times the activation l1
     assert np.abs(got[0] - ref[0]).max() < scale * EMBED
+    sched.close()
+
+
+# ------------------------------------------------------- resident state
+
+def _pools(cache):
+    return np.array(cache.k_pages), np.array(cache.v_pages)
+
+
+def test_decode_pad_rows_write_nowhere(lm):
+    """A decode step at bucket 4 with ONE live row, while another
+    sequence holds block 0 (where a pad row's all-zero table and
+    position 0 point): after the step's write every slot of both pools
+    but the live row's own is bit-identical."""
+    be = _backend(lm)
+    rng = np.random.RandomState(5)
+    be.cache.allocate("other", 4)
+    assert be.cache.block_table("other", 1)[0] == 0
+    _, k, v, _ = be.prefill(rng.randint(0, VOCAB, 8).astype(np.int32), 4)
+    be.cache.write_prefill("other", k, v, 4)
+    be.cache.allocate("live", 8)
+    _, k, v, _ = be.prefill(rng.randint(0, VOCAB, 8).astype(np.int32), 5)
+    be.cache.write_prefill("live", k, v, 5)
+    k0, v0 = _pools(be.cache)
+
+    tables = np.zeros((4, be.max_blocks_per_seq), np.int32)
+    tables[0] = be.cache.block_table("live", be.max_blocks_per_seq)
+    positions = np.array([5, 0, 0, 0], np.int32)
+    _, ks, vs, _ = be.decode(np.array([7, 0, 0, 0], np.int32), positions,
+                             tables, np.array([6, 1, 1, 1], np.int32))
+    be.cache.write_tokens(["live"], [5], ks, vs)
+    k1, v1 = _pools(be.cache)
+    blk, off = tables[0][5 // 4], 5 % 4
+    for before, after, step in ((k0, k1, ks), (v0, v1, vs)):
+        assert np.array_equal(after[:, blk, off], np.asarray(step)[:, 0])
+        changed = np.argwhere((before != after).any(axis=(0, 3)))
+        assert changed.tolist() == [[blk, off]], \
+            "a pad row's K/V reached the pool"
+    assert be.cache.length("live") == 6 and be.cache.length("other") == 4
+
+
+def test_prefill_writes_length_positions_and_no_more(lm):
+    """A prefill at bucket 8 with ``length`` 5 writes five positions of
+    the sequence's blocks and no sixth."""
+    be = _backend(lm)
+    be.cache.allocate("s", 8)                   # two blocks of 4
+    _, k, v, _ = be.prefill(np.arange(1, 9, dtype=np.int32), 5)
+    assert k.shape == (LAYERS, 8, EMBED)   # rows of heads * dim
+    be.cache.write_prefill("s", k, v, 5)
+    table = be.cache.block_table("s", 2)
+    for pool, src in zip(_pools(be.cache), (k, v)):
+        rows = pool[:, table].reshape(LAYERS, 8, EMBED)
+        assert np.array_equal(rows[:, :5], np.asarray(src)[:, :5])
+        assert not rows[:, 5:].any(), "a pad position was written"
+        others = np.delete(pool, table, axis=1)
+        assert not others.any(), "another sequence's block was written"
+    assert be.cache.length("s") == 5
+    with pytest.raises(MXNetError):             # beyond the allocation
+        be.cache.write_prefill("s", np.zeros((LAYERS, 16, EMBED)),
+                               np.zeros((LAYERS, 16, EMBED)), 9)
+    with pytest.raises(MXNetError):
+        be.cache.write_tokens(["s"], [8], k[:, :1], v[:, :1])
+
+
+def test_everything_a_call_reads_is_resident(lm):
+    """After a scheduler run every leaf of ``backend.params`` and both
+    pools are device arrays, and the benchmark's own expression for the
+    host bytes a decode call stages gives 0."""
+    import jax
+
+    for int8 in (False, True):
+        sched, be = _scheduler(lm, int8_head=int8)
+        assert len(sched.generate("lm", [3, 9, 1, 7],
+                                  max_new_tokens=6)) == 6
+        sched.close()
+        assert isinstance(be.params, dict) and "pred_weight" in be.params
+        for name, leaf in be.params.items():
+            assert isinstance(leaf, jax.Array), name
+        assert isinstance(be.cache.k_pages, jax.Array)
+        assert isinstance(be.cache.v_pages, jax.Array)
+        staged = sum(a.nbytes for a in list(be.params.values())
+                     + [be.cache.k_pages, be.cache.v_pages]
+                     if isinstance(a, np.ndarray))
+        assert staged == 0
+
+
+def test_pool_writes_alias_the_pool(lm):
+    """Donation took: the compiled write program (the one program that
+    has the pool among its outputs, at a prefill and at a decode shape)
+    aliases both pools to its outputs, so the pool never exists twice;
+    the prefill and decode programs have no pool-shaped output at all.
+    Checked on the lowered programs, not by timing."""
+    import jax
+
+    be = _backend(lm)
+    cache = be.cache
+    pool = jax.ShapeDtypeStruct(cache.k_pages.shape, cache.k_pages.dtype)
+    for rows in (8, 4):            # a prefill bucket, a decode bucket
+        kv = jax.ShapeDtypeStruct((LAYERS, rows, EMBED), np.float32)
+        idx = jax.ShapeDtypeStruct((rows,), np.int32)
+        text = kv_cache._write_pages.lower(
+            pool, pool, kv, kv, idx, idx).compile().as_text()
+        header = next(l for l in text.splitlines()
+                      if l.startswith("HloModule"))
+        assert "input_output_alias={ {0}: (0, {}, may-alias), " \
+               "{1}: (1, {}, may-alias) }" in header, header
+    # the dispatches themselves never hold a second pool: donated
+    # buffers are gone after a write, and the pool is re-bound
+    old_k, old_v = cache.k_pages, cache.v_pages
+    cache.allocate("s", 8)
+    _, k, v, _ = be.prefill(np.arange(1, 9, dtype=np.int32), 8)
+    for out in jax.tree_util.tree_leaves((k, v)):
+        assert out.shape != cache.k_pages.shape
+    cache.write_prefill("s", k, v, 8)
+    assert old_k.is_deleted() and old_v.is_deleted()
+    assert not cache.k_pages.is_deleted()
+
+
+def test_transfer_counters_read_logits_out_and_kilobytes_in(lm):
+    """``generation_*_bytes_total``: a decode call at bucket ``B`` copies
+    ``B x V x 4`` bytes back and hands over only ids, positions, tables,
+    lengths and slot indices; a numpy array slipping back among the
+    weights shows at once."""
+    from mxnet_tpu.observability import metrics as om
+
+    be = _backend(lm, model="bytes")
+    h2d = om.REGISTRY.get("generation_host_to_device_bytes_total")
+    d2h = om.REGISTRY.get("generation_device_to_host_bytes_total")
+    be.cache.allocate("s", 16)
+    _, k, v, _ = be.prefill(np.arange(1, 9, dtype=np.int32), 6)
+    assert d2h.labels("bytes", "prefill").value == VOCAB * 4
+    assert h2d.labels("bytes", "prefill").value == 8 * 4 + 4
+    be.cache.write_prefill("s", k, v, 6)
+    bucket = 4
+    tables = np.zeros((bucket, be.max_blocks_per_seq), np.int32)
+    tables[0] = be.cache.block_table("s", be.max_blocks_per_seq)
+    call = (np.zeros(bucket, np.int32), np.array([6, 0, 0, 0], np.int32),
+            tables, np.array([7, 1, 1, 1], np.int32))
+    be.decode(*call)
+    assert d2h.labels("bytes", "decode").value == bucket * VOCAB * 4
+    per_call = bucket * 4 * (3 + be.max_blocks_per_seq)
+    assert h2d.labels("bytes", "decode").value == per_call
+    be.params["pred_bias"] = np.asarray(be.params["pred_bias"])
+    be.decode(*call)
+    assert h2d.labels("bytes", "decode").value \
+        == 2 * per_call + VOCAB * 4
+
+
+def test_lost_pool_fails_live_sequences_and_serves_again(lm, monkeypatch):
+    """A pool write that fails AFTER its buffers were donated is not
+    retried on the consumed buffers: the cache rebuilds a zeroed pool,
+    the lane fails its live sequences, frees their blocks and goes on
+    serving."""
+    sched, be = _scheduler(lm)
+    clean = sched.generate("lm", [1, 2, 3], max_new_tokens=6)
+    real = kv_cache._write_pages
+    calls = {"n": 0}
+
+    def consumed(k_pages, v_pages, *rest):
+        calls["n"] += 1
+        if calls["n"] == 3:           # a decode write, mid-generation
+            k_pages.delete()
+            v_pages.delete()
+            raise RuntimeError("device fault after donation")
+        return real(k_pages, v_pages, *rest)
+
+    monkeypatch.setattr(kv_cache, "_write_pages", consumed)
+    req = sched.submit("lm", np.array([1, 2, 3], np.int32),
+                       max_new_tokens=6)
+    with pytest.raises(CachePoolLostError):
+        req.result(timeout=30)
+    deadline = time.monotonic() + 10
+    while be.cache.stats()["used"] and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert be.cache.stats()["used"] == 0
+    assert not be.cache.k_pages.is_deleted()
+    assert not np.array(be.cache.k_pages).any()
+    assert sched.generate("lm", [1, 2, 3], max_new_tokens=6) == clean
     sched.close()
 
 

@@ -9,7 +9,8 @@ ledger, the KV-block economy, and OOM-proximity alerting.
   within tolerance (the ``wire_reconciles`` contract).
 - **KV-block economy**: occupancy/headroom/fragmentation gauges,
   alloc/free/exhaustion counters, the blocks-per-session histogram,
-  and the pool bytes booked under ``kv_cache{device=host}``.
+  and the pool bytes booked under ``kv_cache{device=all}`` (the
+  pools are live device arrays).
 - **Alerting**: a headroom squeeze fires ``oom_proximity`` exactly
   once per edge with exactly ONE flight bundle whose manifest names
   the pool ledger and the top-K largest live buffers;
@@ -187,7 +188,9 @@ def test_kv_cache_books_pool_and_economy_gauges():
     cache = PagedKVCache(num_layers=1, num_heads=2, head_dim=4,
                          block_size=4, num_blocks=8, model="eco")
     pool_b = cache.k_pages.nbytes + cache.v_pages.nbytes
-    assert _pool_bytes("kv_cache", "host") == pool_b
+    # the pools are live device arrays: the row the reconcile gate sums
+    assert _pool_bytes("kv_cache", "all") == pool_b
+    assert _pool_bytes("kv_cache", "host") == 0
     assert cache.stats()["pool_bytes"] == pool_b
     cache.allocate("a", 12)                  # 3 of 8 blocks
     reg = om.REGISTRY
@@ -211,12 +214,12 @@ def test_kv_cache_books_pool_and_economy_gauges():
 def test_kv_cache_collection_untags_the_pool():
     cache = PagedKVCache(num_layers=1, num_heads=1, head_dim=2,
                          block_size=2, num_blocks=4, model="tmp")
-    assert _pool_bytes("kv_cache", "host") > 0
+    assert _pool_bytes("kv_cache", "all") > 0
     del cache
     import gc
 
     gc.collect()
-    assert _pool_bytes("kv_cache", "host") == 0
+    assert _pool_bytes("kv_cache", "all") == 0
 
 
 # ----------------------------------------------------------------- alerting

@@ -131,8 +131,6 @@ def phase_fit(ckpt_dir):
 
 def phase_serving():
     """Generation-lane serving run over a paged KV cache."""
-    import jax
-
     from mxnet_tpu import serving
     from mxnet_tpu.models import transformer as tfm
     from mxnet_tpu.observability import metrics as om
@@ -141,10 +139,9 @@ def phase_serving():
     om.reset_metrics()
     cfg = tfm.lm_config(num_classes=128, seq_len=64, num_embed=64,
                         num_heads=4, num_layers=2)
-    # commit the weight tree to the device: the ledger books jax.Array
-    # leaves only, and host-numpy weights would leave both the books and
-    # the live-array truth empty (a vacuous — therefore failing — gate)
-    params = jax.device_put(tfm.init_lm_params(cfg, seed=0))
+    # host-numpy weights, as a checkpoint gives them: the backend places
+    # the tree on the device and books what it placed
+    params = tfm.init_lm_params(cfg, seed=0)
     sched = serving.GenerationScheduler()
     be = serving.LMBackend(params, cfg, block_size=8, num_blocks=32)
     sched.register("lm", be, decode_buckets=[1, 2],
@@ -164,9 +161,9 @@ def phase_serving():
           % rep["pools"].get("params", {}).get("all", 0),
           "params pool is empty — the LMBackend seam did not tag")
     check("serving",
-          rep["pools"].get("kv_cache", {}).get("host", 0) > 0,
-          "block pools booked %d B into kv_cache{device=host}"
-          % rep["pools"].get("kv_cache", {}).get("host", 0),
+          rep["pools"].get("kv_cache", {}).get("all", 0) > 0,
+          "block pools booked %d B into kv_cache{device=all}"
+          % rep["pools"].get("kv_cache", {}).get("all", 0),
           "kv_cache pool is empty — the PagedKVCache seam did not tag")
     reg = om.REGISTRY
     hist = reg.get("serving_kv_blocks_per_session")
