@@ -1,0 +1,41 @@
+"""What the generation lane needs to know of a language model.
+
+:class:`~mxnet_tpu.serving.LMBackend` serves any model that hands it an
+:class:`LMDefinition`: the functions its jitted programs are built from
+and the row its paged cache keeps per token.  The backend, the
+scheduler and the cache's allocator know nothing else of the model.
+"""
+
+import collections
+
+__all__ = ["LMDefinition"]
+
+
+class LMDefinition(collections.namedtuple(
+        "LMDefinition", "cfg forward prefill decode cache_row book "
+                        "prepare")):
+    """One model, as the generation lane serves it.
+
+    - ``cfg``: a dict with at least ``seq_len`` (the context limit the
+      block tables are sized for), ``num_layers`` and ``num_classes``
+      (the vocabulary admission checks token ids against).
+    - ``forward(params, tokens [B, T]) -> logits [B, T, V]``: the whole
+      sequence, no cache.
+    - ``prefill(params, tokens [T], length) -> (logits [V], k_rows,
+      v_rows, counts)``: one padded prompt; the logits after token
+      ``length - 1``; the cache rows ``[L, T, width]`` of every pool of
+      the row (``v_rows`` is ``None`` for a row of one pool).
+    - ``decode(params, tokens [B], positions [B], k_pages, v_pages,
+      block_tables, context_lens) -> (logits [B, V], k_rows [L, B,
+      width], v_rows, counts)``: one token a sequence through the paged
+      pools, read as of before the step.
+    - ``cache_row``: the :class:`~mxnet_tpu.ops.kv_cache.CacheRow`.
+    - ``book(model, counts)``: adds a call's ``counts`` (a small integer
+      vector the program computed, copied back with the logits) to the
+      model's counters; ``None`` where the programs count nothing
+      (their ``counts`` is ``None`` then).
+    - ``prepare(params) -> params``: applied once before the weights are
+      placed (a quantized head); ``None`` for as they are.
+    """
+
+    __slots__ = ()

@@ -307,6 +307,42 @@ def lm_decode_step(params, tokens, positions, k_pages, v_pages,
     return logits[:, 0], jnp.stack(ks), jnp.stack(vs)
 
 
+def lm_definition(cfg, int8_head=False):
+    """This model as :class:`~mxnet_tpu.serving.LMBackend` serves it:
+    float32 key and value rows of ``num_embed`` values, the programs of
+    :func:`lm_prefill` and :func:`lm_decode_step`."""
+    from ..ops.kv_cache import CacheRow
+    from .lm import LMDefinition
+
+    def page_rows(kv):
+        # K/V of one dispatch, [L, 1, T, H, D] (prefill) or [L, B, H, D]
+        # (decode), as the cache's rows [L, N, H * D]: done inside the
+        # dispatch, so that it emits them as they will be written (a
+        # [.., H, 64] output gets a device layout the write re-lays)
+        return kv.reshape(kv.shape[0], -1, kv.shape[-2] * kv.shape[-1])
+
+    def prefill(params, tokens, length):
+        # ``length`` is traced: one program per bucket, whatever the
+        # prompt's real length, and only row ``length - 1`` of the
+        # logits leaves the device
+        logits, k, v = lm_prefill(params, tokens[None], cfg)
+        return logits[0, length - 1], page_rows(k), page_rows(v), None
+
+    def decode(params, tokens, positions, k_pages, v_pages, block_tables,
+               context_lens):
+        logits, k, v = lm_decode_step(
+            params, tokens, positions, k_pages, v_pages, block_tables,
+            context_lens, cfg, int8_head=int8_head)
+        return logits, page_rows(k), page_rows(v), None
+
+    return LMDefinition(
+        cfg=dict(cfg),
+        forward=lambda params, tokens: lm_prefill(params, tokens, cfg)[0],
+        prefill=prefill, decode=decode,
+        cache_row=CacheRow("kv", cfg["num_embed"], np.float32, 2),
+        book=None, prepare=quantize_lm_head if int8_head else None)
+
+
 def quantize_lm_head(params):
     """Opt-in int8 vocab head: stage ``pred_weight`` on the
     ``contrib.quantization`` symmetric int8/127 grid.

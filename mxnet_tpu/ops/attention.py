@@ -29,7 +29,8 @@ from jax import lax
 from .registry import ParamSpec as P, dispatch_variant, register
 
 __all__ = ["flash_attention", "ring_attention", "paged_decode_attention",
-           "stable_causal_attention"]
+           "stable_causal_attention", "latent_prefill_attention",
+           "latent_paged_decode_attention"]
 
 _NEG_INF = -1e30
 # Mosaic tiles the last two block dims as (8 sublanes, 128 lanes); per-row
@@ -197,6 +198,58 @@ def _paged_decode_attention_stock(q, k_step, v_step, k_pages, v_pages,
 
 
 # ----------------------------------------------------------------------
+# latent attention (MLA): expanded prefill, absorbed paged decode
+# ----------------------------------------------------------------------
+
+
+def latent_prefill_attention(q, k, v, sm_scale):
+    """Causal attention of a latent-attention prefill in the expanded
+    form: ``q``/``k`` ``[B, H, T, nope + rope]``, ``v`` ``[B, H, T,
+    v_dim]`` narrower than the keys.  Never holds ``[H, T, T]`` scores
+    where the flash kernel runs (a TPU, T >= 1024); below that, and
+    elsewhere, the exact softmax."""
+    with jax.named_scope("latent_prefill_attention"):
+        return _flash_dispatch(q, k, v, True, float(sm_scale), False)
+
+
+def latent_paged_decode_attention(q, row_step, pages, block_tables,
+                                  context_lens, sm_scale, kv_rank):
+    """One decode step of latent attention in the absorbed form, over
+    the latent pool.
+
+    - ``q`` ``[B, H, W]``: per head ``[q_nope . W_uk | rotated q_rope]``,
+      ``W = kv_rank + rope_dim``: every head reads the same cache row.
+    - ``row_step`` ``[B, W]``: this token's row ``[N(c_kv) | rotated
+      k_rope]`` (written to the pool by the caller after the step).
+    - ``pages`` ``[num_blocks, block_size, W]``: the pool as of before
+      the step; ``block_tables`` ``int32 [B, max_blocks]``;
+      ``context_lens`` ``int32 [B]`` counting the current token.
+
+    Returns ``p . c_kv`` ``[B, H, kv_rank]`` (the caller applies
+    ``W_uv``).  Scores and softmax in float32; the current token enters
+    as a score of its own, so the gathered rows are read as they lie."""
+    with jax.named_scope("latent_decode_attention"):
+        bsz, max_blocks = block_tables.shape
+        kmax = max_blocks * pages.shape[1]
+        rows = pages[block_tables].reshape(bsz, kmax, -1)
+        s = jnp.einsum("bhw,bkw->bhk", q, rows,
+                       preferred_element_type=jnp.float32) * sm_scale
+        pos = lax.broadcasted_iota(jnp.int32, (1, 1, kmax), 2)
+        s = jnp.where(pos < (context_lens - 1)[:, None, None], s, _NEG_INF)
+        s_self = jnp.einsum("bhw,bw->bh", q, row_step,
+                            preferred_element_type=jnp.float32) * sm_scale
+        m = jnp.maximum(jnp.max(s, axis=-1), s_self)
+        p = jnp.exp(s - m[..., None])
+        p_self = jnp.exp(s_self - m)
+        out = jnp.einsum("bhk,bkc->bhc", p.astype(rows.dtype),
+                         rows[..., :kv_rank],
+                         preferred_element_type=jnp.float32)
+        out = out + p_self[..., None] * row_step[:, None, :kv_rank]
+        return (out / (jnp.sum(p, axis=-1) + p_self)[..., None]
+                ).astype(q.dtype)
+
+
+# ----------------------------------------------------------------------
 # Pallas TPU forward kernel
 # ----------------------------------------------------------------------
 
@@ -302,7 +355,7 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q=1024, block_k=2048,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, D = q.shape
-    Tk = k.shape[2]
+    Tk, Dv = k.shape[2], v.shape[3]   # values may be narrower than keys
     block_q = min(block_q, max(8, T))
     if Tk > block_k and Tk % block_k:
         # a ragged key tail adds a second [bq, bk] mask to the causal
@@ -322,7 +375,7 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q=1024, block_k=2048,
         v = jnp.pad(v, pad)
     qf = q.reshape(B * H, Tp, D)
     kf = k.reshape(B * H, Tkp, D)
-    vf = v.reshape(B * H, Tkp, D)
+    vf = v.reshape(B * H, Tkp, Dv)
     n_k = Tkp // block_k
     grid = (B * H, Tp // block_q, n_k)
     kernel = functools.partial(
@@ -332,8 +385,8 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q=1024, block_k=2048,
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
-    out_shape = [jax.ShapeDtypeStruct((B * H, Tp, D), q.dtype)]
-    out_specs = [pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))]
+    out_shape = [jax.ShapeDtypeStruct((B * H, Tp, Dv), q.dtype)]
+    out_specs = [pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0))]
     if return_lse:
         out_shape.append(
             jax.ShapeDtypeStruct((B * H, Tp, _LANE), jnp.float32))
@@ -346,18 +399,18 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q=1024, block_k=2048,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         interpret=interpret,
         **kwargs,
     )(qf, kf, vf)
-    out = res[0].reshape(B, H, Tp, D)[:, :, :T]
+    out = res[0].reshape(B, H, Tp, Dv)[:, :, :T]
     if return_lse:
         return out, res[1][:, :, 0].reshape(B, H, Tp)[:, :, :T]
     return out

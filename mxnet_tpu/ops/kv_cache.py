@@ -50,6 +50,7 @@ allocation delay without filling the pool.
 
 from __future__ import annotations
 
+import collections
 import os
 import threading
 import weakref
@@ -63,8 +64,25 @@ from ..base import MXNetError
 from ..observability import memory as _memory
 from ..observability import metrics as _metrics
 
-__all__ = ["CacheExhaustedError", "CachePoolLostError", "PagedKVCache",
-           "default_block_size", "default_num_blocks"]
+__all__ = ["CacheExhaustedError", "CachePoolLostError", "CacheRow",
+           "PagedKVCache", "default_block_size", "default_num_blocks"]
+
+
+class CacheRow(collections.namedtuple("CacheRow", "kind width dtype pools")):
+    """What one token of one layer leaves in the cache, as the model
+    defines it: ``pools`` rows of ``width`` values of ``dtype``.
+
+    ``("kv", heads * dim, float32, 2)`` is a key row and a value row;
+    ``("latent", kv_rank + rope_dim, bfloat16, 1)`` is one row of a
+    latent-attention model (the compressed key-value vector and the
+    rotated shared key), which has no value pool.  The allocator, the
+    block tables and the donated write are the same for every row."""
+
+    __slots__ = ()
+
+    @property
+    def bytes(self):
+        return self.pools * self.width * np.dtype(self.dtype).itemsize
 
 
 class CacheExhaustedError(MXNetError):
@@ -123,6 +141,10 @@ _M_ALLOCS = _metrics.counter(
 _M_FREES = _metrics.counter(
     "serving_kv_cache_free_blocks_total",
     "Blocks returned to the free list, by model", ["model"])
+_M_ROW_BYTES = _metrics.gauge(
+    "kv_cache_row_bytes",
+    "Bytes one token of one layer takes in the KV cache (every pool of "
+    "its row), by model", ["model"])
 _M_SESS_BLOCKS = _metrics.histogram(
     "serving_kv_blocks_per_session",
     "Blocks one sequence held when it was freed, by model", ["model"],
@@ -130,10 +152,11 @@ _M_SESS_BLOCKS = _metrics.histogram(
 
 
 def _scatter_pages(k_pages, v_pages, k, v, blocks, offsets):
-    """``k``/``v`` ``[L, N, ...]`` (``heads * dim`` values a row) into
-    slot ``(blocks[n], offsets[n])`` of every layer.  A slot whose block
-    id is out of range (the pad rows of a decode bucket, the pad
-    positions of a prefill bucket) is dropped: it writes nowhere."""
+    """``k``/``v`` ``[L, N, ...]`` (one row's values each) into slot
+    ``(blocks[n], offsets[n])`` of every layer.  A slot whose block id
+    is out of range (the pad rows of a decode bucket, the pad positions
+    of a prefill bucket) is dropped: it writes nowhere.  A row of one
+    pool has no ``v_pages`` and no ``v`` (both ``None``)."""
     layers, count = k.shape[:2]
     # the layer is an index like block and offset, so that the indexed
     # axes are the pool's leading ones and a row is written where it
@@ -142,6 +165,8 @@ def _scatter_pages(k_pages, v_pages, k, v, blocks, offsets):
     at = (jnp.arange(layers)[:, None], blocks[None, :], offsets[None, :])
 
     def put(pages, rows):
+        if pages is None:
+            return None
         rows = rows.reshape(layers, count, -1).astype(pages.dtype)
         return pages.at[at].set(rows, mode="drop")
 
@@ -164,8 +189,9 @@ class PagedKVCache(object):
     array is host memory, so there is no second path).
     """
 
-    def __init__(self, num_layers, num_heads, head_dim, block_size=None,
-                 num_blocks=None, dtype=np.float32, model="default"):
+    def __init__(self, num_layers, num_heads=None, head_dim=None,
+                 block_size=None, num_blocks=None, dtype=np.float32,
+                 model="default", row=None):
         self.block_size = int(block_size or default_block_size())
         self.num_blocks = int(num_blocks or default_num_blocks())
         if self.block_size <= 0 or self.num_blocks <= 0:
@@ -173,12 +199,15 @@ class PagedKVCache(object):
                              "num_blocks (got %d/%d)"
                              % (self.block_size, self.num_blocks))
         self.num_layers = int(num_layers)
-        self.num_heads = int(num_heads)
-        self.head_dim = int(head_dim)
+        # the row is the model's to define; heads and head size alone
+        # describe the plain key row + value row
+        self.row = row or CacheRow("kv", int(num_heads) * int(head_dim),
+                                   dtype, 2)
         self.model = model
         self._shape = (self.num_layers, self.num_blocks, self.block_size,
-                       self.num_heads * self.head_dim)
-        self._dtype = np.dtype(dtype)
+                       int(self.row.width))
+        self._dtype = np.dtype(self.row.dtype)
+        _M_ROW_BYTES.labels(model).set(self.row.bytes)
         self._zero_pools()
         self._lock = threading.Lock()
         self._free = list(range(self.num_blocks - 1, -1, -1))
@@ -205,11 +234,13 @@ class PagedKVCache(object):
         # drop the old pair first: two pools never exist at once
         self.k_pages = self.v_pages = None
         self.k_pages = jnp.zeros(self._shape, self._dtype)
-        self.v_pages = jnp.zeros(self._shape, self._dtype)
+        if self.row.pools == 2:
+            self.v_pages = jnp.zeros(self._shape, self._dtype)
 
     @property
     def pool_bytes(self):
-        return 2 * int(np.prod(self._shape)) * self._dtype.itemsize
+        return self.row.pools * int(np.prod(self._shape)) \
+            * self._dtype.itemsize
 
     # -- allocation --------------------------------------------------
 
@@ -311,8 +342,8 @@ class PagedKVCache(object):
             self.k_pages, self.v_pages = _write_pages(
                 self.k_pages, self.v_pages, k, v, blocks, offsets)
         except Exception as exc:
-            if not (self.k_pages.is_deleted()
-                    or self.v_pages.is_deleted()):
+            if not any(p is not None and p.is_deleted()
+                       for p in (self.k_pages, self.v_pages)):
                 raise       # refused before donation: pool untouched
             self._zero_pools()
             self._lengths = dict.fromkeys(self._lengths, 0)

@@ -7,6 +7,16 @@ expert dimension annotated with ``with_sharding_constraint`` — GSPMD then
 inserts the all-to-alls that move tokens from data-sharded to
 expert-sharded layout and back (the scaling-book recipe: annotate, let XLA
 place collectives on ICI).
+
+Serving a large expert model is the other regime (second half of this
+file): **dropless** top-k routing with sigmoid scores, a selection bias
+and a group-limited choice (DeepSeek-V3's ``noaux_tc``), SwiGLU experts,
+and an expert layer that is *told which experts it holds*: it routes
+over all of them and computes its own experts' part of the sum, as one
+member of an expert-parallel deployment does.  Tokens are sorted by
+expert and the products run as grouped matmuls (``jax.lax.ragged_dot``)
+over the held experts only, so no token is dropped at any skew and no
+shape depends on the routing.
 """
 
 from __future__ import annotations
@@ -15,7 +25,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-__all__ = ["moe_ffn", "init_moe_params", "router_top1", "router_topk"]
+from ..observability import metrics as _metrics
+
+__all__ = ["moe_ffn", "init_moe_params", "router_top1", "router_topk",
+           "route_group_limited", "dropless_experts", "swiglu",
+           "book_expert_counts", "EXPERT_COUNTS"]
 
 
 def _route_indexed(logits, capacity, k, renorm=None):
@@ -175,3 +189,175 @@ def moe_ffn(params, x, *, capacity_factor=2.0, expert_axis="expert",
             jax.sharding.NamedSharding(mesh, P(expert_axis, None, None)))
     out = jnp.einsum("tec,ecd->td", combine, out_buf)
     return out.reshape(B, S, d), aux_loss
+
+
+# ----------------------------------------------------------------------
+# dropless routing for serving: sigmoid scores, group-limited top-k
+# ----------------------------------------------------------------------
+
+
+def route_group_limited(logits, bias, *, top_k, n_group=1, topk_group=1,
+                        scale=1.0, normalize=True):
+    """DeepSeek-V3's ``noaux_tc`` choice.  ``logits`` float32 ``[T, E]``
+    over ALL the experts of the model, ``bias`` ``[E]`` (the
+    ``e_score_correction_bias``).  Scores are ``s = sigmoid(logits)``;
+    the choice is made on ``s + bias``: a group of ``E / n_group``
+    neighbouring experts scores the sum of its two largest, the
+    ``topk_group`` best groups are kept, and the ``top_k`` largest among
+    them are chosen.  The gates are ``s`` (without the bias) at the
+    chosen experts, divided by their sum if ``normalize``, times
+    ``scale``.  Returns ``(experts int32 [T, top_k], gates float32 [T,
+    top_k])``; every token keeps all its ``top_k`` experts."""
+    tokens, experts = logits.shape
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    choice = s + bias.astype(jnp.float32)
+    if n_group > 1:
+        grouped = choice.reshape(tokens, n_group, experts // n_group)
+        group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)
+        kept = jax.lax.top_k(group_score, topk_group)[1]
+        keep = jnp.zeros((tokens, n_group), bool).at[
+            jnp.arange(tokens)[:, None], kept].set(True)
+        choice = jnp.where(keep[:, :, None], grouped,
+                           -jnp.inf).reshape(tokens, experts)
+    chosen = jax.lax.top_k(choice, top_k)[1].astype(jnp.int32)
+    gates = jnp.take_along_axis(s, chosen, axis=1)
+    if normalize:
+        gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+    return chosen, gates * scale
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    """``W_down(silu(W_gate x) * W_up x)`` with ``[out, in]`` weights,
+    float32 accumulation, activations kept in ``x``'s dtype."""
+    def dot(a, w):
+        return jnp.einsum("tc,fc->tf", a, w,
+                          preferred_element_type=jnp.float32)
+
+    h = (jax.nn.silu(dot(x, w_gate)) * dot(x, w_up)).astype(x.dtype)
+    return dot(h, w_down).astype(x.dtype)
+
+
+# what an expert layer counts, in this order, as one int32 vector a
+# call (summed over its expert layers inside the program, so that it
+# rides back with the logits)
+EXPERT_COUNTS = ("moe_assignments_total", "moe_local_assignments_total",
+                 "moe_local_experts_hit_total", "moe_layer_steps_total")
+_M_EXPERT = [_metrics.counter(name, text + ", by model", ["model"])
+             for name, text in zip(EXPERT_COUNTS, (
+                 "Token-expert pairs routed, over all the model's experts",
+                 "Token-expert pairs that fell on experts held here",
+                 "Held experts that got at least one token, summed over "
+                 "expert layers and calls",
+                 "Expert layers run, summed over calls (the divisor of the "
+                 "other three)"))]
+
+
+def book_expert_counts(model, counts):
+    """Add one call's :data:`EXPERT_COUNTS` vector to the counters."""
+    for family, value in zip(_M_EXPERT, counts):
+        family.labels(model).inc(int(value))
+
+
+# a product of this many rows or fewer with an expert's weights is bound
+# by reading the weights (a v5e does 240 operations in the time it reads
+# a byte; 128 leaves room for a product that misses the peak)
+EVERY_ROW_LIMIT = 128
+
+
+def few_rows_hit_most(tokens, k, n_experts):
+    """True where a call of ``tokens`` rows, each choosing ``k`` of
+    ``n_experts``, is small enough for an expert's product to cost its
+    weights' read whatever the rows (:data:`EVERY_ROW_LIMIT`) and still
+    expected to reach more than half the experts: then
+    :func:`dropless_experts` does best with ``every_row``."""
+    reached = 1.0 - (1.0 - k / float(n_experts)) ** tokens
+    return tokens <= EVERY_ROW_LIMIT and reached > 0.5
+
+
+def dropless_experts(x, chosen, gates, w_gate, w_up, w_down, held,
+                     valid=None, expert_axis=None, every_row=False):
+    """The routed part of an expert layer, for the experts held here.
+
+    ``x`` ``[T, d]``; ``chosen``/``gates`` ``[T, k]`` from the router,
+    over all the model's experts; ``w_gate``/``w_up`` ``[G, d, h]`` and
+    ``w_down`` ``[G, h, d]`` the SwiGLU weights of the ``G`` held
+    experts, ids ``held[0] .. held[0] + G - 1``; ``valid`` bool ``[T]``
+    marks rows that are tokens (a bucket's pad rows are routed
+    nowhere).  Returns ``(y [T, d], counts)``: ``y`` is the sum over the
+    chosen experts *that are held here* of ``gate * E(x)``, and what the
+    absent experts would add is left out: summed over the shares of an
+    expert-parallel deployment it is the whole layer's routed part.
+    ``counts`` is the layer's :data:`EXPERT_COUNTS`.
+
+    Inside ``shard_map`` over ``expert_axis`` every member passes its
+    own weights, ``held[0]`` is taken from its place on the axis and the
+    parts are summed there (``psum``): the sharded layer.  On one chip
+    the same code runs without that exchange.
+
+    No token is dropped: the ``T * k`` pairs are sorted by expert (pairs
+    of absent experts last, in a group nothing is computed for) and the
+    three products are grouped matmuls over the sorted rows.  With
+    ``every_row`` (a decode step: :func:`few_rows_hit_most`) every held
+    expert is computed over every row instead and a row keeps the
+    outputs of those it chose: the same sum, in three batched products
+    that read each held expert once whatever the choice, so that a step
+    takes the same time whichever experts its tokens hit."""
+    tokens, k = chosen.shape
+    first, count = held[0], int(w_gate.shape[0])
+    if expert_axis is not None:
+        first = jax.lax.axis_index(expert_axis) * count
+    local = (chosen >= first) & (chosen < first + count)
+    routed = jnp.ones_like(local) if valid is None \
+        else jnp.broadcast_to(valid[:, None], local.shape)
+    local = local & routed
+    key = jnp.where(local, chosen - first, count)
+    sizes = jnp.zeros(count + 1, jnp.int32).at[key.reshape(-1)].add(
+        1)[:count]
+    if every_row:
+        y = _every_row(x, key, gates, w_gate, w_up, w_down)
+    else:
+        y = _grouped_rows(x, key, local, gates, sizes, w_gate, w_up, w_down)
+    counts = jnp.stack([routed.sum(), local.sum(), (sizes > 0).sum(),
+                        jnp.int32(1)]).astype(jnp.int32)
+    if expert_axis is not None:
+        y = jax.lax.psum(y, expert_axis)
+    return y, counts
+
+
+def _grouped_rows(x, key, local, gates, sizes, w_gate, w_up, w_down):
+    """The held experts' gated sum as grouped products over the pairs
+    sorted by ``key`` (an expert's place here; ``G`` for a pair that is
+    not computed here)."""
+    tokens, k = key.shape
+    order = jnp.argsort(key.reshape(-1), stable=True)
+
+    def grouped(a, w, out):
+        return jax.lax.ragged_dot(a, w, sizes, preferred_element_type=out)
+
+    rows = x[order // k]
+    h = (jax.nn.silu(grouped(rows, w_gate, jnp.float32))
+         * grouped(rows, w_up, jnp.float32)).astype(x.dtype)
+    y = grouped(h, w_down, x.dtype)
+    # back to (token, choice) order; the rows of absent experts were
+    # never computed and count as nothing
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(order.size))
+    y = jnp.where(local[:, :, None], y[back].reshape(tokens, k, -1), 0)
+    return jnp.einsum("tkd,tk->td", y, gates.astype(x.dtype),
+                      preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def _every_row(x, key, gates, w_gate, w_up, w_down):
+    """The same sum with every held expert computed over every row: a
+    row's gate for an expert it did not choose is 0."""
+    count = w_gate.shape[0]
+    gate_of = jnp.where(key[:, :, None] == jnp.arange(count),
+                        gates[:, :, None], 0).sum(1)             # [T, G]
+
+    def every(spec, a, w, out):
+        return jnp.einsum(spec, a, w, preferred_element_type=out)
+
+    h = (jax.nn.silu(every("td,gdh->gth", x, w_gate, jnp.float32))
+         * every("td,gdh->gth", x, w_up, jnp.float32)).astype(x.dtype)
+    y = every("gth,ghd->gtd", h, w_down, x.dtype)
+    return every("gtd,tg->td", y, gate_of.astype(x.dtype),
+                 jnp.float32).astype(x.dtype)

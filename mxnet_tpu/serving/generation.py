@@ -52,13 +52,17 @@ site (name ``<model>:prefill:<bucket>``).
 
 Streaming: each :class:`GenerationRequest` is a token queue —
 :meth:`GenerationRequest.tokens` yields ids as the loop produces them
-(the front-end turns this into chunked HTTP on ``/v1/generate``), and
+(the front-end turns this into chunked HTTP on ``/v1/generate``; a
+first token is handed over at once, a decode step's tokens once the
+loop's next device call is on its way, so their readers run beside the
+device), and
 :meth:`GenerationRequest.cancel` (client disconnect) retires the
 sequence and frees its blocks at the next iteration.
 """
 
 from __future__ import annotations
 
+import collections as _collections
 import os
 import queue as _queue
 import threading
@@ -69,7 +73,6 @@ import numpy as _np
 
 from .. import chaos
 from ..base import MXNetError
-from ..models import transformer as _tfm
 from ..observability import memory as _memory
 from ..observability import metrics as _metrics
 from ..observability import tracing as _tracing
@@ -150,7 +153,7 @@ class GenerationRequest(object):
     __slots__ = ("model", "prompt", "max_new_tokens", "eos_id", "deadline",
                  "tenant", "t_admit", "trace", "generated", "error",
                  "finish_reason", "latency_s", "first_token_s", "seq_id",
-                 "_tokens", "_event", "_cancelled", "_h_tenant",
+                 "_tokens", "_held", "_event", "_cancelled", "_h_tenant",
                  "_h_tokens")
 
     def __init__(self, model, prompt, max_new_tokens, eos_id, deadline,
@@ -170,6 +173,7 @@ class GenerationRequest(object):
         self.first_token_s = None
         self.seq_id = None
         self._tokens = _queue.Queue()
+        self._held = _collections.deque()
         self._event = threading.Event()
         self._cancelled = False
         # pre-resolved per-tenant counter handles (attached at submit,
@@ -194,16 +198,32 @@ class GenerationRequest(object):
     # -- loop side ---------------------------------------------------
 
     def _push(self, token):
+        """Record a token; :meth:`_deliver` hands it to the stream."""
         if self.first_token_s is None:
             self.first_token_s = time.monotonic() - self.t_admit
         self.generated.append(int(token))
-        self._tokens.put(int(token))
+        self._held.append(int(token))
+
+    def _deliver(self):
+        """Hand the recorded tokens to the stream: each wakes the thread
+        that reads it.  The loop holds a decode step's tokens (one a
+        request) until its next device call is on the way, so the
+        readers run beside the device and not between two of its calls.
+        Pops are atomic: a kill racing the loop cannot hand a token over
+        twice."""
+        while True:
+            try:
+                token = self._held.popleft()
+            except IndexError:
+                return
+            self._tokens.put(token)
 
     def _finish(self, reason):
         if self._event.is_set():   # idempotent: kill vs loop race
             return
         self.finish_reason = reason
         self.latency_s = time.monotonic() - self.t_admit
+        self._deliver()
         self._tokens.put(_DONE)
         self._event.set()
 
@@ -213,6 +233,7 @@ class GenerationRequest(object):
         self.error = error
         self.finish_reason = "error"
         self.latency_s = time.monotonic() - self.t_admit
+        self._deliver()
         self._tokens.put(_DONE)
         self._event.set()
 
@@ -240,17 +261,16 @@ class GenerationRequest(object):
         return list(self.generated)
 
 
-def _page_rows(kv):
-    """K/V of one dispatch, ``[L, 1, T, H, D]`` (prefill) or ``[L, B, H,
-    D]`` (decode), as the cache's rows: ``[L, N, H * D]``.  Done inside
-    the dispatch, so that it emits them as they will be written (a
-    ``[.., H, 64]`` output gets a device layout the write re-lays)."""
-    return kv.reshape(kv.shape[0], -1, kv.shape[-2] * kv.shape[-1])
-
-
 class LMBackend(Backend):
-    """Generative serving backend: transformer params + paged KV cache
+    """Generative serving backend: a model's params + paged KV cache
     + shape-keyed jit caches for prefill and decode.
+
+    The model is whatever ``definition`` (an
+    :class:`~mxnet_tpu.models.lm.LMDefinition`) says: the functions the
+    prefill and decode programs are built from, and the row its cache
+    keeps per token.  Without one, ``cfg`` is a
+    :func:`~mxnet_tpu.models.transformer.lm_config` and the model is
+    that transformer.
 
     Registers through the stock :class:`~.registry.ModelRegistry` (it IS
     a :class:`~.registry.Backend`), so ``swap``'s ``dispatch_lock``
@@ -277,19 +297,27 @@ class LMBackend(Backend):
     stays on the parity contract.
     """
 
-    def __init__(self, params, cfg, block_size=None, num_blocks=None,
-                 int8_head=False, model="lm"):
+    def __init__(self, params, cfg=None, block_size=None, num_blocks=None,
+                 int8_head=False, model="lm", definition=None):
         import jax
 
-        self.cfg = dict(cfg)
+        if definition is None:
+            from ..models import transformer
+
+            definition = transformer.lm_definition(cfg, int8_head)
+        elif int8_head:
+            raise MXNetError("int8_head is the default transformer's; a "
+                             "definition brings its own `prepare`")
+        self.definition = definition
+        self.model = model
+        self.cfg = dict(definition.cfg)
         self.int8_head = bool(int8_head)
         self.params = jax.device_put(
-            _tfm.quantize_lm_head(params) if int8_head else dict(params))
+            definition.prepare(params) if definition.prepare
+            else dict(params))
         self.input_shapes = {"data": (self.cfg["seq_len"],)}
         self.cache = PagedKVCache(
-            num_layers=self.cfg["num_layers"],
-            num_heads=self.cfg["num_heads"],
-            head_dim=self.cfg["num_embed"] // self.cfg["num_heads"],
+            num_layers=self.cfg["num_layers"], row=definition.cache_row,
             block_size=block_size, num_blocks=num_blocks, model=model)
         # every sequence gets a fixed-width block table: the decode jit
         # signature depends only on the batch bucket, never on how long
@@ -298,6 +326,11 @@ class LMBackend(Backend):
                                     // self.cache.block_size)
         self._jits = {}
         self._jit_lock = threading.Lock()
+        # what the caller wants done while the device runs a prefill or
+        # decode call: called once the program is on its way and before
+        # the call waits for the logits (the generation loop hands the
+        # last step's tokens to their streams here)
+        self.beside_device = None
         self._moved = {(phase, way): fam.labels(model, phase)
                        for phase in ("prefill", "decode")
                        for way, fam in (("h2d", _M_H2D), ("d2h", _M_D2H))}
@@ -307,15 +340,16 @@ class LMBackend(Backend):
         _memory.tag_tree("params", id(self), self.params)
         _weakref.finalize(self, _memory.untag, "params", id(self))
 
-    def _jit(self, key, build):
-        """Shape-keyed jit cache; returns (fn, cold)."""
+    def _jit(self, key, program):
+        """Shape-keyed jit cache of the definition's ``program``;
+        returns (fn, cold)."""
         with self._jit_lock:
             fn = self._jits.get(key)
             cold = fn is None
             if cold:
                 import jax
 
-                fn = jax.jit(build())
+                fn = jax.jit(program)
                 self._jits[key] = fn
         return fn, cold
 
@@ -331,43 +365,34 @@ class LMBackend(Backend):
         """Full-sequence forward (no cache) — the classifier-lane
         protocol, and the bench's naive re-prefill baseline."""
         tokens = _np.asarray(batch["data"], dtype=_np.int32)
-        fn, cold = self._jit(("infer",) + tokens.shape, self._build_infer)
+        fn, cold = self._jit(("infer",) + tokens.shape,
+                             self.definition.forward)
         return [_np.asarray(fn(self.params, tokens))], cold
 
-    def _build_infer(self):
-        cfg = self.cfg
-
-        def run(params, tokens):
-            return _tfm.lm_prefill(params, tokens, cfg)[0]
-        return run
-
-    def _build_prefill(self):
-        cfg = self.cfg
-
-        def run(params, tokens, length):
-            # ``length`` is traced: one program per bucket, whatever the
-            # prompt's real length, and only row ``length - 1`` of the
-            # logits leaves the device
-            logits, k, v = _tfm.lm_prefill(params, tokens[None], cfg)
-            return logits[0, length - 1], _page_rows(k), _page_rows(v)
-        return run
-
-    def _build_decode(self):
-        cfg, int8 = self.cfg, self.int8_head
-
-        def run(params, tokens, positions, k_pages, v_pages,
-                block_tables, context_lens):
-            logits, k, v = _tfm.lm_decode_step(
-                params, tokens, positions, k_pages, v_pages,
-                block_tables, context_lens, cfg, int8_head=int8)
-            return logits, _page_rows(k), _page_rows(v)
-        return run
+    def _fetch(self, phase, args, logits, counts):
+        """The call's logits as a host copy (in ordinary memory:
+        np.asarray of a device array is a view of the runtime's transfer
+        buffer); what the program counted rides back beside them and is
+        booked; the bytes moved either way are booked too."""
+        if counts is not None:
+            counts.copy_to_host_async()
+        if self.beside_device is not None:
+            self.beside_device()
+        logits = _np.array(logits)
+        d2h = logits.nbytes
+        if counts is not None:
+            counts = _np.asarray(counts)
+            d2h += counts.nbytes
+            self.definition.book(self.model, counts)
+        self.moved(phase, _host_nbytes((*self.params.values(), *args)), d2h)
+        return logits
 
     # -- generation entry points -------------------------------------
 
     def prefill(self, tokens, length):
         """One prompt (``tokens`` int32 ``[T_bucket]`` padded, ``length``
-        real) → ``(last_logits [V], k [L, T_bucket, H * D], v, cold)``.
+        real) → ``(last_logits [V], k [L, T_bucket, row width], v,
+        cold)``; ``v`` is ``None`` where the cache's row has one pool.
         The logits are a host copy; ``k``/``v`` are device arrays over
         the whole bucket, for ``cache.write_prefill(seq, k, v, length)``
         (which drops the pad positions); ``cold`` reports the jit-cache
@@ -375,19 +400,14 @@ class LMBackend(Backend):
         tokens = _np.asarray(tokens, dtype=_np.int32)
         args = (tokens, _np.asarray(length, dtype=_np.int32))
         fn, cold = self._jit(("prefill",) + tokens.shape,
-                             self._build_prefill)
-        logits, k, v = fn(self.params, *args)
-        # a copy in ordinary host memory: np.asarray of a device array
-        # is a view of the runtime's transfer buffer
-        logits = _np.array(logits)
-        self.moved("prefill", _host_nbytes((*self.params.values(), *args)),
-                   logits.nbytes)
-        return logits, k, v, cold
+                             self.definition.prefill)
+        logits, k, v, counts = fn(self.params, *args)
+        return self._fetch("prefill", args, logits, counts), k, v, cold
 
     def decode(self, tokens, positions, block_tables, context_lens):
         """One decode step over a padded batch.  Returns ``(logits
-        [B, V], k_step [L, B, H * D], v_step, cold)``: the logits a host
-        copy, ``k_step``/``v_step`` device arrays the caller hands to
+        [B, V], k_step [L, B, row width], v_step, cold)``: the logits a
+        host copy, ``k_step``/``v_step`` device arrays the caller hands to
         ``cache.write_tokens`` after the step succeeded.  The pool is
         read as of before the step and not written here."""
         args = (_np.asarray(tokens, dtype=_np.int32),
@@ -395,12 +415,10 @@ class LMBackend(Backend):
                 self.cache.k_pages, self.cache.v_pages,
                 _np.asarray(block_tables, dtype=_np.int32),
                 _np.asarray(context_lens, dtype=_np.int32))
-        fn, cold = self._jit(("decode", len(tokens)), self._build_decode)
-        logits, k, v = fn(self.params, *args)
-        logits = _np.array(logits)
-        self.moved("decode", _host_nbytes((*self.params.values(), *args)),
-                   logits.nbytes)
-        return logits, k, v, cold
+        fn, cold = self._jit(("decode", len(tokens)),
+                             self.definition.decode)
+        logits, k, v, counts = fn(self.params, *args)
+        return self._fetch("decode", args, logits, counts), k, v, cold
 
     def describe(self):
         d = Backend.describe(self)
@@ -434,7 +452,7 @@ class _GenLane(object):
                  "tenant_handles",
                  "m_req", "m_prefill", "m_itl", "m_depth", "m_occ",
                  "m_active", "m_requests", "m_tokens", "m_steps",
-                 "m_compiles", "m_errors", "m_reprefills")
+                 "m_context", "m_compiles", "m_errors", "m_reprefills")
 
     def __init__(self, entry, weight_fn=None):
         self.entry = entry
@@ -514,6 +532,11 @@ class GenerationScheduler(object):
             "steps": reg.counter(
                 "generation_decode_steps_total",
                 "Decode steps dispatched", ["model"]),
+            "context": reg.counter(
+                "generation_decode_context_tokens_total",
+                "Cached tokens the decode steps attended over: the live "
+                "sequences' context lengths, summed over steps",
+                ["model"]),
             "compiles": reg.counter(
                 "generation_compiles_total",
                 "Cold (compiling) prefill/decode shapes; flat after "
@@ -586,6 +609,7 @@ class GenerationScheduler(object):
                           ("occ", "m_occ"), ("active", "m_active"),
                           ("requests", "m_requests"),
                           ("tokens", "m_tokens"), ("steps", "m_steps"),
+                          ("context", "m_context"),
                           ("compiles", "m_compiles"),
                           ("errors", "m_errors"),
                           ("reprefills", "m_reprefills")):
@@ -895,7 +919,8 @@ class GenerationScheduler(object):
                     try:
                         chaos.visit("serving.dispatch",
                                     name="%s:prefill:%d" % (name, bucket))
-                        out = backend.prefill(padded, t)
+                        out = self._beside(lane, backend,
+                                           backend.prefill, padded, t)
                     except Exception as exc:  # noqa: BLE001
                         sp.set(error=type(exc).__name__)
                         raise
@@ -927,6 +952,7 @@ class GenerationScheduler(object):
         if resume is None:
             first = int(_np.argmax(logits))
             req._push(first)
+            req._deliver()         # a first token waits for nothing
             seq.last_token = first
             seq.new_tokens = 1
         else:
@@ -938,6 +964,26 @@ class GenerationScheduler(object):
         lane.active.append(seq)
         if _metrics.metrics_enabled():
             lane.m_prefill.observe(time.monotonic() - t0, req.trace)
+
+    @staticmethod
+    def _beside(lane, backend, call, *args):
+        """``call(*args)``, a prefill or decode call of ``backend``, with
+        the last decode step's tokens handed to their streams while the
+        device runs it.  The threads the tokens wake (a front end's
+        writers, their clients) take the interpreter: woken as each
+        token was known they held back the loop between two calls, woken
+        before the call its dispatch, the device idle meanwhile (23 ms
+        of a 100 ms step with 64 callers).  An :class:`LMBackend` calls
+        ``beside_device`` once its program is on the way."""
+        def deliver():
+            for seq in lane.active:
+                seq.req._deliver()
+
+        backend.beside_device = deliver
+        try:
+            return call(*args)
+        finally:
+            backend.beside_device = None
 
     @staticmethod
     def _fail_live(lane, error):
@@ -978,8 +1024,9 @@ class GenerationScheduler(object):
                     try:
                         chaos.visit("serving.decode",
                                     name="%s:%d" % (name, bucket))
-                        out = backend.decode(tokens, positions, tables,
-                                             context)
+                        out = self._beside(lane, backend, backend.decode,
+                                           tokens, positions, tables,
+                                           context)
                     except Exception as exc:  # noqa: BLE001
                         sp.set(error=type(exc).__name__)
                         raise
@@ -1024,6 +1071,7 @@ class GenerationScheduler(object):
         lane.max_step_rows = max(lane.max_step_rows, n)
         if _metrics.metrics_enabled():
             lane.m_steps.inc()
+            lane.m_context.inc(int(context[:n].sum()))
             lane.m_occ.set(n / float(bucket))
             if cold:
                 lane.m_compiles.inc()

@@ -340,3 +340,106 @@ def test_sharded_lm_step_compiles(topo, on_tpu):
     # flash forward + its two backward passes + three LayerNorms
     assert text.count("tpu_custom_call") >= 6
     assert not registry.fused_fallbacks()
+
+
+# ----------------------------------------------------------------------
+# the latent-attention, sparse-expert model at the benchmark's sizes:
+# 11 GB of abstract weights, nothing allocated
+
+
+def _latent_moe_shapes(one):
+    """``dots-vlm1-ep16`` as the benchmark builds it: the program's
+    configuration and its weights as shapes on the described chip."""
+    import json
+
+    from benchmark.spec import load_module
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "dots-vlm1-ep16.json")) as f:
+        doc = json.load(f)
+    family = load_module(os.path.join(root, "benchmark", "models",
+                                      "latent_moe.py"), "family_latent_moe")
+    params = {k: jax.ShapeDtypeStruct(
+        v, F32 if k.endswith("router_bias") else BF16, sharding=one)
+        for k, v in family.weight_shapes(doc).items()}
+    return doc, family.program_config(doc), params
+
+
+def _big_moves(text, least_bytes):
+    """``copy``/``transpose`` ops at a program's top level that move
+    more than ``least_bytes``."""
+    import re
+
+    size = {"bf16": 2, "f32": 4, "s32": 4}
+    out = []
+    for line in text[text.index("ENTRY"):].splitlines():
+        m = re.search(r"= (\w+)\[([\d,]+)\]\S* (copy|transpose)\(", line)
+        if m and size.get(m.group(1), 4) * np.prod(
+                [int(d) for d in m.group(2).split(",")]) > least_bytes:
+            out.append(line.strip()[:160])
+    return out
+
+
+def test_latent_decode_step_reads_the_pool_where_it_lies(topo, on_tpu):
+    """The decode program of ``dots-vlm1-serve-chat64`` (64 rows,
+    256-block tables, the 9600-block latent pool of 640-wide bfloat16
+    rows): no pool-sized copy, no ``[heads, T, T]`` temporary, and under
+    a gigabyte of temporaries in all.  (With 576-wide rows, 4.5 lane
+    tiles, the chip lays the pool out with its block axis innermost and
+    the same program re-lays all of it, 1 GB, before the gathers of
+    every step.)"""
+    from mxnet_tpu.models import latent_moe as lm
+
+    one = SingleDeviceSharding(topo.devices[0])
+    doc, cfg, params = _latent_moe_shapes(one)
+    serve = doc["deployment"]["serve"]
+    width = lm.cache_row_width(cfg)
+    assert width == 640 and width % 128 == 0
+
+    def s(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    pool = (cfg["num_layers"], serve["num_blocks"], serve["block_size"],
+            width)
+    rows = s((64,))
+    compiled = jax.jit(
+        lambda p, t, pos, pages, tables, lens: lm.decode_step(
+            p, t, pos, pages, tables, lens, cfg)).lower(
+        params, rows, rows, s(pool, BF16),
+        s((64, cfg["seq_len"] // serve["block_size"])), rows).compile()
+    pool_bytes = 2 * int(np.prod(pool))
+    assert _big_moves(compiled.as_text(), pool_bytes // 8) == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 ** 30
+    assert mem.argument_size_in_bytes > 11.9e9    # weights and pool
+    # the routed experts' products of a 64-row step: every held expert
+    # over every row, three batched products a layer that read the
+    # weights where they lie (no copy above), and no grouped kernel
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    entry = text[text.index("ENTRY"):]
+    assert entry.count("expert_layer/td,gdh->gth/dot_general") \
+        == 2 * (cfg["num_layers"] - 1)
+    assert entry.count("expert_layer/gth,ghd->gtd/dot_general") \
+        == cfg["num_layers"] - 1
+
+
+def test_latent_prefill_holds_no_score_matrix(topo, on_tpu):
+    """The largest prefill bucket (3328 tokens): the attention is the
+    flash kernel on 192-wide queries and keys and 128-wide values,
+    named by its scope, and the program's temporaries stay far under
+    the 5.7 GB a ``[128, 3328, 3328]`` float32 score matrix takes."""
+    from mxnet_tpu.models import latent_moe as lm
+
+    one = SingleDeviceSharding(topo.devices[0])
+    _, cfg, params = _latent_moe_shapes(one)
+    compiled = jax.jit(lambda p, t, n: lm.prefill(p, t, n, cfg)).lower(
+        params, jax.ShapeDtypeStruct((3328,), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one)).compile()
+    text = compiled.as_text()
+    assert text.count("%latent_prefill_attention") >= cfg["num_layers"]
+    # the routed experts' products are the chip's grouped-matmul kernels
+    assert text.count("ragged-dot") >= 15
+    assert "f32[128,3328,3328]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30

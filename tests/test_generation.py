@@ -502,6 +502,37 @@ def test_iteration_level_admission(lm):
     sched.close()
 
 
+def test_tokens_reach_their_streams_beside_the_next_device_call(lm):
+    """A decode step's tokens are held until the loop's next device call
+    is on its way, and no longer: where a call goes on to wait for its
+    logits some stream is still owed a token, when it has them none is;
+    a first token is in its stream at once; the streams end with exactly
+    what was generated, in order."""
+    sched, be = _scheduler(lm)
+    reqs, owed = [], []
+    fetch = be._fetch
+
+    def watched(*args):
+        before = sum(len(r._held) for r in reqs)
+        out = fetch(*args)
+        owed.append((before, sum(len(r._held) for r in reqs)))
+        return out
+
+    be._fetch = watched
+    reqs.append(sched.submit("lm", np.array([1, 2, 3], np.int32),
+                             max_new_tokens=12))
+    reqs.append(sched.submit("lm", np.array([9, 8], np.int32),
+                             max_new_tokens=7))
+    streams = [list(r.tokens(timeout=30)) for r in reqs]
+    assert streams == [r.generated for r in reqs]
+    assert [len(s) for s in streams] == [12, 7]
+    assert len(owed) >= 2 + 11
+    assert all(after == 0 for _, after in owed)
+    assert max(before for before, _ in owed) == 2
+    assert be.beside_device is None
+    sched.close()
+
+
 # ------------------------------------------------------------- chaos
 
 def test_decode_fault_retries_without_corruption(lm):

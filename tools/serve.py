@@ -15,6 +15,11 @@ v1 HTTP front-end (``mxnet_tpu/serving/``):
     python tools/serve.py --model mlp=ckpt/model:3 \
         --input-shape mlp.data=16x6 --replicas 2
 
+    # a language model by its configuration file (seeded weights): the
+    # file names its family, benchmark/models/<family>.py builds it
+    python tools/serve.py \
+        --lm dots=benchmark/configs/dots-vlm1-ep16.json:7 --port 8080
+
 ``--smoke`` (the ``make serve`` target) is self-contained: it builds a
 tiny in-memory MLP, serves it on a 2-replica group, drives the HTTP
 API end to end — predict, models listing, readiness — kills one
@@ -79,24 +84,49 @@ def _backend_factory(name, src, shapes):
         prefix, int(epoch), dict(shapes[name]))
 
 
+def lm_backend(name, src):
+    """``path/config.json[:seed]`` -> an ``LMBackend`` over seeded
+    weights.  The configuration is a benchmark configuration file: its
+    ``family`` names the module under ``benchmark/models/`` that makes
+    the weights and hands ``LMBackend`` the model's definition, its
+    ``deployment.serve`` sizes the cache."""
+    from benchmark.spec import Spec
+
+    path, _, seed = src.partition(":")
+    with open(path) as f:
+        cfg = json.load(f)
+    family = Spec(ROOT).model(cfg["family"])
+    return family.build_backend(
+        cfg, cfg["deployment"]["serve"],
+        family.make_weights(cfg, int(seed or 0)), name, lambda base: base)
+
+
 def serve(args):
     from mxnet_tpu import serving
 
     shapes = _parse_shapes(args.input_shape)
     models = _parse_models(args.model)
-    if not models:
-        raise SystemExit("nothing to serve: pass --model (or --smoke)")
     buckets = ([int(b) for b in args.buckets.split(",")]
                if args.buckets else None)
-    if args.replicas > 1:
+    group = None
+    if args.lm:         # generation lanes behind /v1/generate
+        target = serving.GenerationScheduler()
+        for name, src in _parse_models(args.lm):
+            target.register(name, lm_backend(name, src))
+            target.warmup(name)
+        models, route = args.lm, "/v1/generate"
+    elif not models:
+        raise SystemExit("nothing to serve: pass --model, --lm (or "
+                         "--smoke)")
+    elif args.replicas > 1:
         group = serving.ReplicaGroup(replicas=args.replicas)
         for name, src in models:
             group.register(name, _backend_factory(name, src, shapes),
                            buckets=buckets, max_queue=args.max_queue)
             group.warmup(name)
-        target = serving.ServingRouter(group)
+        target, route = serving.ServingRouter(group), "/v1/predict"
     else:
-        target = serving.Scheduler()
+        target, route = serving.Scheduler(), "/v1/predict"
         for name, src in models:
             target.register(name, _backend_factory(name, src, shapes)(),
                             buckets=buckets, max_queue=args.max_queue)
@@ -104,7 +134,7 @@ def serve(args):
     fe = serving.start_frontend(target, port=args.port, addr=args.addr)
     print("serving %d model(s) on %s (%d replica(s))"
           % (len(models), fe.url, args.replicas))
-    print("  POST %s/v1/predict   GET %s/v1/models" % (fe.url, fe.url))
+    print("  POST %s%s   GET %s/v1/models" % (fe.url, route, fe.url))
     try:
         import time
 
@@ -112,10 +142,7 @@ def serve(args):
             time.sleep(3600)
     except KeyboardInterrupt:
         print("draining...")
-        if args.replicas > 1:
-            group.close()
-        else:
-            target.close()
+        (group or target).close()
         fe.close()
     return 0
 
@@ -202,6 +229,10 @@ def main(argv=None):
     ap.add_argument("--model", action="append", default=[],
                     metavar="NAME=PREFIX:EPOCH|NAME=PATH.mxtpu",
                     help="model to serve (repeatable)")
+    ap.add_argument("--lm", action="append", default=[],
+                    metavar="NAME=CONFIG.json[:SEED]",
+                    help="language model to generate from, by its "
+                         "configuration file (repeatable; seeded weights)")
     ap.add_argument("--input-shape", action="append", default=[],
                     metavar="MODEL.INPUT=16x6",
                     help="batched input shape for checkpoint models "
