@@ -155,9 +155,12 @@ def paged_decode_attention(q, k_step, v_step, k_pages, v_pages,
     reduction order, and the padded-key masking keeps garbage in
     unwritten page tails away from the output bits.
 
-    Dispatches through the fused tier: the Pallas block-table kernel
-    (``ops/fused/attention_kernels.py``) is bitwise-equal to the stock
-    body below, so the decode parity contract survives either way.
+    Dispatches through the fused tier: on a TPU the block-table walk
+    (``ops/fused/attention_kernels.py`` over :func:`_walk_pages`) reads
+    the row's live blocks only and folds them into an online softmax,
+    equal to the stock body below within float32 rounding (class
+    ``tolerance``).  The bitwise decode-parity contract is the stock
+    body's, on the CPU.
     """
     return dispatch_variant("paged_decode_attention",
                             _paged_decode_attention_stock,
@@ -227,26 +230,262 @@ def latent_paged_decode_attention(q, row_step, pages, block_tables,
 
     Returns ``p . c_kv`` ``[B, H, kv_rank]`` (the caller applies
     ``W_uv``).  Scores and softmax in float32; the current token enters
-    as a score of its own, so the gathered rows are read as they lie."""
+    as a score of its own, so the pool is read as it lies.  On a TPU
+    the block-table walk (:func:`_walk_pages`) reads the blocks that
+    hold live tokens and no other; elsewhere XLA gathers every table
+    block and masks."""
+    if jax.default_backend() == "tpu" and _walk_tiles(pages):
+        return _latent_decode_pallas(
+            q, row_step, pages, block_tables, context_lens,
+            float(sm_scale), int(kv_rank))
     with jax.named_scope("latent_decode_attention"):
-        bsz, max_blocks = block_tables.shape
-        kmax = max_blocks * pages.shape[1]
-        rows = pages[block_tables].reshape(bsz, kmax, -1)
-        s = jnp.einsum("bhw,bkw->bhk", q, rows,
-                       preferred_element_type=jnp.float32) * sm_scale
-        pos = lax.broadcasted_iota(jnp.int32, (1, 1, kmax), 2)
-        s = jnp.where(pos < (context_lens - 1)[:, None, None], s, _NEG_INF)
-        s_self = jnp.einsum("bhw,bw->bh", q, row_step,
-                            preferred_element_type=jnp.float32) * sm_scale
-        m = jnp.maximum(jnp.max(s, axis=-1), s_self)
-        p = jnp.exp(s - m[..., None])
-        p_self = jnp.exp(s_self - m)
-        out = jnp.einsum("bhk,bkc->bhc", p.astype(rows.dtype),
-                         rows[..., :kv_rank],
-                         preferred_element_type=jnp.float32)
-        out = out + p_self[..., None] * row_step[:, None, :kv_rank]
-        return (out / (jnp.sum(p, axis=-1) + p_self)[..., None]
-                ).astype(q.dtype)
+        return _latent_decode_xla(q, row_step, pages, block_tables,
+                                  context_lens, sm_scale, kv_rank)
+
+
+def _latent_decode_xla(q, row_step, pages, block_tables, context_lens,
+                       sm_scale, kv_rank):
+    bsz, max_blocks = block_tables.shape
+    kmax = max_blocks * pages.shape[1]
+    rows = pages[block_tables].reshape(bsz, kmax, -1)
+    s = jnp.einsum("bhw,bkw->bhk", q, rows,
+                   preferred_element_type=jnp.float32) * sm_scale
+    pos = lax.broadcasted_iota(jnp.int32, (1, 1, kmax), 2)
+    s = jnp.where(pos < (context_lens - 1)[:, None, None], s, _NEG_INF)
+    s_self = jnp.einsum("bhw,bw->bh", q, row_step,
+                        preferred_element_type=jnp.float32) * sm_scale
+    m = jnp.maximum(jnp.max(s, axis=-1), s_self)
+    p = jnp.exp(s - m[..., None])
+    p_self = jnp.exp(s_self - m)
+    out = jnp.einsum("bhk,bkc->bhc", p.astype(rows.dtype),
+                     rows[..., :kv_rank],
+                     preferred_element_type=jnp.float32)
+    out = out + p_self[..., None] * row_step[:, None, :kv_rank]
+    return (out / (jnp.sum(p, axis=-1) + p_self)[..., None]
+            ).astype(q.dtype)
+
+
+# ----------------------------------------------------------------------
+# paged decode on a TPU: one block-table walk under both decode bodies
+# ----------------------------------------------------------------------
+#
+# A decode step attends over ``context_len - 1`` cached tokens a row,
+# which lie in the first ``ceil((context_len - 1) / block_size)`` blocks
+# of the row's table; the table is as wide as the longest sequence the
+# server admits.  The XLA bodies gather, re-lay and score every table
+# block and mask afterwards.  The walk below runs one program a row:
+# tables and lengths are scalar-prefetched, the pools stay in HBM, and a
+# loop over the row's live blocks alone copies a chunk of pages into
+# VMEM while the body folds the chunk before it into an online softmax.
+# A block that holds no live token costs neither a copy nor arithmetic.
+# The current token is the walk's initial state (``init``), so the pool
+# is read as of before the step.
+
+#: bytes of pool pages (all pools of the cache together) a chunk of the
+#: walk holds; a second chunk is in flight behind it.  A page is 2 x 64
+#: KB (GPT-2 medium's float32 key and value rows) or 20 KB (a 640-wide
+#: bfloat16 latent row): one page a loop step would leave the loop's
+#: own cost and a copy's latency larger than the arithmetic on it.
+_WALK_CHUNK_BYTES = 1 << 20
+
+
+def _walk_tiles(*pools):
+    """Whether a page of every pool is whole tiles of the chip's
+    memory (8 x 128 words of 32 bits): a page is copied as it lies."""
+    return all(
+        p.shape[-1] % _LANE == 0
+        and p.shape[-2] % (8 * 4 // jnp.dtype(p.dtype).itemsize) == 0
+        for p in pools)
+
+
+def _walk_chunk_pages(pools, max_blocks):
+    """Pages a chunk: what of a power of two fits the chunk's bytes, at
+    most the table."""
+    page = sum(p.shape[1] * p.shape[2] * jnp.dtype(p.dtype).itemsize
+               for p in pools)
+    fit = max(1, _WALK_CHUNK_BYTES // page)
+    return min(1 << (fit.bit_length() - 1), max_blocks)
+
+
+def _walk_kernel(tables_ref, lens_ref, *refs, n_rows, n_pools, pages, blk,
+                 max_blocks, init, chunk, finish):
+    """One row of the batch.  ``refs``: the row's operands and those
+    every row shares, the pools (in HBM), the output, then scratch: a
+    two-slot chunk buffer a pool, the copies' semaphores ``[pool,
+    slot]`` and the body's state."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    row_refs = refs[:n_rows]
+    pool_refs = refs[n_rows:n_rows + n_pools]
+    out_ref = refs[n_rows + n_pools]
+    scratch = refs[n_rows + n_pools + 1:]
+    bufs, sem, state = scratch[:n_pools], scratch[n_pools], \
+        scratch[n_pools + 1:]
+    b = pl.program_id(0)
+    tokens = pages * blk
+    cached = jnp.maximum(lens_ref[b] - 1, 0)
+    live_pages = (cached + blk - 1) // blk
+    n_chunks = (live_pages + pages - 1) // pages
+
+    def copies(c, slot, wait):
+        # the live pages of chunk ``c``, each copied from where its
+        # table entry says it lies; a wait needs the copy's size only
+        for i in range(pages):
+            page = c * pages + i
+
+            @pl.when(page < live_pages)
+            def _():
+                at = 0 if wait else tables_ref[b * max_blocks + page]
+                for n in range(n_pools):
+                    copy = pltpu.make_async_copy(
+                        pool_refs[n].at[at],
+                        bufs[n].at[slot, pl.ds(i * blk, blk)],
+                        sem.at[n, slot])
+                    copy.wait() if wait else copy.start()
+
+    init(row_refs, state)
+
+    @pl.when(n_chunks > 0)
+    def _():
+        copies(0, 0, False)
+
+    def step(c, carry):
+        slot = lax.rem(c, 2)
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            copies(c + 1, 1 - slot, False)
+
+        copies(c, slot, True)
+        live = cached - c * tokens
+        held = [buf.at[slot] for buf in bufs]
+
+        @pl.when(live >= tokens)
+        def _():
+            chunk(row_refs, held, state, None)
+
+        @pl.when(live < tokens)
+        def _():
+            # the row's last chunk: its tail was not copied and holds
+            # whatever the buffer held
+            chunk(row_refs, held, state, live)
+
+        return carry
+
+    lax.fori_loop(0, n_chunks, step, 0)
+    finish(row_refs, state, out_ref)
+
+
+def _walk_pages(init, chunk, finish, rows, shared, pools, block_tables,
+                context_lens, out, state, interpret=False):
+    """Run ``init`` / ``chunk`` / ``finish`` over every row's live
+    blocks.
+
+    ``rows``: per-row operands ``[B, r, c]``, handed to the bodies as
+    ``[1, r, c]`` refs, followed by the ``shared`` ones, whole;
+    ``pools``: ``[num_blocks, block_size, W]`` arrays read through
+    ``block_tables`` ``int32 [B, max_blocks]`` up to ``context_lens -
+    1`` tokens; ``out``: the ``[B, r, c]`` result's
+    ``ShapeDtypeStruct``; ``state``: the bodies' VMEM scratch.
+    ``chunk(row_refs, held, state, live)`` folds ``pages * block_size``
+    tokens (``held``: one ``[tokens, W]`` ref a pool) into the state;
+    ``live`` is None where every token counts and else the number that
+    do, the rest being unspecified bits."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bsz, max_blocks = block_tables.shape
+    blk = pools[0].shape[1]
+    pages = _walk_chunk_pages(pools, max_blocks)
+    kernel = functools.partial(
+        _walk_kernel, n_rows=len(rows) + len(shared), n_pools=len(pools),
+        pages=pages, blk=blk, max_blocks=max_blocks, init=init, chunk=chunk,
+        finish=finish)
+
+    def one_row(x):
+        return pl.BlockSpec((1,) + x.shape[1:],
+                            lambda b, tables, lens: (b, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(bsz,),
+        in_specs=[one_row(x) for x in rows]
+        + [pl.BlockSpec(x.shape, lambda b, tables, lens, n=x.ndim: (0,) * n)
+           for x in shared]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=one_row(out),
+        scratch_shapes=[pltpu.VMEM((2, pages * blk, p.shape[2]), p.dtype)
+                        for p in pools]
+        + [pltpu.SemaphoreType.DMA((len(pools), 2))] + list(state))
+    kwargs = {}
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",))
+    return pl.pallas_call(
+        kernel, out_shape=out, grid_spec=grid_spec, interpret=interpret,
+        **kwargs)(block_tables.reshape(-1).astype(jnp.int32),
+                  context_lens.astype(jnp.int32), *rows, *shared, *pools)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "kv_rank", "interpret"))
+def _latent_decode_pallas(q, row_step, pages, block_tables, context_lens,
+                          sm_scale, kv_rank, interpret=False):
+    """The absorbed decode over the walk: a chunk of 640-wide rows is
+    the keys of every head and, its first ``kv_rank`` columns, the
+    values; both products ride the MXU in the rows' dtype, scores,
+    softmax and accumulator stay float32.  Jitted so that a model's
+    layers share one trace and one lowering of the kernel."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    bsz, heads, _ = q.shape
+
+    def init(row_refs, state):
+        q_ref, step_ref = row_refs
+        m_ref, l_ref, acc_ref = state
+        step = step_ref[0].astype(jnp.float32)              # [1, W]
+        m_ref[...] = jnp.sum(q_ref[0].astype(jnp.float32) * step, axis=1,
+                             keepdims=True) * sm_scale
+        l_ref[...] = jnp.ones_like(l_ref)
+        acc_ref[...] = jnp.broadcast_to(step[:, :kv_rank], acc_ref.shape)
+
+    def chunk(row_refs, held, state, live):
+        m_ref, l_ref, acc_ref = state
+        rows = held[0][...]                                 # [T, W]
+        values = rows[:, :kv_rank]
+        s = lax.dot_general(
+            row_refs[0][0], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale  # [H, T]
+        if live is not None:
+            at = lax.broadcasted_iota(jnp.int32, (1, rows.shape[0]), 1)
+            s = jnp.where(at < live, s, _NEG_INF)
+            at = lax.broadcasted_iota(jnp.int32, (rows.shape[0], 1), 0)
+            values = jnp.where(at < live, values, 0)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + lax.dot_general(
+            p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    def finish(row_refs, state, out_ref):
+        _, l_ref, acc_ref = state
+        out_ref[0] = (acc_ref[...] / l_ref[...]).astype(out_ref.dtype)
+
+    # the scope names the kernel in a trace; it has to be the innermost
+    with jax.named_scope("latent_decode_attention"):
+        return _walk_pages(
+            init, chunk, finish, (q, row_step[:, None, :]), (), (pages,),
+            block_tables, context_lens,
+            jax.ShapeDtypeStruct((bsz, heads, kv_rank), q.dtype),
+            [pltpu.VMEM((heads, 1), jnp.float32),
+             pltpu.VMEM((heads, 1), jnp.float32),
+             pltpu.VMEM((heads, kv_rank), jnp.float32)],
+            interpret=interpret)
 
 
 # ----------------------------------------------------------------------

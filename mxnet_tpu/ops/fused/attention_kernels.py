@@ -10,20 +10,21 @@ Two generation-lane hot paths from ISSUE 19:
   ``tolerance`` class — the generation lane keeps its bitwise
   prefill/decode contract by selecting it only where that contract is
   not in play (TPU serving, or explicit override).
-* ``paged_decode_attention``/``fused`` — a Pallas kernel that gathers
-  K/V pages through the block table with scalar-prefetch index maps
-  (one page DMA per (sequence, page) grid step) instead of the stock
-  XLA gather that materialises ``[B, max_blocks, blk, H, D]`` twice.
-  The final grid step replays stock's exact fp32 score/softmax/PV
-  spelling on the gathered pages, so the variant is ``bitwise`` — the
-  PR-14 decode-parity contract survives kernel replacement.
+* ``paged_decode_attention``/``fused`` — the block-table walk of
+  ``ops/attention.py`` (``_walk_pages``) under GPT-2's float32 key and
+  value pools: one program a row reads the blocks that hold the row's
+  live tokens, from the pool where it lies, and folds them chunk by
+  chunk into an online softmax; the stock XLA body gathers, re-lays and
+  scores every block of the table and masks afterwards (89% of the
+  serving chip's busy time, PR 26's trace).  The online softmax
+  reorders the sums, so the variant is ``tolerance`` class: on the
+  chip the decode no longer holds the bitwise prefill/decode contract,
+  which lives on the CPU's stock bodies (ROADMAP D2).
 
-Both run under ``interpret=True`` off-TPU, which is how the parity
-harness pins them on CPU.  The prefill variant is eligible on ``"tpu"``
-only (CPU interpret is an emulation, not a win); the paged-decode
-kernel is eligible nowhere — the chip's compiler refuses it, see its
-registration below.  ``MXNET_TPU_OPS_FUSED_OVERRIDE`` forces either
-anywhere.
+Both are eligible on ``"tpu"`` only and run under ``interpret=True``
+off-TPU, which is how the parity harness pins them on CPU (CPU
+interpret is an emulation, not a win).  ``MXNET_TPU_OPS_FUSED_OVERRIDE``
+forces either anywhere.
 """
 
 from __future__ import annotations
@@ -71,111 +72,136 @@ register_variant("stable_causal_attention", "fused",
 
 
 # ----------------------------------------------------------------------
-# paged decode: block-table gather as a scalar-prefetch Pallas kernel
+# paged decode: the block-table walk over float32 key and value pools
 # ----------------------------------------------------------------------
-
-
-def _paged_decode_kernel(bt_ref, cl_ref, q_ref, ks_ref, vs_ref, clv_ref,
-                         kp_ref, vp_ref, o_ref, k_scr, v_scr, *,
-                         sm_scale, bsz, max_blocks, blk):
-    """Grid ``(B, max_blocks)``: step ``(b, j)`` lands page
-    ``block_tables[b, j]`` (already staged into VMEM by the
-    scalar-prefetch index map) into the gather scratch; the last step
-    scatters the current token at ``context_len - 1`` and replays
-    stock's exact fp32 score/softmax/PV ops on the full gathered batch
-    so the output bits match ``paged_decode_attention`` exactly."""
-    import jax.experimental.pallas as pl
-
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    k_scr[b, pl.ds(j * blk, blk)] = kp_ref[0]
-    v_scr[b, pl.ds(j * blk, blk)] = vp_ref[0]
-
-    @pl.when(j == max_blocks - 1)
-    def _scatter_current():
-        pos = cl_ref[b] - 1
-        k_scr[b, pl.ds(pos, 1)] = ks_ref[b][None]
-        v_scr[b, pl.ds(pos, 1)] = vs_ref[b][None]
-
-    @pl.when(jnp.logical_and(b == bsz - 1, j == max_blocks - 1))
-    def _attend():
-        kmax = max_blocks * blk
-        k = k_scr[...].transpose(0, 2, 1, 3)      # [B, H, Kmax, D]
-        v = v_scr[...].transpose(0, 2, 1, 3)
-        q = q_ref[...]
-        cl = clv_ref[...][:, 0]
-        # stock's exact spelling (ops/attention.py paged_decode_attention)
-        s = _att._stable_scores(q[:, :, None, :], k) * sm_scale
-        pos = lax.broadcasted_iota(jnp.int32, (1, 1, 1, kmax), 3)
-        s = jnp.where(pos < cl[:, None, None, None], s, _att._NEG_INF)
-        p = _att._stable_softmax(s)
-        out = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
-        o_ref[...] = out[:, :, 0, :]
 
 
 def fused_paged_decode_attention(q, k_step, v_step, k_pages, v_pages,
                                  block_tables, context_lens,
                                  sm_scale=None):
     """Pallas twin of :func:`~mxnet_tpu.ops.attention.
-    paged_decode_attention` — same signature, bitwise-equal output.
+    paged_decode_attention` — same signature, float32 out — over the
+    walk of :func:`~mxnet_tpu.ops.attention._walk_pages`: only the
+    blocks that hold a row's live tokens are read, from the pool where
+    it lies.
 
-    The gather scratch holds ``[B, max_blocks * blk, H, D]`` per side,
-    which bounds batch x context by VMEM.
-    """
-    import jax.experimental.pallas as pl
+    A cached row is ``heads * dim`` values wide and a head owns ``dim``
+    = 64 of them, half a lane tile, so nothing here is reshaped to
+    ``[.., heads, dim]``.  The chunk's keys are multiplied by the row's
+    query on the VPU, all heads at once, and a product with the 0/1
+    selector ``[heads, heads * dim]`` sums each head's lanes; the same
+    selector spreads a head's softmax weight over its lanes for p.v.
+    The model is served in float32 and a default-precision product of
+    float32 operands is a bfloat16 one on the chip, so the float32
+    operand goes through the MXU as three bfloat16 terms that sum to
+    it: the selector's entries are exact in bfloat16, so these are
+    float32 sums and copies in half the passes of ``HIGHEST`` (which
+    splits the selector too: 4.19 against 2.85 ms a step of 24 layers
+    on a v5e, both 9e-7 from a float64 softmax).  On a TPU, pools whose
+    pages are not whole tiles keep the stock body."""
+    bsz, heads, dim = q.shape
+    width = heads * dim
+    flat = k_pages.shape[:2] + (width,)
+    k_pages, v_pages = k_pages.reshape(flat), v_pages.reshape(flat)
+    if not (_interpret() or _att._walk_tiles(k_pages, v_pages)):
+        return _att._paged_decode_attention_stock(
+            q, k_step, v_step, k_pages, v_pages, block_tables,
+            context_lens, sm_scale=sm_scale)
+    if sm_scale is None:
+        sm_scale = 1.0 / float(dim) ** 0.5
+
+    def rows(x):
+        return x.astype(jnp.float32).reshape(bsz, 1, width)
+
+    out = _kv_decode_walk(rows(q), rows(k_step), rows(v_step), k_pages,
+                          v_pages, block_tables, context_lens, heads=heads,
+                          scale=float(sm_scale), interpret=_interpret())
+    return out.reshape(bsz, heads, dim)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "scale", "interpret"))
+def _kv_decode_walk(q, k_step, v_step, k_pages, v_pages, block_tables,
+                    context_lens, heads, scale, interpret):
+    """``q`` / ``k_step`` / ``v_step`` as float32 rows ``[B, 1, W]``,
+    the pools ``[num_blocks, block_size, W]``.  Jitted so that a
+    model's layers share one trace and one lowering of the kernel: 24
+    of them took a warm start 3.7 s longer, every one lowered anew."""
     from jax.experimental.pallas import tpu as pltpu
 
-    if sm_scale is None:
-        sm_scale = 1.0 / float(q.shape[-1]) ** 0.5
-    bsz, max_blocks = block_tables.shape
-    blk = k_pages.shape[1]
-    heads, dim = k_pages.shape[2], k_pages.shape[3]
-    kmax = max_blocks * blk
-    block_tables = block_tables.astype(jnp.int32)
-    context_lens = context_lens.astype(jnp.int32)
-    cl_vec = context_lens.reshape(bsz, 1)
-    kernel = functools.partial(
-        _paged_decode_kernel, sm_scale=float(sm_scale), bsz=bsz,
-        max_blocks=max_blocks, blk=blk)
-    full = lambda b, j, bt, cl: (0,) * 3  # noqa: E731 - whole-array blocks
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,               # block_tables, context_lens
-        grid=(bsz, max_blocks),
-        in_specs=[
-            pl.BlockSpec((bsz, heads, dim), full),          # q
-            pl.BlockSpec((bsz, heads, dim), full),          # k_step
-            pl.BlockSpec((bsz, heads, dim), full),          # v_step
-            pl.BlockSpec((bsz, 1), lambda b, j, bt, cl: (0, 0)),
-            # the page gather: the index map picks this step's page
-            pl.BlockSpec((1, blk, heads, dim),
-                         lambda b, j, bt, cl: (bt[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, blk, heads, dim),
-                         lambda b, j, bt, cl: (bt[b, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bsz, heads, dim), full),
-        scratch_shapes=[
-            pltpu.VMEM((bsz, kmax, heads, dim), k_pages.dtype),
-            pltpu.VMEM((bsz, kmax, heads, dim), v_pages.dtype),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((bsz, heads, dim), jnp.float32),
-        grid_spec=grid_spec,
-        interpret=_interpret(),
-    )(block_tables, context_lens, q, k_step, v_step, cl_vec, k_pages,
-      v_pages)
+    width = q.shape[-1]
+    dim = width // heads
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    lane_head = lax.broadcasted_iota(jnp.int32, (heads, width), 1) // dim
+    selector = (lane_head == lax.broadcasted_iota(
+        jnp.int32, (heads, width), 0)).astype(bf16)
+
+    def by_selector(x, sel, contract):
+        # float32 ``x`` as three bfloat16 terms that sum to it, each
+        # through the MXU against the selector, accumulated in float32
+        hi = x.astype(bf16)
+        rest = x - hi.astype(f32)
+        mid = rest.astype(bf16)
+        terms = (hi, mid, (rest - mid.astype(f32)).astype(bf16))
+        return sum(lax.dot_general(t, sel, (contract, ((), ())),
+                                   preferred_element_type=f32)
+                   for t in terms)
+
+    def head_sums(x, sel):                  # [n, W] -> [n, H]
+        return by_selector(x, sel, ((1,), (1,)))
+
+    def over_lanes(x, sel):                 # [n, H] -> [n, W]
+        return by_selector(x, sel, ((1,), (0,)))
+
+    def init(row_refs, state):
+        q_ref, k_ref, v_ref, sel_ref = row_refs
+        m_ref, l_ref, acc_ref = state
+        own = jnp.broadcast_to(k_ref[0] * q_ref[0], (8, width))
+        m_ref[...] = head_sums(own, sel_ref[...])[:1] * scale
+        l_ref[...] = jnp.ones_like(l_ref)
+        acc_ref[...] = v_ref[0]
+
+    def chunk(row_refs, held, state, live):
+        sel = row_refs[3][...]
+        m_ref, l_ref, acc_ref = state
+        keys, values = held[0][...].astype(f32), held[1][...].astype(f32)
+        tokens = keys.shape[0]
+        s = head_sums(keys * row_refs[0][0], sel) * scale       # [T, H]
+        if live is not None:
+            at = lax.broadcasted_iota(jnp.int32, (tokens, 1), 0)
+            s = jnp.where(at < live, s, _att._NEG_INF)
+            values = jnp.where(at < live, values, 0.0)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)                         # [1, H]
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0, keepdims=True)
+        m_ref[...] = m_new
+        # one product spreads the weights and the rescale of what was
+        # accumulated: the selector is loaded once for both
+        spread = over_lanes(jnp.concatenate(
+            [p, jnp.broadcast_to(alpha, (8, heads))], axis=0), sel)
+        acc_ref[...] = spread[tokens:tokens + 1] * acc_ref[...] + jnp.sum(
+            spread[:tokens] * values, axis=0, keepdims=True)
+
+    def finish(row_refs, state, out_ref):
+        _, l_ref, acc_ref = state
+        sums = over_lanes(jnp.broadcast_to(l_ref[...], (8, heads)),
+                          row_refs[3][...])[:1]
+        out_ref[0] = acc_ref[...] / sums
+
+    with jax.named_scope("paged_decode_attention"):
+        return _att._walk_pages(
+            init, chunk, finish, (q, k_step, v_step), (selector,),
+            (k_pages, v_pages), block_tables, context_lens,
+            jax.ShapeDtypeStruct(q.shape, f32),
+            [pltpu.VMEM((1, heads), f32), pltpu.VMEM((1, heads), f32),
+             pltpu.VMEM((1, width), f32)],
+            interpret=interpret)
 
 
-# Eligible on no backend: the v5e compiler refuses this kernel at every
-# shape (``tpu.matmul: Up to 1 batch dim supported`` for the batched
-# score, and the ``[B, max_blocks * blk, H, D]`` x2 gather scratch cannot
-# fit VMEM at LM width).  It stays registered so the interpret-mode
-# parity grid keeps pinning the block-table gather until the decode
-# kernel is redesigned (ROADMAP S1/D3); an override still forces it.
 register_variant("paged_decode_attention", "fused",
-                 fused_paged_decode_attention, backends=(),
-                 parity="bitwise")
+                 fused_paged_decode_attention, backends=("tpu",),
+                 parity="tolerance")
 
 
 # ----------------------------------------------------------------------
@@ -255,4 +281,8 @@ register_parity(
         ("float32", 4, 32, 16, 2, (1, 17, 32)),  # ctx=1 and full tail
         ("float32", 2, 8, 4, 4, (3, 16, 9)),
         ("bfloat16", 2, 64, 8, 2, (3, 9)),       # bf16 pool, fp32 math
+        # the served width (16 heads of 64, blocks of 16): no cached
+        # token, a block less one, exactly a block, a block and one,
+        # the whole table
+        ("float32", 16, 64, 16, 4, (1, 16, 17, 18, 64)),
     ))

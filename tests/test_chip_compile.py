@@ -199,12 +199,14 @@ def test_every_tpu_variant_compiles_at_widest_parity_shape(
     _compile(fused, args, SingleDeviceSharding(topo.devices[0]))
 
 
-def test_paged_decode_variant_is_withdrawn_from_tpu():
-    """The compiler refuses this kernel at every shape (``tpu.matmul``:
-    one batch dim at most), so it is eligible nowhere; an override still
-    reaches it and the interpret-mode parity grid still pins it."""
+def test_paged_decode_variant_is_eligible_on_tpu():
+    """The block-table walk took the slot of the kernel the compiler
+    refused at every shape: eligible on ``"tpu"`` and nowhere else, held
+    to its stock twin within float32 rounding (the online softmax
+    reorders the sums), and in the interpret-mode parity grid."""
     var = registry.FUSED_VARIANTS["paged_decode_attention"]["fused"]
-    assert var.backends == ()
+    assert var.backends == ("tpu",)
+    assert var.parity == "tolerance"
     assert ("paged_decode_attention", "fused") in parity._PARITY
 
 
@@ -251,13 +253,71 @@ def test_pool_write_is_in_place_on_the_chip(topo, rows):
     assert mem.temp_size_in_bytes < pool_bytes // 4
 
 
-def test_decode_step_reads_the_pool_where_it_lies(topo, monkeypatch):
+_LATENT_POOL = (6 * 9600, 16, 640)     # layers x blocks, block size, W
+
+
+def _named_calls(text, scope):
+    """The custom calls of a compiled program that carry ``scope`` as
+    their instruction name (``%scope.N``): what a trace tells them by."""
+    return sum(line.split(" = ")[0].split()[-1].startswith("%" + scope)
+               for line in text.splitlines() if " custom-call(" in line)
+
+
+def _kv_kernel(q, k_step, v_step, k_pool, v_pool, tables, lens):
+    heads = (-1, _POOL[2], 16, 64)
+    return attention_kernels.fused_paged_decode_attention(
+        q, k_step, v_step, k_pool.reshape(heads), v_pool.reshape(heads),
+        tables, lens)
+
+
+def _latent_kernel(q, row, pool, tables, lens):
+    return att.latent_paged_decode_attention(q, row, pool, tables, lens,
+                                             0.1, 512)
+
+
+_PAGED_KERNELS = {
+    # gpt2m-serve-chat: 16 rows, 64-block tables, two float32 pools
+    "kv": (_kv_kernel, "paged_decode_attention",
+           (_s((16, 16, 64), F32),) * 3 + (_s(_POOL, F32),) * 2
+           + (_s((16, 64), jnp.int32), _s((16,), jnp.int32))),
+    # dots-vlm1-serve-chat64: 64 rows, 256-block tables, one bf16 pool
+    "latent": (_latent_kernel, "latent_decode_attention",
+               (_s((64, 128, 640), BF16), _s((64, 640), BF16),
+                _s(_LATENT_POOL, BF16), _s((64, 256), jnp.int32),
+                _s((64,), jnp.int32))),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(_PAGED_KERNELS))
+def test_paged_decode_kernels_compile_at_the_cells_shapes(
+        topo, on_tpu, kernel):
+    """Both bodies of the block-table walk at the serving cells' sizes:
+    one custom call under its scope's name, the pools handed to it as
+    they lie (nothing of a megabyte is copied or re-laid, no temporary:
+    the program is the kernel)."""
+    fn, name, args = _PAGED_KERNELS[kernel]
+    compiled = _compile(fn, args, SingleDeviceSharding(topo.devices[0]))
+    text = compiled.as_text()
+    assert _named_calls(text, name) == 1
+    assert _big_moves(text, 2 ** 20) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+    assert not registry.fused_fallbacks()
+
+
+@pytest.mark.parametrize("body", ["xla", "kernel"])
+def test_decode_step_reads_the_pool_where_it_lies(topo, monkeypatch,
+                                                  request, body):
     """GPT-2 medium's whole decode step at the benchmark's sizes (16
     rows, 64-block tables, the 680-block pool): no layer of the pool is
-    re-laid before its gather and none is sliced out of it.  (Sliced as
-    ``k_pages[i]`` the program copied each layer's 44 MB out of the pool
-    every step and held all 48 copies, 1.8 GB, as temporaries.)"""
+    re-laid before it is read and none is sliced out of it, with the
+    XLA body's gather and with the kernel a TPU runs (24 custom calls
+    named by their scope).  (Sliced as ``k_pages[i]`` the program copied
+    each layer's 44 MB out of the pool every step and held all 48
+    copies, 1.8 GB, as temporaries.)"""
     from mxnet_tpu.models import transformer as tfm
+
+    if body == "kernel":
+        request.getfixturevalue("on_tpu")
 
     class Shapes(object):
         """``init_lm_params`` for its names and shapes alone."""
@@ -287,8 +347,15 @@ def test_decode_step_reads_the_pool_where_it_lies(topo, monkeypatch):
     compiled = jax.jit(step).lower(
         params, rows, rows, s(_POOL), s(_POOL), s((16, 64), jnp.int32),
         rows).compile()
-    assert _pool_sized(compiled.as_text()) == []
-    assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20
+    text = compiled.as_text()
+    assert _pool_sized(text) == []
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if body == "kernel":
+        assert _named_calls(text, "paged_decode_attention") == _POOL[0]
+        assert temp < 64 * 2 ** 20      # the gathered keys are gone
+        assert not registry.fused_fallbacks()
+    else:
+        assert temp < 512 * 2 ** 20
 
 
 # ----------------------------------------------------------------------
@@ -384,8 +451,9 @@ def _big_moves(text, least_bytes):
 def test_latent_decode_step_reads_the_pool_where_it_lies(topo, on_tpu):
     """The decode program of ``dots-vlm1-serve-chat64`` (64 rows,
     256-block tables, the 9600-block latent pool of 640-wide bfloat16
-    rows): no pool-sized copy, no ``[heads, T, T]`` temporary, and under
-    a gigabyte of temporaries in all.  (With 576-wide rows, 4.5 lane
+    rows, its attention the kernel a TPU runs): no pool-sized copy, no
+    ``[heads, T, T]`` temporary, and under a gigabyte of temporaries in
+    all.  (With 576-wide rows, 4.5 lane
     tiles, the chip lays the pool out with its block axis innermost and
     the same program re-lays all of it, 1 GB, before the gathers of
     every step.)"""
@@ -412,6 +480,9 @@ def test_latent_decode_step_reads_the_pool_where_it_lies(topo, on_tpu):
     assert _big_moves(compiled.as_text(), pool_bytes // 8) == []
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 ** 30
+    # the decode attention is the block-table walk, once a layer
+    assert _named_calls(compiled.as_text(), "latent_decode_attention") \
+        == cfg["num_layers"]
     assert mem.argument_size_in_bytes > 11.9e9    # weights and pool
     # the routed experts' products of a 64-row step: every held expert
     # over every row, three batched products a layer that read the

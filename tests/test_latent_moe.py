@@ -768,6 +768,8 @@ def test_new_cell_rehearsed_on_the_cpu(tiny_benchmark, trace, capsys):
         assert 0 < metrics["moe_tokens_per_held_expert"]["value"] < 16
         assert 0 < metrics["moe_held_experts_hit_share"]["value"] <= 100
         assert metrics["kv_occupancy_peak"]["value"] > 0
+        # short prompts, 4-16 new tokens: what a row attends over a step
+        assert 4 < metrics["decode_context_tokens_mean"]["value"] < 64
         # no device trace on a CPU: nothing read, nothing raised
         for name in ("moe_expert_share.serve", "mla_decode_roofline.serve",
                      "device_idle_share.serve"):

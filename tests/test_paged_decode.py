@@ -1,0 +1,235 @@
+"""The paged decode kernels (the block-table walk of ``ops/attention.py``
+under GPT-2's key and value pools and under the latent pool) against the
+XLA bodies they stand in for on a TPU, on the CPU under Pallas interpret
+mode: ragged contexts, several chunks a row, dead blocks poisoned with
+NaN, buffers that start as NaN.  Nothing here says anything about speed;
+``tests/test_chip_compile.py`` compiles both for the described chip."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import mxnet_tpu  # noqa: F401  (registers ops and variants)
+from mxnet_tpu.ops import attention as att
+from mxnet_tpu.ops.fused import attention_kernels as ak
+
+BLK = 16
+# context lengths (they count the current token) at blocks of 16: no
+# cached token, a block less one, exactly a block, a block and one, ...
+RAGGED = {
+    "one": (1,),
+    "block": (17,),
+    "block-and-one": (18,),
+    "full-table": (8 * BLK,),
+    "mixed": (1, 16, 17, 18, 8 * BLK, 50, 100),
+}
+
+
+def _tables(ctx, max_blocks, cached_only):
+    """Distinct live blocks a row, block 0 as the table's pad.  The
+    kernels read ``ceil((c - 1) / blk)`` blocks of a row, the stock
+    GPT-2 body also scatters into block ``(c - 1) // blk``."""
+    bt = np.zeros((len(ctx), max_blocks), np.int32)
+    nxt = 1
+    for i, c in enumerate(ctx):
+        n = -(-max(c - 1, 0) // BLK) if cached_only else -(-c // BLK)
+        bt[i, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    return bt, nxt
+
+
+def _poison(pool, ctx, bt):
+    """NaN in every block no row's cached tokens reach."""
+    live = {int(b) for i, c in enumerate(ctx)
+            for b in bt[i, :-(-max(c - 1, 0) // BLK)]}
+    pool = np.array(pool)
+    for n in range(pool.shape[0]):
+        if n not in live:
+            pool[n] = np.nan
+    return pool
+
+
+def _kv_case(ctx, dtype="float32", heads=16, dim=64, max_blocks=8, seed=0):
+    rng = np.random.default_rng(seed)
+    bt, n = _tables(ctx, max_blocks, cached_only=False)
+    shape = (n + 1, BLK, heads, dim)
+
+    def rand(s):
+        return jnp.asarray(rng.standard_normal(s), jnp.float32).astype(dtype)
+
+    return [rand((len(ctx), heads, dim)) for _ in range(3)] + [
+        rand(shape), rand(shape), jnp.asarray(bt),
+        jnp.asarray(ctx, jnp.int32)]
+
+
+def _latent_case(ctx, dtype="bfloat16", heads=8, width=640, max_blocks=8,
+                 seed=0):
+    rng = np.random.default_rng(seed)
+    bt, n = _tables(ctx, max_blocks, cached_only=True)
+
+    def rand(s):
+        return jnp.asarray(rng.standard_normal(s), jnp.float32).astype(dtype)
+
+    return [rand((len(ctx), heads, width)), rand((len(ctx), width)),
+            rand((n + 1, BLK, width)), jnp.asarray(bt),
+            jnp.asarray(ctx, jnp.int32)]
+
+
+def _f32(x):
+    return np.asarray(x, np.float32)
+
+
+@pytest.fixture(params=["one-chunk", "two-pages-a-chunk"])
+def chunking(request, monkeypatch):
+    """The walk with the whole table in one chunk, and cut so that a
+    row takes several chunks and ends inside one."""
+    if request.param == "two-pages-a-chunk":
+        # the kernels are jitted: a trace made under the other chunking
+        # must not answer for this one
+        monkeypatch.setattr(att, "_walk_chunk_pages", lambda pools, n: 2)
+        jax.clear_caches()
+    yield request.param
+    if request.param == "two-pages-a-chunk":
+        jax.clear_caches()
+
+
+# ----------------------------------------------------------------------
+# against the XLA bodies
+
+
+@pytest.mark.parametrize("ctx", sorted(RAGGED))
+def test_kv_kernel_equals_the_stock_body(ctx, chunking):
+    """16 heads of 64 in float32, the served width (H.D = 1024)."""
+    args = _kv_case(RAGGED[ctx])
+    ref = att._paged_decode_attention_stock(*args)
+    got = ak.fused_paged_decode_attention(*args)
+    assert got.dtype == ref.dtype and got.shape == ref.shape
+    np.testing.assert_allclose(_f32(got), _f32(ref), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("ctx", sorted(RAGGED))
+def test_latent_kernel_equals_the_xla_body(ctx, dtype, chunking):
+    """640-wide rows (512 of them the values), as the pool holds them."""
+    args = _latent_case(RAGGED[ctx], dtype)
+    ref = att._latent_decode_xla(*args, 0.07, 512)
+    got = att._latent_decode_pallas(*args, 0.07, 512, interpret=True)
+    assert got.dtype == ref.dtype and got.shape == ref.shape
+    tol = 2e-2 if dtype == "bfloat16" else 2e-5
+    np.testing.assert_allclose(_f32(got), _f32(ref), rtol=tol, atol=tol)
+
+
+def test_kv_kernel_takes_a_bfloat16_pool():
+    """A pool one precision down: float32 arithmetic, float32 out."""
+    args = _kv_case(RAGGED["mixed"], "bfloat16", heads=2, dim=64)
+    ref = att._paged_decode_attention_stock(*args)
+    got = ak.fused_paged_decode_attention(*args)
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(_f32(got), _f32(ref), rtol=2e-5, atol=2e-5)
+
+
+# ----------------------------------------------------------------------
+# dead blocks, pad rows, fresh buffers
+
+
+def test_kv_kernel_reads_no_dead_block(chunking):
+    """Every block that a row's cached tokens do not reach holds NaN:
+    the kernel's output is what it was.  The stock body gathers those
+    blocks and its p.v turns 0 x NaN into NaN: that is what it costs."""
+    ctx = RAGGED["mixed"]
+    args = _kv_case(ctx)
+    bt, _ = _tables(ctx, 8, cached_only=True)
+    args[5] = jnp.asarray(bt)
+    clean = ak.fused_paged_decode_attention(*args)
+    args[3] = jnp.asarray(_poison(args[3], ctx, bt))
+    args[4] = jnp.asarray(_poison(args[4], ctx, bt))
+    got = ak.fused_paged_decode_attention(*args)
+    assert np.isfinite(_f32(got)).all()
+    np.testing.assert_array_equal(_f32(got), _f32(clean))
+    assert np.isnan(_f32(att._paged_decode_attention_stock(*args))).any()
+
+
+def test_latent_kernel_reads_no_dead_block(chunking):
+    ctx = RAGGED["mixed"]
+    args = _latent_case(ctx)
+    clean = att._latent_decode_pallas(*args, 0.07, 512, interpret=True)
+    args[2] = jnp.asarray(_poison(_f32(args[2]), ctx, np.asarray(args[3])),
+                          args[2].dtype)
+    got = att._latent_decode_pallas(*args, 0.07, 512, interpret=True)
+    assert np.isfinite(_f32(got)).all()
+    np.testing.assert_array_equal(_f32(got), _f32(clean))
+    assert np.isnan(_f32(att._latent_decode_xla(*args, 0.07, 512))).any()
+
+
+@pytest.mark.parametrize("kernel", ["kv", "latent"])
+def test_a_row_without_cached_tokens_comes_out_finite(kernel):
+    """A pad row (context 0) and a first step (context 1) attend over
+    the current token alone: its value."""
+    ctx = (0, 1, 40)
+    if kernel == "kv":
+        args = _kv_case(ctx)
+        got, own = ak.fused_paged_decode_attention(*args), args[2]
+    else:
+        args = _latent_case(ctx)
+        got = att._latent_decode_pallas(*args, 0.07, 512, interpret=True)
+        own = jnp.broadcast_to(args[1][:, None, :512], got.shape)
+    assert np.isfinite(_f32(got)).all()
+    np.testing.assert_allclose(_f32(got[:2]), _f32(own[:2]), rtol=1e-6)
+
+
+@pytest.mark.parametrize("kernel", ["kv", "latent"])
+def test_the_tail_of_a_chunk_is_never_read(kernel, monkeypatch):
+    """Under the TPU interpreter a buffer starts as NaN, as it may on
+    the chip: what the walk did not copy into a chunk stays out of the
+    result."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    nan_start = pltpu.InterpretParams(uninitialized_memory="nan")
+    ctx = RAGGED["mixed"]
+    if kernel == "kv":
+        args = _kv_case(ctx, heads=2, dim=64)
+        ref = att._paged_decode_attention_stock(*args)
+        monkeypatch.setattr(ak, "_interpret", lambda: nan_start)
+        got = ak.fused_paged_decode_attention(*args)
+        tol = 2e-5
+    else:
+        args = _latent_case(ctx, heads=8)
+        ref = att._latent_decode_xla(*args, 0.07, 512)
+        got = att._latent_decode_pallas(*args, 0.07, 512,
+                                        interpret=nan_start)
+        tol = 2e-2
+    np.testing.assert_allclose(_f32(got), _f32(ref), rtol=tol, atol=tol)
+
+
+# ----------------------------------------------------------------------
+# which path runs
+
+
+def test_chunk_pages_follow_from_the_page_bytes():
+    """A chunk holds what of a power of two pages fits its bytes: 8
+    pages of GPT-2's two float32 pools (128 tokens), 32 of the latent
+    pool's (512), never more than the table."""
+    kv = jax.ShapeDtypeStruct((24 * 680, 16, 1024), jnp.float32)
+    latent = jax.ShapeDtypeStruct((6 * 9600, 16, 640), jnp.bfloat16)
+    assert att._walk_chunk_pages((kv, kv), 64) == 8
+    assert att._walk_chunk_pages((latent,), 256) == 32
+    assert att._walk_chunk_pages((latent,), 4) == 4
+    assert att._walk_tiles(kv, latent)
+    assert not att._walk_tiles(
+        jax.ShapeDtypeStruct((9, 16, 576), jnp.bfloat16))
+    assert not att._walk_tiles(
+        jax.ShapeDtypeStruct((9, 8, 640), jnp.bfloat16))
+
+
+def test_off_the_chip_the_xla_bodies_run():
+    """On the CPU neither entry point reaches a kernel: the bitwise
+    decode parity of ``tests/test_generation.py`` is the stock body's."""
+    from mxnet_tpu.ops import registry
+
+    assert registry.select_variant("paged_decode_attention") is None
+    args = _latent_case((5, 20), "float32")
+    np.testing.assert_array_equal(
+        _f32(att.latent_paged_decode_attention(*args, 0.07, 512)),
+        _f32(att._latent_decode_xla(*args, 0.07, 512)))
