@@ -83,7 +83,7 @@ slo:
 fairness:
 	$(CPU) python tools/loadgen.py
 
-# fused-kernel tier (PR-19): full parity grid (exit nonzero on any
+# kernels: the full parity grid (exit nonzero on any
 # mismatch), then the BENCH_KERNELS=1 lane (which re-gates on the quick
 # grid and measures the optimizer-tree CPU win)
 kernels:

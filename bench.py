@@ -792,10 +792,11 @@ def kernels_main():
     everything else rides on: the quick parity grid (2 cases per
     variant) must be green or the lane's headline value is 0 and
     ``make kernels`` exits nonzero.  ``attn_prefill_ms`` and
-    ``paged_decode_tokens_per_sec`` time the PUBLIC dispatch seam —
-    whatever variant the backend selects, which on CPU is stock, so off
-    TPU they are a stock baseline and never a fused claim (the Pallas
-    variants gate on parity + their ``trainer_compile_flops`` rows).
+    ``paged_decode_tokens_per_sec`` time the PUBLIC functions —
+    whatever body the platform and the shape choose, which on CPU is the
+    reference body, so off TPU they are a baseline and never a kernel
+    claim (the Pallas kernels gate on parity + their
+    ``trainer_compile_flops`` rows).
     ``fused_opt_step_ms`` vs ``stock_opt_step_ms`` is the one measured
     CPU claim: one jitted fused optimizer tree step against the eager
     per-param updater dispatch (the imperative ``model._update_params``
@@ -807,7 +808,7 @@ def kernels_main():
     from mxnet_tpu import observability as obs
     from mxnet_tpu.observability import efficiency as eff
     from mxnet_tpu.ops import attention as oatt
-    from mxnet_tpu.ops.fused import attention_kernels as fak
+    from mxnet_tpu.ops import paged_attention as opaged
     from mxnet_tpu.ops.fused import parity as fpar
     from mxnet_tpu.parallel import trainer as ptr
 
@@ -854,7 +855,7 @@ def kernels_main():
     v_step = jnp.asarray(rs.randn(bsz, heads, dim).astype(np.float32))
     dargs = (dq, k_step, v_step, k_pages, v_pages, jnp.asarray(bt),
              jnp.asarray(ctx, dtype=jnp.int32))
-    decode_ms = _med_ms(jax.jit(oatt.paged_decode_attention), *dargs)
+    decode_ms = _med_ms(jax.jit(opaged.paged_decode_attention), *dargs)
     paged_decode_tokens_per_sec = bsz / (decode_ms / 1e3)
 
     # the optimizer-tree fusion's measured CPU win: eager per-param
@@ -874,28 +875,24 @@ def kernels_main():
         lambda p, g, m: ptr.fused_sgd_mom_tree(attrs, p, g, m))
     fused_opt_step_ms = _med_ms(fused_tree, params, grads, moms)
 
-    # per-variant compile cost: the trainer_compile_flops{cache} rows
-    # the attention variants gate on (analysis only, nothing executes)
-    eff.record_variant_compile("stable_causal_attention", "stock",
-                               oatt._stable_causal_attention_stock,
-                               q, k, v)
-    eff.record_variant_compile("stable_causal_attention", "fused",
-                               fak.fused_prefill_attention, q, k, v)
-    eff.record_variant_compile("paged_decode_attention", "stock",
-                               oatt._paged_decode_attention_stock,
-                               *dargs)
-    eff.record_variant_compile("paged_decode_attention", "fused",
-                               fak.fused_paged_decode_attention, *dargs)
+    # per-kernel compile cost: the trainer_compile_flops{cache} rows the
+    # attention kernels gate on (analysis only, nothing executes), each
+    # kernel and its reference body at its first parity-grid case
+    regs = fpar.parity_registrations()
+    sides = []
+    for kernel in ("flash_prefill_attention", "paged_decode_attention"):
+        reference_fn, kernel_fn, args = regs[kernel].builder(
+            regs[kernel].grid[0])[:3]
+        for side, fn in (("reference", reference_fn), ("kernel", kernel_fn)):
+            eff.record_variant_compile(kernel, side, fn, *args)
+            sides.append("variant:%s:%s" % (kernel, side))
     flops_fam = obs.REGISTRY.get("trainer_compile_flops")
     variant_flops = {}
     if flops_fam is not None:
-        for op_name in ("stable_causal_attention",
-                        "paged_decode_attention"):
-            for var in ("stock", "fused"):
-                cache = "variant:%s:%s" % (op_name, var)
-                val = flops_fam.labels(cache).value
-                if val:
-                    variant_flops[cache] = float(val)
+        for cache in sides:
+            val = flops_fam.labels(cache).value
+            if val:
+                variant_flops[cache] = float(val)
 
     dt = time.perf_counter() - t_start
     print(json.dumps({

@@ -20,10 +20,11 @@ layers on a data=2 x model=2 mesh of four chips, and the same seed on one
 chip, and compares the losses.
 
 Every phase prints one JSON line: the device it ran on, compile seconds
-against step seconds, the compile cache's hits and misses, the fused
-variant each op dispatches to and which native library loaded.  The
-script exits non-zero if a phase fails, if the platform is not ``tpu``
-or if ``ops.registry.fused_fallbacks()`` is non-empty.  On success, and
+against step seconds, the compile cache's hits and misses and which
+native library loaded; a last row says which hot paths lower to their
+Pallas kernel on this platform.  The script exits non-zero if a phase
+fails, if the platform is not ``tpu`` or if one of them lowers without
+its kernel there.  On success, and
 only then, the last line of stdout is
 
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
@@ -123,14 +124,33 @@ def _device():
             "count": len(jax.devices())}
 
 
-def _selected_variants():
-    from mxnet_tpu.ops import registry
+def _kernels_chosen():
+    """Whether each hot path with a Pallas kernel lowers to it on this
+    platform, at the widths the phases above ran (lowering only: a
+    fraction of a second, nothing runs)."""
+    import jax
+    import jax.numpy as jnp
 
-    out = {}
-    for op in sorted(registry.FUSED_VARIANTS):
-        var = registry.select_variant(op)
-        out[op] = "stock" if var is None else var.name
-    return out
+    from mxnet_tpu.ops import attention, paged_attention
+
+    def lowered_with_kernel(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(shape, dtype)
+                for shape, dtype in shapes]
+        return "tpu_custom_call" in jax.jit(fn).lower(*args).as_text()
+
+    f32, i32 = jnp.float32, jnp.int32
+    qkv = ((1, 16, 1536, 64), f32)
+    step, pool = ((4, 16, 64), f32), ((64, 16, 16, 64), f32)
+    return {
+        "flash_attention": lowered_with_kernel(
+            lambda q, k, v: attention.flash_attention(q, k, v, causal=True),
+            *[((8, 16, 2048, 64), jnp.bfloat16)] * 3),
+        "stable_causal_attention": lowered_with_kernel(
+            attention.stable_causal_attention, qkv, qkv, qkv),
+        "paged_decode_attention": lowered_with_kernel(
+            paged_attention.paged_decode_attention, step, step, step,
+            pool, pool, ((4, 8), i32), ((4,), i32)),
+    }
 
 
 def _run_phase(name, fn, cache):
@@ -154,7 +174,6 @@ def _run_phase(name, fn, cache):
     row.update({
         "device": _device(), "wall_s": round(time.perf_counter() - t0, 2),
         "compile_cache": cache.since(before),
-        "fused_variants": _selected_variants(),
         "native": _native.status(),
     })
     print(json.dumps(row), flush=True)
@@ -646,7 +665,6 @@ def main(argv=None, sizes=None):
     import jax
 
     from mxnet_tpu import compile_cache
-    from mxnet_tpu.ops import registry
 
     device = _device()
     on_chip = device["platform"] == "tpu"
@@ -668,13 +686,14 @@ def main(argv=None, sizes=None):
                   ("fit", phase_fit)]
     failed = [name for name, fn in phases
               if not _run_phase(name, lambda f=fn: f(sizes, ns.seed), cache)]
-    fallbacks = registry.fused_fallbacks()
-    print(json.dumps({"fused_fallbacks": {
-        "%s:%s" % k: v for k, v in fallbacks.items()}}), flush=True)
-    if failed or fallbacks or not on_chip:
-        print("chip_smoke: FAILED (phases failed: %s; fused fallbacks: %d; "
-              "platform: %s)" % (failed or "none", len(fallbacks),
-                                 device["platform"]), file=sys.stderr)
+    kernels = _kernels_chosen()
+    print(json.dumps({"kernels_chosen": kernels}), flush=True)
+    without = sorted(k for k, chosen in kernels.items() if not chosen)
+    if failed or not on_chip or without:
+        print("chip_smoke: FAILED (phases failed: %s; lowered without "
+              "their kernel: %s; platform: %s)"
+              % (failed or "none", without or "none", device["platform"]),
+              file=sys.stderr)
         return 1
     print(json.dumps({"ok": True, "device": device}))
     return 0

@@ -75,16 +75,6 @@ site                      planted at
                           ``CorruptMessageError`` the production
                           skip-and-count handler catches; ``delay``
                           stretches the stream-stall window
-``ops.fused``             fused-kernel variant dispatch
-                          (``ops.registry``; ``name`` is
-                          ``<op>:<variant>``).  ``drop``/``raise`` fire
-                          inside the variant path, so the dispatch seam
-                          falls back to stock exactly once and books
-                          ``ops_fused_fallback_total``; ``corrupt``
-                          garbles the variant's output bytes as seen by
-                          the parity harness (``ops/fused/parity.py``),
-                          which must catch the mismatch — the
-                          falsifiability drill for the whole tier
 ========================  ==================================================
 
 Four failure modes:
@@ -137,7 +127,6 @@ SITES = frozenset({
     "kvstore.resize_drop", "checkpoint.write", "storage.write",
     "serving.admit", "serving.dispatch", "serving.scale",
     "serving.decode", "serving.kv_alloc", "serving.route", "data.read",
-    "ops.fused",
 })
 
 

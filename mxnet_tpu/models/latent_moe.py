@@ -30,8 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.attention import (latent_paged_decode_attention,
-                             latent_prefill_attention)
+from ..ops.attention import latent_prefill_attention
+from ..ops.paged_attention import latent_paged_decode_attention
 from ..ops.kv_cache import CacheRow
 from ..parallel import moe as _moe
 from .lm import LMDefinition

@@ -125,19 +125,22 @@ def get_symbol(num_classes=32000, seq_len=1024, num_embed=512, num_heads=8,
 # ``pred_weight``, ...), so a trained ``save_checkpoint`` arg dict drops
 # straight in.
 #
-# Every op is drawn from the shape-stable set in ``ops/attention.py``
-# (mul-reduce scores, elementwise fp32 softmax, ``einsum("btc,fc->btf")``
-# projections, minor-axis layernorm): the bits of token ``t``'s logits
-# are identical whether computed in a T-row prefill, a full-sequence
-# forward, or a 1-row decode step — the KV-cache correctness gate in
-# tests/test_generation.py asserts exact equality.
+# Off the chip every op is drawn from the shape-stable set in
+# ``ops/attention.py`` (mul-reduce scores, elementwise fp32 softmax,
+# ``einsum("btc,fc->btf")`` projections, minor-axis layernorm): the bits
+# of token ``t``'s logits are the same whether computed in a T-row
+# prefill, a full-sequence forward, or a 1-row decode step — the
+# KV-cache correctness gate in tests/test_generation.py.  On a TPU the
+# two attentions take their kernels where the shape allows
+# (``stable_causal_attention``, ``paged_decode_attention``: each says
+# its rule in its own body) and the contract is float32 rounding.
 
 import numpy as np
 import jax.numpy as jnp
 from jax import nn as jnn
 
-from ..ops.attention import paged_decode_attention, stable_causal_attention
-from ..ops.registry import dispatch_variant
+from ..ops.attention import stable_causal_attention
+from ..ops.paged_attention import paged_decode_attention
 
 _LN_EPS = 1e-5
 
@@ -184,13 +187,6 @@ def init_lm_params(cfg, seed=0, scale=0.02):
 
 
 def _lm_ln(x, gamma, beta):
-    # fused-tier seam: the Pallas epilogue kernel is bitwise-equal to
-    # _lm_ln_stock, so the prefill/decode parity gate holds either way
-    return dispatch_variant("lm_layer_norm", _lm_ln_stock, x, gamma,
-                            beta)
-
-
-def _lm_ln_stock(x, gamma, beta):
     mean = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.var(x, axis=-1, keepdims=True)
     y = (x - mean) / jnp.sqrt(var + _LN_EPS)
@@ -207,14 +203,9 @@ def _lm_qkv(x, qkv_weight, cfg):
     return qkv[0], qkv[1], qkv[2]
 
 
-def _lm_gelu_bias_stock(h, bias):
-    return jnn.gelu(h + bias)
-
-
 def _lm_ffn(x, i, params):
     h = jnp.einsum("btc,fc->btf", x, params["l%d_ffn1_weight" % i])
-    h = dispatch_variant("lm_gelu_bias", _lm_gelu_bias_stock, h,
-                         params["l%d_ffn1_bias" % i])
+    h = jnn.gelu(h + params["l%d_ffn1_bias" % i])
     h = jnp.einsum("btc,fc->btf", h, params["l%d_ffn2_weight" % i])
     return h + params["l%d_ffn2_bias" % i]
 

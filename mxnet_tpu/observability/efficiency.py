@@ -305,13 +305,13 @@ def record_compile(cache, lower, steps=1):
 
 
 def record_variant_compile(op_name, variant, fn, *args, **kwargs):
-    """Record one fused-tier variant's compile cost under the cache key
-    ``variant:<op>:<variant>``.
+    """Record the compile cost of one body of a hot path (a kernel or
+    its reference body) under the cache key ``variant:<op>:<variant>``.
 
-    The per-variant ``trainer_compile_flops{cache}`` row is how MFU
-    attribution credits a kernel-level win to the variant that earned
-    it (ISSUE 19) — attention/paged-decode variants gate on parity plus
-    this row, never on a quoted CPU timing.  ``fn(*args, **kwargs)`` is
+    The per-body ``trainer_compile_flops{cache}`` row is how MFU
+    attribution credits a kernel-level win to the body that earned it
+    — the attention kernels gate on parity plus this row, never on a
+    quoted CPU timing.  ``fn(*args, **kwargs)`` is
     jit-lowered for analysis only; nothing executes.  Never raises
     (:func:`record_compile`'s fallback chain applies).
     """

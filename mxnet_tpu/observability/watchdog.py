@@ -596,23 +596,6 @@ def default_rules():
                          "window — check serving_rejected_total"
                          "{reason=quota} by tenant for the runaway "
                          "client or a misconfigured budget"),
-        # fused-kernel tier (ops/registry.py dispatch_variant): each
-        # (op, variant) falls back at most once per process, so any
-        # increase at all is news — a surge past the threshold means a
-        # whole family of kernels went dark (bad deploy, driver/backend
-        # mismatch), not one flaky kernel
-        Rule("fused_fallback_surge", "ops_fused_fallback_total",
-             kind="increase",
-             threshold=_env_float("MXNET_TPU_WATCHDOG_FUSED_FALLBACKS",
-                                  0.0),
-             window_s=_env_float(
-                 "MXNET_TPU_WATCHDOG_FUSED_FALLBACKS_WINDOW_S", 300.0),
-             severity="warning",
-             description="fused-kernel variants fell back to stock "
-                         "inside the window — ops_fused_fallback_total"
-                         "{op,reason} and the ops.fused.fallback event "
-                         "name the kernels; training is correct but "
-                         "slower"),
     ]
     # wire-bandwidth rules (observability/wire.py books): both derive a
     # ratio from two families, so they ride the value_fn seam instead of

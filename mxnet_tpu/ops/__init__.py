@@ -7,11 +7,8 @@ from . import tensor  # noqa: F401
 from . import nn  # noqa: F401
 from . import rnn_op  # noqa: F401
 from . import attention  # noqa: F401
+from . import paged_attention  # noqa: F401  (no op: the decode steps)
 from . import contrib_op  # noqa: F401
 
 # not an op: the generation lane's paged KV-cache allocator
 from . import kv_cache  # noqa: F401
-
-# fused-kernel variant tier: registers Pallas/fused variants of the
-# stock ops above (plus their parity twins), so it imports last
-from . import fused  # noqa: F401

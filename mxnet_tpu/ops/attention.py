@@ -14,8 +14,18 @@ bucketing and model-parallel LSTM; here they are handled the TPU way:
   a mesh ``seq`` axis: K/V blocks rotate around the ring via ``ppermute``
   while each device's query block folds them into an online softmax.  Used
   inside ``shard_map``; communication rides ICI and overlaps with compute.
-* ``MultiHeadAttention`` / ``LayerNorm`` symbol ops so transformer models
-  compose the same way the reference's CNN/RNN layers do.
+* ``stable_causal_attention`` / ``latent_prefill_attention`` — the
+  prefills of the two served models; the decode steps over the paged
+  cache are in :mod:`~mxnet_tpu.ops.paged_attention`.
+* ``MultiHeadAttention`` / ``LayerNorm`` / ``MoE`` symbol ops so
+  transformer models compose the same way the reference's CNN/RNN layers
+  do.
+
+A function with a Pallas kernel holds its whole choice of body in its
+own definition — where the kernel runs
+(:func:`~mxnet_tpu.ops.platform.pallas_mode`) and the shape is one it
+takes, the kernel; otherwise the plain body — and registers the pair
+with the parity harness (:mod:`~mxnet_tpu.ops.fused.parity`).
 """
 
 from __future__ import annotations
@@ -26,13 +36,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .registry import ParamSpec as P, dispatch_variant, register
+from . import platform as _platform
+from .fused.parity import case_rng, register_parity
+from .registry import ParamSpec as P, register
 
-__all__ = ["flash_attention", "ring_attention", "paged_decode_attention",
-           "stable_causal_attention", "latent_prefill_attention",
-           "latent_paged_decode_attention"]
+__all__ = ["flash_attention", "ring_attention", "stable_causal_attention",
+           "latent_prefill_attention", "stable_scores", "stable_softmax",
+           "NEG_INF"]
 
-_NEG_INF = -1e30
+NEG_INF = -1e30
 # Mosaic tiles the last two block dims as (8 sublanes, 128 lanes); per-row
 # vectors (lse, delta) cross pallas_call boundaries broadcast over a
 # 128-lane trailing dim (the layout jax's own TPU flash kernel uses).
@@ -57,7 +69,7 @@ def _attention_fwd_ref(q, k, v, causal, sm_scale, return_lse=False):
                    preferred_element_type=jnp.float32) * sm_scale
     if causal:
         mask = _causal_mask(q.shape[2], k.shape[2], 0, 0)
-        s = jnp.where(mask[None, None], s, _NEG_INF)
+        s = jnp.where(mask[None, None], s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
@@ -91,13 +103,13 @@ def _attention_fwd_ref(q, k, v, causal, sm_scale, return_lse=False):
 # to the last few float32 bits, no longer to all of them.)
 
 
-def _stable_scores(q, k):
+def stable_scores(q, k):
     """fp32 [B, H, T, K] scores via mul-reduce (bitwise stable in T/K)."""
     return jnp.sum(q.astype(jnp.float32)[:, :, :, None, :] *
                    k.astype(jnp.float32)[:, :, None, :, :], axis=-1)
 
 
-def _stable_softmax(s):
+def stable_softmax(s):
     """Row softmax of fp32 scores; masked lanes must already be -1e30."""
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
@@ -105,103 +117,39 @@ def _stable_softmax(s):
 
 
 def stable_causal_attention(q, k, v, sm_scale=None):
-    """Exact causal attention on ``[B, H, T, D]``, shape-stable bits.
+    """Exact causal attention on ``[B, H, T, D]``, float32 out: the
+    generation lane's prefill / full-forward path.
 
-    The generation lane's prefill / full-forward path.  Slower than
-    :func:`flash_attention` (materialises the score matrix) but its
-    output bits do not depend on the query length — the property the
-    paged-decode parity gate relies on.
-
-    Dispatches through the fused tier (``ops/fused``): on eligible
-    backends (or under ``MXNET_TPU_OPS_FUSED_OVERRIDE``) the
-    tolerance-class flash variant runs instead; ``MXNET_TPU_OPS_FUSED=0``
-    pins the stock body below.
+    On a TPU a self-attention prefill (``q`` and ``k`` the same length)
+    is :func:`_flash_dispatch`'s: the flash kernel from 1024 tokens,
+    the exact einsum softmax below that (within float32 rounding of the
+    body below; the parity harness's class ``tolerance``).  Elsewhere,
+    and for a prefill continuation (``k`` longer than ``q``: the flash
+    kernel's mask starts both clocks at zero), the shape-stable body:
+    it materialises the score matrix, but its output bits do not depend
+    on the query length, the property the CPU's paged-decode parity
+    gate relies on.
     """
-    return dispatch_variant("stable_causal_attention",
-                            _stable_causal_attention_stock,
-                            q, k, v, sm_scale=sm_scale)
-
-
-def _stable_causal_attention_stock(q, k, v, sm_scale=None):
     if sm_scale is None:
         sm_scale = 1.0 / float(q.shape[-1]) ** 0.5
-    s = _stable_scores(q, k) * sm_scale
+    mode = _platform.pallas_mode()
+    if mode and q.shape[2] == k.shape[2]:
+        return _flash_dispatch(q, k, v, True, float(sm_scale),
+                               mode == "interpret").astype(jnp.float32)
+    return _stable_causal_attention(q, k, v, sm_scale)
+
+
+def _stable_causal_attention(q, k, v, sm_scale):
+    s = stable_scores(q, k) * sm_scale
     mask = _causal_mask(q.shape[2], k.shape[2], k.shape[2] - q.shape[2], 0)
-    s = jnp.where(mask[None, None], s, _NEG_INF)
-    p = _stable_softmax(s)
+    s = jnp.where(mask[None, None], s, NEG_INF)
+    p = stable_softmax(s)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
 
 
-def paged_decode_attention(q, k_step, v_step, k_pages, v_pages,
-                           block_tables, context_lens, sm_scale=None):
-    """One decode step's attention, K/V gathered through the block table.
-
-    - ``q`` / ``k_step`` / ``v_step``: ``[B, H, D]`` — this step's
-      single query per sequence and its freshly projected K/V (the
-      caller scatters them into the pool on the device, with
-      ``PagedKVCache.write_tokens``, *after* the step succeeded, so a
-      retried dispatch never leaves half-written pages).
-    - ``k_pages`` / ``v_pages``: ``[num_blocks, block_size, H, D]`` —
-      one layer's slice of the shared :class:`~mxnet_tpu.ops.kv_cache.
-      PagedKVCache` pool (device-resident), as of before this step.
-    - ``block_tables``: ``int32 [B, max_blocks]`` — per-sequence page
-      lists, zero-padded (pad rows are masked off below).
-    - ``context_lens``: ``int32 [B]`` — valid tokens per sequence,
-      INCLUDING the current one (whose K/V arrives via ``k_step``).
-
-    Returns ``[B, H, D]``.  The current token is scattered into the
-    gathered keys at position ``context_len - 1`` so the valid keys form
-    the same contiguous prefix a full-sequence forward sees — identical
-    reduction order, and the padded-key masking keeps garbage in
-    unwritten page tails away from the output bits.
-
-    Dispatches through the fused tier: on a TPU the block-table walk
-    (``ops/fused/attention_kernels.py`` over :func:`_walk_pages`) reads
-    the row's live blocks only and folds them into an online softmax,
-    equal to the stock body below within float32 rounding (class
-    ``tolerance``).  The bitwise decode-parity contract is the stock
-    body's, on the CPU.
-    """
-    return dispatch_variant("paged_decode_attention",
-                            _paged_decode_attention_stock,
-                            q, k_step, v_step, k_pages, v_pages,
-                            block_tables, context_lens,
-                            sm_scale=sm_scale)
-
-
-def _paged_decode_attention_stock(q, k_step, v_step, k_pages, v_pages,
-                                  block_tables, context_lens,
-                                  sm_scale=None):
-    if sm_scale is None:
-        sm_scale = 1.0 / float(q.shape[-1]) ** 0.5
-    bsz, max_blocks = block_tables.shape
-    num_blocks, blk = k_pages.shape[:2]
-    heads, dim = q.shape[1], q.shape[2]
-    kmax = max_blocks * blk
-    rows = jnp.arange(bsz)
-    positions = context_lens - 1
-    # gather whole blocks as rows of H*D: that is how the cache stores
-    # them, so the reshape undoes the caller's and the gather reads the
-    # pool where it lies (a gather over [.., H, D] re-lays the layer's
-    # whole pool on a TPU first: its D = 64 is half a lane tile)
-    k = k_pages.reshape(num_blocks, blk, heads * dim)[block_tables]
-    v = v_pages.reshape(num_blocks, blk, heads * dim)[block_tables]
-    k = k.reshape(bsz, kmax, heads, dim)
-    v = v.reshape(bsz, kmax, heads, dim)
-    k = k.at[rows, positions].set(k_step)
-    v = v.at[rows, positions].set(v_step)
-    k = k.transpose(0, 2, 1, 3)            # [B, H, Kmax, D]
-    v = v.transpose(0, 2, 1, 3)
-    s = _stable_scores(q[:, :, None, :], k) * sm_scale   # [B, H, 1, Kmax]
-    pos = lax.broadcasted_iota(jnp.int32, (1, 1, 1, kmax), 3)
-    s = jnp.where(pos < context_lens[:, None, None, None], s, _NEG_INF)
-    p = _stable_softmax(s)
-    out = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
-    return out[:, :, 0, :]
-
-
 # ----------------------------------------------------------------------
-# latent attention (MLA): expanded prefill, absorbed paged decode
+# latent attention (MLA): the expanded prefill (the absorbed paged
+# decode is in ops/paged_attention.py)
 # ----------------------------------------------------------------------
 
 
@@ -213,279 +161,6 @@ def latent_prefill_attention(q, k, v, sm_scale):
     elsewhere, the exact softmax."""
     with jax.named_scope("latent_prefill_attention"):
         return _flash_dispatch(q, k, v, True, float(sm_scale), False)
-
-
-def latent_paged_decode_attention(q, row_step, pages, block_tables,
-                                  context_lens, sm_scale, kv_rank):
-    """One decode step of latent attention in the absorbed form, over
-    the latent pool.
-
-    - ``q`` ``[B, H, W]``: per head ``[q_nope . W_uk | rotated q_rope]``,
-      ``W = kv_rank + rope_dim``: every head reads the same cache row.
-    - ``row_step`` ``[B, W]``: this token's row ``[N(c_kv) | rotated
-      k_rope]`` (written to the pool by the caller after the step).
-    - ``pages`` ``[num_blocks, block_size, W]``: the pool as of before
-      the step; ``block_tables`` ``int32 [B, max_blocks]``;
-      ``context_lens`` ``int32 [B]`` counting the current token.
-
-    Returns ``p . c_kv`` ``[B, H, kv_rank]`` (the caller applies
-    ``W_uv``).  Scores and softmax in float32; the current token enters
-    as a score of its own, so the pool is read as it lies.  On a TPU
-    the block-table walk (:func:`_walk_pages`) reads the blocks that
-    hold live tokens and no other; elsewhere XLA gathers every table
-    block and masks."""
-    if jax.default_backend() == "tpu" and _walk_tiles(pages):
-        return _latent_decode_pallas(
-            q, row_step, pages, block_tables, context_lens,
-            float(sm_scale), int(kv_rank))
-    with jax.named_scope("latent_decode_attention"):
-        return _latent_decode_xla(q, row_step, pages, block_tables,
-                                  context_lens, sm_scale, kv_rank)
-
-
-def _latent_decode_xla(q, row_step, pages, block_tables, context_lens,
-                       sm_scale, kv_rank):
-    bsz, max_blocks = block_tables.shape
-    kmax = max_blocks * pages.shape[1]
-    rows = pages[block_tables].reshape(bsz, kmax, -1)
-    s = jnp.einsum("bhw,bkw->bhk", q, rows,
-                   preferred_element_type=jnp.float32) * sm_scale
-    pos = lax.broadcasted_iota(jnp.int32, (1, 1, kmax), 2)
-    s = jnp.where(pos < (context_lens - 1)[:, None, None], s, _NEG_INF)
-    s_self = jnp.einsum("bhw,bw->bh", q, row_step,
-                        preferred_element_type=jnp.float32) * sm_scale
-    m = jnp.maximum(jnp.max(s, axis=-1), s_self)
-    p = jnp.exp(s - m[..., None])
-    p_self = jnp.exp(s_self - m)
-    out = jnp.einsum("bhk,bkc->bhc", p.astype(rows.dtype),
-                     rows[..., :kv_rank],
-                     preferred_element_type=jnp.float32)
-    out = out + p_self[..., None] * row_step[:, None, :kv_rank]
-    return (out / (jnp.sum(p, axis=-1) + p_self)[..., None]
-            ).astype(q.dtype)
-
-
-# ----------------------------------------------------------------------
-# paged decode on a TPU: one block-table walk under both decode bodies
-# ----------------------------------------------------------------------
-#
-# A decode step attends over ``context_len - 1`` cached tokens a row,
-# which lie in the first ``ceil((context_len - 1) / block_size)`` blocks
-# of the row's table; the table is as wide as the longest sequence the
-# server admits.  The XLA bodies gather, re-lay and score every table
-# block and mask afterwards.  The walk below runs one program a row:
-# tables and lengths are scalar-prefetched, the pools stay in HBM, and a
-# loop over the row's live blocks alone copies a chunk of pages into
-# VMEM while the body folds the chunk before it into an online softmax.
-# A block that holds no live token costs neither a copy nor arithmetic.
-# The current token is the walk's initial state (``init``), so the pool
-# is read as of before the step.
-
-#: bytes of pool pages (all pools of the cache together) a chunk of the
-#: walk holds; a second chunk is in flight behind it.  A page is 2 x 64
-#: KB (GPT-2 medium's float32 key and value rows) or 20 KB (a 640-wide
-#: bfloat16 latent row): one page a loop step would leave the loop's
-#: own cost and a copy's latency larger than the arithmetic on it.
-_WALK_CHUNK_BYTES = 1 << 20
-
-
-def _walk_tiles(*pools):
-    """Whether a page of every pool is whole tiles of the chip's
-    memory (8 x 128 words of 32 bits): a page is copied as it lies."""
-    return all(
-        p.shape[-1] % _LANE == 0
-        and p.shape[-2] % (8 * 4 // jnp.dtype(p.dtype).itemsize) == 0
-        for p in pools)
-
-
-def _walk_chunk_pages(pools, max_blocks):
-    """Pages a chunk: what of a power of two fits the chunk's bytes, at
-    most the table."""
-    page = sum(p.shape[1] * p.shape[2] * jnp.dtype(p.dtype).itemsize
-               for p in pools)
-    fit = max(1, _WALK_CHUNK_BYTES // page)
-    return min(1 << (fit.bit_length() - 1), max_blocks)
-
-
-def _walk_kernel(tables_ref, lens_ref, *refs, n_rows, n_pools, pages, blk,
-                 max_blocks, init, chunk, finish):
-    """One row of the batch.  ``refs``: the row's operands and those
-    every row shares, the pools (in HBM), the output, then scratch: a
-    two-slot chunk buffer a pool, the copies' semaphores ``[pool,
-    slot]`` and the body's state."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    row_refs = refs[:n_rows]
-    pool_refs = refs[n_rows:n_rows + n_pools]
-    out_ref = refs[n_rows + n_pools]
-    scratch = refs[n_rows + n_pools + 1:]
-    bufs, sem, state = scratch[:n_pools], scratch[n_pools], \
-        scratch[n_pools + 1:]
-    b = pl.program_id(0)
-    tokens = pages * blk
-    cached = jnp.maximum(lens_ref[b] - 1, 0)
-    live_pages = (cached + blk - 1) // blk
-    n_chunks = (live_pages + pages - 1) // pages
-
-    def copies(c, slot, wait):
-        # the live pages of chunk ``c``, each copied from where its
-        # table entry says it lies; a wait needs the copy's size only
-        for i in range(pages):
-            page = c * pages + i
-
-            @pl.when(page < live_pages)
-            def _():
-                at = 0 if wait else tables_ref[b * max_blocks + page]
-                for n in range(n_pools):
-                    copy = pltpu.make_async_copy(
-                        pool_refs[n].at[at],
-                        bufs[n].at[slot, pl.ds(i * blk, blk)],
-                        sem.at[n, slot])
-                    copy.wait() if wait else copy.start()
-
-    init(row_refs, state)
-
-    @pl.when(n_chunks > 0)
-    def _():
-        copies(0, 0, False)
-
-    def step(c, carry):
-        slot = lax.rem(c, 2)
-
-        @pl.when(c + 1 < n_chunks)
-        def _():
-            copies(c + 1, 1 - slot, False)
-
-        copies(c, slot, True)
-        live = cached - c * tokens
-        held = [buf.at[slot] for buf in bufs]
-
-        @pl.when(live >= tokens)
-        def _():
-            chunk(row_refs, held, state, None)
-
-        @pl.when(live < tokens)
-        def _():
-            # the row's last chunk: its tail was not copied and holds
-            # whatever the buffer held
-            chunk(row_refs, held, state, live)
-
-        return carry
-
-    lax.fori_loop(0, n_chunks, step, 0)
-    finish(row_refs, state, out_ref)
-
-
-def _walk_pages(init, chunk, finish, rows, shared, pools, block_tables,
-                context_lens, out, state, interpret=False):
-    """Run ``init`` / ``chunk`` / ``finish`` over every row's live
-    blocks.
-
-    ``rows``: per-row operands ``[B, r, c]``, handed to the bodies as
-    ``[1, r, c]`` refs, followed by the ``shared`` ones, whole;
-    ``pools``: ``[num_blocks, block_size, W]`` arrays read through
-    ``block_tables`` ``int32 [B, max_blocks]`` up to ``context_lens -
-    1`` tokens; ``out``: the ``[B, r, c]`` result's
-    ``ShapeDtypeStruct``; ``state``: the bodies' VMEM scratch.
-    ``chunk(row_refs, held, state, live)`` folds ``pages * block_size``
-    tokens (``held``: one ``[tokens, W]`` ref a pool) into the state;
-    ``live`` is None where every token counts and else the number that
-    do, the rest being unspecified bits."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    bsz, max_blocks = block_tables.shape
-    blk = pools[0].shape[1]
-    pages = _walk_chunk_pages(pools, max_blocks)
-    kernel = functools.partial(
-        _walk_kernel, n_rows=len(rows) + len(shared), n_pools=len(pools),
-        pages=pages, blk=blk, max_blocks=max_blocks, init=init, chunk=chunk,
-        finish=finish)
-
-    def one_row(x):
-        return pl.BlockSpec((1,) + x.shape[1:],
-                            lambda b, tables, lens: (b, 0, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(bsz,),
-        in_specs=[one_row(x) for x in rows]
-        + [pl.BlockSpec(x.shape, lambda b, tables, lens, n=x.ndim: (0,) * n)
-           for x in shared]
-        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
-        out_specs=one_row(out),
-        scratch_shapes=[pltpu.VMEM((2, pages * blk, p.shape[2]), p.dtype)
-                        for p in pools]
-        + [pltpu.SemaphoreType.DMA((len(pools), 2))] + list(state))
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",))
-    return pl.pallas_call(
-        kernel, out_shape=out, grid_spec=grid_spec, interpret=interpret,
-        **kwargs)(block_tables.reshape(-1).astype(jnp.int32),
-                  context_lens.astype(jnp.int32), *rows, *shared, *pools)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("sm_scale", "kv_rank", "interpret"))
-def _latent_decode_pallas(q, row_step, pages, block_tables, context_lens,
-                          sm_scale, kv_rank, interpret=False):
-    """The absorbed decode over the walk: a chunk of 640-wide rows is
-    the keys of every head and, its first ``kv_rank`` columns, the
-    values; both products ride the MXU in the rows' dtype, scores,
-    softmax and accumulator stay float32.  Jitted so that a model's
-    layers share one trace and one lowering of the kernel."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    bsz, heads, _ = q.shape
-
-    def init(row_refs, state):
-        q_ref, step_ref = row_refs
-        m_ref, l_ref, acc_ref = state
-        step = step_ref[0].astype(jnp.float32)              # [1, W]
-        m_ref[...] = jnp.sum(q_ref[0].astype(jnp.float32) * step, axis=1,
-                             keepdims=True) * sm_scale
-        l_ref[...] = jnp.ones_like(l_ref)
-        acc_ref[...] = jnp.broadcast_to(step[:, :kv_rank], acc_ref.shape)
-
-    def chunk(row_refs, held, state, live):
-        m_ref, l_ref, acc_ref = state
-        rows = held[0][...]                                 # [T, W]
-        values = rows[:, :kv_rank]
-        s = lax.dot_general(
-            row_refs[0][0], rows, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [H, T]
-        if live is not None:
-            at = lax.broadcasted_iota(jnp.int32, (1, rows.shape[0]), 1)
-            s = jnp.where(at < live, s, _NEG_INF)
-            at = lax.broadcasted_iota(jnp.int32, (rows.shape[0], 1), 0)
-            values = jnp.where(at < live, values, 0)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + lax.dot_general(
-            p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    def finish(row_refs, state, out_ref):
-        _, l_ref, acc_ref = state
-        out_ref[0] = (acc_ref[...] / l_ref[...]).astype(out_ref.dtype)
-
-    # the scope names the kernel in a trace; it has to be the innermost
-    with jax.named_scope("latent_decode_attention"):
-        return _walk_pages(
-            init, chunk, finish, (q, row_step[:, None, :]), (), (pages,),
-            block_tables, context_lens,
-            jax.ShapeDtypeStruct((bsz, heads, kv_rank), q.dtype),
-            [pltpu.VMEM((heads, 1), jnp.float32),
-             pltpu.VMEM((heads, 1), jnp.float32),
-             pltpu.VMEM((heads, kv_rank), jnp.float32)],
-            interpret=interpret)
 
 
 # ----------------------------------------------------------------------
@@ -520,7 +195,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
 
     @pl.when(ki == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
@@ -551,7 +226,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
             valid = col < kv_len
             mask = valid if mask is None else (mask & valid)
         if mask is not None:
-            s = jnp.where(mask, s, _NEG_INF)
+            s = jnp.where(mask, s, NEG_INF)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
         p = jnp.exp(s - m_new[:, None])
@@ -584,8 +259,8 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q=1024, block_k=2048,
                       interpret=False, return_lse=False):
     """Pallas forward on [B, H, T, D].  T is padded to block multiples.
 
-    Default blocks re-tuned r5 on v5e (tools/attn_bench.py sweep at
-    b8h16d64): (1024, 2048) beats the old (512, 1024) by 4-14% across
+    Default blocks re-tuned r5 on a v5e (a sweep at b8h16d64, by a
+    probe that is gone): (1024, 2048) beats the old (512, 1024) by 4-14% across
     T=1024..8192 (e.g. 16.6 -> 14.9 ms at T4096); the backward kernels
     keep (1024, 1024) — their dk/dv pass at block_k=2048 exceeds what
     the compiler will schedule."""
@@ -666,16 +341,16 @@ def _flash(q, k, v, causal, sm_scale, interpret):
 
 
 def _flash_dispatch(q, k, v, causal, sm_scale, interpret):
-    platform = jax.default_backend()
+    """The forward's choice of body: ``interpret`` asks for the kernel
+    whatever the length (under the interpreter off the chip); else on a
+    TPU the kernel from 1024 tokens (the VJP forward's threshold too;
+    past 8K the blocked kernel is the only option, exact attention
+    OOMs), and the exact softmax below that and elsewhere."""
+    on_chip = _platform.pallas_mode() == "chip"
     if interpret:
         return _flash_fwd_pallas(q, k, v, causal, sm_scale,
-                                 interpret=platform != "tpu")
-    # crossover re-measured r5 (tools/attn_bench.py, docs/PERF.md): the
-    # Pallas kernel wins from T>=1024 in the primal too (9.4 vs 12.5 ms
-    # at T2048 b8h16d64; ~tie at 512), matching the VJP-forward's
-    # threshold — and the blocked kernel is the only option past 8K
-    # where exact attention OOMs
-    if platform == "tpu" and (q.shape[2] >= 1024 or k.shape[2] >= 1024):
+                                 interpret=not on_chip)
+    if on_chip and (q.shape[2] >= 1024 or k.shape[2] >= 1024):
         return _flash_fwd_pallas(q, k, v, causal, sm_scale)
     return _attention_fwd_ref(q, k, v, causal, sm_scale)
 
@@ -684,16 +359,15 @@ def _flash_fwd_vjp(q, k, v, causal, sm_scale, interpret):
     """Forward for the VJP: same dispatch as the primal, but every path
     also emits the per-row log-sum-exp so the backward kernels never have
     to re-derive the softmax statistics."""
-    platform = jax.default_backend()
+    on_chip = _platform.pallas_mode() == "chip"
     if interpret:
         out, lse = _flash_fwd_pallas(q, k, v, causal, sm_scale,
-                                     interpret=platform != "tpu",
+                                     interpret=not on_chip,
                                      return_lse=True)
-    elif platform == "tpu" and (q.shape[2] >= 1024 or k.shape[2] >= 1024):
-        # same T>=1024 crossover as the primal (re-measured r5): the
-        # Pallas bwd kernels consume the kernel's lse directly, and
-        # skipping the [T, T] XLA softmax materialization pays off
-        # (measured on the transformer-LM bench, docs/PERF.md)
+    elif on_chip and (q.shape[2] >= 1024 or k.shape[2] >= 1024):
+        # same T>=1024 crossover as the primal: the Pallas bwd kernels
+        # consume the kernel's lse directly, and skipping the [T, T]
+        # XLA softmax materialization pays off
         out, lse = _flash_fwd_pallas(q, k, v, causal, sm_scale,
                                      return_lse=True)
     else:
@@ -944,7 +618,7 @@ def _flash_bwd_scan(q, k, v, o, lse, do, causal, sm_scale):
                        preferred_element_type=jnp.float32) * sm_scale
         if causal:
             mask = (qi >= k_off + ki_local)[None, None]
-            s = jnp.where(mask, s, _NEG_INF)
+            s = jnp.where(mask, s, NEG_INF)
         return s
 
     delta = jnp.sum(dof * o.astype(jnp.float32), axis=-1)  # [B,H,T]
@@ -973,11 +647,11 @@ def _flash_bwd_vjp(causal, sm_scale, interpret, res, do):
     ``interpret=True`` for CPU testing); plain-jax blockwise scan
     elsewhere."""
     q, k, v, o, lse = res
-    platform = jax.default_backend()
+    on_chip = _platform.pallas_mode() == "chip"
     if interpret:
         return _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale,
-                                 interpret=platform != "tpu")
-    if platform == "tpu":
+                                 interpret=not on_chip)
+    if on_chip:
         return _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale)
     return _flash_bwd_scan(q, k, v, o, lse, do, causal, sm_scale)
 
@@ -996,6 +670,38 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, interpret=False):
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     return _flash(q, k, v, bool(causal), float(sm_scale), bool(interpret))
+
+
+def _prefill_case(case):
+    dtype, b, h, t, d = case
+    rng = case_rng(case)
+    q, k, v = (jnp.asarray(rng.standard_normal((b, h, t, d)), jnp.float32)
+               .astype(dtype) for _ in range(3))
+    scale = 1.0 / float(d) ** 0.5
+
+    def kernel(q, k, v):
+        return _flash_fwd_pallas(
+            q, k, v, True, scale,
+            interpret=_platform.pallas_mode() != "chip").astype(jnp.float32)
+
+    # low-precision inputs dominate the error even though both bodies
+    # emit fp32 — class the tolerance by the input dtype
+    tol = (2e-2, 2e-2) if dtype == "bfloat16" else None
+    return (functools.partial(_stable_causal_attention, sm_scale=scale),
+            kernel, (q, k, v), tol)
+
+
+# the flash forward under stable_causal_attention's contract (the
+# generation lane's prefill), against the shape-stable body
+register_parity(
+    "flash_prefill_attention", _prefill_case, parity="tolerance",
+    grid=(
+        ("float32", 1, 2, 64, 16),
+        ("float32", 2, 4, 128, 32),
+        ("float32", 1, 2, 67, 16),       # ragged T (block tail)
+        ("float32", 2, 2, 200, 8),       # ragged T, narrow head
+        ("bfloat16", 1, 2, 128, 32),
+    ))
 
 
 # ----------------------------------------------------------------------
@@ -1031,7 +737,7 @@ def ring_attention(q, k, v, axis_name, causal=False, sm_scale=None):
             qi = my * Tl + lax.broadcasted_iota(jnp.int32, (Tl, Tl), 0)
             ki = src * Tl + lax.broadcasted_iota(jnp.int32, (Tl, Tl), 1)
             mask = (qi >= ki)[None, None]
-            sc = jnp.where(mask, sc, _NEG_INF)
+            sc = jnp.where(mask, sc, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
         p = jnp.exp(sc - m_new[..., None])
         if causal:
@@ -1047,7 +753,7 @@ def ring_attention(q, k, v, axis_name, causal=False, sm_scale=None):
     # derive the initial carry from q so it inherits q's varying-manual-axes
     # type (newer jax rejects scan carries whose vma set changes)
     o0 = qf * 0.0
-    m0 = qf[..., 0] * 0.0 + _NEG_INF
+    m0 = qf[..., 0] * 0.0 + NEG_INF
     l0 = qf[..., 0] * 0.0
     (o, m, l, _, _), _ = lax.scan(
         jax.checkpoint(step), (o0, m0, l0, k, v), jnp.arange(n))

@@ -1,21 +1,10 @@
-"""Fused-kernel operator tier (ISSUE 19): Pallas / hand-fused variants
-behind the ``ops.registry`` dispatch seam, each with a falsifiable
-stock twin in :mod:`.parity`.
-
-Importing this package registers every shipped variant (the kernel
-modules call :func:`~mxnet_tpu.ops.registry.register_variant` +
-:func:`.parity.register_parity` at import time); ``mxnet_tpu.ops``
-imports it last, after the stock op modules it shadows.  Selection
-semantics — kill-switch, per-op override, backend eligibility,
-fallback-once — live in ``ops/registry.py``; see
-``docs/how_to/kernels.md`` for the variant model and how to add one.
+"""The parity harness of the Pallas kernels (:mod:`.parity`): each
+kernel's module registers it there against the reference body its
+function falls back to.  ``docs/how_to/kernels.md`` says how a hot path
+chooses between the two and how to add a kernel.
 """
 
 from . import parity                               # noqa: F401
-from . import attention_kernels                    # noqa: F401
-from . import norm_kernels                         # noqa: F401
-from . import optimizer_kernels                    # noqa: F401
 from .parity import register_parity, run_parity    # noqa: F401
 
-__all__ = ["parity", "register_parity", "run_parity",
-           "attention_kernels", "norm_kernels", "optimizer_kernels"]
+__all__ = ["parity", "register_parity", "run_parity"]

@@ -28,8 +28,8 @@ where it lies.
 **The pools live on the device** (``jax.Array``); the allocator, the
 block tables, the lengths and the gauges live on the host.  A decode
 step hands the device its per-batch ``int32`` block tables (a few KB)
-and :func:`~mxnet_tpu.ops.attention.paged_decode_attention` reads K/V
-rows through the table inside the jitted step (on a TPU a kernel walks
+and :func:`~mxnet_tpu.ops.paged_attention.paged_decode_attention`
+reads K/V rows through the table inside the jitted step (on a TPU a kernel walks
 the table and copies the blocks that hold live tokens; elsewhere XLA
 gathers every table block) — the pool shape is static, so decode
 dispatches never recompile as sequences come and go.

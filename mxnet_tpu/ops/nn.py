@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as _np
 
+from . import platform as _platform
 from .registry import ParamSpec as P
 from .registry import register
 
@@ -113,7 +114,7 @@ def _conv1x1_dgrad_pallas(dy2, wio, out_dtype, bm):
                   pl.BlockSpec((O, I), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((bm, I), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, I), out_dtype),
-        interpret=jax.default_backend() != "tpu")(dy2, wio)
+        interpret=_platform.pallas_mode() != "chip")(dy2, wio)
 
 
 @jax.custom_vjp

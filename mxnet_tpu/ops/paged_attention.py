@@ -1,0 +1,561 @@
+"""Paged decode attention: one decode step read through a block table.
+
+Both models ``LMBackend`` serves decode over a paged cache
+(:mod:`~mxnet_tpu.ops.kv_cache`): GPT-2 over float32 key and value
+pools (:func:`paged_decode_attention`), the latent-attention model over
+one pool of latent rows in the absorbed form
+(:func:`latent_paged_decode_attention`).  Each public function holds
+its whole choice of body: where a Pallas kernel runs
+(:func:`~mxnet_tpu.ops.platform.pallas_mode`) and a page of every pool
+is whole tiles (:func:`_walk_tiles`), the block-table walk
+(:func:`_walk_pages`) under the model's own arithmetic; otherwise the
+XLA body, which gathers every block of the table and masks.  The XLA
+body of GPT-2 is also the CPU's decode-parity contract
+(``tests/test_generation.py``).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from . import platform as _platform
+from .attention import NEG_INF, stable_scores, stable_softmax
+from .fused.parity import case_rng, register_parity
+
+__all__ = ["paged_decode_attention", "latent_paged_decode_attention"]
+
+# Mosaic tiles the last two dims of a block as (8 sublanes, 128 lanes)
+_LANE = 128
+
+
+def paged_decode_attention(q, k_step, v_step, k_pages, v_pages,
+                           block_tables, context_lens, sm_scale=None):
+    """One decode step's attention, K/V gathered through the block table.
+
+    - ``q`` / ``k_step`` / ``v_step``: ``[B, H, D]`` — this step's
+      single query per sequence and its freshly projected K/V (the
+      caller scatters them into the pool on the device, with
+      ``PagedKVCache.write_tokens``, *after* the step succeeded, so a
+      retried dispatch never leaves half-written pages).
+    - ``k_pages`` / ``v_pages``: ``[num_blocks, block_size, H, D]`` —
+      one layer's slice of the shared :class:`~mxnet_tpu.ops.kv_cache.
+      PagedKVCache` pool (device-resident), as of before this step.
+    - ``block_tables``: ``int32 [B, max_blocks]`` — per-sequence page
+      lists, zero-padded (pad rows are masked off below).
+    - ``context_lens``: ``int32 [B]`` — valid tokens per sequence,
+      INCLUDING the current one (whose K/V arrives via ``k_step``).
+
+    Returns ``[B, H, D]``.  The current token is scattered into the
+    gathered keys at position ``context_len - 1`` so the valid keys form
+    the same contiguous prefix a full-sequence forward sees — identical
+    reduction order, and the padded-key masking keeps garbage in
+    unwritten page tails away from the output bits.
+
+    On a TPU, with pages that are whole tiles, the block-table walk
+    (:func:`_kv_decode_pallas` over :func:`_walk_pages`) reads the
+    row's live blocks only and folds them into an online softmax, equal
+    to the XLA body within float32 rounding (the parity harness's class
+    ``tolerance``).  The decode-parity contract with the prefill is the
+    XLA body's, on the CPU.
+    """
+    heads, dim = q.shape[1:]
+    if sm_scale is None:
+        sm_scale = 1.0 / float(dim) ** 0.5
+    # a cached row is H*D values wide and that is how the pool lies:
+    # the reshape undoes the caller's
+    flat = k_pages.shape[:2] + (heads * dim,)
+    k_pages, v_pages = k_pages.reshape(flat), v_pages.reshape(flat)
+    mode = _platform.pallas_mode()
+    if mode and _walk_tiles(k_pages, v_pages):
+        return _kv_decode_pallas(q, k_step, v_step, k_pages, v_pages,
+                                 block_tables, context_lens,
+                                 float(sm_scale), mode == "interpret")
+    return _kv_decode_xla(q, k_step, v_step, k_pages, v_pages,
+                          block_tables, context_lens, sm_scale)
+
+
+def _kv_decode_xla(q, k_step, v_step, k_pages, v_pages, block_tables,
+                   context_lens, sm_scale):
+    bsz, max_blocks = block_tables.shape
+    heads, dim = q.shape[1], q.shape[2]
+    kmax = max_blocks * k_pages.shape[1]
+    rows = jnp.arange(bsz)
+    positions = context_lens - 1
+    # gather whole blocks as rows of H*D, where the pool lies (a gather
+    # over [.., H, D] re-lays the layer's whole pool on a TPU first: its
+    # D = 64 is half a lane tile)
+    k, v = k_pages[block_tables], v_pages[block_tables]
+    k = k.reshape(bsz, kmax, heads, dim)
+    v = v.reshape(bsz, kmax, heads, dim)
+    k = k.at[rows, positions].set(k_step)
+    v = v.at[rows, positions].set(v_step)
+    k = k.transpose(0, 2, 1, 3)            # [B, H, Kmax, D]
+    v = v.transpose(0, 2, 1, 3)
+    s = stable_scores(q[:, :, None, :], k) * sm_scale   # [B, H, 1, Kmax]
+    pos = lax.broadcasted_iota(jnp.int32, (1, 1, 1, kmax), 3)
+    s = jnp.where(pos < context_lens[:, None, None, None], s, NEG_INF)
+    p = stable_softmax(s)
+    out = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
+    return out[:, :, 0, :]
+
+
+def latent_paged_decode_attention(q, row_step, pages, block_tables,
+                                  context_lens, sm_scale, kv_rank):
+    """One decode step of latent attention in the absorbed form, over
+    the latent pool.
+
+    - ``q`` ``[B, H, W]``: per head ``[q_nope . W_uk | rotated q_rope]``,
+      ``W = kv_rank + rope_dim``: every head reads the same cache row.
+    - ``row_step`` ``[B, W]``: this token's row ``[N(c_kv) | rotated
+      k_rope]`` (written to the pool by the caller after the step).
+    - ``pages`` ``[num_blocks, block_size, W]``: the pool as of before
+      the step; ``block_tables`` ``int32 [B, max_blocks]``;
+      ``context_lens`` ``int32 [B]`` counting the current token.
+
+    Returns ``p . c_kv`` ``[B, H, kv_rank]`` (the caller applies
+    ``W_uv``).  Scores and softmax in float32; the current token enters
+    as a score of its own, so the pool is read as it lies.  On a TPU,
+    with pages that are whole tiles, the block-table walk
+    (:func:`_walk_pages`) reads the blocks that hold live tokens and no
+    other; elsewhere XLA gathers every table block and masks."""
+    mode = _platform.pallas_mode()
+    if mode and _walk_tiles(pages):
+        return _latent_decode_pallas(
+            q, row_step, pages, block_tables, context_lens,
+            float(sm_scale), int(kv_rank), mode == "interpret")
+    with jax.named_scope("latent_decode_attention"):
+        return _latent_decode_xla(q, row_step, pages, block_tables,
+                                  context_lens, sm_scale, kv_rank)
+
+
+def _latent_decode_xla(q, row_step, pages, block_tables, context_lens,
+                       sm_scale, kv_rank):
+    bsz, max_blocks = block_tables.shape
+    kmax = max_blocks * pages.shape[1]
+    rows = pages[block_tables].reshape(bsz, kmax, -1)
+    s = jnp.einsum("bhw,bkw->bhk", q, rows,
+                   preferred_element_type=jnp.float32) * sm_scale
+    pos = lax.broadcasted_iota(jnp.int32, (1, 1, kmax), 2)
+    s = jnp.where(pos < (context_lens - 1)[:, None, None], s, NEG_INF)
+    s_self = jnp.einsum("bhw,bw->bh", q, row_step,
+                        preferred_element_type=jnp.float32) * sm_scale
+    m = jnp.maximum(jnp.max(s, axis=-1), s_self)
+    p = jnp.exp(s - m[..., None])
+    p_self = jnp.exp(s_self - m)
+    out = jnp.einsum("bhk,bkc->bhc", p.astype(rows.dtype),
+                     rows[..., :kv_rank],
+                     preferred_element_type=jnp.float32)
+    out = out + p_self[..., None] * row_step[:, None, :kv_rank]
+    return (out / (jnp.sum(p, axis=-1) + p_self)[..., None]
+            ).astype(q.dtype)
+
+
+# ----------------------------------------------------------------------
+# paged decode on a TPU: one block-table walk under both decode bodies
+# ----------------------------------------------------------------------
+#
+# A decode step attends over ``context_len - 1`` cached tokens a row,
+# which lie in the first ``ceil((context_len - 1) / block_size)`` blocks
+# of the row's table; the table is as wide as the longest sequence the
+# server admits.  The XLA bodies gather, re-lay and score every table
+# block and mask afterwards.  The walk below runs one program a row:
+# tables and lengths are scalar-prefetched, the pools stay in HBM, and a
+# loop over the row's live blocks alone copies a chunk of pages into
+# VMEM while the body folds the chunk before it into an online softmax.
+# A block that holds no live token costs neither a copy nor arithmetic.
+# The current token is the walk's initial state (``init``), so the pool
+# is read as of before the step.
+
+#: bytes of pool pages (all pools of the cache together) a chunk of the
+#: walk holds; a second chunk is in flight behind it.  A page is 2 x 64
+#: KB (GPT-2 medium's float32 key and value rows) or 20 KB (a 640-wide
+#: bfloat16 latent row): one page a loop step would leave the loop's
+#: own cost and a copy's latency larger than the arithmetic on it.
+_WALK_CHUNK_BYTES = 1 << 20
+
+
+def _walk_tiles(*pools):
+    """Whether a page of every pool is whole tiles of the chip's
+    memory (8 x 128 words of 32 bits): a page is copied as it lies."""
+    return all(
+        p.shape[-1] % _LANE == 0
+        and p.shape[-2] % (8 * 4 // jnp.dtype(p.dtype).itemsize) == 0
+        for p in pools)
+
+
+def _walk_chunk_pages(pools, max_blocks):
+    """Pages a chunk: what of a power of two fits the chunk's bytes, at
+    most the table."""
+    page = sum(p.shape[1] * p.shape[2] * jnp.dtype(p.dtype).itemsize
+               for p in pools)
+    fit = max(1, _WALK_CHUNK_BYTES // page)
+    return min(1 << (fit.bit_length() - 1), max_blocks)
+
+
+def _walk_kernel(tables_ref, lens_ref, *refs, n_rows, n_pools, pages, blk,
+                 max_blocks, init, chunk, finish):
+    """One row of the batch.  ``refs``: the row's operands and those
+    every row shares, the pools (in HBM), the output, then scratch: a
+    two-slot chunk buffer a pool, the copies' semaphores ``[pool,
+    slot]`` and the body's state."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    row_refs = refs[:n_rows]
+    pool_refs = refs[n_rows:n_rows + n_pools]
+    out_ref = refs[n_rows + n_pools]
+    scratch = refs[n_rows + n_pools + 1:]
+    bufs, sem, state = scratch[:n_pools], scratch[n_pools], \
+        scratch[n_pools + 1:]
+    b = pl.program_id(0)
+    tokens = pages * blk
+    cached = jnp.maximum(lens_ref[b] - 1, 0)
+    live_pages = (cached + blk - 1) // blk
+    n_chunks = (live_pages + pages - 1) // pages
+
+    def copies(c, slot, wait):
+        # the live pages of chunk ``c``, each copied from where its
+        # table entry says it lies; a wait needs the copy's size only
+        for i in range(pages):
+            page = c * pages + i
+
+            @pl.when(page < live_pages)
+            def _():
+                at = 0 if wait else tables_ref[b * max_blocks + page]
+                for n in range(n_pools):
+                    copy = pltpu.make_async_copy(
+                        pool_refs[n].at[at],
+                        bufs[n].at[slot, pl.ds(i * blk, blk)],
+                        sem.at[n, slot])
+                    copy.wait() if wait else copy.start()
+
+    init(row_refs, state)
+
+    @pl.when(n_chunks > 0)
+    def _():
+        copies(0, 0, False)
+
+    def step(c, carry):
+        slot = lax.rem(c, 2)
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            copies(c + 1, 1 - slot, False)
+
+        copies(c, slot, True)
+        live = cached - c * tokens
+        held = [buf.at[slot] for buf in bufs]
+
+        @pl.when(live >= tokens)
+        def _():
+            chunk(row_refs, held, state, None)
+
+        @pl.when(live < tokens)
+        def _():
+            # the row's last chunk: its tail was not copied and holds
+            # whatever the buffer held
+            chunk(row_refs, held, state, live)
+
+        return carry
+
+    lax.fori_loop(0, n_chunks, step, 0)
+    finish(row_refs, state, out_ref)
+
+
+def _walk_pages(init, chunk, finish, rows, shared, pools, block_tables,
+                context_lens, out, state, interpret=False):
+    """Run ``init`` / ``chunk`` / ``finish`` over every row's live
+    blocks.
+
+    ``rows``: per-row operands ``[B, r, c]``, handed to the bodies as
+    ``[1, r, c]`` refs, followed by the ``shared`` ones, whole;
+    ``pools``: ``[num_blocks, block_size, W]`` arrays read through
+    ``block_tables`` ``int32 [B, max_blocks]`` up to ``context_lens -
+    1`` tokens; ``out``: the ``[B, r, c]`` result's
+    ``ShapeDtypeStruct``; ``state``: the bodies' VMEM scratch.
+    ``chunk(row_refs, held, state, live)`` folds ``pages * block_size``
+    tokens (``held``: one ``[tokens, W]`` ref a pool) into the state;
+    ``live`` is None where every token counts and else the number that
+    do, the rest being unspecified bits."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bsz, max_blocks = block_tables.shape
+    blk = pools[0].shape[1]
+    pages = _walk_chunk_pages(pools, max_blocks)
+    kernel = functools.partial(
+        _walk_kernel, n_rows=len(rows) + len(shared), n_pools=len(pools),
+        pages=pages, blk=blk, max_blocks=max_blocks, init=init, chunk=chunk,
+        finish=finish)
+
+    def one_row(x):
+        return pl.BlockSpec((1,) + x.shape[1:],
+                            lambda b, tables, lens: (b, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(bsz,),
+        in_specs=[one_row(x) for x in rows]
+        + [pl.BlockSpec(x.shape, lambda b, tables, lens, n=x.ndim: (0,) * n)
+           for x in shared]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=one_row(out),
+        scratch_shapes=[pltpu.VMEM((2, pages * blk, p.shape[2]), p.dtype)
+                        for p in pools]
+        + [pltpu.SemaphoreType.DMA((len(pools), 2))] + list(state))
+    kwargs = {}
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",))
+    return pl.pallas_call(
+        kernel, out_shape=out, grid_spec=grid_spec, interpret=interpret,
+        **kwargs)(block_tables.reshape(-1).astype(jnp.int32),
+                  context_lens.astype(jnp.int32), *rows, *shared, *pools)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+def _kv_decode_pallas(q, k_step, v_step, k_pages, v_pages, block_tables,
+                      context_lens, sm_scale, interpret=False):
+    """GPT-2's decode over the walk: ``q`` / ``k_step`` / ``v_step``
+    ``[B, H, D]``, the float32 pools ``[num_blocks, block_size, H*D]``.
+
+    A cached row is ``heads * dim`` values wide and a head owns ``dim``
+    = 64 of them, half a lane tile, so nothing here is reshaped to
+    ``[.., heads, dim]``.  The chunk's keys are multiplied by the row's
+    query on the VPU, all heads at once, and a product with the 0/1
+    selector ``[heads, heads * dim]`` sums each head's lanes; the same
+    selector spreads a head's softmax weight over its lanes for p.v.
+    The model is served in float32 and a default-precision product of
+    float32 operands is a bfloat16 one on the chip, so the float32
+    operand goes through the MXU as three bfloat16 terms that sum to
+    it: the selector's entries are exact in bfloat16, so these are
+    float32 sums and copies in half the passes of ``HIGHEST`` (which
+    splits the selector too: 4.19 against 2.85 ms a step of 24 layers
+    on a v5e, both 9e-7 from a float64 softmax).  Jitted so that a
+    model's layers share one trace and one lowering of the kernel: 24
+    of them took a warm start 3.7 s longer, every one lowered anew."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    bsz, heads, dim = q.shape
+    width, scale = heads * dim, sm_scale
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    lane_head = lax.broadcasted_iota(jnp.int32, (heads, width), 1) // dim
+    selector = (lane_head == lax.broadcasted_iota(
+        jnp.int32, (heads, width), 0)).astype(bf16)
+
+    def by_selector(x, sel, contract):
+        # float32 ``x`` as three bfloat16 terms that sum to it, each
+        # through the MXU against the selector, accumulated in float32
+        hi = x.astype(bf16)
+        rest = x - hi.astype(f32)
+        mid = rest.astype(bf16)
+        terms = (hi, mid, (rest - mid.astype(f32)).astype(bf16))
+        return sum(lax.dot_general(t, sel, (contract, ((), ())),
+                                   preferred_element_type=f32)
+                   for t in terms)
+
+    def head_sums(x, sel):                  # [n, W] -> [n, H]
+        return by_selector(x, sel, ((1,), (1,)))
+
+    def over_lanes(x, sel):                 # [n, H] -> [n, W]
+        return by_selector(x, sel, ((1,), (0,)))
+
+    def init(row_refs, state):
+        q_ref, k_ref, v_ref, sel_ref = row_refs
+        m_ref, l_ref, acc_ref = state
+        own = jnp.broadcast_to(k_ref[0] * q_ref[0], (8, width))
+        m_ref[...] = head_sums(own, sel_ref[...])[:1] * scale
+        l_ref[...] = jnp.ones_like(l_ref)
+        acc_ref[...] = v_ref[0]
+
+    def chunk(row_refs, held, state, live):
+        sel = row_refs[3][...]
+        m_ref, l_ref, acc_ref = state
+        keys, values = held[0][...].astype(f32), held[1][...].astype(f32)
+        tokens = keys.shape[0]
+        s = head_sums(keys * row_refs[0][0], sel) * scale       # [T, H]
+        if live is not None:
+            at = lax.broadcasted_iota(jnp.int32, (tokens, 1), 0)
+            s = jnp.where(at < live, s, NEG_INF)
+            values = jnp.where(at < live, values, 0.0)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)                         # [1, H]
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0, keepdims=True)
+        m_ref[...] = m_new
+        # one product spreads the weights and the rescale of what was
+        # accumulated: the selector is loaded once for both
+        spread = over_lanes(jnp.concatenate(
+            [p, jnp.broadcast_to(alpha, (8, heads))], axis=0), sel)
+        acc_ref[...] = spread[tokens:tokens + 1] * acc_ref[...] + jnp.sum(
+            spread[:tokens] * values, axis=0, keepdims=True)
+
+    def finish(row_refs, state, out_ref):
+        _, l_ref, acc_ref = state
+        sums = over_lanes(jnp.broadcast_to(l_ref[...], (8, heads)),
+                          row_refs[3][...])[:1]
+        out_ref[0] = acc_ref[...] / sums
+
+    rows = tuple(x.astype(f32).reshape(bsz, 1, width)
+                 for x in (q, k_step, v_step))
+    # the scope names the kernel in a trace; it has to be the innermost
+    with jax.named_scope("paged_decode_attention"):
+        out = _walk_pages(
+            init, chunk, finish, rows, (selector,), (k_pages, v_pages),
+            block_tables, context_lens,
+            jax.ShapeDtypeStruct((bsz, 1, width), f32),
+            [pltpu.VMEM((1, heads), f32), pltpu.VMEM((1, heads), f32),
+             pltpu.VMEM((1, width), f32)],
+            interpret=interpret)
+    return out.reshape(bsz, heads, dim)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "kv_rank", "interpret"))
+def _latent_decode_pallas(q, row_step, pages, block_tables, context_lens,
+                          sm_scale, kv_rank, interpret=False):
+    """The absorbed decode over the walk: a chunk of 640-wide rows is
+    the keys of every head and, its first ``kv_rank`` columns, the
+    values; both products ride the MXU in the rows' dtype, scores,
+    softmax and accumulator stay float32.  Jitted so that a model's
+    layers share one trace and one lowering of the kernel."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    bsz, heads, _ = q.shape
+
+    def init(row_refs, state):
+        q_ref, step_ref = row_refs
+        m_ref, l_ref, acc_ref = state
+        step = step_ref[0].astype(jnp.float32)              # [1, W]
+        m_ref[...] = jnp.sum(q_ref[0].astype(jnp.float32) * step, axis=1,
+                             keepdims=True) * sm_scale
+        l_ref[...] = jnp.ones_like(l_ref)
+        acc_ref[...] = jnp.broadcast_to(step[:, :kv_rank], acc_ref.shape)
+
+    def chunk(row_refs, held, state, live):
+        m_ref, l_ref, acc_ref = state
+        rows = held[0][...]                                 # [T, W]
+        values = rows[:, :kv_rank]
+        s = lax.dot_general(
+            row_refs[0][0], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale  # [H, T]
+        if live is not None:
+            at = lax.broadcasted_iota(jnp.int32, (1, rows.shape[0]), 1)
+            s = jnp.where(at < live, s, NEG_INF)
+            at = lax.broadcasted_iota(jnp.int32, (rows.shape[0], 1), 0)
+            values = jnp.where(at < live, values, 0)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + lax.dot_general(
+            p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    def finish(row_refs, state, out_ref):
+        _, l_ref, acc_ref = state
+        out_ref[0] = (acc_ref[...] / l_ref[...]).astype(out_ref.dtype)
+
+    # the scope names the kernel in a trace; it has to be the innermost
+    with jax.named_scope("latent_decode_attention"):
+        return _walk_pages(
+            init, chunk, finish, (q, row_step[:, None, :]), (), (pages,),
+            block_tables, context_lens,
+            jax.ShapeDtypeStruct((bsz, heads, kv_rank), q.dtype),
+            [pltpu.VMEM((heads, 1), jnp.float32),
+             pltpu.VMEM((heads, 1), jnp.float32),
+             pltpu.VMEM((heads, kv_rank), jnp.float32)],
+            interpret=interpret)
+
+
+# ----------------------------------------------------------------------
+# parity grids: each kernel against its XLA body (ragged tails on
+# purpose; the widest case of each is the served shape, which
+# tests/test_chip_compile.py compiles for the described chip)
+# ----------------------------------------------------------------------
+
+
+def _paged_case_pool(rng, dtype, blk, max_blocks, ctx, width):
+    """A pool of ``len(ctx) * max_blocks + 1`` random pages and a table
+    with distinct live pages per sequence; table entries past the
+    context keep page 0 (the pad convention), whose garbage both bodies
+    must mask off identically."""
+    import numpy as np
+
+    bsz = len(ctx)
+    pool = (bsz * max_blocks + 1, blk, width)
+    tables = np.zeros((bsz, max_blocks), np.int32)
+    nxt = 1
+    for i, c in enumerate(ctx):
+        for j in range(-(-int(c) // blk)):
+            tables[i, j] = nxt
+            nxt += 1
+
+    def rand(shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32) \
+            .astype(dtype)
+
+    return rand, pool, jnp.asarray(tables), \
+        jnp.asarray(list(ctx), dtype=jnp.int32)
+
+
+def _interpret():
+    return _platform.pallas_mode() != "chip"
+
+
+def _kv_case(case):
+    dtype, h, d, blk, max_blocks, ctx = case
+    rand, pool, tables, lens = _paged_case_pool(
+        case_rng(case), dtype, blk, max_blocks, ctx, h * d)
+    k_pages, v_pages = rand(pool), rand(pool)
+    q, k_step, v_step = (rand((len(ctx), h, d)) for _ in range(3))
+    scale = 1.0 / float(d) ** 0.5
+    return (functools.partial(_kv_decode_xla, sm_scale=scale),
+            functools.partial(_kv_decode_pallas, sm_scale=scale,
+                              interpret=_interpret()),
+            (q, k_step, v_step, k_pages, v_pages, tables, lens))
+
+
+register_parity(
+    "paged_decode_attention", _kv_case, parity="tolerance",
+    grid=(
+        ("float32", 2, 16, 8, 3, (5, 20)),       # ragged contexts
+        ("float32", 4, 32, 16, 2, (1, 17, 32)),  # ctx=1 and full tail
+        ("float32", 2, 8, 4, 4, (3, 16, 9)),
+        ("bfloat16", 2, 64, 8, 2, (3, 9)),       # bf16 pool, fp32 math
+        # the served width (16 heads of 64, blocks of 16): no cached
+        # token, a block less one, exactly a block, a block and one,
+        # the whole table
+        ("float32", 16, 64, 16, 4, (1, 16, 17, 18, 64)),
+    ))
+
+
+def _latent_case(case):
+    dtype, heads, width, kv_rank, blk, max_blocks, ctx = case
+    rand, pool, tables, lens = _paged_case_pool(
+        case_rng(case), dtype, blk, max_blocks, ctx, width)
+    pages = rand(pool)
+    q, row_step = rand((len(ctx), heads, width)), rand((len(ctx), width))
+    tol = (3e-2, 3e-2) if dtype == "bfloat16" else (1e-4, 1e-4)
+    return (functools.partial(_latent_decode_xla, sm_scale=0.1,
+                              kv_rank=kv_rank),
+            functools.partial(_latent_decode_pallas, sm_scale=0.1,
+                              kv_rank=kv_rank, interpret=_interpret()),
+            (q, row_step, pages, tables, lens), tol)
+
+
+register_parity(
+    "latent_decode_attention", _latent_case, parity="tolerance",
+    grid=(
+        ("float32", 4, 48, 32, 8, 3, (5, 20)),       # ragged contexts
+        ("float32", 2, 40, 24, 4, 4, (1, 16, 9)),    # no cached token
+        # the served row (640 wide, 512 of it values, blocks of 16)
+        ("bfloat16", 16, 640, 512, 16, 4, (1, 17, 64)),
+    ))

@@ -25,18 +25,13 @@ An op declares:
 from __future__ import annotations
 
 import ast
-import os
-import threading
 from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as _np
 
 from ..base import MXNetError
 
-__all__ = ["Op", "ParamSpec", "register", "get_op", "list_ops", "OP_REGISTRY",
-           "OpVariant", "FUSED_VARIANTS", "register_variant", "fused_enabled",
-           "select_variant", "dispatch_variant", "fused_fallbacks",
-           "reset_fused_dispatch"]
+__all__ = ["Op", "ParamSpec", "register", "get_op", "list_ops", "OP_REGISTRY"]
 
 OP_REGISTRY: Dict[str, "Op"] = {}
 _ALIAS: Dict[str, str] = {}
@@ -183,31 +178,13 @@ class Op:
 
     # -- compute -------------------------------------------------------
     def apply(self, attrs, args, auxs=(), is_train=False, rng=None):
-        """Run the compute rule.  Returns (outputs_list, new_aux_list).
-
-        When the fused tier (``MXNET_TPU_OPS_FUSED``) selects a variant
-        for this op, the variant's compute rule runs instead — same
-        ``(attrs, *tensors)`` convention.  A variant that raises at
-        dispatch falls back to the stock rule and is booked out of
-        selection for the rest of the process (exactly one
-        ``ops_fused_fallback_total`` increment + ops event per
-        (op, variant))."""
+        """Run the compute rule.  Returns (outputs_list, new_aux_list)."""
         kw = {}
         if self.needs_mode:
             kw["is_train"] = is_train
         if self.needs_rng:
             kw["rng"] = rng
-        tensors = list(args) + list(auxs)
-        var = select_variant(self.name)
-        if var is not None:
-            try:
-                _chaos_visit(self.name, var.name)
-                out = var.fn(attrs, *tensors, **kw)
-            except Exception as exc:  # noqa: BLE001 — fallback seam
-                _record_fused_fallback(self.name, var.name, exc)
-                out = self.fn(attrs, *tensors, **kw)
-        else:
-            out = self.fn(attrs, *tensors, **kw)
+        out = self.fn(attrs, *args, *auxs, **kw)
         n_out = self.n_outputs(attrs)
         if not isinstance(out, tuple):
             out = (out,)
@@ -262,223 +239,3 @@ def get_op(name: str) -> Op:
 
 def list_ops() -> List[str]:
     return sorted(set(OP_REGISTRY) | set(_ALIAS))
-
-
-# ---------------------------------------------------------------------------
-# fused-kernel variant tier (ops/fused/) — the dispatch seam
-# ---------------------------------------------------------------------------
-#
-# Each op (a registry ``Op`` OR a functional hot path like
-# ``paged_decode_attention``) may carry named *variants*: ``stock`` is
-# the implementation that already lives in the op module, anything else
-# is a Pallas kernel / hand-fused jitted composite registered by
-# ``mxnet_tpu.ops.fused``.  Selection is per jax backend with a global
-# kill-switch (``MXNET_TPU_OPS_FUSED=0`` → stock everywhere,
-# bit-identical to a tree without this tier) and a per-op override
-# (``MXNET_TPU_OPS_FUSED_OVERRIDE="LayerNorm=fused,sgd_mom_update=stock"``
-# — forces a named variant regardless of its backend eligibility, or
-# forces stock).  A variant that raises at dispatch falls back to stock
-# and is booked out of selection: exactly one
-# ``ops_fused_fallback_total{op,reason}`` increment, one
-# ``ops.fused.fallback`` event, per (op, variant).  The ``ops.fused``
-# chaos site is visited on every variant dispatch (``drop`` forces the
-# fallback path; ``corrupt`` is consumed by the parity harness, which
-# routes variant output bytes through the site).
-
-
-class OpVariant:
-    """One named implementation of an op in the fused tier.
-
-    ``fn`` follows the *op convention* ``fn(attrs, *tensors)`` when the
-    name is a registry op dispatched through :meth:`Op.apply`, and the
-    *plain convention* ``fn(*args, **kwargs)`` when dispatched through
-    :func:`dispatch_variant` (functional hot paths).  ``backends`` is
-    the tuple of jax platforms the variant is eligible on by default;
-    ``parity`` is the contract class the parity harness asserts —
-    ``"bitwise"`` (output bits equal stock's) or ``"tolerance"``
-    (dtype-classed allclose; reduction reorder allowed).
-    """
-
-    __slots__ = ("op_name", "name", "fn", "backends", "parity")
-
-    def __init__(self, op_name, name, fn, backends=("tpu",),
-                 parity="bitwise"):
-        if parity not in ("bitwise", "tolerance"):
-            raise MXNetError("variant parity must be 'bitwise' or "
-                             "'tolerance', got %r" % (parity,))
-        if name == "stock":
-            raise MXNetError("'stock' names the built-in path; register "
-                             "variants under another name")
-        self.op_name = op_name
-        self.name = name
-        self.fn = fn
-        self.backends = tuple(backends)
-        self.parity = parity
-
-    def __repr__(self):
-        return "OpVariant(%s:%s)" % (self.op_name, self.name)
-
-
-#: op name -> {variant name -> OpVariant}, in registration order.
-FUSED_VARIANTS: Dict[str, Dict[str, OpVariant]] = {}
-
-_FUSED_LOCK = threading.Lock()
-_FUSED_FAILED: Dict = {}        # (op, variant) -> reason class name
-_FUSED_BACKEND = []             # cached jax.default_backend()
-_OVERRIDE_CACHE = [None, {}]    # [env string, parsed dict]
-_FALLBACK_FAMILY = []           # lazily registered counter family
-
-
-def register_variant(op_name, variant, fn=None, backends=("tpu",),
-                     parity="bitwise"):
-    """Register ``fn`` as variant ``variant`` of op ``op_name``.
-
-    Usable directly or as a decorator.  The graftcheck ``fused-parity``
-    rule requires every call site to pass LITERAL op/variant names and
-    to have a matching ``register_parity`` registration
-    (``mxnet_tpu/ops/fused/parity.py``)."""
-    def deco(f):
-        var = OpVariant(op_name, variant, f, backends=backends,
-                        parity=parity)
-        with _FUSED_LOCK:
-            FUSED_VARIANTS.setdefault(op_name, {})[variant] = var
-        return f
-
-    if fn is not None:
-        return deco(fn)
-    return deco
-
-
-def fused_enabled():
-    """The tier kill-switch: ``MXNET_TPU_OPS_FUSED`` (default on).
-    ``0`` restores stock behavior everywhere, bit for bit."""
-    return os.environ.get("MXNET_TPU_OPS_FUSED", "1").strip().lower() \
-        not in ("0", "false", "off", "no")
-
-
-def _fused_override():
-    """``MXNET_TPU_OPS_FUSED_OVERRIDE="op=variant,..."`` parsed + cached
-    per env value (``variant`` = ``stock`` forces the built-in path)."""
-    env = os.environ.get("MXNET_TPU_OPS_FUSED_OVERRIDE")
-    if env != _OVERRIDE_CACHE[0]:
-        parsed = {}
-        for part in (env or "").split(","):
-            part = part.strip()
-            if not part:
-                continue
-            if "=" not in part:
-                raise MXNetError(
-                    "MXNET_TPU_OPS_FUSED_OVERRIDE entry %r: need "
-                    "op=variant" % part)
-            k, v = part.split("=", 1)
-            parsed[k.strip()] = v.strip()
-        with _FUSED_LOCK:
-            _OVERRIDE_CACHE[0] = env
-            _OVERRIDE_CACHE[1] = parsed
-    return _OVERRIDE_CACHE[1]
-
-
-def _backend():
-    if not _FUSED_BACKEND:
-        import jax
-
-        _FUSED_BACKEND.append(jax.default_backend())
-    return _FUSED_BACKEND[0]
-
-
-def select_variant(op_name) -> Optional[OpVariant]:
-    """The dispatch decision for one op: the variant to run, or ``None``
-    for stock.  Kill-switch beats override beats backend eligibility;
-    a variant that already fell back is never re-selected."""
-    variants = FUSED_VARIANTS.get(op_name)
-    if not variants or not fused_enabled():
-        return None
-    forced = _fused_override().get(op_name)
-    if forced is not None:
-        if forced == "stock":
-            return None
-        var = variants.get(forced)
-        if var is None:
-            raise MXNetError(
-                "MXNET_TPU_OPS_FUSED_OVERRIDE names unknown variant "
-                "%r of op %r (have %s)"
-                % (forced, op_name, sorted(variants)))
-        if (op_name, var.name) in _FUSED_FAILED:
-            return None
-        return var
-    backend = _backend()
-    for var in variants.values():
-        if backend in var.backends \
-                and (op_name, var.name) not in _FUSED_FAILED:
-            return var
-    return None
-
-
-def _chaos_visit(op_name, variant, payload=None):
-    """Visit the ``ops.fused`` chaos site for one variant dispatch
-    (``name`` is ``op:variant`` so ``match`` can drill one kernel)."""
-    from .. import chaos as _chaos
-
-    return _chaos.visit("ops.fused", payload,
-                        name="%s:%s" % (op_name, variant))
-
-
-def _fallback_counter():
-    if not _FALLBACK_FAMILY:
-        from ..observability import metrics as _metrics
-
-        with _FUSED_LOCK:
-            if not _FALLBACK_FAMILY:
-                _FALLBACK_FAMILY.append(_metrics.counter(
-                    "ops_fused_fallback_total",
-                    "fused-tier variants that raised at dispatch and "
-                    "fell back to stock (one increment per (op, variant) "
-                    "per process — the fast path silently degraded)",
-                    ["op", "reason"]))
-    return _FALLBACK_FAMILY[0]
-
-
-def _record_fused_fallback(op_name, variant, exc):
-    """Book a variant out of selection — once per (op, variant)."""
-    reason = type(exc).__name__
-    with _FUSED_LOCK:
-        if (op_name, variant) in _FUSED_FAILED:
-            return
-        _FUSED_FAILED[(op_name, variant)] = reason
-    _fallback_counter().labels(op_name, reason).inc()
-    from ..observability.events import emit as _emit
-
-    _emit("ops.fused.fallback", op=op_name, variant=variant,
-          reason=reason, error=str(exc)[:200])
-
-
-def dispatch_variant(op_name, stock_fn, *args, **kwargs):
-    """The functional seam: run ``op_name``'s selected variant over
-    plain arrays (``fn(*args, **kwargs)``), falling back to
-    ``stock_fn`` with the same once-per-(op, variant) bookkeeping as
-    :meth:`Op.apply`.  Constant-time when no variant is registered."""
-    var = select_variant(op_name)
-    if var is None:
-        return stock_fn(*args, **kwargs)
-    try:
-        _chaos_visit(op_name, var.name)
-        return var.fn(*args, **kwargs)
-    except Exception as exc:  # noqa: BLE001 — fallback seam
-        _record_fused_fallback(op_name, var.name, exc)
-        return stock_fn(*args, **kwargs)
-
-
-def fused_fallbacks():
-    """Snapshot of booked fallbacks {(op, variant): reason class}."""
-    with _FUSED_LOCK:
-        return dict(_FUSED_FAILED)
-
-
-def reset_fused_dispatch():
-    """Test hook: clear the fallback book and cached backend/override so
-    a re-configured environment re-selects from scratch."""
-    with _FUSED_LOCK:
-        _FUSED_FAILED.clear()
-        del _FUSED_BACKEND[:]
-        _OVERRIDE_CACHE[0] = None
-        _OVERRIDE_CACHE[1] = {}

@@ -17,6 +17,7 @@ per-layer ``priority=-index`` push/pull scheduling plays by hand
 from __future__ import annotations
 
 import contextlib as _contextlib
+import functools
 import time as _time
 
 from typing import Dict, Optional
@@ -31,6 +32,7 @@ from ..observability import attribution as _attr
 from ..observability import efficiency as _eff
 from ..observability import memory as _mem
 from ..observability import metrics as _metrics
+from ..ops.fused.parity import case_rng, register_parity
 
 __all__ = ["ShardedTrainer", "auto_tp_specs", "zero_extend_spec"]
 
@@ -151,18 +153,20 @@ def resolve_update_op(optimizer, optimizer_params, momentum, learning_rate,
 
 
 def sgd_mom_tree_stock(attrs, params, grads, moms, ok=None):
-    """Stock whole-tree momentum step: one ``sgd_mom_update`` per
-    parameter, then (when ``ok`` is given) the ``skip_nonfinite`` guard
-    as separate keep-old passes over each subtree — the per-parameter
-    dispatch shape the reference updater (``model.py _update_params``)
-    and the trainer's generic loop both spell.  Returns
-    ``(new_params, new_moms)`` dicts over the same keys."""
-    from ..ops.tensor import _sgd_mom_update
+    """The reference spelling of the whole-tree momentum step, kept for
+    the parity harness and the old bench (the trainer does not reach
+    it): one ``sgd_mom_update`` per parameter, then (when ``ok`` is
+    given) the ``skip_nonfinite`` guard as separate keep-old passes over
+    each subtree — the per-parameter dispatch shape the reference
+    updater (``model.py _update_params``) and the trainer's generic loop
+    both spell.  Returns ``(new_params, new_moms)`` dicts over the same
+    keys."""
+    from ..ops.registry import get_op
 
+    update = get_op("sgd_mom_update").fn
     new_p, new_m = {}, {}
     for n in params:
-        new_p[n], new_m[n] = _sgd_mom_update(attrs, params[n], grads[n],
-                                             moms[n])
+        new_p[n], new_m[n] = update(attrs, params[n], grads[n], moms[n])
     if ok is not None:
         keep = jax.tree_util.tree_map
         new_p = keep(lambda a, b: jnp.where(ok, a, b), new_p,
@@ -173,16 +177,13 @@ def sgd_mom_tree_stock(attrs, params, grads, moms, ok=None):
 
 
 def fused_sgd_mom_tree(attrs, params, grads, moms, ok=None):
-    """Fused whole-tree momentum step (ISSUE 19 hot path b): rescale +
-    clip + weight decay + momentum + the ``skip_nonfinite`` select, all
-    folded into ONE pass per leaf, one jitted dispatch for the whole
-    parameter tree — no per-parameter op dispatches and no post-update
-    guard round trips over the tree.  Registered as the
-    ``sgd_mom_tree_update``/``fused`` variant
-    (``ops/fused/optimizer_kernels.py``); bitwise-equal to
-    :func:`sgd_mom_tree_stock` (the parity harness holds it to byte
-    equality, and the trainer reaches it only through the dispatch
-    seam, so ``MXNET_TPU_OPS_FUSED=0`` restores the stock spelling)."""
+    """The whole-tree momentum step the trainer's bare-momentum SGD
+    runs: rescale + clip + weight decay + momentum + the
+    ``skip_nonfinite`` select, all folded into ONE pass per leaf inside
+    the jitted step — no per-parameter op dispatches and no post-update
+    guard round trips over the tree.  Plain jax on every backend (not a
+    Pallas kernel); the parity harness holds it to
+    :func:`sgd_mom_tree_stock`'s bytes."""
     lr, wd = attrs["lr"], attrs["wd"]
     mu, rescale = attrs["momentum"], attrs["rescale_grad"]
     clip = attrs.get("clip_gradient")
@@ -201,6 +202,34 @@ def fused_sgd_mom_tree(attrs, params, grads, moms, ok=None):
     out = {n: leaf(params[n], grads[n], moms[n]) for n in params}
     return ({n: wm[0] for n, wm in out.items()},
             {n: wm[1] for n, wm in out.items()})
+
+
+def _sgd_mom_tree_case(case):
+    guard, clip = case
+    rng = case_rng(case)
+    shapes = {"w1": (64,), "w2": (7, 9), "w3": (128, 3), "b": (5,)}
+
+    def tree():
+        return {n: jnp.asarray(rng.standard_normal(s), jnp.float32)
+                for n, s in shapes.items()}
+
+    params, grads, moms = tree(), tree(), tree()
+    attrs = {"lr": 0.05, "wd": 1e-4, "momentum": 0.9,
+             "rescale_grad": 1.0, "clip_gradient": clip}
+    ok = None if guard is None else jnp.asarray(guard)
+    return (functools.partial(sgd_mom_tree_stock, attrs),
+            functools.partial(fused_sgd_mom_tree, attrs),
+            (params, grads, moms, ok))
+
+
+register_parity(
+    "sgd_mom_tree", _sgd_mom_tree_case, parity="bitwise", pallas=False,
+    grid=(
+        (None, -1.0),    # no guard
+        (True, -1.0),    # guard passes: update applies
+        (False, 0.5),    # guard trips: every leaf keeps old state
+        (True, 0.25),    # guard + clip
+    ))
 
 
 def resolve_lr_fn(lr_scheduler, learning_rate):
@@ -624,13 +653,9 @@ class ShardedTrainer:
                 if lr_fn is not None:
                     attrs["lr"] = lr_fn(t_new)
             if use_tree:
-                from ..ops.registry import dispatch_variant
-
-                okv = ok if guard else None
-                tree_p, tree_m = dispatch_variant(
-                    "sgd_mom_tree_update", sgd_mom_tree_stock, attrs,
-                    {n: params[n] for n in diff}, grads,
-                    {n: moms[n] for n in diff}, okv)
+                tree_p, tree_m = fused_sgd_mom_tree(
+                    attrs, {n: params[n] for n in diff}, grads,
+                    {n: moms[n] for n in diff}, ok if guard else None)
                 new_params.update(tree_p)
                 new_moms.update(tree_m)
                 if guard:

@@ -3,8 +3,10 @@
 The chip's compiler is installed here and compiles for a chip that is
 described, not attached (``/opt/skills/guides/on-chip-measurement`` §2.3).
 Every Pallas kernel on ``chip_smoke.py``'s path is compiled at the
-smoke's widths, and every fused variant that is eligible on ``"tpu"`` at
-its widest parity-grid shape: scoped-VMEM overflows, unsupported vector
+smoke's widths, every kernel of the parity harness at its widest grid
+shape, and the serving cells' programs at the benchmark's buckets, where
+the choice of body each hot path states (the platform and the shape) is
+read off the compiled program: scoped-VMEM overflows, unsupported vector
 types and Mosaic kernels that GSPMD cannot partition are refused HERE,
 at no chip time, while they pass every interpret-mode test.  Nothing
 runs, so these say nothing about results or speed; a compile that passes
@@ -28,11 +30,10 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-import mxnet_tpu  # noqa: F401  (registers ops and variants)
+import mxnet_tpu  # noqa: F401  (registers ops and the kernels' parity)
 from mxnet_tpu.ops import attention as att
-from mxnet_tpu.ops import registry
-from mxnet_tpu.ops.fused import attention_kernels, norm_kernels
-from mxnet_tpu.ops.fused import optimizer_kernels, parity
+from mxnet_tpu.ops import paged_attention as paged
+from mxnet_tpu.ops.fused import parity
 
 BF16, F32 = jnp.bfloat16, jnp.float32
 
@@ -57,12 +58,10 @@ def topo():
 
 @pytest.fixture
 def on_tpu(monkeypatch):
-    """Make platform-sniffing code take its TPU branch: real kernels,
-    not interpret mode, and the variants eligible on ``"tpu"``."""
+    """Make the platform test (``ops.platform.pallas_mode``) answer as
+    on the chip: every rule takes its TPU branch, real kernels and not
+    interpret mode."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    registry.reset_fused_dispatch()
-    yield
-    registry.reset_fused_dispatch()
 
 
 def _compile(fn, args, sharding):
@@ -104,81 +103,27 @@ def test_flash_kernels_compile(topo, on_tpu, shape, direction):
         _compile(_flash_bwd, (x, x, x, x, lse, x), one)
 
 
-# ----------------------------------------------------------------------
-# the fused variants at the smoke's widths
-
-_LN_ATTRS = {"axis": -1, "eps": 1e-5}
-_SGD_ATTRS = {"lr": 0.1, "wd": 1e-4, "momentum": 0.9, "rescale_grad": 1.0,
-              "clip_gradient": -1.0}
-
-
-def _ln_fwd_bwd(x, g, b):
-    # the train step differentiates this op: compile both directions
-    def loss(x, g, b):
-        out = norm_kernels.fused_layer_norm_op(_LN_ATTRS, x, g, b)
-        return out.astype(F32).sum(), out
-
-    return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(x, g, b)
-
-
-_AT_SMOKE_WIDTH = {
-    # train_lm: [B, T, C] bf16 activations, fp32 scale and shift
-    "LayerNorm-8x2048x1024-bf16": (
-        _ln_fwd_bwd,
-        (_s((8, 2048, 1024), BF16), _s((1024,), F32), _s((1024,), F32))),
-    # serve_lm prefill (one prompt of 1536) and decode (bucket 4)
-    "lm_layer_norm-prefill": (
-        norm_kernels.fused_lm_layer_norm,
-        (_s((1, 1536, 1024), F32), _s((1024,), F32), _s((1024,), F32))),
-    "lm_layer_norm-decode": (
-        norm_kernels.fused_lm_layer_norm,
-        (_s((4, 1, 1024), F32), _s((1024,), F32), _s((1024,), F32))),
-    "lm_gelu_bias-prefill": (
-        norm_kernels.fused_lm_gelu_bias,
-        (_s((1, 1536, 4096), F32), _s((4096,), F32))),
-    "lm_gelu_bias-decode": (
-        norm_kernels.fused_lm_gelu_bias,
-        (_s((4, 1, 4096), F32), _s((4096,), F32))),
-    "stable_causal_attention-prefill": (
-        attention_kernels.fused_prefill_attention,
-        (_s((1, 16, 1536, 64), F32),) * 3),
-    # the widest shapes refused before the row grid (ISSUE 21's table)
-    "lm_layer_norm-8x2048x1024": (
-        norm_kernels.fused_lm_layer_norm,
-        (_s((8, 2048, 1024), F32), _s((1024,), F32), _s((1024,), F32))),
-    "lm_gelu_bias-8x2048x4096": (
-        norm_kernels.fused_lm_gelu_bias,
-        (_s((8, 2048, 4096), F32), _s((4096,), F32))),
-    # the eager optimizer step of Module.fit, per parameter: the FFN
-    # weight, the vocab bias (1-D), a conv weight (3-wide minor dim)
-    "sgd_mom_update-1024x4096": (
-        lambda w, g, m: optimizer_kernels.fused_sgd_mom_update(
-            _SGD_ATTRS, w, g, m), (_s((1024, 4096), F32),) * 3),
-    "sgd_mom_update-32000": (
-        lambda w, g, m: optimizer_kernels.fused_sgd_mom_update(
-            _SGD_ATTRS, w, g, m), (_s((32000,), F32),) * 3),
-    "sgd_mom_update-512x512x3x3": (
-        lambda w, g, m: optimizer_kernels.fused_sgd_mom_update(
-            _SGD_ATTRS, w, g, m), (_s((512, 512, 3, 3), F32),) * 3),
-}
-
-
-@pytest.mark.parametrize("case", sorted(_AT_SMOKE_WIDTH))
-def test_fused_variants_compile_at_smoke_width(topo, on_tpu, case):
-    fn, args = _AT_SMOKE_WIDTH[case]
-    compiled = _compile(fn, args, SingleDeviceSharding(topo.devices[0]))
-    assert "tpu_custom_call" in compiled.as_text(), \
-        "%s compiled without its kernel" % case
+def test_gpt2_prefill_takes_flash_at_the_smoke_width(topo, on_tpu):
+    """``chip_smoke.py``'s serve_lm prefill (one prompt of 1536, 16
+    heads of 64, float32) is past the 1024 crossover: there
+    ``stable_causal_attention`` is the flash forward, and no ``[T, T]``
+    score matrix is held."""
+    x = _s((1, 16, 1536, 64), F32)
+    compiled = _compile(att.stable_causal_attention, (x, x, x),
+                        SingleDeviceSharding(topo.devices[0]))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "1536,1536]" not in text
 
 
 # ----------------------------------------------------------------------
-# the rule: nothing is eligible on "tpu" that this compile refuses
+# every Pallas kernel of the parity harness at its widest grid shape:
+# nothing is chosen on a TPU that this compile refuses
 
 
-def _tpu_variants():
-    return sorted(
-        (op, name) for op, variants in registry.FUSED_VARIANTS.items()
-        for name, var in variants.items() if "tpu" in var.backends)
+def _pallas_kernels():
+    return sorted(name for name, reg in
+                  parity.parity_registrations().items() if reg.pallas)
 
 
 def _widest(reg):
@@ -190,24 +135,14 @@ def _widest(reg):
     return max(reg.grid, key=size)
 
 
-@pytest.mark.parametrize("op,variant", _tpu_variants(),
-                         ids=["%s:%s" % k for k in _tpu_variants()])
-def test_every_tpu_variant_compiles_at_widest_parity_shape(
-        topo, on_tpu, op, variant):
-    reg = parity._PARITY[(op, variant)]
-    _, fused, args = reg.builder(_widest(reg))[:3]
-    _compile(fused, args, SingleDeviceSharding(topo.devices[0]))
-
-
-def test_paged_decode_variant_is_eligible_on_tpu():
-    """The block-table walk took the slot of the kernel the compiler
-    refused at every shape: eligible on ``"tpu"`` and nowhere else, held
-    to its stock twin within float32 rounding (the online softmax
-    reorders the sums), and in the interpret-mode parity grid."""
-    var = registry.FUSED_VARIANTS["paged_decode_attention"]["fused"]
-    assert var.backends == ("tpu",)
-    assert var.parity == "tolerance"
-    assert ("paged_decode_attention", "fused") in parity._PARITY
+@pytest.mark.parametrize("kernel", _pallas_kernels())
+def test_every_kernel_compiles_at_its_widest_parity_shape(
+        topo, on_tpu, kernel):
+    reg = parity.parity_registrations()[kernel]
+    _, fn, args = reg.builder(_widest(reg))[:3]
+    compiled = _compile(fn, args, SingleDeviceSharding(topo.devices[0]))
+    assert "tpu_custom_call" in compiled.as_text(), \
+        "%s compiled without its kernel" % kernel
 
 
 # ----------------------------------------------------------------------
@@ -265,14 +200,14 @@ def _named_calls(text, scope):
 
 def _kv_kernel(q, k_step, v_step, k_pool, v_pool, tables, lens):
     heads = (-1, _POOL[2], 16, 64)
-    return attention_kernels.fused_paged_decode_attention(
+    return paged.paged_decode_attention(
         q, k_step, v_step, k_pool.reshape(heads), v_pool.reshape(heads),
         tables, lens)
 
 
 def _latent_kernel(q, row, pool, tables, lens):
-    return att.latent_paged_decode_attention(q, row, pool, tables, lens,
-                                             0.1, 512)
+    return paged.latent_paged_decode_attention(q, row, pool, tables, lens,
+                                               0.1, 512)
 
 
 _PAGED_KERNELS = {
@@ -301,7 +236,16 @@ def test_paged_decode_kernels_compile_at_the_cells_shapes(
     assert _named_calls(text, name) == 1
     assert _big_moves(text, 2 ** 20) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
-    assert not registry.fused_fallbacks()
+
+
+class _Shapes(object):
+    """``init_lm_params`` for its names and shapes alone."""
+
+    def __init__(self, seed):
+        pass
+
+    def randn(self, *shape):
+        return np.broadcast_to(np.float32(0), shape)
 
 
 @pytest.mark.parametrize("body", ["xla", "kernel"])
@@ -318,17 +262,7 @@ def test_decode_step_reads_the_pool_where_it_lies(topo, monkeypatch,
 
     if body == "kernel":
         request.getfixturevalue("on_tpu")
-
-    class Shapes(object):
-        """``init_lm_params`` for its names and shapes alone."""
-
-        def __init__(self, seed):
-            pass
-
-        def randn(self, *shape):
-            return np.broadcast_to(np.float32(0), shape)
-
-    monkeypatch.setattr(np.random, "RandomState", Shapes)
+    monkeypatch.setattr(np.random, "RandomState", _Shapes)
     cfg = tfm.lm_config(num_classes=50257, seq_len=1024, num_embed=1024,
                         num_heads=16, num_layers=_POOL[0])
     one = SingleDeviceSharding(topo.devices[0])
@@ -351,11 +285,134 @@ def test_decode_step_reads_the_pool_where_it_lies(topo, monkeypatch,
     assert _pool_sized(text) == []
     temp = compiled.memory_analysis().temp_size_in_bytes
     if body == "kernel":
+        # exactly its 24 decode kernels and no other custom call (the
+        # LayerNorm and GELU row kernels went in PR 28)
         assert _named_calls(text, "paged_decode_attention") == _POOL[0]
+        assert text.count("tpu_custom_call") == _POOL[0]
         assert temp < 64 * 2 ** 20      # the gathered keys are gone
-        assert not registry.fused_fallbacks()
     else:
         assert temp < 512 * 2 ** 20
+
+
+# ----------------------------------------------------------------------
+# which body each hot path chooses, read off the compiled program
+
+
+def _traffic(name):
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "traffic", name)) as f:
+        return json.load(f)
+
+
+def _holds(text, shape):
+    """Whether an array of that shape (a regex) is in the program."""
+    import re
+
+    return re.search(shape, text) is not None
+
+
+_GPT2_BUCKETS = _traffic("serve-chat-closed16.json")["prefill_buckets"]
+_DOTS_BUCKETS = _traffic("serve-chat-closed64-4k.json")["prefill_buckets"]
+
+
+@pytest.mark.parametrize("bucket", _GPT2_BUCKETS)
+def test_gpt2_prefill_buckets_run_the_exact_softmax(topo, on_tpu,
+                                                    monkeypatch, bucket):
+    """``gpt2m-serve-chat``'s prefill at every bucket (all under 1024),
+    two layers at the model's width: ``stable_causal_attention`` hands a
+    TPU prefill to ``_flash_dispatch``, which below 1024 tokens takes the
+    einsum softmax: no flash custom call, and not the CPU contract's
+    mul-reduce over ``[B, H, T, K, D]`` either."""
+    from mxnet_tpu.models import transformer as tfm
+
+    monkeypatch.setattr(np.random, "RandomState", _Shapes)
+    cfg = tfm.lm_config(num_classes=50257, seq_len=1024, num_embed=1024,
+                        num_heads=16, num_layers=2)
+    one = SingleDeviceSharding(topo.devices[0])
+    params = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one)
+              for k, v in tfm.init_lm_params(cfg).items()}
+    text = jax.jit(lambda p, t: tfm.lm_prefill(p, t, cfg)).lower(
+        params, jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=one)
+    ).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert not _holds(text, r"f32\[(1,)?16,%d,%d,64\]" % (bucket, bucket))
+    assert _holds(text, r"f32\[(1,)?16,%d,%d\]" % (bucket, bucket))
+
+
+@pytest.mark.parametrize("bucket", _DOTS_BUCKETS)
+def test_latent_prefill_buckets_take_flash_from_1024(topo, on_tpu, bucket):
+    """``dots-vlm1-serve-chat64``'s prefill at every bucket, one dense
+    and one expert layer at the model's widths: the flash kernel under
+    its scope's name from 1024 tokens, the exact softmax (a ``[128, T,
+    T]`` score matrix, no custom call of that name) below."""
+    from mxnet_tpu.models import latent_moe as lm
+
+    one = SingleDeviceSharding(topo.devices[0])
+    _, cfg, params = _latent_moe_shapes(one, num_hidden_layers=2)
+    text = jax.jit(lambda p, t, n: lm.prefill(p, t, n, cfg)).lower(
+        params, jax.ShapeDtypeStruct((bucket,), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
+    ).compile().as_text()
+    scores = r"f32\[(1,)?128,%d,%d\]" % (bucket, bucket)
+    if bucket >= 1024:
+        assert _named_calls(text, "latent_prefill_attention") \
+            == cfg["num_layers"]
+        assert not _holds(text, scores)
+    else:
+        assert _named_calls(text, "latent_prefill_attention") == 0
+        assert _holds(text, scores)
+
+
+def _ragged_kv(q, k_step, v_step, k_pool, v_pool, tables, lens):
+    return paged.paged_decode_attention(q, k_step, v_step, k_pool, v_pool,
+                                        tables, lens)
+
+
+_RAGGED_POOLS = {
+    # 12 heads of 40: a cached row of 480 values is 3.75 lane tiles
+    "kv": (_ragged_kv,
+           (_s((16, 12, 40), F32),) * 3 + (_s((680, 16, 12, 40), F32),) * 2
+           + (_s((16, 64), jnp.int32), _s((16,), jnp.int32))),
+    # the latent row before it was padded to 640: 4.5 lane tiles
+    "latent": (lambda q, row, pool, tables, lens:
+               paged.latent_paged_decode_attention(q, row, pool, tables,
+                                                   lens, 0.1, 512),
+               (_s((64, 128, 576), BF16), _s((64, 576), BF16),
+                _s((9600, 16, 576), BF16), _s((64, 256), jnp.int32),
+                _s((64,), jnp.int32))),
+}
+
+
+@pytest.mark.parametrize("model", sorted(_RAGGED_POOLS))
+def test_a_pool_of_ragged_pages_gets_the_xla_body(topo, on_tpu, model):
+    """The shape half of the rule: a pool whose pages are not whole
+    tiles cannot be copied as it lies, so on a TPU too both entry
+    points take their XLA body (the program compiles, with no custom
+    call)."""
+    fn, args = _RAGGED_POOLS[model]
+    compiled = _compile(fn, args, SingleDeviceSharding(topo.devices[0]))
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("kernel", sorted(_PAGED_KERNELS))
+def test_a_kernel_that_fails_to_trace_raises_to_the_caller(
+        topo, on_tpu, monkeypatch, kernel):
+    """There is no fallback book: a kernel body that raises while it is
+    traced fails the program that asked for it, at the shapes the
+    serving cells run, and is never turned into a slower program."""
+    def broken(*args, **kwargs):
+        raise RuntimeError("the walk cannot be traced")
+
+    monkeypatch.setattr(paged, "_walk_pages", broken)
+    jax.clear_caches()      # the kernels are jitted: drop sound traces
+    fn, _, args = _PAGED_KERNELS[kernel]
+    try:
+        with pytest.raises(RuntimeError, match="cannot be traced"):
+            _compile(fn, args, SingleDeviceSharding(topo.devices[0]))
+    finally:
+        jax.clear_caches()
 
 
 # ----------------------------------------------------------------------
@@ -404,9 +461,8 @@ def test_sharded_lm_step_compiles(topo, on_tpu):
         type_dict={"data": "int32"}, learning_rate=1e-3, momentum=0.9,
         rescale_grad=1.0 / (batch * seq))
     text = _lower_step(trainer).compile().as_text()
-    # flash forward + its two backward passes + three LayerNorms
-    assert text.count("tpu_custom_call") >= 6
-    assert not registry.fused_fallbacks()
+    # flash forward + its two backward passes
+    assert text.count("tpu_custom_call") >= 3
 
 
 # ----------------------------------------------------------------------
@@ -414,9 +470,10 @@ def test_sharded_lm_step_compiles(topo, on_tpu):
 # 11 GB of abstract weights, nothing allocated
 
 
-def _latent_moe_shapes(one):
+def _latent_moe_shapes(one, **cut):
     """``dots-vlm1-ep16`` as the benchmark builds it: the program's
-    configuration and its weights as shapes on the described chip."""
+    configuration and its weights as shapes on the described chip
+    (``cut``: fields of the file to override, a shallower model)."""
     import json
 
     from benchmark.spec import load_module
@@ -424,7 +481,7 @@ def _latent_moe_shapes(one):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs",
                            "dots-vlm1-ep16.json")) as f:
-        doc = json.load(f)
+        doc = dict(json.load(f), **cut)
     family = load_module(os.path.join(root, "benchmark", "models",
                                       "latent_moe.py"), "family_latent_moe")
     params = {k: jax.ShapeDtypeStruct(

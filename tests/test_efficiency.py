@@ -324,7 +324,7 @@ def test_worker_serves_metrics_alerts_and_profile(monkeypatch):
             srv.url.replace("/metrics", "/alerts"), timeout=10)
             .read().decode())
         assert isinstance(alerts["alerts"], list)
-        assert alerts["rules"] == 22  # incl. efficiency, SLO burn, wire, quarantine, fused + memory rules
+        assert alerts["rules"] == 21  # incl. efficiency, SLO burn, wire, quarantine + memory rules
         prof = json.loads(urllib.request.urlopen(
             srv.url.replace("/metrics", "/profile?ms=5"), timeout=60)
             .read().decode())

@@ -711,76 +711,6 @@ def test_atomic_write_pragma_suppresses(tmp_path):
     assert _run(root, "atomic-write") == []
 
 
-# -- fused-parity -----------------------------------------------------------
-
-def test_fused_parity_orphan_variant_flagged(tmp_path):
-    root = _mini(tmp_path, {"mxnet_tpu/ops/fused/k.py": """\
-        from ..registry import register_variant
-
-        def fused_foo(x):
-            return x
-        register_variant("foo", "fused", fused_foo, backends=("tpu",))
-        """})
-    findings = _run(root, "fused-parity")
-    assert [(f.path, f.line) for f in findings] == [
-        (os.path.join("mxnet_tpu", "ops", "fused", "k.py"), 5)]
-    assert "foo:fused" in findings[0].message
-    assert "register_parity" in findings[0].message
-
-
-def test_fused_parity_matched_pair_clean(tmp_path):
-    root = _mini(tmp_path, {"mxnet_tpu/ops/fused/k.py": """\
-        from ..registry import register_variant
-        from .parity import register_parity
-
-        def fused_foo(x):
-            return x
-        register_variant("foo", "fused", fused_foo, backends=("tpu",))
-
-        def _case(case):
-            return (lambda x: x), fused_foo, (case,)
-        register_parity("foo", "fused", _case, grid=(1, 2))
-        """})
-    assert _run(root, "fused-parity") == []
-
-
-def test_fused_parity_non_literal_name_flagged(tmp_path):
-    # a computed op name defeats the static pairing this rule exists
-    # to give reviewers — flagged even if a parity twin might exist
-    root = _mini(tmp_path, {"mxnet_tpu/ops/fused/k.py": """\
-        from ..registry import register_variant
-
-        OP = "foo"
-        register_variant(OP, "fused", lambda x: x)
-        """})
-    findings = _run(root, "fused-parity")
-    assert [(f.path, f.line) for f in findings] == [
-        (os.path.join("mxnet_tpu", "ops", "fused", "k.py"), 4)]
-    assert "literal" in findings[0].message
-
-
-def test_fused_parity_pragma_suppresses(tmp_path):
-    root = _mini(tmp_path, {"mxnet_tpu/ops/fused/k.py": """\
-        from ..registry import register_variant
-
-        # experiment-only kernel, parity twin lands with the real PR
-        # graftcheck: disable-next=fused-parity
-        register_variant("foo", "fused", lambda x: x)
-        """})
-    assert _run(root, "fused-parity") == []
-
-
-def test_fused_parity_test_fixtures_exempt(tmp_path):
-    # tests may register deliberately broken variants for the harness
-    # to catch; only runtime files are in scope
-    root = _mini(tmp_path, {"tests/test_k.py": """\
-        from mxnet_tpu.ops.registry import register_variant
-
-        register_variant("foo", "broken", lambda x: x + 1)
-        """})
-    assert _run(root, "fused-parity") == []
-
-
 # -- the tier-1 gate: this repo stays clean ---------------------------------
 
 def test_whole_repo_zero_unbaselined(capsys):

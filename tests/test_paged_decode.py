@@ -1,7 +1,7 @@
-"""The paged decode kernels (the block-table walk of ``ops/attention.py``
-under GPT-2's key and value pools and under the latent pool) against the
-XLA bodies they stand in for on a TPU, on the CPU under Pallas interpret
-mode: ragged contexts, several chunks a row, dead blocks poisoned with
+"""The paged decode kernels (the block-table walk of
+``ops/paged_attention.py`` under GPT-2's key and value pools and under
+the latent pool) against the XLA bodies they stand in for on a TPU, on
+the CPU under Pallas interpret mode: ragged contexts, several chunks a row, dead blocks poisoned with
 NaN, buffers that start as NaN.  Nothing here says anything about speed;
 ``tests/test_chip_compile.py`` compiles both for the described chip."""
 
@@ -11,11 +11,11 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-import mxnet_tpu  # noqa: F401  (registers ops and variants)
-from mxnet_tpu.ops import attention as att
-from mxnet_tpu.ops.fused import attention_kernels as ak
+import mxnet_tpu  # noqa: F401  (registers ops)
+from mxnet_tpu.ops import paged_attention as att
 
 BLK = 16
+SCALE = 0.125       # 1 / sqrt(64), what paged_decode_attention derives
 # context lengths (they count the current token) at blocks of 16: no
 # cached token, a block less one, exactly a block, a block and one, ...
 RAGGED = {
@@ -54,7 +54,7 @@ def _poison(pool, ctx, bt):
 def _kv_case(ctx, dtype="float32", heads=16, dim=64, max_blocks=8, seed=0):
     rng = np.random.default_rng(seed)
     bt, n = _tables(ctx, max_blocks, cached_only=False)
-    shape = (n + 1, BLK, heads, dim)
+    shape = (n + 1, BLK, heads * dim)    # a pool as the cache holds it
 
     def rand(s):
         return jnp.asarray(rng.standard_normal(s), jnp.float32).astype(dtype)
@@ -75,6 +75,14 @@ def _latent_case(ctx, dtype="bfloat16", heads=8, width=640, max_blocks=8,
     return [rand((len(ctx), heads, width)), rand((len(ctx), width)),
             rand((n + 1, BLK, width)), jnp.asarray(bt),
             jnp.asarray(ctx, jnp.int32)]
+
+
+def _kv_xla(*args):
+    return att._kv_decode_xla(*args, SCALE)
+
+
+def _kv_kernel(*args, interpret=True):
+    return att._kv_decode_pallas(*args, SCALE, interpret)
 
 
 def _f32(x):
@@ -100,11 +108,11 @@ def chunking(request, monkeypatch):
 
 
 @pytest.mark.parametrize("ctx", sorted(RAGGED))
-def test_kv_kernel_equals_the_stock_body(ctx, chunking):
+def test_kv_kernel_equals_the_xla_body(ctx, chunking):
     """16 heads of 64 in float32, the served width (H.D = 1024)."""
     args = _kv_case(RAGGED[ctx])
-    ref = att._paged_decode_attention_stock(*args)
-    got = ak.fused_paged_decode_attention(*args)
+    ref = _kv_xla(*args)
+    got = _kv_kernel(*args)
     assert got.dtype == ref.dtype and got.shape == ref.shape
     np.testing.assert_allclose(_f32(got), _f32(ref), rtol=2e-5, atol=2e-5)
 
@@ -124,8 +132,8 @@ def test_latent_kernel_equals_the_xla_body(ctx, dtype, chunking):
 def test_kv_kernel_takes_a_bfloat16_pool():
     """A pool one precision down: float32 arithmetic, float32 out."""
     args = _kv_case(RAGGED["mixed"], "bfloat16", heads=2, dim=64)
-    ref = att._paged_decode_attention_stock(*args)
-    got = ak.fused_paged_decode_attention(*args)
+    ref = _kv_xla(*args)
+    got = _kv_kernel(*args)
     assert got.dtype == jnp.float32
     np.testing.assert_allclose(_f32(got), _f32(ref), rtol=2e-5, atol=2e-5)
 
@@ -142,13 +150,13 @@ def test_kv_kernel_reads_no_dead_block(chunking):
     args = _kv_case(ctx)
     bt, _ = _tables(ctx, 8, cached_only=True)
     args[5] = jnp.asarray(bt)
-    clean = ak.fused_paged_decode_attention(*args)
+    clean = _kv_kernel(*args)
     args[3] = jnp.asarray(_poison(args[3], ctx, bt))
     args[4] = jnp.asarray(_poison(args[4], ctx, bt))
-    got = ak.fused_paged_decode_attention(*args)
+    got = _kv_kernel(*args)
     assert np.isfinite(_f32(got)).all()
     np.testing.assert_array_equal(_f32(got), _f32(clean))
-    assert np.isnan(_f32(att._paged_decode_attention_stock(*args))).any()
+    assert np.isnan(_f32(_kv_xla(*args))).any()
 
 
 def test_latent_kernel_reads_no_dead_block(chunking):
@@ -170,7 +178,7 @@ def test_a_row_without_cached_tokens_comes_out_finite(kernel):
     ctx = (0, 1, 40)
     if kernel == "kv":
         args = _kv_case(ctx)
-        got, own = ak.fused_paged_decode_attention(*args), args[2]
+        got, own = _kv_kernel(*args), args[2]
     else:
         args = _latent_case(ctx)
         got = att._latent_decode_pallas(*args, 0.07, 512, interpret=True)
@@ -180,7 +188,7 @@ def test_a_row_without_cached_tokens_comes_out_finite(kernel):
 
 
 @pytest.mark.parametrize("kernel", ["kv", "latent"])
-def test_the_tail_of_a_chunk_is_never_read(kernel, monkeypatch):
+def test_the_tail_of_a_chunk_is_never_read(kernel):
     """Under the TPU interpreter a buffer starts as NaN, as it may on
     the chip: what the walk did not copy into a chunk stays out of the
     result."""
@@ -190,9 +198,8 @@ def test_the_tail_of_a_chunk_is_never_read(kernel, monkeypatch):
     ctx = RAGGED["mixed"]
     if kernel == "kv":
         args = _kv_case(ctx, heads=2, dim=64)
-        ref = att._paged_decode_attention_stock(*args)
-        monkeypatch.setattr(ak, "_interpret", lambda: nan_start)
-        got = ak.fused_paged_decode_attention(*args)
+        ref = _kv_xla(*args)
+        got = _kv_kernel(*args, interpret=nan_start)
         tol = 2e-5
     else:
         args = _latent_case(ctx, heads=8)
@@ -223,13 +230,53 @@ def test_chunk_pages_follow_from_the_page_bytes():
         jax.ShapeDtypeStruct((9, 8, 640), jnp.bfloat16))
 
 
-def test_off_the_chip_the_xla_bodies_run():
-    """On the CPU neither entry point reaches a kernel: the bitwise
-    decode parity of ``tests/test_generation.py`` is the stock body's."""
-    from mxnet_tpu.ops import registry
+def _pallas_calls(fn, *args):
+    return str(jax.make_jaxpr(fn)(*args)).count("pallas_call")
 
-    assert registry.select_variant("paged_decode_attention") is None
+
+def test_off_the_chip_the_xla_bodies_run():
+    """On the CPU neither entry point reaches a kernel: the decode
+    parity of ``tests/test_generation.py`` is the XLA body's."""
+    kv = _kv_case((5, 20))
+    pools = [p.reshape(p.shape[:2] + (16, 64)) for p in kv[3:5]]
+    public = kv[:3] + pools + kv[5:]
+    assert _pallas_calls(att.paged_decode_attention, *public) == 0
+    np.testing.assert_array_equal(
+        _f32(att.paged_decode_attention(*public)), _f32(_kv_xla(*kv)))
     args = _latent_case((5, 20), "float32")
+    assert _pallas_calls(
+        lambda *a: att.latent_paged_decode_attention(*a, 0.07, 512),
+        *args) == 0
     np.testing.assert_array_equal(
         _f32(att.latent_paged_decode_attention(*args, 0.07, 512)),
         _f32(att._latent_decode_xla(*args, 0.07, 512)))
+
+
+@pytest.mark.parametrize("model", ["kv", "latent"])
+def test_where_pallas_runs_the_rule_takes_the_kernel(model, monkeypatch):
+    """The platform test patched to the interpreter: both entry points
+    take their kernel for pages of whole tiles (one ``pallas_call``, the
+    XLA body's result within the kernel's class) and keep the XLA body
+    for a pool whose pages are not."""
+    from mxnet_tpu.ops import platform
+
+    monkeypatch.setattr(platform, "pallas_mode", lambda: "interpret")
+    if model == "kv":
+        kv = _kv_case((5, 20, 40))
+        pools = [p.reshape(p.shape[:2] + (16, 64)) for p in kv[3:5]]
+        args, fn = kv[:3] + pools + kv[5:], att.paged_decode_attention
+        ref, tol = _kv_xla(*kv), 2e-5
+        ragged = _kv_case((5, 20, 40), heads=2, dim=24)
+        ragged[3:5] = [p.reshape(p.shape[:2] + (2, 24)) for p in ragged[3:5]]
+    else:
+        args = _latent_case((5, 20, 40))
+        ref, tol = att._latent_decode_xla(*args, 0.07, 512), 2e-2
+        ragged = _latent_case((5, 20, 40), width=576)
+
+        def fn(*a):
+            return att.latent_paged_decode_attention(*a, 0.07, 512)
+
+    assert _pallas_calls(fn, *args) == 1
+    np.testing.assert_allclose(_f32(fn(*args)), _f32(ref), rtol=tol,
+                               atol=tol)
+    assert _pallas_calls(fn, *ragged) == 0

@@ -307,34 +307,11 @@ def test_default_rules_clean_registry_fires_nothing():
                      "stream_stall",
                      "request_p99_slo", "inter_token_p99",
                      "queue_saturation", "quota_shed_surge",
-                     "fused_fallback_surge",
                      "wire_bytes_regression", "wire_codec_share",
                      "oom_proximity", "kv_cache_pressure",
                      "slo_availability_fast_burn",
                      "slo_availability_slow_burn",
                      "slo_latency_fast_burn", "slo_latency_slow_burn"]
-
-
-def test_fused_fallback_surge_once_per_edge():
-    state = {"v": 0.0}
-
-    def src():
-        return ("# TYPE ops_fused_fallback_total counter\n"
-                "ops_fused_fallback_total{op=\"foo\","
-                "reason=\"variant_error\"} %s\n" % state["v"])
-
-    rule = [r for r in obs.default_rules()
-            if r.name == "fused_fallback_surge"][0]
-    wd = obs.Watchdog([rule], source=src)
-    assert wd.evaluate(now=0.0) == []          # flat: no fallbacks
-    state["v"] = 2.0
-    (alert,) = wd.evaluate(now=1.0)            # rose within the window
-    assert alert.name == "fused_fallback_surge"
-    assert alert.severity == "warning"
-    assert len(wd.evaluate(now=2.0)) == 1      # stays red…
-    fired = obs.REGISTRY.get("cluster_alerts_fired_total")
-    # …but a continuing red is still the SAME episode: one rising edge
-    assert fired.labels("fused_fallback_surge").value == 1
 
 
 # ---------------------------------------------------------------------------

@@ -342,20 +342,19 @@ def gen_python_md():
     w("\n## Random (`mx.random`)\n")
     _doc_functions(w, mx.random, ["seed"])
 
-    w("\n## Fused-kernel variants (`mxnet_tpu.ops.fused`)\n")
+    w("\n## Kernels and their parity harness (`mxnet_tpu.ops.fused`)\n")
     from mxnet_tpu.ops import fused as _fused
-    from mxnet_tpu.ops import registry as _opreg
+    from mxnet_tpu.ops import platform as _opplat
     from mxnet_tpu.ops.fused import parity as _fpar
-    w(_first_para(_fused.__doc__) + "\n\nDispatch seam "
-      "(`mxnet_tpu.ops.registry`):\n")
-    _doc_functions(w, _opreg, ["register_variant", "select_variant",
-                               "dispatch_variant", "fused_enabled",
-                               "fused_fallbacks", "reset_fused_dispatch"])
+    w(_first_para(_fused.__doc__) + "\n\nThe platform test "
+      "(`mxnet_tpu.ops.platform`):\n")
+    _doc_functions(w, _opplat, ["pallas_mode"])
     w("\nParity harness (`mxnet_tpu.ops.fused.parity`):\n")
     _doc_functions(w, _fpar, ["register_parity", "run_parity",
                               "parity_registrations"])
-    w("\nSee [the fused-kernels how-to](../how_to/kernels.md) for the "
-      "selection\npriority, parity classes, and the fallback contract.\n")
+    w("\nSee [the kernels how-to](../how_to/kernels.md) for the rule a "
+      "hot path\nstates in its own body, the parity classes and the "
+      "compile-only guards.\n")
 
     w("\n## Observability (`mxnet_tpu.observability`)\n")
     obs = mx.observability

@@ -14,7 +14,6 @@ from .typed_errors import check_typed_errors
 from .lock_discipline import check_lock_discipline
 from .jit_purity import check_jit_purity
 from .golden_metrics import check_golden_metrics
-from .fused_parity import check_fused_parity
 
 ALL_RULES = {
     "env-var-registry": check_env_var_registry,
@@ -25,7 +24,6 @@ ALL_RULES = {
     "jit-purity": check_jit_purity,
     "golden-metrics": check_golden_metrics,
     "atomic-write": check_atomic_write,
-    "fused-parity": check_fused_parity,
 }
 
 __all__ = ["ALL_RULES"]

@@ -9,12 +9,6 @@ documented structural-equivalence lists below.
 
 Exit 0 iff every reference op is registered, aliased, or explicitly
 accounted for.  Run:  python tools/op_audit.py [--ref PATH] [-v]
-
-``--variants`` prints the fused-tier coverage table instead (PR-19):
-one row per (op, variant) from ``FUSED_VARIANTS`` with its backends,
-parity class, parity-grid size, and the latest bench reading of the
-kernel key that variant feeds (with the delta against the prior round
-that carried it, when BENCH_r*.json artifacts are present).
 """
 
 import argparse
@@ -79,66 +73,10 @@ def reference_ops(ref):
     return names
 
 
-# which schema-15 bench key a fused variant's win shows up under (ops
-# without a row gate on parity + compile-FLOPs only)
-_VARIANT_BENCH_KEY = {
-    "stable_causal_attention": "attn_prefill_ms",
-    "paged_decode_attention": "paged_decode_tokens_per_sec",
-    "sgd_mom_tree_update": "fused_opt_step_ms",
-}
-
-
-def variants_table():
-    """Fused-tier coverage: every registered variant, its parity twin's
-    grid size, and the last bench delta for the key it feeds."""
-    from mxnet_tpu.ops import registry
-    from mxnet_tpu.ops.fused import parity as fpar
-
-    try:
-        from tools.bench_table import load_bench_rounds
-        rounds = load_bench_rounds(ROOT)
-    except Exception:
-        rounds = []
-    cases = fpar.parity_registrations()
-    print("%-28s %-8s %-12s %-9s %-6s %s" % (
-        "op", "variant", "backends", "parity", "cases", "last bench"))
-    missing = 0
-    for op_name in sorted(registry.FUSED_VARIANTS):
-        for vname, var in sorted(
-                registry.FUSED_VARIANTS[op_name].items()):
-            n_cases = cases.get((op_name, vname), 0)
-            if n_cases == 0:
-                missing += 1
-            key = _VARIANT_BENCH_KEY.get(op_name)
-            bench = "-"
-            if key:
-                vals = [(n, row[key]) for n, row in rounds
-                        if key in row]
-                if vals:
-                    n, cur = vals[-1]
-                    bench = "%s=%.6g (r%02d)" % (key, float(cur), n)
-                    if len(vals) > 1:
-                        prev = float(vals[-2][1])
-                        if prev:
-                            bench += " %+.1f%%" % (
-                                100.0 * (float(cur) - prev) / prev)
-                else:
-                    bench = key + " (no artifact yet)"
-            print("%-28s %-8s %-12s %-9s %-6d %s" % (
-                op_name, vname, ",".join(var.backends), var.parity,
-                n_cases, bench))
-    print("variants: %d  ops: %d  without parity twin: %d" % (
-        sum(len(v) for v in registry.FUSED_VARIANTS.values()),
-        len(registry.FUSED_VARIANTS), missing))
-    return 1 if missing else 0
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--ref", default="/root/reference")
     ap.add_argument("-v", "--verbose", action="store_true")
-    ap.add_argument("--variants", action="store_true",
-                    help="print the fused-variant coverage table")
     args = ap.parse_args()
 
     # static audit, no device work: force the CPU platform so the audit
@@ -149,8 +87,6 @@ def main():
         jax.config.update("jax_platforms", "cpu")
     except Exception:
         pass
-    if args.variants:
-        return variants_table()
     from mxnet_tpu.ops import registry
 
     ours = set(registry.OP_REGISTRY) | set(registry._ALIAS)
