@@ -373,7 +373,8 @@ def decode_step(params, tokens, positions, pages, block_tables,
     """One token for each of ``B`` sequences through the latent pool
     ``pages [L, num_blocks, block_size, W]``, read as of before the
     step.  Returns ``(logits [B, V], rows [L, B, W], None, counts)``;
-    the caller writes ``rows`` after the step succeeded."""
+    the caller writes ``rows`` in a dispatch of its own, behind this
+    one (:meth:`~mxnet_tpu.serving.LMBackend.decode` does)."""
     x = params["embed_weight"][tokens]
     num_blocks = pages.shape[1]
     pool = pages.reshape((-1,) + pages.shape[2:])

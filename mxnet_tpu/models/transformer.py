@@ -265,8 +265,10 @@ def lm_decode_step(params, tokens, positions, k_pages, v_pages,
     k_step [L, B, H, D], v_step [L, B, H, D])``.  The pool is read as
     of before the step and not written here: the caller hands
     ``k_step``/``v_step``, still on the device, to
-    ``PagedKVCache.write_tokens`` after the dispatch succeeded, so a
-    dropped or retried dispatch cannot touch any sequence's blocks.
+    ``PagedKVCache.write_tokens`` in a dispatch of its own
+    (:meth:`~mxnet_tpu.serving.LMBackend.decode` does, right behind
+    this one), so a dropped dispatch touches no sequence's blocks and a
+    repeated one stores the same rows again.
     """
     x = (params["embed_weight"][tokens]
          + params["pos_embed_weight"][0][positions])[:, None, :]

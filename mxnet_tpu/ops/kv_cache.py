@@ -214,6 +214,7 @@ class PagedKVCache(object):
         self._lock = threading.Lock()
         self._free = list(range(self.num_blocks - 1, -1, -1))
         self._tables = {}      # seq_id -> [block ids]
+        self._owner = {}       # block id -> seq_id, for every block in use
         self._lengths = {}     # seq_id -> tokens written
         self._occ = _M_OCC.labels(model)
         self._used = _M_BLOCKS.labels(model)
@@ -278,6 +279,7 @@ class PagedKVCache(object):
             if grow > 0:
                 fresh = [self._free.pop() for _ in range(grow)]
                 self._tables[seq_id] = table + fresh
+                self._owner.update(dict.fromkeys(fresh, seq_id))
                 self._lengths.setdefault(seq_id, 0)
                 self._allocs.inc(grow)
             self._set_gauges_locked()
@@ -289,6 +291,8 @@ class PagedKVCache(object):
         with self._lock:
             table = self._tables.pop(seq_id, None) or []
             self._lengths.pop(seq_id, None)
+            for block in table:
+                del self._owner[block]
             if table:
                 self._free.extend(reversed(table))
                 self._frees.inc(len(table))
@@ -387,35 +391,51 @@ class PagedKVCache(object):
                                         length)
         return staged
 
-    def write_tokens(self, seq_ids, positions, k, v):
+    def write_tokens(self, block_tables, positions, k, v):
         """Store one decode step's K/V for the whole batch in one call:
         ``k``/``v`` device arrays ``[L, B, heads * dim]`` (or ``[L, B,
         heads, dim]``) as the decode dispatch produced them, row ``i``
-        belonging to ``seq_ids[i]`` at token position ``positions[i]``.
-        Rows beyond ``len(seq_ids)`` are the bucket's pad rows and write
-        nowhere.  Returns the host bytes handed to the device (two
-        ``int32[B]`` index vectors)."""
+        going to token position ``positions[i]`` of the sequence whose
+        padded table (:meth:`block_table`) is ``block_tables[i]``: the
+        arrays the dispatch itself read through, so a step can be
+        written by whoever dispatched it, with no sequence named.  A
+        row at position 0 is a pad row of the bucket (a live row follows
+        a prefill of at least one token) and writes nowhere.  A row
+        whose slot lies in no allocated block is refused before
+        anything is written.  Writing a slot again with the same values
+        changes nothing, so a step dispatched twice (a retry, a queued
+        step thrown away and run again) is harmless.  Returns the host
+        bytes handed to the device (two ``int32[B]`` index vectors)."""
+        tables = np.asarray(block_tables, dtype=np.int32)
+        positions = np.asarray(positions, dtype=np.int32)
         bucket = int(k.shape[1])
-        if len(seq_ids) > bucket or len(seq_ids) != len(positions):
+        if tables.ndim != 2 or positions.shape != (bucket,) \
+                or len(tables) != bucket:
             raise MXNetError(
-                "write_tokens: %d sequences, %d positions, %d rows"
-                % (len(seq_ids), len(positions), bucket))
+                "write_tokens: tables %r, positions %r, %d rows"
+                % (tables.shape, positions.shape, bucket))
+        index = positions // self.block_size
+        if positions.min() < 0 or index.max() >= tables.shape[1]:
+            raise MXNetError(
+                "write_tokens: positions %d..%d outside a table of %d "
+                "blocks" % (positions.min(), positions.max(),
+                            tables.shape[1]))
+        live = np.flatnonzero(positions)
+        blocks = np.full(bucket, self.num_blocks, dtype=np.int32)
+        blocks[live] = tables[live, index[live]]
+        offsets = positions % self.block_size
         with self._lock:
-            blocks = np.full(bucket, self.num_blocks, dtype=np.int32)
-            offsets = np.zeros(bucket, dtype=np.int32)
-            for i, (seq_id, pos) in enumerate(zip(seq_ids, positions)):
-                table, pos = self._tables.get(seq_id), int(pos)
-                if (table is None
-                        or not 0 <= pos < len(table) * self.block_size):
-                    raise MXNetError(
-                        "write_tokens(%r, pos=%d) exceeds allocation"
-                        % (seq_id, pos))
-                blocks[i] = table[pos // self.block_size]
-                offsets[i] = pos % self.block_size
+            owners = [self._owner.get(b) for b in blocks[live].tolist()]
+            if None in owners:
+                row = live[owners.index(None)]
+                raise MXNetError(
+                    "write_tokens: row %d at position %d exceeds "
+                    "allocation (block %d is free)"
+                    % (row, positions[row], blocks[row]))
             staged = self._write_locked(k, v, blocks, offsets)
-            for seq_id, pos in zip(seq_ids, positions):
-                self._lengths[seq_id] = max(self._lengths[seq_id],
-                                            int(pos) + 1)
+            for seq_id, pos in zip(owners, positions[live].tolist()):
+                if pos >= self._lengths[seq_id]:
+                    self._lengths[seq_id] = pos + 1
         return staged
 
     # -- introspection ----------------------------------------------

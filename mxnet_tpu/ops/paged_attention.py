@@ -39,8 +39,9 @@ def paged_decode_attention(q, k_step, v_step, k_pages, v_pages,
     - ``q`` / ``k_step`` / ``v_step``: ``[B, H, D]`` — this step's
       single query per sequence and its freshly projected K/V (the
       caller scatters them into the pool on the device, with
-      ``PagedKVCache.write_tokens``, *after* the step succeeded, so a
-      retried dispatch never leaves half-written pages).
+      ``PagedKVCache.write_tokens``, in a dispatch of its own behind
+      this one: a step never writes the pool it reads, and a step
+      dispatched again stores the same rows in the same slots).
     - ``k_pages`` / ``v_pages``: ``[num_blocks, block_size, H, D]`` —
       one layer's slice of the shared :class:`~mxnet_tpu.ops.kv_cache.
       PagedKVCache` pool (device-resident), as of before this step.

@@ -32,11 +32,25 @@ scheduler was already styled after:
   tables and lengths and gets logits back
   (``generation_host_to_device_bytes_total`` /
   ``generation_device_to_host_bytes_total`` are the evidence).  The
-  pool write follows the dispatch that produced its K/V and targets
-  only the calling sequences' own reserved slots, so a chaos-dropped or
-  retried step can never corrupt another sequence's blocks; a write
-  that fails after the pool was donated fails the lane's live
-  sequences on a rebuilt, zeroed pool.
+  pool write follows the dispatch that produced its K/V (a decode
+  step's inside :meth:`LMBackend.decode`, right behind the program) and
+  targets only the calling sequences' own reserved slots, so a
+  chaos-dropped or retried step can never corrupt another sequence's
+  blocks; a write that fails after the pool was donated fails the
+  lane's live sequences on a rebuilt, zeroed pool.
+- **A full decode batch runs one step ahead.**  The decode program
+  makes the greedy choice itself (``int32[B]`` beside the logits), so
+  the only thing step n+1 needs from step n is already on the device:
+  where the loop can see that no request could be admitted before step
+  n+1 is answered (the batch full, no row at its last token, nothing
+  shutting down: :meth:`GenerationScheduler._may_run_ahead`) the
+  backend queues step n+1 before it waits for step n's logits, and the
+  device computes while the host copies 3 MB of logits, pushes tokens
+  and builds the next call.  Every logit still reaches the host and
+  every served token is the ``argmax`` of them; a step that a request
+  would have to wait behind is never queued, so first tokens wait no
+  longer (``generation_decode_ahead_used_total`` /
+  ``generation_decode_ahead_dropped_total``).
 - **Cache is backend state.**  ``ModelRegistry.swap`` replaces backend
   and cache together (the registry machinery is untouched); the loop
   notices the swap under ``dispatch_lock`` and transparently
@@ -133,6 +147,16 @@ _M_D2H = _metrics.counter(
     "generation_device_to_host_bytes_total",
     "Device bytes generation calls copied back to the host (logits), "
     "by model and phase", ["model", "phase"])
+
+
+_M_AHEAD_USED = _metrics.counter(
+    "generation_decode_ahead_used_total",
+    "Decode calls answered by a step that was queued behind the one "
+    "before it (of generation_decode_steps_total), by model", ["model"])
+_M_AHEAD_DROPPED = _metrics.counter(
+    "generation_decode_ahead_dropped_total",
+    "Queued decode steps thrown away: the next call asked for another "
+    "step, or a fault, kill or swap came first, by model", ["model"])
 
 
 def _host_nbytes(arrays):
@@ -261,6 +285,32 @@ class GenerationRequest(object):
         return list(self.generated)
 
 
+def with_greedy_ids(decode):
+    """An :class:`~mxnet_tpu.models.lm.LMDefinition`'s ``decode`` with
+    the greedy choice made beside it, from the very logits the host
+    gets (the first maximum, as ``numpy.argmax``): the program
+    :meth:`LMBackend.decode` runs, ``(logits, ids int32 [B], k_rows,
+    v_rows, counts)``."""
+    import jax.numpy as jnp
+
+    def program(params, *args):
+        logits, k, v, counts = decode(params, *args)
+        return (logits, jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                k, v, counts)
+
+    return program
+
+
+class _Step(_collections.namedtuple(
+        "_Step", "fed logits ids k v counts cold")):
+    """One decode step on the device's queue: what it was fed (numpy;
+    a queued step's tokens are known once the step before it is read)
+    and its outputs, device arrays whose copies to the host are on
+    their way."""
+
+    __slots__ = ()
+
+
 class LMBackend(Backend):
     """Generative serving backend: a model's params + paged KV cache
     + shape-keyed jit caches for prefill and decode.
@@ -283,12 +333,25 @@ class LMBackend(Backend):
     dict under the same names, its leaves device arrays) and the cache
     keeps its pools there; :meth:`prefill` and :meth:`decode` hand over
     token ids, positions, block tables and lengths and copy back only
-    logits.  The K/V they return are device arrays for
-    :meth:`~mxnet_tpu.ops.kv_cache.PagedKVCache.write_prefill` /
-    :meth:`~mxnet_tpu.ops.kv_cache.PagedKVCache.write_tokens`.  During
+    logits (and, from a decode step, the ``int32[B]`` of their greedy
+    ids).  The K/V they return are device arrays; a prefill's are for
+    :meth:`~mxnet_tpu.ops.kv_cache.PagedKVCache.write_prefill`, a decode
+    step's are written by :meth:`decode` itself.  During
     a hot swap's brownout two backends are alive, so two resident sets
     (weights + pool each) are on the device at once: 2 x 3.76 GB =
     7.5 GB for GPT-2 medium with a 680-block pool, of 16 GB.
+
+    **A decode step may run one step ahead.**  Where its caller sets
+    ``run_ahead`` for a call (the generation loop does, per call, from
+    what it sees of its lane: :meth:`GenerationScheduler._may_run_ahead`)
+    :meth:`decode` queues the step after this one, fed by this step's
+    greedy ids as the device holds them, before it waits for this
+    step's logits; the next call is answered by the queued step if it
+    asks for exactly that step, and throws it away if not.  Nothing is
+    skipped or approximated: every step's logits are computed and
+    copied back whole, behind the next step's compute and not in front
+    of it.  ``generation_decode_ahead_used_total`` /
+    ``generation_decode_ahead_dropped_total`` count both outcomes.
 
     ``int8_head=True`` opts into the
     :func:`~mxnet_tpu.contrib.quantization.quantize_weight_int8` vocab
@@ -326,11 +389,21 @@ class LMBackend(Backend):
                                     // self.cache.block_size)
         self._jits = {}
         self._jit_lock = threading.Lock()
+        self._decode_program = with_greedy_ids(definition.decode)
         # what the caller wants done while the device runs a prefill or
         # decode call: called once the program is on its way and before
         # the call waits for the logits (the generation loop hands the
         # last step's tokens to their streams here)
         self.beside_device = None
+        # set by the caller for one decode call: queue the step after it
+        # too (see the class docstring); the queued step, if any; and
+        # the greedy ids of the step the last decode call answered,
+        # int32 [B] on the host: what the caller serves and feeds on
+        self.run_ahead = False
+        self._ahead = None
+        self.greedy_ids = None
+        self._ahead_used = _M_AHEAD_USED.labels(model)
+        self._ahead_dropped = _M_AHEAD_DROPPED.labels(model)
         self._moved = {(phase, way): fam.labels(model, phase)
                        for phase in ("prefill", "decode")
                        for way, fam in (("h2d", _M_H2D), ("d2h", _M_D2H))}
@@ -369,23 +442,34 @@ class LMBackend(Backend):
                              self.definition.forward)
         return [_np.asarray(fn(self.params, tokens))], cold
 
-    def _fetch(self, phase, args, logits, counts):
-        """The call's logits as a host copy (in ordinary memory:
-        np.asarray of a device array is a view of the runtime's transfer
-        buffer); what the program counted rides back beside them and is
-        booked; the bytes moved either way are booked too."""
-        if counts is not None:
-            counts.copy_to_host_async()
+    def _fetch(self, phase, logits, counts, ids=None):
+        """A dispatched call's logits as a host copy (in ordinary
+        memory: np.asarray of a device array is a view of the runtime's
+        transfer buffer), with a decode step's greedy ids; what the
+        program counted rides back beside them and is booked, and so
+        are the bytes copied.  The caller's ``beside_device`` runs
+        first: the device is at work by now."""
         if self.beside_device is not None:
             self.beside_device()
         logits = _np.array(logits)
         d2h = logits.nbytes
+        if ids is not None:
+            ids = _np.array(ids)
+            d2h += ids.nbytes
         if counts is not None:
             counts = _np.asarray(counts)
             d2h += counts.nbytes
             self.definition.book(self.model, counts)
-        self.moved(phase, _host_nbytes((*self.params.values(), *args)), d2h)
-        return logits
+        self.moved(phase, d2h=d2h)
+        return logits, ids
+
+    @staticmethod
+    def _copy_back(*outputs):
+        """Start the outputs' copies to the host: they move while the
+        host (and, behind a decode step, the device) does what is next."""
+        for out in outputs:
+            if out is not None:
+                out.copy_to_host_async()
 
     # -- generation entry points -------------------------------------
 
@@ -402,23 +486,95 @@ class LMBackend(Backend):
         fn, cold = self._jit(("prefill",) + tokens.shape,
                              self.definition.prefill)
         logits, k, v, counts = fn(self.params, *args)
-        return self._fetch("prefill", args, logits, counts), k, v, cold
+        self._copy_back(counts)
+        self.moved("prefill", h2d=_host_nbytes(
+            (*self.params.values(), *args)))
+        return self._fetch("prefill", logits, counts)[0], k, v, cold
+
+    def _dispatch_decode(self, tokens, positions, block_tables,
+                         context_lens):
+        """Put one decode step on the device's queue and return it: the
+        program, the copies of what it gives back, and right behind it
+        the write of its K/V rows into the pool, so that whatever is
+        dispatched next reads a pool that holds this step.  ``tokens``
+        is numpy, or the device's ids of the step before."""
+        args = (tokens, positions, self.cache.k_pages, self.cache.v_pages,
+                block_tables, context_lens)
+        fn, cold = self._jit(("decode", len(positions)),
+                             self._decode_program)
+        logits, ids, k, v, counts = fn(self.params, *args)
+        self._copy_back(logits, ids, counts)
+        self.moved("decode", h2d=_host_nbytes(
+            (*self.params.values(), *args))
+            + self.cache.write_tokens(block_tables, positions, k, v))
+        return _Step((tokens, positions, block_tables, context_lens),
+                     logits, ids, k, v, counts, cold)
+
+    def drop_ahead(self):
+        """Throw the queued step away, if there is one (what it wrote is
+        harmless: see :meth:`decode`).  For the thread that calls
+        :meth:`decode`."""
+        if self._ahead is not None:
+            self._ahead = None
+            self._ahead_dropped.inc()
 
     def decode(self, tokens, positions, block_tables, context_lens):
         """One decode step over a padded batch.  Returns ``(logits
         [B, V], k_step [L, B, row width], v_step, cold)``: the logits a
-        host copy, ``k_step``/``v_step`` device arrays the caller hands to
-        ``cache.write_tokens`` after the step succeeded.  The pool is
-        read as of before the step and not written here."""
-        args = (_np.asarray(tokens, dtype=_np.int32),
-                _np.asarray(positions, dtype=_np.int32),
-                self.cache.k_pages, self.cache.v_pages,
-                _np.asarray(block_tables, dtype=_np.int32),
-                _np.asarray(context_lens, dtype=_np.int32))
-        fn, cold = self._jit(("decode", len(tokens)),
-                             self.definition.decode)
-        logits, k, v, counts = fn(self.params, *args)
-        return self._fetch("decode", args, logits, counts), k, v, cold
+        host copy, ``k_step``/``v_step`` device arrays.  The step's
+        greedy ids (``argmax`` of those logits, made on the device) are
+        left in ``self.greedy_ids``, int32 ``[B]`` on the host.
+
+        The program reads the pool as of before the step; **the write
+        of ``k_step``/``v_step`` follows it inside this call**, into the
+        slots ``(block_tables[i, positions[i] // block_size],
+        positions[i] % block_size)``; a row at position 0 is a pad row
+        and writes nowhere.  A slot written again gets the same values,
+        so a call may be repeated (a retry) and the caller need write
+        nothing; a caller that still hands ``k_step``/``v_step`` to
+        ``cache.write_tokens`` changes nothing.
+
+        With ``self.run_ahead`` set, the step after this one is put on
+        the device's queue before this step's logits are waited for:
+        the same bucket and tables, ``tokens`` the device's own ids of
+        this step, ``positions + 1``, ``context_lens + 1``, then its
+        write.  The next call gets that step's results if its four
+        arguments equal what was queued; any other call drops it and is
+        dispatched afresh, as is any call after an error.  A dropped
+        step's write is harmless: a row that goes on rewrites the slot
+        with the same values; one that does not owns the slot until its
+        blocks are freed, whoever gets them next writes a position
+        before ``context_lens`` lets a step read it, and the device
+        runs what it is handed in order.
+
+        A subclass that overrides this method with these four
+        arguments and calls it (the benchmark's wrapper does) sees one
+        call a step, numpy arguments, and a numpy ``out[0]`` that
+        belongs to those arguments."""
+        fed = tuple(_np.asarray(a, dtype=_np.int32) for a in
+                    (tokens, positions, block_tables, context_lens))
+        step, self._ahead = self._ahead, None
+        if step is not None and all(map(_np.array_equal, step.fed, fed)):
+            self._ahead_used.inc()
+        else:
+            if step is not None:
+                self._ahead_dropped.inc()
+            step = self._dispatch_decode(*fed)
+        ahead = None
+        try:
+            if self.run_ahead:
+                ahead = self._dispatch_decode(
+                    step.ids, fed[1] + 1, fed[2], fed[3] + 1)
+            logits, ids = self._fetch("decode", step.logits, step.counts,
+                                      step.ids)
+        except Exception:
+            if ahead is not None:
+                self._ahead_dropped.inc()
+            raise
+        if ahead is not None:
+            self._ahead = ahead._replace(fed=(ids,) + ahead.fed[1:])
+        self.greedy_ids = ids
+        return logits, step.k, step.v, step.cold
 
     def describe(self):
         d = Backend.describe(self)
@@ -629,7 +785,10 @@ class GenerationScheduler(object):
     def warmup(self, name):
         """Pre-compile every prefill bucket (B=1) and decode bucket,
         with the pool write that follows each, so steady-state
-        generation never compiles.  Returns cold count."""
+        generation never compiles.  The largest decode bucket, the one
+        a full batch runs ahead in, also makes a queued step and is
+        answered by it: the program fed the device's own ids.  Returns
+        cold count."""
         lane = self._lane(name)
         entry = lane.entry
         cold_n = 0
@@ -637,7 +796,8 @@ class GenerationScheduler(object):
             backend = entry.backend
             cache = backend.cache
             sid = "__warm"
-            cache.allocate(sid, 1)
+            # positions 0 (prefill), 1 (decode), 2 (the queued step)
+            cache.allocate(sid, 3)
             try:
                 for t in self._prefill_buckets[name]:
                     _, k, v, cold = backend.prefill(
@@ -648,12 +808,18 @@ class GenerationScheduler(object):
                     tables = _np.stack(
                         [cache.block_table(
                             sid, backend.max_blocks_per_seq)] * b)
-                    _, k, v, cold = backend.decode(
-                        _np.zeros(b, _np.int32), _np.zeros(b, _np.int32),
-                        tables, _np.ones(b, _np.int32))
-                    cache.write_tokens([sid], [0], k, v)
+                    step = [_np.zeros(b, _np.int32), _np.ones(b, _np.int32),
+                            tables, _np.full(b, 2, _np.int32)]
+                    backend.run_ahead = b == entry.buckets[-1]
+                    cold = backend.decode(*step)[3]
+                    if backend.run_ahead:
+                        backend.run_ahead = False
+                        backend.decode(backend.greedy_ids, step[1] + 1,
+                                       tables, step[3] + 1)
                     cold_n += bool(cold)
             finally:
+                backend.run_ahead = False
+                backend.drop_ahead()
                 cache.free(sid)
         if cold_n and _metrics.metrics_enabled():
             lane.m_compiles.inc(cold_n)
@@ -767,9 +933,9 @@ class GenerationScheduler(object):
                        and not self._killed and not self._stopping):
                     self._cond.wait(0.05)
                     self.last_beat = time.monotonic()
-                if self._killed:
-                    return
-                if self._stopping and not lane.queue and not lane.active:
+                if self._killed or (self._stopping and not lane.queue
+                                    and not lane.active):
+                    lane.entry.backend.drop_ahead()
                     return
             self._iterate(name, lane)
 
@@ -807,6 +973,7 @@ class GenerationScheduler(object):
             return
         for seq in stale:
             lane.active.remove(seq)
+            seq.backend_ref.drop_ahead()
             # the old backend (and usually its cache) is on the way out,
             # but freeing keeps its occupancy gauges honest during the
             # brownout window where both backends are alive
@@ -966,7 +1133,7 @@ class GenerationScheduler(object):
             lane.m_prefill.observe(time.monotonic() - t0, req.trace)
 
     @staticmethod
-    def _beside(lane, backend, call, *args):
+    def _beside(lane, backend, call, *args, run_ahead=False):
         """``call(*args)``, a prefill or decode call of ``backend``, with
         the last decode step's tokens handed to their streams while the
         device runs it.  The threads the tokens wake (a front end's
@@ -974,16 +1141,18 @@ class GenerationScheduler(object):
         token was known they held back the loop between two calls, woken
         before the call its dispatch, the device idle meanwhile (23 ms
         of a 100 ms step with 64 callers).  An :class:`LMBackend` calls
-        ``beside_device`` once its program is on the way."""
+        ``beside_device`` once its program is on the way.  ``run_ahead``
+        is the loop's word, for this decode call alone, that the step
+        after it may be queued too."""
         def deliver():
             for seq in lane.active:
                 seq.req._deliver()
 
-        backend.beside_device = deliver
+        backend.beside_device, backend.run_ahead = deliver, run_ahead
         try:
             return call(*args)
         finally:
-            backend.beside_device = None
+            backend.beside_device, backend.run_ahead = None, False
 
     @staticmethod
     def _fail_live(lane, error):
@@ -991,6 +1160,27 @@ class GenerationScheduler(object):
         the next ``_retire`` frees their blocks."""
         for seq in lane.active:
             seq.req._fail(error)
+
+    def _may_run_ahead(self, lane, live):
+        """May the decode call over ``live`` also queue the step after
+        it?  Only where no request could be admitted before that step
+        is answered, so that no prefill ever waits behind a queued step
+        (a first token would wait a whole step longer): after this step
+        the decode batch has no free slot — it is full, and no row
+        reaches its ``max_new_tokens`` in this step (one that does in
+        the *next* step rides the queued step and blocks the one
+        after), is cancelled, or may stop at an ``eos_id`` no one can
+        foresee — and no drain, close, kill or fence is under way.
+        Everywhere else the step is dispatched alone, as ever.  (A swap
+        lands between two iterations; the old backend's queued step is
+        dropped when its sequences are re-prefilled.)"""
+        if (len(live) < lane.entry.buckets[-1] or self._killed
+                or self._stopping or self._fenced_epoch is not None
+                or self.admission.draining):
+            return False
+        return all(seq.new_tokens + 1 < seq.req.max_new_tokens
+                   and seq.req.eos_id is None and not seq.req.cancelled
+                   for seq in live)
 
     def _decode_step(self, name, lane, backend):
         """ONE iteration-level decode step over every live sequence,
@@ -1024,46 +1214,43 @@ class GenerationScheduler(object):
                     try:
                         chaos.visit("serving.decode",
                                     name="%s:%d" % (name, bucket))
-                        out = self._beside(lane, backend, backend.decode,
-                                           tokens, positions, tables,
-                                           context)
+                        out = self._beside(
+                            lane, backend, backend.decode, tokens,
+                            positions, tables, context,
+                            run_ahead=self._may_run_ahead(lane, live))
                     except Exception as exc:  # noqa: BLE001
                         sp.set(error=type(exc).__name__)
                         raise
                 break
             except Exception as exc:   # noqa: BLE001 - fault path
+                # a retry dispatches program and write again (the write
+                # stores the same values); nothing stays queued
+                backend.drop_ahead()
                 if _metrics.metrics_enabled():
                     lane.m_errors.inc()
                 last_exc = exc
+                if isinstance(exc, CachePoolLostError):
+                    break       # the pages a retry would read are gone
         if self._killed:
+            backend.drop_ahead()
             for seq in live:
                 seq.req._fail(_admission.ReplicaDeadError(
                     "replica %r died mid-generation" % self.name))
             return
         if out is None:
-            err = MXNetError(
-                "model %r: decode step failed after %d attempts: %s"
-                % (name, default_retries() + 1, last_exc))
-            for seq in live:
-                seq.req._fail(err)
+            # the step's logits or its K/V are lost (and after
+            # CachePoolLostError the whole pool, rebuilt zeroed): no
+            # live sequence can go on
+            self._fail_live(
+                lane, last_exc if isinstance(last_exc, CachePoolLostError)
+                else MXNetError(
+                    "model %r: decode step failed after %d attempts: %s"
+                    % (name, default_retries() + 1, last_exc)))
             return
-        logits, k_step, v_step, cold = out
-        # the step succeeded for the whole batch: NOW write its K/V, one
-        # donated device write into the live rows' own slots (the
-        # bucket's pad rows write nowhere) — a dropped or retried
-        # dispatch above never touched the pool
-        try:
-            backend.moved("decode", h2d=backend.cache.write_tokens(
-                [s.seq_id for s in live], positions[:n], k_step, v_step))
-        except Exception as exc:   # noqa: BLE001 - fault path
-            # the step's K/V are lost (and after CachePoolLostError the
-            # whole pool, rebuilt zeroed): no live sequence can go on
-            if _metrics.metrics_enabled():
-                lane.m_errors.inc()
-            self._fail_live(lane, exc if isinstance(exc, MXNetError) else
-                            MXNetError("kv cache write failed: %s" % exc))
-            return
-        tokens_out = logits[:n].argmax(axis=1)
+        cold = out[3]
+        # what is served is what the next step is fed: the ids the
+        # decode program chose from these very logits
+        tokens_out = backend.greedy_ids
         now = time.monotonic()
         lane.steps += 1
         lane.rows += n

@@ -259,6 +259,7 @@ def test_decode_step_reads_the_pool_where_it_lies(topo, monkeypatch,
     each layer's 44 MB out of the pool every step and held all 48
     copies, 1.8 GB, as temporaries.)"""
     from mxnet_tpu.models import transformer as tfm
+    from mxnet_tpu.serving import generation
 
     if body == "kernel":
         request.getfixturevalue("on_tpu")
@@ -273,10 +274,9 @@ def test_decode_step_reads_the_pool_where_it_lies(topo, monkeypatch,
     params = {k: s(v.shape, v.dtype)
               for k, v in tfm.init_lm_params(cfg).items()}
 
-    def step(params, tokens, positions, k_pages, v_pages, tables, lens):
-        return tfm.lm_decode_step(params, tokens, positions, k_pages,
-                                  v_pages, tables, lens, cfg)
-
+    # the program LMBackend.decode runs: the step, its K/V as the
+    # cache's rows, the greedy ids beside the logits
+    step = generation.with_greedy_ids(tfm.lm_definition(cfg).decode)
     rows = s((16,), jnp.int32)
     compiled = jax.jit(step).lower(
         params, rows, rows, s(_POOL), s(_POOL), s((16, 64), jnp.int32),
@@ -515,6 +515,7 @@ def test_latent_decode_step_reads_the_pool_where_it_lies(topo, on_tpu):
     the same program re-lays all of it, 1 GB, before the gathers of
     every step.)"""
     from mxnet_tpu.models import latent_moe as lm
+    from mxnet_tpu.serving import generation
 
     one = SingleDeviceSharding(topo.devices[0])
     doc, cfg, params = _latent_moe_shapes(one)
@@ -528,11 +529,12 @@ def test_latent_decode_step_reads_the_pool_where_it_lies(topo, on_tpu):
     pool = (cfg["num_layers"], serve["num_blocks"], serve["block_size"],
             width)
     rows = s((64,))
-    compiled = jax.jit(
-        lambda p, t, pos, pages, tables, lens: lm.decode_step(
-            p, t, pos, pages, tables, lens, cfg)).lower(
-        params, rows, rows, s(pool, BF16),
+    compiled = jax.jit(generation.with_greedy_ids(
+        lm.lm_definition(cfg).decode)).lower(
+        params, rows, rows, s(pool, BF16), None,
         s((64, cfg["seq_len"] // serve["block_size"])), rows).compile()
+    assert [o.shape for o in compiled.out_info[:2]] == [
+        (64, cfg["vocab_size"]), (64,)]           # logits, greedy ids
     pool_bytes = 2 * int(np.prod(pool))
     assert _big_moves(compiled.as_text(), pool_bytes // 8) == []
     mem = compiled.memory_analysis()

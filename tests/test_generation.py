@@ -116,8 +116,7 @@ def test_decode_equals_full_forward_to_the_last_bits(lm):
             tables, np.array([length + 1], np.int32))
         assert _row_ulps(logits[0], ref_logits[t]) <= 8, \
             "decode step %d logits differ from full forward" % t
-        be.cache.write_tokens(["s"], [length], ks, vs)
-        length += 1
+        length += 1        # the step wrote its own K/V: nothing to do
         generated.append(int(np.argmax(logits[0])))
     assert generated == toks[len(prompt):]
 
@@ -205,8 +204,9 @@ def _pools(cache):
 def test_decode_pad_rows_write_nowhere(lm):
     """A decode step at bucket 4 with ONE live row, while another
     sequence holds block 0 (where a pad row's all-zero table and
-    position 0 point): after the step's write every slot of both pools
-    but the live row's own is bit-identical."""
+    position 0 point): after the step's write, made inside the call,
+    every slot of both pools but the live row's own is bit-identical,
+    and writing the step again changes nothing."""
     be = _backend(lm)
     rng = np.random.RandomState(5)
     be.cache.allocate("other", 4)
@@ -223,8 +223,10 @@ def test_decode_pad_rows_write_nowhere(lm):
     positions = np.array([5, 0, 0, 0], np.int32)
     _, ks, vs, _ = be.decode(np.array([7, 0, 0, 0], np.int32), positions,
                              tables, np.array([6, 1, 1, 1], np.int32))
-    be.cache.write_tokens(["live"], [5], ks, vs)
-    k1, v1 = _pools(be.cache)
+    k1, v1 = _pools(be.cache)       # the call wrote the step itself
+    be.cache.write_tokens(tables, positions, ks, vs)
+    for once, again in zip((k1, v1), _pools(be.cache)):
+        assert np.array_equal(once, again), "a second write moved the pool"
     blk, off = tables[0][5 // 4], 5 % 4
     for before, after, step in ((k0, k1, ks), (v0, v1, vs)):
         assert np.array_equal(after[:, blk, off], np.asarray(step)[:, 0])
@@ -253,8 +255,11 @@ def test_prefill_writes_length_positions_and_no_more(lm):
     with pytest.raises(MXNetError):             # beyond the allocation
         be.cache.write_prefill("s", np.zeros((LAYERS, 16, EMBED)),
                                np.zeros((LAYERS, 16, EMBED)), 9)
-    with pytest.raises(MXNetError):
-        be.cache.write_tokens(["s"], [8], k[:, :1], v[:, :1])
+    with pytest.raises(MXNetError):             # beyond the table
+        be.cache.write_tokens(table[None], [8], k[:, :1], v[:, :1])
+    be.cache.free("s")
+    with pytest.raises(MXNetError):             # into a freed block
+        be.cache.write_tokens(table[None], [5], k[:, :1], v[:, :1])
 
 
 def test_everything_a_call_reads_is_resident(lm):
@@ -332,8 +337,10 @@ def test_transfer_counters_read_logits_out_and_kilobytes_in(lm):
     call = (np.zeros(bucket, np.int32), np.array([6, 0, 0, 0], np.int32),
             tables, np.array([7, 1, 1, 1], np.int32))
     be.decode(*call)
-    assert d2h.labels("bytes", "decode").value == bucket * VOCAB * 4
-    per_call = bucket * 4 * (3 + be.max_blocks_per_seq)
+    # the logits and their greedy ids out; ids, positions, lengths,
+    # tables and the write's two slot vectors in
+    assert d2h.labels("bytes", "decode").value == bucket * (VOCAB + 1) * 4
+    per_call = bucket * 4 * (5 + be.max_blocks_per_seq)
     assert h2d.labels("bytes", "decode").value == per_call
     be.params["pred_bias"] = np.asarray(be.params["pred_bias"])
     be.decode(*call)

@@ -132,7 +132,6 @@ def test_prefill_then_decode_through_the_latent_cache_is_the_reference(
     for t in range(5, 20):
         table = be.cache.block_table("s", be.max_blocks_per_seq)[None]
         logits, k, v, _ = be.decode([toks[t]], [t], table, [t + 1])
-        be.cache.write_tokens(["s"], [t], k, v)
         np.testing.assert_allclose(logits[0], want[t], atol=1e-4, rtol=0)
 
 
@@ -770,6 +769,10 @@ def test_new_cell_rehearsed_on_the_cpu(tiny_benchmark, trace, capsys):
         assert metrics["kv_occupancy_peak"]["value"] > 0
         # short prompts, 4-16 new tokens: what a row attends over a step
         assert 4 < metrics["decode_context_tokens_mean"]["value"] < 64
+        # the share of decode calls a queued step answered (4 callers
+        # on 4 rows with 4-16 new tokens: a row ends every other step)
+        assert 0 <= metrics["decode_ahead_share"]["value"] < 100
+        assert metrics["decode_ahead_dropped_share"]["value"] == 0
         # no device trace on a CPU: nothing read, nothing raised
         for name in ("moe_expert_share.serve", "mla_decode_roofline.serve",
                      "device_idle_share.serve"):
