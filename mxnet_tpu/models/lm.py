@@ -13,7 +13,8 @@ __all__ = ["LMDefinition"]
 
 class LMDefinition(collections.namedtuple(
         "LMDefinition", "cfg forward prefill decode cache_row book "
-                        "prepare")):
+                        "prepare cache_layers state",
+        defaults=(None, None))):
     """One model, as the generation lane serves it.
 
     - ``cfg``: a dict with at least ``seq_len`` (the context limit the
@@ -36,6 +37,24 @@ class LMDefinition(collections.namedtuple(
       (their ``counts`` is ``None`` then).
     - ``prepare(params) -> params``: applied once before the weights are
       placed (a quantized head); ``None`` for as they are.
+    - ``cache_layers``: how many of the model's layers keep a row per
+      token (the leading axis of ``k_rows`` and of the paged pools);
+      ``None`` for all ``num_layers`` of them.
+    - ``state``: the :class:`~mxnet_tpu.ops.kv_cache.StateRows` of the
+      layers that keep a fixed-size **state per sequence** instead
+      (recurrent layers), ``None`` for a model without any.  With one,
+      ``prefill`` returns a fifth value, the state rows of the prompt
+      taken at ``length`` (a tuple, one ``[state layers, ...]`` array a
+      row of ``state.rows``), and ``decode`` takes two more arguments
+      and returns one more value: ``decode(params, tokens, positions,
+      k_pages, v_pages, block_tables, context_lens, state_pools, slots)
+      -> (logits, k_rows, v_rows, counts, state_pools)``.  The pools are
+      the cache's (``[state layers * 2 * slots + 1, ...]``: two versions a
+      slot, row ``(layer * 2 + version) * slots + slot``, and a last
+      row for pad rows to write), donated to
+      the call and written where they lie: row ``i`` reads version
+      ``positions[i] % 2`` of slot ``slots[i]`` and writes the other;
+      a slot id of ``slots`` or more is a pad row.
     """
 
     __slots__ = ()

@@ -9,6 +9,8 @@ Every live device byte is booked into a named **pool** —
 - ``optimizer``  — the momentum/optimizer-state tree,
 - ``kv_cache``   — :class:`~mxnet_tpu.ops.kv_cache.PagedKVCache` block
   pools (host-resident numpy pages, booked under ``device="host"``),
+- ``recurrent_state`` — the same cache's pool of per-sequence state
+  slots (a model with recurrent layers: two versions a slot),
 - ``prefetch``   — superbatches staged on device by
   :class:`~mxnet_tpu.parallel.prefetch.PrefetchFeeder`,
 - ``compile``    — the XLA ``memory_analysis()`` footprint of the live
@@ -62,8 +64,8 @@ __all__ = ["POOLS", "tag", "tag_tree", "untag", "ledger_entries",
 
 #: The named pools; ``other`` is the derived residual and cannot be
 #: tagged directly.
-POOLS = ("params", "optimizer", "kv_cache", "prefetch", "compile",
-         "other")
+POOLS = ("params", "optimizer", "kv_cache", "recurrent_state",
+         "prefetch", "compile", "other")
 
 _M_POOL = _metrics.gauge(
     "memory_pool_bytes",

@@ -163,6 +163,21 @@ def latent_prefill_attention(q, k, v, sm_scale):
         return _flash_dispatch(q, k, v, True, float(sm_scale), False)
 
 
+def gqa_prefill_attention(q, k, v, sm_scale):
+    """Causal grouped-query attention of a prefill: ``q`` ``[B, Hq, T,
+    D]``, ``k``/``v`` ``[B, Hkv, T, D]``, key-value head ``g`` serving
+    the query heads ``g * Hq / Hkv`` and the ``Hq / Hkv - 1`` after it
+    (each key-value head is repeated for its queries: 16 MB a pool at
+    4096 tokens of 2 heads of 256).  Never holds ``[H, T, T]`` scores
+    where the flash kernel runs (a TPU, T >= 1024); below that, and
+    elsewhere, the exact softmax."""
+    per = q.shape[1] // k.shape[1]
+    with jax.named_scope("gqa_prefill_attention"):
+        return _flash_dispatch(q, jnp.repeat(k, per, axis=1),
+                               jnp.repeat(v, per, axis=1), True,
+                               float(sm_scale), False)
+
+
 # ----------------------------------------------------------------------
 # Pallas TPU forward kernel
 # ----------------------------------------------------------------------
@@ -271,6 +286,11 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q=1024, block_k=2048,
     B, H, T, D = q.shape
     Tk, Dv = k.shape[2], v.shape[3]   # values may be narrower than keys
     block_q = min(block_q, max(8, T))
+    if D > 192:
+        # a 256-wide head: the blocks of q, k and v and the accumulator
+        # beside the [bq, bk] scores pass the v5e's 16 MiB of scoped
+        # VMEM at block_k=2048 (17.23 MiB at T=4096), at 1024 they fit
+        block_k = min(block_k, 1024)
     if Tk > block_k and Tk % block_k:
         # a ragged key tail adds a second [bq, bk] mask to the causal
         # one; at block_k=2048 the v5e compiler refuses that kernel

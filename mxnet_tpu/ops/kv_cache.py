@@ -40,6 +40,26 @@ pools and re-binds them to its outputs: the pool is updated in place,
 never exists twice, and no K/V byte visits the host.  The host computes
 only the target slots, from the tables it owns.
 
+**A model with recurrent layers keeps two kinds of state** and the cache
+holds both.  Its per-token layers have the paged pools above (over
+those layers only: a layer that caches nothing per token takes no
+block); its recurrent layers keep a fixed-size state per *sequence*
+(:class:`StateRows`), which lies in a second pool of **state slots**,
+``[state layers * 2 * slots + 1, ...]`` an array of the state (the last
+row is no sequence's: a decode batch's pad rows write there): a sequence
+takes one slot when its blocks are allocated and gives it back when
+they are freed (one ``allocate``, one ``free``; a pool without a free
+slot is the same typed 429).  A slot has **two versions**, chosen by
+the parity of the position: the decode step at position ``p`` reads
+version ``p % 2`` and writes ``(p + 1) % 2``, and a prefill of
+``length`` tokens writes version ``length % 2``.  A key row written
+again holds the same values; a recurrent update applied again would
+not, and with two versions a step dispatched again still finds what it
+read (``serving.generation.LMBackend`` has the whole account).  The
+decode program itself updates the state pool, donated to it
+(:meth:`PagedKVCache.swap_state`); a prefill's state is written by
+:meth:`PagedKVCache.write_prefill`.
+
 The cache is **backend state**: ``serving.generation.LMBackend`` owns
 one, the ``ModelRegistry`` swap machinery replaces cache and weights
 together, and the generation lane re-prefills live sequences after a
@@ -67,7 +87,8 @@ from ..observability import memory as _memory
 from ..observability import metrics as _metrics
 
 __all__ = ["CacheExhaustedError", "CachePoolLostError", "CacheRow",
-           "PagedKVCache", "default_block_size", "default_num_blocks"]
+           "StateRows", "PagedKVCache", "default_block_size",
+           "default_num_blocks"]
 
 
 class CacheRow(collections.namedtuple("CacheRow", "kind width dtype pools")):
@@ -87,8 +108,28 @@ class CacheRow(collections.namedtuple("CacheRow", "kind width dtype pools")):
         return self.pools * self.width * np.dtype(self.dtype).itemsize
 
 
+class StateRows(collections.namedtuple("StateRows", "layers rows")):
+    """What one sequence keeps between steps in the layers that hold a
+    fixed-size state instead of a row per token: ``layers`` such layers,
+    each keeping one array of every ``(shape, dtype)`` in ``rows``.
+
+    ``(6, (((32, 128, 128), float32), ((48, 512), bfloat16)))`` is six
+    Gated DeltaNet layers: the ``[heads, key, value]`` matrix of the
+    delta rule and the rows the short convolution saw last."""
+
+    __slots__ = ()
+
+    @property
+    def bytes(self):
+        """Bytes of one version of one sequence's state."""
+        return self.layers * sum(
+            int(np.prod(shape)) * np.dtype(dtype).itemsize
+            for shape, dtype in self.rows)
+
+
 class CacheExhaustedError(MXNetError):
-    """No free KV-cache blocks for a new sequence or a grown one.
+    """No free KV-cache blocks (or, for a model with recurrent layers,
+    no free state slot) for a new sequence or a grown one.
 
     Carries ``http_status = 429`` so the serving front-end maps it like
     the other typed admission rejections (the client should back off and
@@ -153,6 +194,18 @@ _M_SESS_BLOCKS = _metrics.histogram(
     buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256))
 
 
+_M_SLOTS = _metrics.gauge(
+    "serving_state_slots_used",
+    "Recurrent-state slots held by live sequences, by model", ["model"])
+_M_STATE_BYTES = _metrics.gauge(
+    "serving_state_bytes",
+    "Bytes of recurrent state held by live sequences (both versions of "
+    "every slot in use), by model", ["model"])
+_M_STATE_PREFILL = _metrics.counter(
+    "serving_state_prefill_slots_total",
+    "State slots written by a prefill, by model", ["model"])
+
+
 def _scatter_pages(k_pages, v_pages, k, v, blocks, offsets):
     """``k``/``v`` ``[L, N, ...]`` (one row's values each) into slot
     ``(blocks[n], offsets[n])`` of every layer.  A slot whose block id
@@ -180,6 +233,17 @@ def _scatter_pages(k_pages, v_pages, k, v, blocks, offsets):
 _write_pages = jax.jit(_scatter_pages, donate_argnums=(0, 1))
 
 
+def _scatter_state(pools, rows, at):
+    """A prefill's state rows (``[state layers, ...]`` an array of
+    ``rows``) into rows ``at`` ``int32 [state layers]`` of the state
+    pools."""
+    return tuple(pool.at[at].set(row.astype(pool.dtype))
+                 for pool, row in zip(pools, rows))
+
+
+_write_state = jax.jit(_scatter_state, donate_argnums=(0,))
+
+
 class PagedKVCache(object):
     """Free-list block allocator + per-sequence block tables + the pools.
 
@@ -193,7 +257,7 @@ class PagedKVCache(object):
 
     def __init__(self, num_layers, num_heads=None, head_dim=None,
                  block_size=None, num_blocks=None, dtype=np.float32,
-                 model="default", row=None):
+                 model="default", row=None, state=None, state_slots=None):
         self.block_size = int(block_size or default_block_size())
         self.num_blocks = int(num_blocks or default_num_blocks())
         if self.block_size <= 0 or self.num_blocks <= 0:
@@ -210,8 +274,19 @@ class PagedKVCache(object):
                        int(self.row.width))
         self._dtype = np.dtype(self.row.dtype)
         _M_ROW_BYTES.labels(model).set(self.row.bytes)
+        # the recurrent layers' state, one slot a sequence (none for a
+        # model whose every layer keeps rows per token)
+        self.state = state
+        if state and not state_slots:
+            raise MXNetError("PagedKVCache needs state_slots for a model "
+                             "with recurrent state: one a live sequence, "
+                             "at least the largest decode bucket")
+        self.num_slots = int(state_slots) if state else 0
         self._zero_pools()
+        self._zero_state()
         self._lock = threading.Lock()
+        self._free_slots = list(range(self.num_slots - 1, -1, -1))
+        self._slots = {}       # seq_id -> state slot
         self._free = list(range(self.num_blocks - 1, -1, -1))
         self._tables = {}      # seq_id -> [block ids]
         self._owner = {}       # block id -> seq_id, for every block in use
@@ -232,6 +307,14 @@ class PagedKVCache(object):
         _memory.tag("kv_cache", self._ledger_key, self.pool_bytes)
         weakref.finalize(self, _memory.untag, "kv_cache",
                          self._ledger_key)
+        if state:
+            self._slots_used = _M_SLOTS.labels(model)
+            self._state_held = _M_STATE_BYTES.labels(model)
+            self._state_prefills = _M_STATE_PREFILL.labels(model)
+            _memory.tag("recurrent_state", self._ledger_key,
+                        self.state_bytes)
+            weakref.finalize(self, _memory.untag, "recurrent_state",
+                             self._ledger_key)
 
     def _zero_pools(self):
         # drop the old pair first: two pools never exist at once
@@ -240,10 +323,25 @@ class PagedKVCache(object):
         if self.row.pools == 2:
             self.v_pages = jnp.zeros(self._shape, self._dtype)
 
+    def _zero_state(self):
+        self.state_pools = None
+        if self.state:
+            # one row more than the slots': where pad rows write
+            rows = self.state.layers * 2 * self.num_slots + 1
+            self.state_pools = tuple(
+                jnp.zeros((rows,) + tuple(shape), dtype)
+                for shape, dtype in self.state.rows)
+
     @property
     def pool_bytes(self):
         return self.row.pools * int(np.prod(self._shape)) \
             * self._dtype.itemsize
+
+    @property
+    def state_bytes(self):
+        """Bytes of the state pool: two versions of every slot, and the
+        pad rows' row."""
+        return sum(int(p.nbytes) for p in self.state_pools or ())
 
     # -- allocation --------------------------------------------------
 
@@ -263,10 +361,14 @@ class PagedKVCache(object):
         with self._lock:
             table = self._tables.get(seq_id, [])
             grow = need_total - len(table)
-            if grow > len(self._free):
+            no_slot = bool(self.state) and seq_id not in self._slots \
+                and not self._free_slots
+            if grow > len(self._free) or no_slot:
                 self._exhausted.inc()
                 used = self.num_blocks - len(self._free)
                 err = CacheExhaustedError(
+                    "kv cache exhausted: seq %r needs a state slot, none "
+                    "free of %d" % (seq_id, self.num_slots) if no_slot else
                     "kv cache exhausted: seq %r needs %d more block(s), "
                     "%d free of %d" % (seq_id, grow, len(self._free),
                                        self.num_blocks))
@@ -282,15 +384,20 @@ class PagedKVCache(object):
                 self._owner.update(dict.fromkeys(fresh, seq_id))
                 self._lengths.setdefault(seq_id, 0)
                 self._allocs.inc(grow)
+            if self.state and seq_id not in self._slots:
+                self._slots[seq_id] = self._free_slots.pop()
             self._set_gauges_locked()
 
     def free(self, seq_id):
-        """Return ``seq_id``'s blocks to the pool; returns the freed
-        block ids (empty for an unknown sequence — freeing is always
-        safe to call from retire paths)."""
+        """Return ``seq_id``'s blocks (and its state slot) to the pool;
+        returns the freed block ids (empty for an unknown sequence —
+        freeing is always safe to call from retire paths)."""
         with self._lock:
             table = self._tables.pop(seq_id, None) or []
             self._lengths.pop(seq_id, None)
+            slot = self._slots.pop(seq_id, None)
+            if slot is not None:
+                self._free_slots.append(slot)
             for block in table:
                 del self._owner[block]
             if table:
@@ -310,6 +417,9 @@ class PagedKVCache(object):
             self._frag.set(1.0 - written / float(used * self.block_size))
         else:
             self._frag.set(0.0)
+        if self.state:
+            self._slots_used.set(len(self._slots))
+            self._state_held.set(2 * len(self._slots) * self.state.bytes)
 
     # -- reads -------------------------------------------------------
 
@@ -339,6 +449,42 @@ class PagedKVCache(object):
         out[:len(table)] = table
         return out
 
+    def state_slots(self, block_tables, positions):
+        """``int32 [B]``: the state slot of the sequence each row of a
+        decode call belongs to, found through the first block of its
+        table (the slot rides with the block table); ``num_slots`` for a
+        pad row (position 0), which the decode program reads as "writes
+        nowhere"."""
+        tables = np.asarray(block_tables)
+        out = np.full(len(tables), self.num_slots, dtype=np.int32)
+        with self._lock:
+            for row in np.flatnonzero(np.asarray(positions)):
+                owner = self._owner.get(int(tables[row, 0]))
+                if owner is None:
+                    raise MXNetError(
+                        "state_slots: row %d reads block %d, which is "
+                        "free" % (row, tables[row, 0]))
+                out[row] = self._slots[owner]
+        return out
+
+    def swap_state(self, pools):
+        """Re-bind the state pools to what the decode program that was
+        handed them (donated) gave back."""
+        self.state_pools = tuple(pools)
+
+    def state_lost(self, exc):
+        """A program failed after the state pools were donated to it:
+        rebuild them zeroed and return the error to raise (every live
+        sequence lost its state); ``None`` if they are intact."""
+        if not any(p.is_deleted() for p in self.state_pools or ()):
+            return None
+        self._zero_state()
+        return CachePoolLostError(
+            "kv cache %r: a program failed after the state pool was "
+            "donated (%s: %s); the pool was rebuilt zeroed and every "
+            "live sequence lost its state"
+            % (self.model, type(exc).__name__, exc))
+
     # -- writes ------------------------------------------------------
 
     def _write_locked(self, k, v, blocks, offsets):
@@ -360,11 +506,15 @@ class PagedKVCache(object):
                 % (self.model, type(exc).__name__, exc)) from exc
         return blocks.nbytes + offsets.nbytes
 
-    def write_prefill(self, seq_id, k, v, length):
+    def write_prefill(self, seq_id, k, v, length, state=None):
         """Store prompt K/V: ``k``/``v`` device arrays ``[L, T, heads *
         dim]`` (or ``[L, T, heads, dim]``) as the prefill dispatch
         produced them, ``T`` its bucket; positions ``< length`` are
-        written, the bucket's pad positions are dropped.
+        written, the bucket's pad positions are dropped.  ``state``
+        (a model with recurrent layers): the prompt's state rows taken
+        at ``length``, written to version ``length % 2`` of the
+        sequence's slot, where the decode step at position ``length``
+        reads them.
 
         Requires a prior :meth:`allocate` covering ``length`` tokens.
         Call it only after the prefill dispatch succeeded: it targets
@@ -387,9 +537,26 @@ class PagedKVCache(object):
                 positions // self.block_size]
             offsets[:length] = positions % self.block_size
             staged = self._write_locked(k, v, blocks, offsets)
+            if self.state:
+                staged += self._write_state_locked(
+                    self._slots[seq_id], length % 2, state)
             self._lengths[seq_id] = max(self._lengths.get(seq_id, 0),
                                         length)
         return staged
+
+    def _write_state_locked(self, slot, version, rows):
+        at = ((np.arange(self.state.layers, dtype=np.int32) * 2 + version)
+              * self.num_slots + slot).astype(np.int32)
+        try:
+            self.state_pools = _write_state(self.state_pools, tuple(rows),
+                                            at)
+        except Exception as exc:
+            lost = self.state_lost(exc)
+            if lost is None:
+                raise
+            raise lost from exc
+        self._state_prefills.inc()
+        return at.nbytes
 
     def write_tokens(self, block_tables, positions, k, v):
         """Store one decode step's K/V for the whole batch in one call:
@@ -453,4 +620,7 @@ class PagedKVCache(object):
                                      if used else 0.0,
                     "sequences": len(self._tables),
                     "block_size": self.block_size,
-                    "pool_bytes": self.pool_bytes}
+                    "pool_bytes": self.pool_bytes,
+                    "state_slots": self.num_slots,
+                    "state_slots_used": len(self._slots),
+                    "state_bytes": self.state_bytes}

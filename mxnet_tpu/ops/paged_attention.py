@@ -1,10 +1,12 @@
 """Paged decode attention: one decode step read through a block table.
 
-Both models ``LMBackend`` serves decode over a paged cache
+The models ``LMBackend`` serves decode over a paged cache
 (:mod:`~mxnet_tpu.ops.kv_cache`): GPT-2 over float32 key and value
 pools (:func:`paged_decode_attention`), the latent-attention model over
 one pool of latent rows in the absorbed form
-(:func:`latent_paged_decode_attention`).  Each public function holds
+(:func:`latent_paged_decode_attention`), a grouped-query model over key
+and value pools whose rows hold its few key-value heads
+(:func:`gqa_paged_decode_attention`).  Each public function holds
 its whole choice of body: where a Pallas kernel runs
 (:func:`~mxnet_tpu.ops.platform.pallas_mode`) and a page of every pool
 is whole tiles (:func:`_walk_tiles`), the block-table walk
@@ -26,7 +28,8 @@ from . import platform as _platform
 from .attention import NEG_INF, stable_scores, stable_softmax
 from .fused.parity import case_rng, register_parity
 
-__all__ = ["paged_decode_attention", "latent_paged_decode_attention"]
+__all__ = ["paged_decode_attention", "latent_paged_decode_attention",
+           "gqa_paged_decode_attention"]
 
 # Mosaic tiles the last two dims of a block as (8 sublanes, 128 lanes)
 _LANE = 128
@@ -155,8 +158,63 @@ def _latent_decode_xla(q, row_step, pages, block_tables, context_lens,
             ).astype(q.dtype)
 
 
+def gqa_paged_decode_attention(q, k_step, v_step, k_pages, v_pages,
+                               block_tables, context_lens, sm_scale):
+    """One decode step of grouped-query attention over key and value
+    pools.
+
+    - ``q`` ``[B, Hq, D]``; ``k_step``/``v_step`` ``[B, Hkv, D]`` this
+      token's keys and values (written to the pools by the caller after
+      the step); key-value head ``g`` serves the query heads ``g * Hq /
+      Hkv`` and the ``Hq / Hkv - 1`` after it.
+    - ``k_pages``/``v_pages`` ``[num_blocks, block_size, Hkv * D]``: a
+      cached row holds every key-value head, and lies as it is indexed;
+      ``block_tables`` ``int32 [B, max_blocks]``; ``context_lens``
+      ``int32 [B]`` counting the current token.
+
+    Returns ``[B, Hq, D]`` in ``q``'s dtype.  Scores and softmax in
+    float32, the products in the pools' dtype; the current token enters
+    as a score of its own, so the pools are read as they lie.  On a TPU,
+    with pages that are whole tiles, the block-table walk
+    (:func:`_walk_pages`) reads the blocks that hold live tokens and no
+    other; elsewhere XLA gathers every table block and masks."""
+    mode = _platform.pallas_mode()
+    if mode and _walk_tiles(k_pages, v_pages):
+        return _gqa_decode_pallas(q, k_step, v_step, k_pages, v_pages,
+                                  block_tables, context_lens,
+                                  float(sm_scale), mode == "interpret")
+    with jax.named_scope("paged_decode_gqa_attention"):
+        return _gqa_decode_xla(q, k_step, v_step, k_pages, v_pages,
+                               block_tables, context_lens, sm_scale)
+
+
+def _gqa_decode_xla(q, k_step, v_step, k_pages, v_pages, block_tables,
+                    context_lens, sm_scale):
+    bsz, max_blocks = block_tables.shape
+    groups, dim = k_step.shape[1:]
+    kmax = max_blocks * k_pages.shape[1]
+    f32 = jnp.float32
+    keys = k_pages[block_tables].reshape(bsz, kmax, groups, dim)
+    values = v_pages[block_tables].reshape(bsz, kmax, groups, dim)
+    qg = q.reshape(bsz, groups, -1, dim)                # [B, G, R, D]
+    s = jnp.einsum("bgrd,bkgd->bgrk", qg.astype(keys.dtype), keys,
+                   preferred_element_type=f32) * sm_scale
+    pos = lax.broadcasted_iota(jnp.int32, (1, 1, 1, kmax), 3)
+    s = jnp.where(pos < (context_lens - 1)[:, None, None, None], s, NEG_INF)
+    s_self = jnp.einsum("bgrd,bgd->bgr", qg.astype(f32),
+                        k_step.astype(f32)) * sm_scale
+    m = jnp.maximum(jnp.max(s, axis=-1), s_self)
+    p = jnp.exp(s - m[..., None])
+    p_self = jnp.exp(s_self - m)
+    out = jnp.einsum("bgrk,bkgd->bgrd", p.astype(values.dtype), values,
+                     preferred_element_type=f32)
+    out = out + p_self[..., None] * v_step.astype(f32)[:, :, None, :]
+    out = out / (jnp.sum(p, axis=-1) + p_self)[..., None]
+    return out.reshape(q.shape).astype(q.dtype)
+
+
 # ----------------------------------------------------------------------
-# paged decode on a TPU: one block-table walk under both decode bodies
+# paged decode on a TPU: one block-table walk under every decode body
 # ----------------------------------------------------------------------
 #
 # A decode step attends over ``context_len - 1`` cached tokens a row,
@@ -476,6 +534,88 @@ def _latent_decode_pallas(q, row_step, pages, block_tables, context_lens,
             interpret=interpret)
 
 
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+def _gqa_decode_pallas(q, k_step, v_step, k_pages, v_pages, block_tables,
+                       context_lens, sm_scale, interpret=False):
+    """The grouped-query decode over the walk: a chunk's key rows hold
+    every key-value head side by side (``D`` a whole number of lane
+    tiles), so a head's keys are a slice of lanes and its ``Hq / Hkv``
+    queries one small product with them on the MXU, in the pools' dtype;
+    scores, softmax and accumulator stay float32.  Jitted so that a
+    model's layers share one trace and one lowering of the kernel."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bsz, heads, dim = q.shape
+    groups = k_step.shape[1]
+    per = heads // groups
+    f32 = jnp.float32
+
+    def group(ref, g):                  # a group's rows of [Hq, .] state
+        return ref.at[pl.ds(g * per, per)]
+
+    def init(row_refs, state):
+        q_ref, k_ref, v_ref = row_refs
+        m_ref, l_ref, acc_ref = state
+        l_ref[...] = jnp.ones_like(l_ref)
+        for g in range(groups):
+            lanes = slice(g * dim, (g + 1) * dim)
+            q_g = q_ref[0, g * per:(g + 1) * per, :]            # [R, D]
+            group(m_ref, g)[...] = jnp.sum(
+                q_g * k_ref[0][:, lanes].astype(f32), axis=1,
+                keepdims=True) * sm_scale
+            group(acc_ref, g)[...] = jnp.broadcast_to(
+                v_ref[0][:, lanes].astype(f32), (per, dim))
+
+    def chunk(row_refs, held, state, live):
+        m_ref, l_ref, acc_ref = state
+        keys, values = held[0][...], held[1][...]               # [T, W]
+        tokens = keys.shape[0]
+        if live is not None:
+            at = lax.broadcasted_iota(jnp.int32, (tokens, 1), 0)
+            values = jnp.where(at < live, values, 0)
+        for g in range(groups):
+            lanes = slice(g * dim, (g + 1) * dim)
+            q_g = row_refs[0][0, g * per:(g + 1) * per, :]
+            s = lax.dot_general(
+                q_g.astype(keys.dtype), keys[:, lanes],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=f32) * sm_scale          # [R, T]
+            if live is not None:
+                at = lax.broadcasted_iota(jnp.int32, (1, tokens), 1)
+                s = jnp.where(at < live, s, NEG_INF)
+            m_prev = group(m_ref, g)[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            group(l_ref, g)[...] = alpha * group(l_ref, g)[...] \
+                + jnp.sum(p, axis=1, keepdims=True)
+            group(acc_ref, g)[...] = alpha * group(acc_ref, g)[...] \
+                + lax.dot_general(
+                    p.astype(values.dtype), values[:, lanes],
+                    (((1,), (0,)), ((), ())), preferred_element_type=f32)
+            group(m_ref, g)[...] = m_new
+
+    def finish(row_refs, state, out_ref):
+        _, l_ref, acc_ref = state
+        out_ref[0] = (acc_ref[...] / l_ref[...]).astype(out_ref.dtype)
+
+    width = groups * dim
+    # the queries in float32: a group's rows are then whole sublane
+    # tiles whatever the pools' dtype packs
+    rows = (q.astype(f32), k_step.reshape(bsz, 1, width),
+            v_step.reshape(bsz, 1, width))
+    # the scope names the kernel in a trace; it has to be the innermost
+    with jax.named_scope("paged_decode_gqa_attention"):
+        return _walk_pages(
+            init, chunk, finish, rows, (), (k_pages, v_pages),
+            block_tables, context_lens,
+            jax.ShapeDtypeStruct((bsz, heads, dim), q.dtype),
+            [pltpu.VMEM((heads, 1), f32), pltpu.VMEM((heads, 1), f32),
+             pltpu.VMEM((heads, dim), f32)],
+            interpret=interpret)
+
+
 # ----------------------------------------------------------------------
 # parity grids: each kernel against its XLA body (ragged tails on
 # purpose; the widest case of each is the served shape, which
@@ -559,4 +699,30 @@ register_parity(
         ("float32", 2, 40, 24, 4, 4, (1, 16, 9)),    # no cached token
         # the served row (640 wide, 512 of it values, blocks of 16)
         ("bfloat16", 16, 640, 512, 16, 4, (1, 17, 64)),
+    ))
+
+
+def _gqa_case(case):
+    dtype, heads, groups, dim, blk, max_blocks, ctx = case
+    rand, pool, tables, lens = _paged_case_pool(
+        case_rng(case), dtype, blk, max_blocks, ctx, groups * dim)
+    k_pages, v_pages = rand(pool), rand(pool)
+    q = rand((len(ctx), heads, dim))
+    k_step, v_step = (rand((len(ctx), groups, dim)) for _ in range(2))
+    tol = (3e-2, 3e-2) if dtype == "bfloat16" else (1e-4, 1e-4)
+    scale = 1.0 / float(dim) ** 0.5
+    return (functools.partial(_gqa_decode_xla, sm_scale=scale),
+            functools.partial(_gqa_decode_pallas, sm_scale=scale,
+                              interpret=_interpret()),
+            (q, k_step, v_step, k_pages, v_pages, tables, lens), tol)
+
+
+register_parity(
+    "paged_decode_gqa_attention", _gqa_case, parity="tolerance",
+    grid=(
+        ("float32", 4, 2, 64, 8, 3, (5, 20)),        # ragged contexts
+        ("float32", 6, 1, 128, 4, 4, (1, 16, 9)),    # one group, no cache
+        # the served heads (16 queries over 2 key-value heads of 256,
+        # 512-wide bfloat16 rows, blocks of 16)
+        ("bfloat16", 16, 2, 256, 16, 4, (1, 16, 17, 18, 64)),
     ))

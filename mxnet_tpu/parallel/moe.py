@@ -28,7 +28,8 @@ from jax.sharding import PartitionSpec as P
 from ..observability import metrics as _metrics
 
 __all__ = ["moe_ffn", "init_moe_params", "router_top1", "router_topk",
-           "route_group_limited", "dropless_experts", "swiglu",
+           "route_group_limited", "route_softmax_topk", "dropless_experts",
+           "swiglu", "gated_shared_expert",
            "book_expert_counts", "EXPERT_COUNTS"]
 
 
@@ -226,6 +227,21 @@ def route_group_limited(logits, bias, *, top_k, n_group=1, topk_group=1,
     return chosen, gates * scale
 
 
+def route_softmax_topk(logits, *, top_k, normalize=True):
+    """The softmax router of the Qwen expert models.  ``logits``
+    ``[T, E]`` over ALL the experts of the model: ``p = softmax(logits)``
+    in float32, the ``top_k`` largest are chosen, and the gates are
+    ``p`` at the chosen experts, divided by their sum if ``normalize``
+    (``norm_topk_prob``).  Returns ``(experts int32 [T, top_k], gates
+    float32 [T, top_k])``; every token keeps all its ``top_k``
+    experts."""
+    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    gates, chosen = jax.lax.top_k(p, top_k)
+    if normalize:
+        gates = gates / gates.sum(-1, keepdims=True)
+    return chosen.astype(jnp.int32), gates
+
+
 def swiglu(x, w_gate, w_up, w_down):
     """``W_down(silu(W_gate x) * W_up x)`` with ``[out, in]`` weights,
     float32 accumulation, activations kept in ``x``'s dtype."""
@@ -235,6 +251,15 @@ def swiglu(x, w_gate, w_up, w_down):
 
     h = (jax.nn.silu(dot(x, w_gate)) * dot(x, w_up)).astype(x.dtype)
     return dot(h, w_down).astype(x.dtype)
+
+
+def gated_shared_expert(x, w_gate, w_up, w_down, w_shared_gate):
+    """A shared expert behind a sigmoid gate of its own: ``sigmoid(w_sg
+    . x) * swiglu(x)``, ``w_shared_gate`` ``[1, d]``; the gate in
+    float32."""
+    gate = jax.nn.sigmoid(jnp.einsum(
+        "tc,fc->tf", x, w_shared_gate, preferred_element_type=jnp.float32))
+    return (gate * swiglu(x, w_gate, w_up, w_down)).astype(x.dtype)
 
 
 # what an expert layer counts, in this order, as one int32 vector a

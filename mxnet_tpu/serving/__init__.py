@@ -52,7 +52,7 @@ from .admission import (AdmissionController, CacheExhaustedError,
                         default_deadline_ms)
 from .frontend import ServingFrontend, start_frontend
 from .generation import (GenerationRequest, GenerationScheduler,
-                         LMBackend)
+                         LMBackend, RecurrentStateHazard)
 from .registry import (Backend, ExportedBackend, ModelRegistry,
                        PredictorBackend, as_backend, default_buckets)
 from .replication import ReplicaGroup, ServingRouter
@@ -66,7 +66,7 @@ __all__ = [
     "DEFAULT_TENANT", "DeadlineExceededError", "ExportedBackend",
     "FairQueue", "GenerationRequest", "GenerationScheduler",
     "InferenceRequest", "InvalidDeadlineError", "KVAffinityRouter",
-    "LMBackend", "ModelRegistry", "PredictorBackend",
+    "LMBackend", "RecurrentStateHazard", "ModelRegistry", "PredictorBackend",
     "QuotaExceededError", "ReplicaDeadError", "ReplicaGroup",
     "Scheduler", "ServerDrainingError", "ServerOverloadedError",
     "ServingError", "ServingFrontend", "ServingRouter", "TenantPolicy",

@@ -327,11 +327,12 @@ def start_frontend(target, port=None, addr="127.0.0.1", timeout=30.0,
 
         def _chunk(self, data):
             # manual chunked-transfer framing: hex length, CRLF, data,
-            # CRLF — flushed per token so the client reads the stream
-            # mid-generation, not after it
-            self.wfile.write(b"%x\r\n" % len(data))
-            self.wfile.write(data)
-            self.wfile.write(b"\r\n")
+            # CRLF — one write a chunk (the handler's wfile is
+            # unbuffered: every write is a send of its own, a segment
+            # of its own on the wire and a wake-up of the reader), sent
+            # per token so the client reads the stream mid-generation,
+            # not after it
+            self.wfile.write(b"%x\r\n%s\r\n" % (len(data), data))
             self.wfile.flush()
 
         def _generate(self, body):

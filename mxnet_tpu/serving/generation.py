@@ -51,6 +51,20 @@ scheduler was already styled after:
   would have to wait behind is never queued, so first tokens wait no
   longer (``generation_decode_ahead_used_total`` /
   ``generation_decode_ahead_dropped_total``).
+- **Recurrent state beside keys and values.**  A model whose
+  definition has a ``state`` (layers that keep a fixed-size state per
+  sequence: :class:`~mxnet_tpu.ops.kv_cache.StateRows`) gets a state
+  slot with its blocks; its prefill writes the slot once and its decode
+  program updates the state pool where it lies.  A recurrent update is
+  not idempotent the way a key row's write is, so a slot keeps two
+  versions by the parity of the position (:meth:`LMBackend.decode` has
+  the account): a step that is dispatched again, or queued ahead and
+  dropped, leaves every state as if each position had been consumed
+  once, and the run-ahead above stays on.  The one case two versions do
+  not cover, a step that fails *after* the step behind it was queued,
+  is raised as :class:`RecurrentStateHazard`, and the loop re-prefills
+  its live sequences (prompt + tokens so far) instead of retrying in
+  place (``generation_state_hazard_total``).
 - **Cache is backend state.**  ``ModelRegistry.swap`` replaces backend
   and cache together (the registry machinery is untouched); the loop
   notices the swap under ``dispatch_lock`` and transparently
@@ -60,7 +74,10 @@ scheduler was already styled after:
 
 Chaos sites: ``serving.decode`` fires inside the decode window before
 the device call (name ``<model>:<bucket>``, retried
-``MXNET_TPU_SERVING_RETRIES`` times); ``serving.kv_alloc`` fires in the
+``MXNET_TPU_SERVING_RETRIES`` times) and, for a model with recurrent
+state, once more inside :meth:`LMBackend.decode` behind the dispatch
+(name ``<model>:fetch``: a step that fails once it, and maybe the step
+after it, is on the device); ``serving.kv_alloc`` fires in the
 allocator.  Prefill dispatches visit the existing ``serving.dispatch``
 site (name ``<model>:prefill:<bucket>``).
 
@@ -99,8 +116,8 @@ from .registry import Backend, ModelRegistry
 from .scheduler import default_retries
 
 __all__ = ["GenerationRequest", "GenerationScheduler", "LMBackend",
-           "default_decode_buckets", "default_prefill_buckets",
-           "default_max_new_tokens"]
+           "RecurrentStateHazard", "default_decode_buckets",
+           "default_prefill_buckets", "default_max_new_tokens"]
 
 
 def default_prefill_buckets():
@@ -157,6 +174,24 @@ _M_AHEAD_DROPPED = _metrics.counter(
     "generation_decode_ahead_dropped_total",
     "Queued decode steps thrown away: the next call asked for another "
     "step, or a fault, kill or swap came first, by model", ["model"])
+
+
+_M_STATE_MOVED = _metrics.counter(
+    "generation_state_bytes_total",
+    "Bytes of recurrent state decode steps read and wrote (every live "
+    "row's state once each way a step), by model", ["model"])
+_M_STATE_HAZARD = _metrics.counter(
+    "generation_state_hazard_total",
+    "Live sequences re-prefilled (resumed) or failed because a decode "
+    "step failed after the step behind it had overwritten the state it "
+    "read, by model and outcome", ["model", "outcome"])
+
+
+class RecurrentStateHazard(MXNetError):
+    """A decode step of a model with recurrent state failed after the
+    step behind it was queued: the queued step has overwritten the
+    version of the state this step read, so it cannot be run again in
+    place.  The live sequences have to be re-prefilled or failed."""
 
 
 def _host_nbytes(arrays):
@@ -290,13 +325,14 @@ def with_greedy_ids(decode):
     the greedy choice made beside it, from the very logits the host
     gets (the first maximum, as ``numpy.argmax``): the program
     :meth:`LMBackend.decode` runs, ``(logits, ids int32 [B], k_rows,
-    v_rows, counts)``."""
+    v_rows, counts)`` (and the state pools, where the model has
+    them)."""
     import jax.numpy as jnp
 
     def program(params, *args):
-        logits, k, v, counts = decode(params, *args)
+        logits, *rest = decode(params, *args)
         return (logits, jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                k, v, counts)
+                *rest)
 
     return program
 
@@ -353,6 +389,20 @@ class LMBackend(Backend):
     of it.  ``generation_decode_ahead_used_total`` /
     ``generation_decode_ahead_dropped_total`` count both outcomes.
 
+    **A model with recurrent state** (``definition.state``) has a slot
+    of ``state_slots`` a live sequence beside its blocks, in two
+    versions: the step at position ``p`` reads version ``p % 2`` and
+    writes ``(p + 1) % 2``, inside the decode program, which is handed
+    the state pool donated and updates it where it lies.  So a step
+    that is dispatched again (a retry) finds what it read untouched and
+    writes the same values again, and a queued step that is dropped has
+    read what the step before it wrote and overwritten only what that
+    step had read, which no one needs again: every state is as if each
+    position had been consumed once, and run-ahead stays on.  What two
+    versions cannot cover is a step that fails after the step behind it
+    was queued: :meth:`decode` raises :class:`RecurrentStateHazard`
+    then, and the caller re-prefills (the generation loop does).
+
     ``int8_head=True`` opts into the
     :func:`~mxnet_tpu.contrib.quantization.quantize_weight_int8` vocab
     head for decode logits (storage/bandwidth win on the model's
@@ -361,7 +411,8 @@ class LMBackend(Backend):
     """
 
     def __init__(self, params, cfg=None, block_size=None, num_blocks=None,
-                 int8_head=False, model="lm", definition=None):
+                 int8_head=False, model="lm", definition=None,
+                 state_slots=None):
         import jax
 
         if definition is None:
@@ -380,8 +431,10 @@ class LMBackend(Backend):
             else dict(params))
         self.input_shapes = {"data": (self.cfg["seq_len"],)}
         self.cache = PagedKVCache(
-            num_layers=self.cfg["num_layers"], row=definition.cache_row,
-            block_size=block_size, num_blocks=num_blocks, model=model)
+            num_layers=definition.cache_layers or self.cfg["num_layers"],
+            row=definition.cache_row, block_size=block_size,
+            num_blocks=num_blocks, model=model, state=definition.state,
+            state_slots=state_slots)
         # every sequence gets a fixed-width block table: the decode jit
         # signature depends only on the batch bucket, never on how long
         # any sequence has run — the zero-recompile contract
@@ -404,6 +457,7 @@ class LMBackend(Backend):
         self.greedy_ids = None
         self._ahead_used = _M_AHEAD_USED.labels(model)
         self._ahead_dropped = _M_AHEAD_DROPPED.labels(model)
+        self._state_moved = _M_STATE_MOVED.labels(model)
         self._moved = {(phase, way): fam.labels(model, phase)
                        for phase in ("prefill", "decode")
                        for way, fam in (("h2d", _M_H2D), ("d2h", _M_D2H))}
@@ -413,8 +467,9 @@ class LMBackend(Backend):
         _memory.tag_tree("params", id(self), self.params)
         _weakref.finalize(self, _memory.untag, "params", id(self))
 
-    def _jit(self, key, program):
-        """Shape-keyed jit cache of the definition's ``program``;
+    def _jit(self, key, program, donate=()):
+        """Shape-keyed jit cache of the definition's ``program``
+        (``donate``: the arguments it may write where they lie);
         returns (fn, cold)."""
         with self._jit_lock:
             fn = self._jits.get(key)
@@ -422,7 +477,7 @@ class LMBackend(Backend):
             if cold:
                 import jax
 
-                fn = jax.jit(program)
+                fn = jax.jit(program, donate_argnums=donate)
                 self._jits[key] = fn
         return fn, cold
 
@@ -480,29 +535,51 @@ class LMBackend(Backend):
         The logits are a host copy; ``k``/``v`` are device arrays over
         the whole bucket, for ``cache.write_prefill(seq, k, v, length)``
         (which drops the pad positions); ``cold`` reports the jit-cache
-        miss for compile accounting."""
+        miss for compile accounting.  A model with recurrent state
+        returns a fifth value, the prompt's state rows at ``length``
+        (device arrays), for the same ``write_prefill``."""
         tokens = _np.asarray(tokens, dtype=_np.int32)
         args = (tokens, _np.asarray(length, dtype=_np.int32))
         fn, cold = self._jit(("prefill",) + tokens.shape,
                              self.definition.prefill)
-        logits, k, v, counts = fn(self.params, *args)
+        logits, k, v, counts, *state = fn(self.params, *args)
         self._copy_back(counts)
         self.moved("prefill", h2d=_host_nbytes(
             (*self.params.values(), *args)))
-        return self._fetch("prefill", logits, counts)[0], k, v, cold
+        return (self._fetch("prefill", logits, counts)[0], k, v, cold,
+                *state)
 
     def _dispatch_decode(self, tokens, positions, block_tables,
-                         context_lens):
+                         context_lens, slots=None):
         """Put one decode step on the device's queue and return it: the
         program, the copies of what it gives back, and right behind it
         the write of its K/V rows into the pool, so that whatever is
         dispatched next reads a pool that holds this step.  ``tokens``
-        is numpy, or the device's ids of the step before."""
+        is numpy, or the device's ids of the step before.  ``slots``
+        (a model with recurrent state): the rows' state slots; the
+        program is handed the state pool donated and the cache is
+        re-bound to what it gives back."""
         args = (tokens, positions, self.cache.k_pages, self.cache.v_pages,
                 block_tables, context_lens)
-        fn, cold = self._jit(("decode", len(positions)),
-                             self._decode_program)
-        logits, ids, k, v, counts = fn(self.params, *args)
+        if slots is None:
+            fn, cold = self._jit(("decode", len(positions)),
+                                 self._decode_program)
+            logits, ids, k, v, counts = fn(self.params, *args)
+        else:
+            fn, cold = self._jit(("decode", len(positions)),
+                                 self._decode_program, donate=(7,))
+            try:
+                logits, ids, k, v, counts, pools = fn(
+                    self.params, *args, self.cache.state_pools, slots)
+            except Exception as exc:
+                lost = self.cache.state_lost(exc)
+                if lost is None:
+                    raise
+                raise lost from exc
+            self.cache.swap_state(pools)
+            self._state_moved.inc(
+                2 * int((slots < self.cache.num_slots).sum())
+                * self.cache.state.bytes)
         self._copy_back(logits, ids, counts)
         self.moved("decode", h2d=_host_nbytes(
             (*self.params.values(), *args))
@@ -547,29 +624,53 @@ class LMBackend(Backend):
         before ``context_lens`` lets a step read it, and the device
         runs what it is handed in order.
 
+        **Recurrent state** (a definition with ``state``) is updated by
+        the program itself, in the donated state pool: row ``i`` reads
+        version ``positions[i] % 2`` of its sequence's slot (found
+        through its block table) and writes the other version.  A call
+        that is repeated therefore reads what the first one read and
+        writes the same values again, and a queued step that is dropped
+        has overwritten only the version the step before it read.  Not
+        so a call that fails once the step after it is queued: that
+        step has overwritten what a repeat would read, and the error
+        raised is :class:`RecurrentStateHazard`; the caller re-prefills
+        its sequences or fails them.
+
         A subclass that overrides this method with these four
         arguments and calls it (the benchmark's wrapper does) sees one
         call a step, numpy arguments, and a numpy ``out[0]`` that
         belongs to those arguments."""
         fed = tuple(_np.asarray(a, dtype=_np.int32) for a in
                     (tokens, positions, block_tables, context_lens))
+        slots = self.cache.state_slots(fed[2], fed[1]) \
+            if self.cache.state else None
         step, self._ahead = self._ahead, None
         if step is not None and all(map(_np.array_equal, step.fed, fed)):
             self._ahead_used.inc()
         else:
             if step is not None:
                 self._ahead_dropped.inc()
-            step = self._dispatch_decode(*fed)
+            step = self._dispatch_decode(*fed, slots)
         ahead = None
         try:
             if self.run_ahead:
                 ahead = self._dispatch_decode(
-                    step.ids, fed[1] + 1, fed[2], fed[3] + 1)
+                    step.ids, fed[1] + 1, fed[2], fed[3] + 1, slots)
+            if slots is not None:
+                # the drill of a step that fails behind its dispatch
+                chaos.visit("serving.decode", name="%s:fetch" % self.model)
             logits, ids = self._fetch("decode", step.logits, step.counts,
                                       step.ids)
-        except Exception:
+        except Exception as exc:
             if ahead is not None:
                 self._ahead_dropped.inc()
+            if (slots is not None and self.run_ahead
+                    and not isinstance(exc, CachePoolLostError)):
+                raise RecurrentStateHazard(
+                    "model %r: a decode step failed after the step "
+                    "behind it was queued (%s: %s); the state it read "
+                    "is overwritten" % (self.model, type(exc).__name__,
+                                        exc)) from exc
             raise
         if ahead is not None:
             self._ahead = ahead._replace(fed=(ids,) + ahead.fed[1:])
@@ -800,9 +901,9 @@ class GenerationScheduler(object):
             cache.allocate(sid, 3)
             try:
                 for t in self._prefill_buckets[name]:
-                    _, k, v, cold = backend.prefill(
+                    _, k, v, cold, *state = backend.prefill(
                         _np.zeros(t, dtype=_np.int32), 1)
-                    cache.write_prefill(sid, k, v, 1)
+                    cache.write_prefill(sid, k, v, 1, *state)
                     cold_n += bool(cold)
                 for b in entry.buckets:
                     tables = _np.stack(
@@ -972,23 +1073,32 @@ class GenerationScheduler(object):
         if not stale:
             return
         for seq in stale:
-            lane.active.remove(seq)
             seq.backend_ref.drop_ahead()
-            # the old backend (and usually its cache) is on the way out,
-            # but freeing keeps its occupancy gauges honest during the
-            # brownout window where both backends are alive
-            seq.backend_ref.cache.free(seq.seq_id)
-            if seq.req.cancelled or seq.req.done:
-                continue
-            try:
-                self._start_sequence(name, lane, backend, seq.req,
-                                     resume=seq)
-                if _metrics.metrics_enabled():
-                    lane.m_reprefills.inc()
-            except Exception as exc:  # noqa: BLE001 - fault path
-                seq.req._fail(exc if isinstance(exc, MXNetError) else
-                              MXNetError("re-prefill after hot swap "
-                                         "failed: %s" % exc))
+            self._resume(name, lane, backend, seq, "hot swap")
+
+    def _resume(self, name, lane, backend, seq, why):
+        """Re-prefill one live sequence on ``backend`` over its prompt
+        and the tokens it has (its old blocks, and state slot, freed
+        where it held them).  Returns whether it goes on (``None``: it
+        was over already); a sequence whose re-prefill fails is
+        failed."""
+        lane.active.remove(seq)
+        # after a swap the old backend (and usually its cache) is on the
+        # way out, but freeing keeps its occupancy gauges honest during
+        # the brownout window where both backends are alive
+        seq.backend_ref.cache.free(seq.seq_id)
+        if seq.req.cancelled or seq.req.done:
+            return None
+        try:
+            self._start_sequence(name, lane, backend, seq.req, resume=seq)
+        except Exception as exc:  # noqa: BLE001 - fault path
+            seq.req._fail(exc if isinstance(exc, MXNetError) else
+                          MXNetError("re-prefill after %s failed: %s"
+                                     % (why, exc)))
+            return False
+        if _metrics.metrics_enabled():
+            lane.m_reprefills.inc()
+        return True
 
     def _retire(self, lane, backend):
         """Free cache blocks of finished/cancelled sequences."""
@@ -1101,14 +1211,14 @@ class GenerationScheduler(object):
             raise MXNetError(
                 "model %r: prefill failed after %d attempts: %s"
                 % (name, default_retries() + 1, last_exc))
-        logits, k, v, cold = out
+        logits, k, v, cold, *state = out
         if cold and _metrics.metrics_enabled():
             lane.m_compiles.inc()
         # the pool write follows the dispatch that succeeded and targets
-        # only this sequence's own reserved slots
+        # only this sequence's own reserved slots (and its state slot)
         try:
             backend.moved("prefill", h2d=backend.cache.write_prefill(
-                seq_id, k, v, t))
+                seq_id, k, v, t, *state))
         except Exception as exc:
             backend.cache.free(seq_id)
             if isinstance(exc, CachePoolLostError):
@@ -1160,6 +1270,19 @@ class GenerationScheduler(object):
         the next ``_retire`` frees their blocks."""
         for seq in lane.active:
             seq.req._fail(error)
+
+    def _resume_live(self, name, lane, backend, error):
+        """The recurrent state a retry would read is overwritten
+        (:class:`RecurrentStateHazard`): re-prefill every live sequence
+        over its prompt and the tokens it has, as after a hot swap, so
+        that the next step reads a state every position entered once;
+        a sequence whose re-prefill fails is failed.  No token is ever
+        served from the overwritten state."""
+        for seq in list(lane.active):
+            resumed = self._resume(name, lane, backend, seq, error)
+            if resumed is not None:
+                _M_STATE_HAZARD.labels(
+                    name, "resumed" if resumed else "failed").inc()
 
     def _may_run_ahead(self, lane, live):
         """May the decode call over ``live`` also queue the step after
@@ -1229,13 +1352,17 @@ class GenerationScheduler(object):
                 if _metrics.metrics_enabled():
                     lane.m_errors.inc()
                 last_exc = exc
-                if isinstance(exc, CachePoolLostError):
-                    break       # the pages a retry would read are gone
+                if isinstance(exc, (CachePoolLostError,
+                                    RecurrentStateHazard)):
+                    break       # what a retry would read is gone
         if self._killed:
             backend.drop_ahead()
             for seq in live:
                 seq.req._fail(_admission.ReplicaDeadError(
                     "replica %r died mid-generation" % self.name))
+            return
+        if out is None and isinstance(last_exc, RecurrentStateHazard):
+            self._resume_live(name, lane, backend, last_exc)
             return
         if out is None:
             # the step's logits or its K/V are lost (and after

@@ -47,8 +47,9 @@ def _pallas_calls(fn, *args):
 # ------------------------------------------------------------- parity
 
 def test_every_hot_path_kernel_is_registered():
-    assert KERNELS == ["flash_prefill_attention", "latent_decode_attention",
-                       "paged_decode_attention", "sgd_mom_tree"]
+    assert KERNELS == ["flash_prefill_attention", "gated_delta_decode",
+                       "latent_decode_attention", "paged_decode_attention",
+                       "paged_decode_gqa_attention", "sgd_mom_tree"]
     regs = fpar.parity_registrations()
     # the tree step is plain jax on every backend; the rest are Pallas
     assert [k for k in KERNELS if not regs[k].pallas] == ["sgd_mom_tree"]

@@ -624,6 +624,40 @@ def test_streaming_round_trip(lm):
         sched.close()
 
 
+def test_a_streamed_token_is_one_write(lm, monkeypatch):
+    """The handler's wfile is unbuffered, so every write is a send and a
+    wake-up of the reader: a token's chunk (length, line, CRLF) goes out
+    in one, and the stream still parses as chunked HTTP."""
+    import socketserver
+
+    writes = []
+    real = socketserver._SocketWriter.write
+
+    def write(self, b):
+        writes.append(bytes(b))
+        return real(self, b)
+
+    monkeypatch.setattr(socketserver._SocketWriter, "write", write)
+    sched, _ = _scheduler(lm)
+    fe = serving.start_frontend(sched)
+    try:
+        sock, buf = _raw_generate(fe.port, {"model": "lm",
+                                            "prompt": [3, 9, 1, 7],
+                                            "max_new_tokens": 5})
+        sock.close()
+    finally:
+        fe.close()
+        sched.close()
+    chunks = [w for w in writes if b'{"token"' in w]
+    assert len(chunks) == 5
+    for w in chunks:
+        size, line, rest = w.split(b"\r\n")
+        assert int(size, 16) == len(line) and rest == b""
+        assert line.endswith(b"\n") and "token" in json.loads(line)
+    body = buf.split(b"\r\n\r\n", 1)[1]
+    assert body.endswith(b"0\r\n\r\n")
+
+
 def test_streaming_disconnect_frees_blocks(lm):
     """A client that drops mid-stream cancels the request; the decode
     loop retires the sequence and frees its cache blocks."""

@@ -77,6 +77,22 @@ def _latent_case(ctx, dtype="bfloat16", heads=8, width=640, max_blocks=8,
             jnp.asarray(ctx, jnp.int32)]
 
 
+def _gqa_case(ctx, dtype="bfloat16", heads=16, groups=2, dim=256,
+              max_blocks=8, seed=0):
+    """The served heads: 16 queries over 2 key-value heads of 256, rows
+    of 512 as the pools hold them."""
+    rng = np.random.default_rng(seed)
+    bt, n = _tables(ctx, max_blocks, cached_only=True)
+    shape = (n + 1, BLK, groups * dim)
+
+    def rand(s):
+        return jnp.asarray(rng.standard_normal(s), jnp.float32).astype(dtype)
+
+    return [rand((len(ctx), heads, dim)), rand((len(ctx), groups, dim)),
+            rand((len(ctx), groups, dim)), rand(shape), rand(shape),
+            jnp.asarray(bt), jnp.asarray(ctx, jnp.int32)]
+
+
 def _kv_xla(*args):
     return att._kv_decode_xla(*args, SCALE)
 
@@ -127,6 +143,54 @@ def test_latent_kernel_equals_the_xla_body(ctx, dtype, chunking):
     assert got.dtype == ref.dtype and got.shape == ref.shape
     tol = 2e-2 if dtype == "bfloat16" else 2e-5
     np.testing.assert_allclose(_f32(got), _f32(ref), rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("ctx", sorted(RAGGED))
+def test_gqa_kernel_equals_the_xla_body(ctx, dtype, chunking):
+    """Grouped queries: 8 query heads share a key-value head's lanes of
+    a 512-wide row."""
+    args = _gqa_case(RAGGED[ctx], dtype)
+    ref = att._gqa_decode_xla(*args, 0.0625)
+    got = att._gqa_decode_pallas(*args, 0.0625, interpret=True)
+    assert got.dtype == ref.dtype and got.shape == ref.shape
+    tol = 2e-2 if dtype == "bfloat16" else 2e-5
+    np.testing.assert_allclose(_f32(got), _f32(ref), rtol=tol, atol=tol)
+
+
+def test_gqa_xla_body_is_plain_attention_with_repeated_heads():
+    """The XLA body against softmax(q k^T / 16) v written out, each
+    key-value head repeated for its 8 query heads."""
+    ctx = RAGGED["mixed"]
+    q, k_step, v_step, k_pool, v_pool, bt, lens = _gqa_case(ctx, "float32")
+    got = _f32(att._gqa_decode_xla(q, k_step, v_step, k_pool, v_pool, bt,
+                                   lens, 0.0625))
+    for i, c in enumerate(ctx):
+        blocks = np.asarray(bt)[i]
+        keys = np.concatenate([_f32(k_pool)[blocks].reshape(-1, 2, 256)
+                               [:c - 1], _f32(k_step)[i][None]])
+        values = np.concatenate([_f32(v_pool)[blocks].reshape(-1, 2, 256)
+                                 [:c - 1], _f32(v_step)[i][None]])
+        for h in range(16):
+            s = keys[:, h // 8] @ _f32(q)[i, h] * 0.0625
+            p = np.exp(s - s.max())
+            want = (p / p.sum()) @ values[:, h // 8]
+            np.testing.assert_allclose(got[i, h], want, rtol=1e-4,
+                                       atol=1e-5)
+
+
+def test_gqa_kernel_reads_no_dead_block(chunking):
+    ctx = RAGGED["mixed"]
+    args = _gqa_case(ctx)
+    clean = att._gqa_decode_pallas(*args, 0.0625, interpret=True)
+    bt = np.asarray(args[5])
+    for at in (3, 4):
+        args[at] = jnp.asarray(_poison(_f32(args[at]), ctx, bt),
+                               args[at].dtype)
+    got = att._gqa_decode_pallas(*args, 0.0625, interpret=True)
+    assert np.isfinite(_f32(got)).all()
+    np.testing.assert_array_equal(_f32(got), _f32(clean))
+    assert np.isnan(_f32(att._gqa_decode_xla(*args, 0.0625))).any()
 
 
 def test_kv_kernel_takes_a_bfloat16_pool():
