@@ -29,12 +29,29 @@ A tiny stdlib ``http.server`` endpoint (same loopback posture as
     ``{"token": id}`` line per generated token as the decode loop
     produces it, closed by a ``{"done": true, "finish_reason": ...,
     "tokens": [...]}`` summary line.  Tokens reach the client
-    mid-generation (chunked transfer encoding, flushed per token);
+    mid-generation (chunked transfer encoding, one chunk a token);
     a client that disconnects mid-stream cancels the request, which
     retires the sequence and frees its KV-cache blocks at the next
     decode iteration.  Served when ``target`` (or the optional
     ``generator=``) is a
     :class:`~.generation.GenerationScheduler`.
+
+    Which thread does what: the connection's handler thread parses,
+    submits, waits for the first outcome (so that a prefill-time
+    failure still maps onto its HTTP status), and sends the status
+    line, the headers and the first token.  Then it hands its socket
+    to the front end's one **stream writer** (:class:`_StreamWriter`)
+    and sleeps until the response is complete.  The writer is woken
+    once a delivery of the generation loop (once a decode step,
+    whatever the number of streams), sends every stream the chunks of
+    the tokens released to it since (non-blocking: a socket that takes
+    no bytes keeps its remainder and holds up no one), and ends a
+    finished stream with its summary line and the last chunk.  So a
+    decode step over 128 streams wakes one thread, not 128
+    (``serving_stream_tokens_total`` over
+    ``serving_stream_writer_wakeups_total`` is the streams served a
+    wake-up; ``serving_stream_blocked_total`` counts sends that took
+    only part of their bytes).
 ``GET /v1/models``
     The registry listing (name, input signature, buckets, max_queue).
 ``GET /healthz`` / ``GET /readyz``
@@ -113,8 +130,39 @@ _H_SWIRE_SEND = _M_SERVING_WIRE.labels("send")
 # "pid:rN" — still unique, just not resolvable in a trace)
 _req_ids = itertools.count(1)
 
-#: sentinel: the generation stream ended before its first token
-_NO_TOKEN = object()
+# the stream writer's books: tokens over wake-ups is how many streams
+# one wake-up of the one writer served (near the decode batch's rows
+# where it works; 1 would be a wake-up a token)
+_M_STREAM_TOKENS = _metrics.counter(
+    "serving_stream_tokens_total",
+    "Token chunks of /v1/generate responses sent by the front end's "
+    "stream writer (every token of a stream but its first, which its "
+    "handler thread sends)")
+_M_STREAM_WAKEUPS = _metrics.counter(
+    "serving_stream_writer_wakeups_total",
+    "Times the front end's stream writer was woken: by a delivery of "
+    "the generation loop (once a decode step, whatever the streams; a "
+    "request's end rides the next one), by a lane going idle behind a "
+    "request's end, or by a stream handed over with tokens owed")
+_M_STREAM_BLOCKED = _metrics.counter(
+    "serving_stream_blocked_total",
+    "Sends of the stream writer that took only part of their bytes or "
+    "none (the socket's buffer was full: the remainder is kept for "
+    "that stream alone)")
+
+#: a stream with bytes owed is tried again this often (seconds)
+_BLOCKED_RETRY_S = 0.01
+
+
+def _chunk(data):
+    """``data`` in chunked-transfer framing: hex length, CRLF, data,
+    CRLF."""
+    return b"%x\r\n%s\r\n" % (len(data), data)
+
+
+def _token_chunk(token):
+    # what json.dumps({"token": token}) gives, without the encoder
+    return _chunk(b'{"token": %d}\n' % token)
 
 
 def _kv_hints(exc):
@@ -140,13 +188,183 @@ def trace_header_enabled():
     return os.environ.get("MXNET_TPU_SERVING_TRACE_HEADER", "1") != "0"
 
 
+class _Stream(object):
+    """One streaming response in the writer's hands: the request, the
+    socket, how many of ``req.generated`` have been put into a send
+    (``sent``), the bytes a send did not take (``owed``), and what the
+    handler thread reads when ``over`` is set."""
+
+    __slots__ = ("req", "sock", "model", "sent", "owed", "closing",
+                 "t_progress", "status", "shed", "over")
+
+    def __init__(self, req, sock, model, sent):
+        self.req, self.sock, self.model, self.sent = req, sock, model, sent
+        self.owed = b""
+        self.closing = False
+        self.t_progress = time.monotonic()
+        self.status, self.shed = 200, None
+        self.over = threading.Event()
+
+
+class _StreamWriter(object):
+    """The one thread that sends what every streaming ``/v1/generate``
+    response holds after its first token.
+
+    It sleeps on one event.  The generation loop sets it once a
+    delivery (beside its next device call: the wake-up every stream's
+    request was given, :meth:`GenerationRequest.stream_to`; a request's
+    end rides the next delivery, or is told at once where the lane goes
+    idle), and so does a hand-over that finds tokens owed.
+    Woken, it walks its streams: each gets the chunks of
+    ``generated[sent:released]`` (several tokens, if it fell behind, in
+    one send) and, once its request is over, the summary line and the
+    last chunk.  Sends never block: what a socket does not take is kept
+    for that stream and tried again every ``_BLOCKED_RETRY_S``; a
+    stream that moved no byte for ``timeout`` seconds, or whose client
+    is gone, is cancelled (499, ``shed="disconnect"``)."""
+
+    def __init__(self, timeout):
+        self._timeout = timeout
+        self._streams = []
+        self._lock = threading.Lock()
+        self._event = threading.Event()
+        self._closed = False
+        self.wake = self._event.set
+        self._thread = threading.Thread(
+            target=self._run, name="mxtpu-serving-stream", daemon=True)
+        self._thread.start()
+
+    def stream(self, req, sock, model, sent):
+        """Called by a handler thread once the first ``sent`` tokens
+        (one or none) are on the wire: hand the rest of the response to
+        the writer and sleep until it is complete.  Returns the
+        :class:`_Stream` (``status``, ``shed``)."""
+        stream = _Stream(req, sock, model, sent)
+        blocking = sock.gettimeout()
+        sock.setblocking(False)
+        with self._lock:
+            self._streams.append(stream)
+        # registered after the stream is in the list and before the
+        # look at what is owed: whatever the loop releases from here on
+        # wakes the writer, whatever it released before is seen here
+        req.stream_to(self.wake)
+        if req.done or req.released > sent:
+            self.wake()
+        stream.over.wait()
+        sock.settimeout(blocking)
+        return stream
+
+    def close(self):
+        """Let the streams in hand run to their end, then stop."""
+        with self._lock:
+            self._closed = True
+        self.wake()
+
+    def _run(self):
+        owed = False
+        while True:
+            if owed:
+                wait = _BLOCKED_RETRY_S
+            else:
+                wait = self._timeout if self._streams else None
+            if self._event.wait(wait):
+                self._event.clear()
+                _M_STREAM_WAKEUPS.inc()
+            with self._lock:
+                streams = list(self._streams)
+                if self._closed and not streams:
+                    return
+            now = time.monotonic()
+            tokens = 0
+            for stream in streams:
+                tokens += self._serve(stream, now)
+            if tokens:
+                _M_STREAM_TOKENS.inc(tokens)
+            live = [s for s in streams if not s.over.is_set()]
+            if len(live) < len(streams):
+                with self._lock:
+                    self._streams = [s for s in self._streams
+                                     if not s.over.is_set()]
+            owed = any(s.owed for s in live)
+
+    def _serve(self, stream, now):
+        """Send ``stream`` what it is owed; returns the token chunks put
+        into the send."""
+        req = stream.req
+        data = stream.owed
+        count = 0
+        if not stream.closing:
+            done = req.done          # before released: final once set
+            released = req.released
+            count = released - stream.sent
+            if count:
+                data += b"".join(map(
+                    _token_chunk, req.generated[stream.sent:released]))
+                stream.sent = released
+            if done:
+                data += self._tail(stream) + b"0\r\n\r\n"
+                stream.closing = True
+        if data:
+            try:
+                took = stream.sock.send(data)
+            except BlockingIOError:
+                took = 0
+            except OSError:
+                # client went away mid-stream: cancel() retires the
+                # sequence and frees its cache blocks at the next
+                # decode iteration
+                self._drop(stream)
+                return 0
+            stream.owed = data[took:]
+            if took:
+                stream.t_progress = now
+            if stream.owed:
+                _M_STREAM_BLOCKED.inc()
+            elif stream.closing:
+                self._end(stream)
+                return count
+        if now - stream.t_progress > self._timeout:
+            self._drop(stream)       # stuck: as a client that went away
+        return count
+
+    @staticmethod
+    def _tail(stream):
+        req = stream.req
+        if req.error is None:
+            tail = {"done": True, "model": stream.model,
+                    "finish_reason": req.finish_reason,
+                    "tokens": list(req.generated)}
+        else:
+            # generation failed after the 200 was committed: the error
+            # rides the stream, the tail line carries the typed error
+            # instead of a token list
+            exc = req.error
+            stream.shed = _admission.reject_reason(exc)
+            tail = {"done": True, "model": stream.model,
+                    "finish_reason": "error", "error": str(exc),
+                    "type": type(exc).__name__}
+            tail.update(_kv_hints(exc))
+        return _chunk(json.dumps(tail).encode("utf-8") + b"\n")
+
+    def _drop(self, stream):
+        stream.req.cancel()
+        stream.status, stream.shed = 499, "disconnect"
+        self._end(stream)
+
+    @staticmethod
+    def _end(stream):
+        stream.req.stream_to(None)
+        stream.over.set()
+
+
 class ServingFrontend(object):
     """Handle for a running front-end: ``.port``, ``.url``,
     ``.close()``.  Also a context manager."""
 
-    def __init__(self, httpd, thread, target):
+    def __init__(self, httpd, thread, target, writer):
         self._httpd = httpd
         self._thread = thread
+        self._writer = writer
         self.target = target
         self.port = httpd.server_address[1]
         self.url = "http://%s:%d" % (httpd.server_address[0], self.port)
@@ -155,6 +373,7 @@ class ServingFrontend(object):
         self._httpd.shutdown()
         self._httpd.server_close()
         self._thread.join(timeout=5)
+        self._writer.close()
 
     def __enter__(self):
         return self
@@ -325,16 +544,6 @@ def start_frontend(target, port=None, addr="127.0.0.1", timeout=30.0,
                 "model": model,
                 "outputs": [_np.asarray(o).tolist() for o in outs]})
 
-        def _chunk(self, data):
-            # manual chunked-transfer framing: hex length, CRLF, data,
-            # CRLF — one write a chunk (the handler's wfile is
-            # unbuffered: every write is a send of its own, a segment
-            # of its own on the wire and a wake-up of the reader), sent
-            # per token so the client reads the stream mid-generation,
-            # not after it
-            self.wfile.write(b"%x\r\n%s\r\n" % (len(data), data))
-            self.wfile.flush()
-
         def _generate(self, body):
             payload = json.loads(body.decode("utf-8"))
             model = self._model = payload["model"]
@@ -353,18 +562,15 @@ def start_frontend(target, port=None, addr="127.0.0.1", timeout=30.0,
                 eos_id=payload.get("eos_id"),
                 deadline_ms=payload.get("deadline_ms"),
                 tenant=self._tenant)
-            # first-outcome gating: pull the first token BEFORE
+            # first-outcome gating: wait for the first token BEFORE
             # committing the status line, so a prefill-time failure
             # (cache exhaustion in the generation loop) maps onto its
             # typed HTTP status — a CacheExhaustedError 429 with
             # Retry-After and occupancy hints — instead of riding an
             # already-committed 200's error tail
-            it = req.tokens(timeout=timeout)
-            first = _NO_TOKEN
-            try:
-                first = next(it)
-            except StopIteration:
-                pass
+            released, _ = req.wait(1, timeout)
+            if not released and req.error is not None:
+                raise req.error
             self._status = 200
             self.send_response(200)
             self.send_header("Content-Type", "application/x-ndjson")
@@ -372,40 +578,21 @@ def start_frontend(target, port=None, addr="127.0.0.1", timeout=30.0,
             if self._rid:
                 self.send_header("X-MXTPU-Request-Id", self._rid)
             self.end_headers()
-            try:
+            sent = min(released, 1)
+            if sent:
+                # the handler's wfile is unbuffered: a write is a send
+                # of its own, a segment of its own on the wire and a
+                # wake-up of the reader, so the client has the first
+                # token mid-generation, not after it
                 try:
-                    if first is not _NO_TOKEN:
-                        self._chunk(json.dumps(
-                            {"token": int(first)}).encode("utf-8")
-                            + b"\n")
-                        for tok in it:
-                            self._chunk(json.dumps(
-                                {"token": int(tok)}).encode("utf-8")
-                                + b"\n")
-                    tail = {"done": True, "model": model,
-                            "finish_reason": req.finish_reason,
-                            "tokens": list(req.generated)}
-                except MXNetError as exc:
-                    # generation failed after the 200 was committed: the
-                    # error rides the stream, and the missing final
-                    # 0-chunk... is NOT missing — the tail line carries
-                    # the typed error instead of a token list
-                    self._shed = _admission.reject_reason(exc)
-                    tail = {"done": True, "model": model,
-                            "finish_reason": "error",
-                            "error": str(exc),
-                            "type": type(exc).__name__}
-                    tail.update(_kv_hints(exc))
-                self._chunk(json.dumps(tail).encode("utf-8") + b"\n")
-                self.wfile.write(b"0\r\n\r\n")
-                self.wfile.flush()
-            except (BrokenPipeError, ConnectionResetError):
-                # client went away mid-stream: cancel() retires the
-                # sequence and frees its cache blocks at the next
-                # decode iteration
-                req.cancel()
-                self._shed = "disconnect"
-                self._status = 499
+                    self.wfile.write(_token_chunk(req.generated[0]))
+                except (BrokenPipeError, ConnectionResetError):
+                    pass        # gone already: the writer's send says so
+            # every byte after the first token is the stream writer's
+            stream = writer.stream(req, self.connection, model, sent)
+            self._status = stream.status
+            self._shed = stream.shed
+            if stream.status != 200:
                 self.close_connection = True
 
         def _predict_frame(self, body, query):
@@ -457,7 +644,8 @@ def start_frontend(target, port=None, addr="127.0.0.1", timeout=30.0,
             pass
 
     httpd = http.server.ThreadingHTTPServer((addr, int(port)), _Handler)
+    writer = _StreamWriter(timeout)
     thread = threading.Thread(target=httpd.serve_forever,
                               name="mxtpu-serving-http", daemon=True)
     thread.start()
-    return ServingFrontend(httpd, thread, target)
+    return ServingFrontend(httpd, thread, target, writer)

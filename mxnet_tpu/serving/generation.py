@@ -81,12 +81,15 @@ after it, is on the device); ``serving.kv_alloc`` fires in the
 allocator.  Prefill dispatches visit the existing ``serving.dispatch``
 site (name ``<model>:prefill:<bucket>``).
 
-Streaming: each :class:`GenerationRequest` is a token queue —
-:meth:`GenerationRequest.tokens` yields ids as the loop produces them
-(the front-end turns this into chunked HTTP on ``/v1/generate``; a
-first token is handed over at once, a decode step's tokens once the
-loop's next device call is on its way, so their readers run beside the
-device), and
+Streaming: each :class:`GenerationRequest` is one ordered record of
+its tokens and a count of how many are *released* to its reader: a
+first token at once, a decode step's tokens once the loop's next device
+call is on its way, so the readers run beside the device.
+:meth:`GenerationRequest.tokens` yields them as they are released (a
+reader in process); the front end's stream writer, one thread for all
+of ``/v1/generate``'s chunked responses, registers a wake-up instead
+(:meth:`GenerationRequest.stream_to`) and is woken once a delivery,
+whatever the number of rows.
 :meth:`GenerationRequest.cancel` (client disconnect) retires the
 sequence and frees its blocks at the next iteration.
 """
@@ -95,7 +98,6 @@ from __future__ import annotations
 
 import collections as _collections
 import os
-import queue as _queue
 import threading
 import time
 import weakref as _weakref
@@ -147,8 +149,6 @@ def default_max_new_tokens():
     except ValueError:
         return 32
 
-
-_DONE = object()
 
 # what crosses between host and device per generation call: the
 # residency contract's witness.  A decode call reads a few KB in and
@@ -203,17 +203,22 @@ def _host_nbytes(arrays):
 class GenerationRequest(object):
     """One admitted generation request: a token stream plus a future.
 
-    The generation loop pushes token ids as decode steps complete;
-    :meth:`tokens` yields them live (the streaming front-end's source)
-    and :meth:`result` blocks for the full list.  ``trace`` is the
+    The generation loop appends token ids to ``generated`` as decode
+    steps complete and *releases* them (``released``: how many of them
+    the stream's reader may have) beside its next device call.  One
+    ordered record, read through a cursor: :meth:`tokens` yields the
+    released tokens live and :meth:`result` blocks for the full list;
+    a reader that serves many requests from one thread (the front
+    end's stream writer) registers its wake-up with :meth:`stream_to`
+    and reads ``generated[:released]`` itself.  ``trace`` is the
     submitter's wire token, the request's identity in the merged trace.
     """
 
     __slots__ = ("model", "prompt", "max_new_tokens", "eos_id", "deadline",
                  "tenant", "t_admit", "trace", "generated", "error",
                  "finish_reason", "latency_s", "first_token_s", "seq_id",
-                 "_tokens", "_held", "_event", "_cancelled", "_h_tenant",
-                 "_h_tokens")
+                 "_released", "_done", "_cond", "_wake", "_cancelled",
+                 "_h_tenant", "_h_tokens")
 
     def __init__(self, model, prompt, max_new_tokens, eos_id, deadline,
                  tenant=_tenancy.DEFAULT_TENANT):
@@ -231,9 +236,10 @@ class GenerationRequest(object):
         self.latency_s = None
         self.first_token_s = None
         self.seq_id = None
-        self._tokens = _queue.Queue()
-        self._held = _collections.deque()
-        self._event = threading.Event()
+        self._released = 0
+        self._done = False
+        self._cond = threading.Condition(threading.Lock())
+        self._wake = None
         self._cancelled = False
         # pre-resolved per-tenant counter handles (attached at submit,
         # None with metrics disabled) — the decode loop never resolves
@@ -243,7 +249,13 @@ class GenerationRequest(object):
 
     @property
     def done(self):
-        return self._event.is_set()
+        return self._done
+
+    @property
+    def released(self):
+        """How many of ``generated`` the stream's reader may have.  Read
+        ``done`` first: once that is set this is final."""
+        return self._released
 
     @property
     def cancelled(self):
@@ -257,64 +269,101 @@ class GenerationRequest(object):
     # -- loop side ---------------------------------------------------
 
     def _push(self, token):
-        """Record a token; :meth:`_deliver` hands it to the stream."""
+        """Record a token; :meth:`_deliver` releases it to the stream."""
         if self.first_token_s is None:
             self.first_token_s = time.monotonic() - self.t_admit
         self.generated.append(int(token))
-        self._held.append(int(token))
 
     def _deliver(self):
-        """Hand the recorded tokens to the stream: each wakes the thread
-        that reads it.  The loop holds a decode step's tokens (one a
-        request) until its next device call is on the way, so the
-        readers run beside the device and not between two of its calls.
-        Pops are atomic: a kill racing the loop cannot hand a token over
-        twice."""
-        while True:
-            try:
-                token = self._held.popleft()
-            except IndexError:
-                return
-            self._tokens.put(token)
+        """Release the recorded tokens to the stream's reader.  The loop
+        holds a decode step's tokens (one a request) until its next
+        device call is on the way, so the readers run beside the device
+        and not between two of its calls.  A reader inside
+        :meth:`tokens` is woken here; for one that registered a wake-up
+        (:meth:`stream_to`) that is returned instead, for the caller to
+        call once for all its rows.  ``None`` where nothing is new."""
+        n = len(self.generated)
+        if n == self._released:
+            return None
+        if self._wake is None:
+            with self._cond:
+                self._released = n
+                self._cond.notify_all()
+                return self._wake       # registered meanwhile: wake it
+        self._released = n
+        return self._wake
 
-    def _finish(self, reason):
-        if self._event.is_set():   # idempotent: kill vs loop race
+    def _finish(self, reason, error=None, owed=None):
+        """End the request.  A reader in process is woken at once; so is
+        a registered one, unless the caller takes its wake-up into
+        ``owed`` (a set) to call it beside its next device call."""
+        with self._cond:
+            if self._done:           # idempotent: kill vs loop race
+                return
+            self.error = error
+            self.finish_reason = reason
+            self.latency_s = time.monotonic() - self.t_admit
+            self._released = len(self.generated)
+            self._done = True
+            self._cond.notify_all()
+            wake = self._wake
+        if wake is None:
             return
-        self.finish_reason = reason
-        self.latency_s = time.monotonic() - self.t_admit
-        self._deliver()
-        self._tokens.put(_DONE)
-        self._event.set()
+        if owed is None:
+            wake()
+        else:
+            owed.add(wake)
 
     def _fail(self, error):
-        if self._event.is_set():   # idempotent: kill vs loop race
-            return
-        self.error = error
-        self.finish_reason = "error"
-        self.latency_s = time.monotonic() - self.t_admit
-        self._deliver()
-        self._tokens.put(_DONE)
-        self._event.set()
+        self._finish("error", error)
 
     # -- client side -------------------------------------------------
 
+    def wait(self, count, timeout=30.0):
+        """Block until ``count`` tokens are released or the request is
+        over; returns ``(released, done)`` as of then."""
+        with self._cond:
+            if not self._cond.wait_for(
+                    lambda: self._released >= count or self._done, timeout):
+                raise MXNetError(
+                    "generation on model %r: no token for %.1fs"
+                    % (self.model, timeout))
+            return self._released, self._done
+
     def tokens(self, timeout=30.0):
-        """Yield generated token ids as they arrive; raises the typed
-        serving error if generation failed."""
+        """Yield generated token ids as they are released; raises the
+        typed serving error if generation failed."""
+        sent = 0
         while True:
-            tok = self._tokens.get(timeout=timeout)
-            if tok is _DONE:
+            released, done = self.wait(sent + 1, timeout)
+            yield from self.generated[sent:released]
+            sent = released
+            if done:
                 if self.error is not None:
                     raise self.error
                 return
-            yield tok
+
+    def stream_to(self, wake):
+        """Register the stream's reader: a thread that serves many
+        requests and reads ``generated[:released]`` itself.  ``wake()``
+        is called, from the loop's thread, once for every delivery that
+        released a token of any of its requests, and for this request's
+        end (beside the loop's next device call, or at once where none
+        follows); it must be cheap and must not block (an
+        ``Event.set``).
+        ``None`` takes the registration back.  A request has one reader:
+        :meth:`tokens` is not woken per token while a wake-up is
+        registered."""
+        with self._cond:
+            self._wake = wake
 
     def result(self, timeout=30.0):
         """Block until generation finishes; returns the generated ids."""
-        if not self._event.wait(timeout):
-            raise MXNetError(
-                "generation on model %r timed out after %.1fs"
-                % (self.model, timeout))
+        with self._cond:
+            if not self._cond.wait_for(lambda: self._done, timeout):
+                raise MXNetError(
+                    "generation on model %r timed out after %.1fs"
+                    % (self.model, timeout))
         if self.error is not None:
             raise self.error
         return list(self.generated)
@@ -704,8 +753,8 @@ class _GenLane(object):
     """Per-model waiting queue + live sequences + the generation thread
     + pre-resolved metric handles."""
 
-    __slots__ = ("entry", "queue", "active", "thread", "steps", "tokens",
-                 "rows", "slots", "max_step_rows", "seq_counter",
+    __slots__ = ("entry", "queue", "active", "owed", "thread", "steps",
+                 "tokens", "rows", "slots", "max_step_rows", "seq_counter",
                  "tenant_handles",
                  "m_req", "m_prefill", "m_itl", "m_depth", "m_occ",
                  "m_active", "m_requests", "m_tokens", "m_steps",
@@ -716,6 +765,9 @@ class _GenLane(object):
         self.queue = _tenancy.FairQueue(weight_fn)
         self.tenant_handles = {}
         self.active = []
+        # wake-ups of stream readers whose requests ended since the last
+        # device call: called beside the next one (or when none follows)
+        self.owed = set()
         self.thread = None
         self.steps = 0
         self.tokens = 0
@@ -1037,6 +1089,7 @@ class GenerationScheduler(object):
                 if self._killed or (self._stopping and not lane.queue
                                     and not lane.active):
                     lane.entry.backend.drop_ahead()
+                    self._wake_owed(lane)
                     return
             self._iterate(name, lane)
 
@@ -1062,6 +1115,8 @@ class GenerationScheduler(object):
             if lane.active:
                 self._decode_step(name, lane, backend)
             self._retire(lane, backend)
+            if not lane.active:
+                self._wake_owed(lane)   # no device call to do it beside
             if _metrics.metrics_enabled():
                 lane.m_active.set(len(lane.active))
 
@@ -1109,10 +1164,11 @@ class GenerationScheduler(object):
                             and req.generated
                             and req.generated[-1] == req.eos_id))
             if req.cancelled and not req.done:
-                req._finish("cancelled")
+                req._finish("cancelled", owed=lane.owed)
             elif finished and not req.done:
                 req._finish("length" if seq.new_tokens
-                            >= req.max_new_tokens else "stop")
+                            >= req.max_new_tokens else "stop",
+                            owed=lane.owed)
                 if _metrics.metrics_enabled():
                     lane.m_requests.inc()
                     if req._h_tenant is not None:
@@ -1229,7 +1285,9 @@ class GenerationScheduler(object):
         if resume is None:
             first = int(_np.argmax(logits))
             req._push(first)
-            req._deliver()         # a first token waits for nothing
+            wake = req._deliver()  # a first token waits for nothing
+            if wake is not None:
+                wake()
             seq.last_token = first
             seq.new_tokens = 1
         else:
@@ -1245,24 +1303,36 @@ class GenerationScheduler(object):
     @staticmethod
     def _beside(lane, backend, call, *args, run_ahead=False):
         """``call(*args)``, a prefill or decode call of ``backend``, with
-        the last decode step's tokens handed to their streams while the
-        device runs it.  The threads the tokens wake (a front end's
-        writers, their clients) take the interpreter: woken as each
-        token was known they held back the loop between two calls, woken
-        before the call its dispatch, the device idle meanwhile (23 ms
-        of a 100 ms step with 64 callers).  An :class:`LMBackend` calls
+        the last decode step's tokens released to their streams while
+        the device runs it.  The threads the tokens wake (a front end's
+        stream writer, their clients) take the interpreter: woken as
+        each token was known they held back the loop between two calls,
+        woken before the call its dispatch, the device idle meanwhile
+        (23 ms of a 100 ms step with 64 callers).  A front end's writer
+        is woken once, whatever the rows (its requests return the same
+        wake-up); a reader in process is woken by its own request.  An
+        :class:`LMBackend` calls
         ``beside_device`` once its program is on the way.  ``run_ahead``
         is the loop's word, for this decode call alone, that the step
         after it may be queued too."""
         def deliver():
-            for seq in lane.active:
-                seq.req._deliver()
+            lane.owed.update(seq.req._deliver() for seq in lane.active)
+            GenerationScheduler._wake_owed(lane)
 
         backend.beside_device, backend.run_ahead = deliver, run_ahead
         try:
             return call(*args)
         finally:
             backend.beside_device, backend.run_ahead = None, False
+
+    @staticmethod
+    def _wake_owed(lane):
+        """Wake the stream readers that are owed it: one call a front
+        end, whatever the number of its rows."""
+        owed, lane.owed = lane.owed, set()
+        owed.discard(None)
+        for wake in owed:
+            wake()
 
     @staticmethod
     def _fail_live(lane, error):

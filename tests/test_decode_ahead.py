@@ -18,6 +18,7 @@ greedy ids, before it waits for this step's logits.
   wrapper) sees one call a step, numpy in, numpy logits out.
 """
 
+import threading
 import time
 
 import numpy as np
@@ -83,11 +84,23 @@ CANCELLED = 3
 class Cancelling(serving.LMBackend):
     """Cancels ``victim`` from the loop's own thread once it has six
     tokens: as a client going away mid-flight, but at a known step (and
-    after the loop chose to run ahead: the queued step is dropped)."""
+    after the loop chose to run ahead: the queued step is dropped).
+    Parks the loop, once, at the decode call that ``first`` enters with
+    two tokens, until the test has submitted the others: whatever the
+    machine's load, they are admitted in one iteration, with ``first``
+    at its third token."""
 
-    victim = None
+    victim = first = None
+
+    def __init__(self, *args, **kwargs):
+        serving.LMBackend.__init__(self, *args, **kwargs)
+        self.parked, self.go_on = threading.Event(), threading.Event()
 
     def decode(self, *step):
+        if (self.first is not None and not self.parked.is_set()
+                and len(self.first.generated) == 2):
+            self.parked.set()
+            assert self.go_on.wait(30), "the test never let the loop go on"
         if self.victim is not None and len(self.victim.generated) >= 6:
             self.victim.cancel()
         return serving.LMBackend.decode(self, *step)
@@ -95,22 +108,28 @@ class Cancelling(serving.LMBackend):
 
 def test_streams_equal_the_serial_paths(lm):
     """Rows finish at different steps, MIX[3] is cancelled mid-flight,
-    the first request is admitted into an empty batch, the next four
-    fill it (one waits: admitted when a row ends, the batch full till
-    then), two more arrive while it is full."""
+    the first request is admitted into an empty batch, the next three
+    fill it (three wait: admitted as rows end, the batch full till
+    then).  The loop is parked while the six are submitted, so every
+    run has the same schedule: the cancelled row's step is one that ran
+    ahead (the batch full, the first request one short of its last
+    token), and its queued step is the one that is dropped."""
     serial, _ = _scheduler(lm, "ahead_ref", ahead=False)
     want = [serial.generate("lm", p, max_new_tokens=m) for p, m in MIX]
     serial.close()
     assert _count("used", "ahead_ref") == 1      # the warm-up's own
 
     sched, be = _scheduler(lm, "ahead_mix", Cancelling)
-    reqs = [sched.submit("lm", np.asarray(MIX[0][0], np.int32),
-                         max_new_tokens=MIX[0][1])]
-    _wait(lambda: len(reqs[0].generated) >= 2, "the first never started")
-    with sched._lanes["lm"].entry.dispatch_lock:     # one admission
-        reqs += [sched.submit("lm", np.asarray(p, np.int32),
-                              max_new_tokens=m) for p, m in MIX[1:]]
-        be.victim = reqs[CANCELLED]
+    with sched._lanes["lm"].entry.dispatch_lock:     # before any step
+        reqs = [sched.submit("lm", np.asarray(MIX[0][0], np.int32),
+                             max_new_tokens=MIX[0][1])]
+        be.first = reqs[0]
+    assert be.parked.wait(30), "the first never started"
+    assert len(reqs[0].generated) == 2
+    reqs += [sched.submit("lm", np.asarray(p, np.int32),
+                          max_new_tokens=m) for p, m in MIX[1:]]
+    be.victim = reqs[CANCELLED]
+    be.go_on.set()
     got = []
     for i, r in enumerate(reqs):
         if i == CANCELLED:
@@ -121,7 +140,7 @@ def test_streams_equal_the_serial_paths(lm):
             got.append(r.result(timeout=60))
     for i, (g, w) in enumerate(zip(got, want)):
         if i == CANCELLED:
-            assert 6 <= len(g) <= 7 and g == w[:len(g)]
+            assert len(g) == 7 and g == w[:7]
         else:
             assert g == w, "request %d differs from the serial path" % i
     assert _count("used", "ahead_mix") > 1, "no step ever ran ahead"

@@ -826,7 +826,7 @@ def test_new_cell_rehearsed_on_the_cpu(tiny_benchmark, trace, capsys):
                           require_chip=False)
     out = capsys.readouterr().out
     assert result["correct"] is True, out
-    assert result["failed"] == 0 and result["attempted"] > 0
+    assert result["failed"] == 0 and result["attempted"] > 0, out
     assert "served_logit_abs_err" in out and " ok" in out
     metrics = result["metrics"]
     if trace:

@@ -519,10 +519,13 @@ def test_tokens_reach_their_streams_beside_the_next_device_call(lm):
     reqs, owed = [], []
     fetch = be._fetch
 
+    def held():
+        return sum(len(r.generated) - r.released for r in reqs)
+
     def watched(*args):
-        before = sum(len(r._held) for r in reqs)
+        before = held()
         out = fetch(*args)
-        owed.append((before, sum(len(r._held) for r in reqs)))
+        owed.append((before, held()))
         return out
 
     be._fetch = watched
@@ -585,7 +588,8 @@ def _raw_generate(port, payload, read_lines=None):
         if not data:
             break
         buf += data
-        if b"0\r\n\r\n" in buf and read_lines is None:
+        # (not the header's end behind a request id that ends in 0)
+        if b"\r\n0\r\n\r\n" in buf and read_lines is None:
             break
         lines = [l for l in buf.split(b"\n") if l.strip().startswith(b"{")]
     return sock, buf
@@ -625,35 +629,52 @@ def test_streaming_round_trip(lm):
 
 
 def test_a_streamed_token_is_one_write(lm, monkeypatch):
-    """The handler's wfile is unbuffered, so every write is a send and a
-    wake-up of the reader: a token's chunk (length, line, CRLF) goes out
-    in one, and the stream still parses as chunked HTTP."""
+    """Every write to the socket is a send and a wake-up of the reader:
+    a token's chunk (length, line, CRLF) goes out whole, the first by
+    the handler's unbuffered wfile and the others by the stream writer
+    (several in one send only where it fell behind), and the stream
+    still parses as chunked HTTP."""
     import socketserver
 
     writes = []
-    real = socketserver._SocketWriter.write
+    real_write = socketserver._SocketWriter.write
+    real_send = socket.socket.send
 
     def write(self, b):
         writes.append(bytes(b))
-        return real(self, b)
+        return real_write(self, b)
+
+    def send(self, b, *flags):
+        writes.append(bytes(b))
+        return real_send(self, b, *flags)
 
     monkeypatch.setattr(socketserver._SocketWriter, "write", write)
+    monkeypatch.setattr(socket.socket, "send", send)
     sched, _ = _scheduler(lm)
     fe = serving.start_frontend(sched)
     try:
-        sock, buf = _raw_generate(fe.port, {"model": "lm",
-                                            "prompt": [3, 9, 1, 7],
-                                            "max_new_tokens": 5})
+        with chaos.inject("serving.decode", "delay", prob=1.0, seed=1,
+                          delay=0.02):
+            sock, buf = _raw_generate(fe.port, {"model": "lm",
+                                                "prompt": [3, 9, 1, 7],
+                                                "max_new_tokens": 5})
         sock.close()
     finally:
         fe.close()
         sched.close()
     chunks = [w for w in writes if b'{"token"' in w]
-    assert len(chunks) == 5
+    assert 2 <= len(chunks) <= 5       # the handler's one, the writer's
+    assert chunks[0].count(b'{"token"') == 1
+    lines = []
     for w in chunks:
-        size, line, rest = w.split(b"\r\n")
-        assert int(size, 16) == len(line) and rest == b""
-        assert line.endswith(b"\n") and "token" in json.loads(line)
+        while w and not w.startswith(b"0\r\n"):
+            size, _, w = w.partition(b"\r\n")
+            line, w = w[:int(size, 16)], w[int(size, 16):]
+            assert w.startswith(b"\r\n") and line.endswith(b"\n")
+            w = w[2:]
+            lines.append(json.loads(line))
+    assert [sorted(l) for l in lines[:5]] == [["token"]] * 5
+    assert lines[5]["done"] and len(lines) == 6
     body = buf.split(b"\r\n\r\n", 1)[1]
     assert body.endswith(b"0\r\n\r\n")
 
