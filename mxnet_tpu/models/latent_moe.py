@@ -1,9 +1,17 @@
 """A decoder of latent attention and sparse experts, built from its
 published configuration (the DeepSeek-V3 language-model block, which
 ``dots.vlm1`` also uses): RMSNorm, multi-head latent attention with
-YaRN rotary on a slice of the head, gated SiLU feed-forward layers,
-dense first and then routed experts plus a shared one, an untied
-bias-free head.
+rotary on a slice of the head (YaRN where the configuration has a
+``rope_scaling``, plain where it has none), gated SiLU feed-forward
+layers, dense first and then routed experts plus a shared one, an
+untied bias-free head.
+
+The latent attention (projections, expanded prefill, absorbed decode,
+cache row), RMSNorm, the rotary turn and the head are this family's
+and every other latent family's (``models/shortcut_latent_moe.py``
+builds its layer from them): a configuration may also give
+``q_lora_scale`` / ``kv_lora_scale``, factors on the two low-rank paths
+behind their norms.
 
 Pure functions of ``(params, cfg)``: :func:`prefill` runs one prompt in
 the *expanded* form of the attention and returns the rows the cache
@@ -46,7 +54,7 @@ _PUBLISHED = (
     "num_hidden_layers", "first_k_dense_replace", "n_routed_experts",
     "n_shared_experts", "num_experts_per_tok", "n_group", "topk_group",
     "routed_scaling_factor", "norm_topk_prob", "rms_norm_eps",
-    "rope_theta", "rope_scaling")
+    "rope_theta")
 
 
 def lm_config(published, seq_len, held=None):
@@ -55,6 +63,8 @@ def lm_config(published, seq_len, held=None):
     context limit) and ``held = (first, count)`` of the
     ``n_routed_experts`` (all of them if not given)."""
     cfg = {key: published[key] for key in _PUBLISHED}
+    # no rope_scaling (absent or null) is plain rotary
+    cfg["rope_scaling"] = published.get("rope_scaling")
     if published.get("scoring_func", "sigmoid") != "sigmoid" \
             or published.get("topk_method", "noaux_tc") != "noaux_tc":
         raise ValueError("only sigmoid scores with the noaux_tc choice "
@@ -147,7 +157,7 @@ def init_params(cfg, seed=0, dtype=jnp.bfloat16, scale=0.02,
 
 
 # ----------------------------------------------------------------------
-# rotary positions (YaRN)
+# rotary positions: YaRN under a ``rope_scaling``, plain without one
 
 
 def _yarn_mscale(factor, mscale):
@@ -158,10 +168,13 @@ def yarn_inv_freq(cfg):
     """``(inv_freq float32 [rope / 2], cos_sin_scale)``: between the two
     correction dimensions the ramp blends ``1 / theta^(2i / rope)`` and
     the same over ``factor``; cos and sin are multiplied by
-    ``m(mscale) / m(mscale_all_dim)``."""
+    ``m(mscale) / m(mscale_all_dim)``.  Without a ``rope_scaling``:
+    ``1 / theta^(2i / rope)`` and 1."""
     dim, base = cfg["qk_rope_head_dim"], float(cfg["rope_theta"])
-    sc = cfg["rope_scaling"]
+    sc = cfg.get("rope_scaling")
     extra = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if sc is None:
+        return extra.astype(np.float32), 1.0
     original = sc["original_max_position_embeddings"]
 
     def correction_dim(rotations):
@@ -179,10 +192,13 @@ def yarn_inv_freq(cfg):
 
 
 def softmax_scale(cfg):
-    """``(nope + rope)^-0.5 * m(mscale_all_dim)^2``."""
-    sc = cfg["rope_scaling"]
-    return (cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]) ** -0.5 \
-        * _yarn_mscale(sc["factor"], sc["mscale_all_dim"]) ** 2
+    """``(nope + rope)^-0.5``, times ``m(mscale_all_dim)^2`` under a
+    ``rope_scaling``."""
+    scale = (cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]) ** -0.5
+    sc = cfg.get("rope_scaling")
+    if sc is None:
+        return scale
+    return scale * _yarn_mscale(sc["factor"], sc["mscale_all_dim"]) ** 2
 
 
 def _rotate(x, positions, cfg):
@@ -206,11 +222,16 @@ def _rotate(x, positions, cfg):
 # layers
 
 
-def _norm(x, gamma, cfg):
+def _norm(x, gamma, cfg, scale=None):
+    """RMSNorm, statistics and gain in float32; ``scale`` is a factor
+    on the gain (a low-rank path's), applied before the rounding."""
     x32 = x.astype(jnp.float32)
     y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
                             + cfg["rms_norm_eps"])
-    return (y * gamma.astype(jnp.float32)).astype(x.dtype)
+    gain = gamma.astype(jnp.float32)
+    if scale is not None:
+        gain = gain * scale
+    return (y * gain).astype(x.dtype)
 
 
 def _dot(x, w):
@@ -221,14 +242,19 @@ def _dot(x, w):
 
 def _latent_projections(params, p, h, positions, cfg):
     """Queries ``[N, H, nope]`` and rotated ``[N, H, rope]``, and the
-    cache row ``[N(c_kv) | rotated k_rope | 0]`` ``[N, row width]``."""
+    cache row ``[N(c_kv) | rotated k_rope | 0]`` ``[N, row width]``.
+    ``q_lora_scale`` multiplies the queries and ``kv_lora_scale``
+    ``N(c_kv)`` (so the row holds the scaled latent); ``W_qb`` is
+    linear, so the queries' factor rides in the float32 gain of
+    ``N(c_q)``; ``k_rope`` takes neither."""
     heads, nope = cfg["num_attention_heads"], cfg["qk_nope_head_dim"]
     kv_rank = cfg["kv_lora_rank"]
     c_q = _norm(_dot(h, params[p + "q_a_weight"]),
-                params[p + "q_a_norm_gamma"], cfg)
+                params[p + "q_a_norm_gamma"], cfg, cfg.get("q_lora_scale"))
     q = _dot(c_q, params[p + "q_b_weight"]).reshape(h.shape[0], heads, -1)
     kv_a = _dot(h, params[p + "kv_a_weight"])
-    c_kv = _norm(kv_a[:, :kv_rank], params[p + "kv_a_norm_gamma"], cfg)
+    c_kv = _norm(kv_a[:, :kv_rank], params[p + "kv_a_norm_gamma"], cfg,
+                 cfg.get("kv_lora_scale"))
     k_rope = _rotate(kv_a[:, kv_rank:], positions, cfg)
     row = _pad_row(jnp.concatenate([c_kv, k_rope], axis=-1), cfg)
     return q[..., :nope], _rotate(q[..., nope:], positions, cfg), row

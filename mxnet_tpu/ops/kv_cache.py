@@ -188,6 +188,11 @@ _M_ROW_BYTES = _metrics.gauge(
     "kv_cache_row_bytes",
     "Bytes one token of one layer takes in the KV cache (every pool of "
     "its row), by model", ["model"])
+_M_CACHE_LAYERS = _metrics.gauge(
+    "kv_cache_layers",
+    "Rows one token keeps in the KV cache (the pools' leading axis): the "
+    "model's layers, fewer where some keep state instead, more where a "
+    "layer has several attention sublayers, by model", ["model"])
 _M_SESS_BLOCKS = _metrics.histogram(
     "serving_kv_blocks_per_session",
     "Blocks one sequence held when it was freed, by model", ["model"],
@@ -274,6 +279,7 @@ class PagedKVCache(object):
                        int(self.row.width))
         self._dtype = np.dtype(self.row.dtype)
         _M_ROW_BYTES.labels(model).set(self.row.bytes)
+        _M_CACHE_LAYERS.labels(model).set(self.num_layers)
         # the recurrent layers' state, one slot a sequence (none for a
         # model whose every layer keeps rows per token)
         self.state = state

@@ -16,7 +16,10 @@ over all of them and computes its own experts' part of the sum, as one
 member of an expert-parallel deployment does.  Tokens are sorted by
 expert and the products run as grouped matmuls (``jax.lax.ragged_dot``)
 over the held experts only, so no token is dropped at any skew and no
-shape depends on the routing.
+shape depends on the routing.  A router may be wider than the experts
+there are: a chosen id beyond them is an **identity expert**
+(:func:`identity_experts`), which hands the token back times its gate
+and computes nothing.
 """
 
 from __future__ import annotations
@@ -29,8 +32,8 @@ from ..observability import metrics as _metrics
 
 __all__ = ["moe_ffn", "init_moe_params", "router_top1", "router_topk",
            "route_group_limited", "route_softmax_topk", "dropless_experts",
-           "swiglu", "gated_shared_expert",
-           "book_expert_counts", "EXPERT_COUNTS"]
+           "identity_experts", "swiglu", "gated_shared_expert",
+           "book_expert_counts", "EXPERT_COUNTS", "ZERO_COUNT"]
 
 
 def _route_indexed(logits, capacity, k, renorm=None):
@@ -227,18 +230,28 @@ def route_group_limited(logits, bias, *, top_k, n_group=1, topk_group=1,
     return chosen, gates * scale
 
 
-def route_softmax_topk(logits, *, top_k, normalize=True):
-    """The softmax router of the Qwen expert models.  ``logits``
-    ``[T, E]`` over ALL the experts of the model: ``p = softmax(logits)``
-    in float32, the ``top_k`` largest are chosen, and the gates are
-    ``p`` at the chosen experts, divided by their sum if ``normalize``
-    (``norm_topk_prob``).  Returns ``(experts int32 [T, top_k], gates
-    float32 [T, top_k])``; every token keeps all its ``top_k``
-    experts."""
+def route_softmax_topk(logits, *, top_k, normalize=True, bias=None,
+                       scale=None):
+    """The softmax router of the Qwen expert models and, with ``bias``
+    and ``scale``, of LongCat-Flash.  ``logits`` ``[T, E]`` over ALL the
+    router's outputs (identity experts among them, where the model has
+    any): ``p = softmax(logits)`` in float32, the ``top_k`` largest of
+    ``p`` (of ``p + bias`` where a selection bias ``[E]`` is given: no
+    groups) are chosen, and the gates are ``p`` (without the bias) at
+    the chosen experts, divided by their sum if ``normalize``
+    (``norm_topk_prob``), times ``scale`` if given.  Returns ``(experts
+    int32 [T, top_k], gates float32 [T, top_k])``; every token keeps all
+    its ``top_k`` experts."""
     p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gates, chosen = jax.lax.top_k(p, top_k)
+    if bias is None:
+        gates, chosen = jax.lax.top_k(p, top_k)
+    else:
+        chosen = jax.lax.top_k(p + bias.astype(jnp.float32), top_k)[1]
+        gates = jnp.take_along_axis(p, chosen, axis=1)
     if normalize:
         gates = gates / gates.sum(-1, keepdims=True)
+    if scale is not None:
+        gates = gates * scale
     return chosen.astype(jnp.int32), gates
 
 
@@ -277,9 +290,19 @@ _M_EXPERT = [_metrics.counter(name, text + ", by model", ["model"])
                  "other three)"))]
 
 
+# a fifth count, of a model with identity experts alone: it follows
+# the four in the vector (:func:`identity_experts` counts it)
+ZERO_COUNT = "moe_zero_assignments_total"
+_M_ZERO = _metrics.counter(
+    ZERO_COUNT, "Token-expert pairs that fell on identity (zero-compute) "
+    "experts: counted in moe_assignments_total, computed nowhere, by model",
+    ["model"])
+
+
 def book_expert_counts(model, counts):
-    """Add one call's :data:`EXPERT_COUNTS` vector to the counters."""
-    for family, value in zip(_M_EXPERT, counts):
+    """Add one call's :data:`EXPERT_COUNTS` vector to the counters, and
+    its :data:`ZERO_COUNT` where the vector has a fifth entry."""
+    for family, value in zip(_M_EXPERT + [_M_ZERO], counts):
         family.labels(model).inc(int(value))
 
 
@@ -294,7 +317,11 @@ def few_rows_hit_most(tokens, k, n_experts):
     ``n_experts``, is small enough for an expert's product to cost its
     weights' read whatever the rows (:data:`EVERY_ROW_LIMIT`) and still
     expected to reach more than half the experts: then
-    :func:`dropless_experts` does best with ``every_row``."""
+    :func:`dropless_experts` does best with ``every_row``.  ``k /
+    n_experts`` is the chance that a row chooses a given *real* expert:
+    a router with identity experts hands over its real choices a token
+    (on average) and its real experts, or its whole ``top_k`` and its
+    whole width where, as under even routing, those agree."""
     reached = 1.0 - (1.0 - k / float(n_experts)) ** tokens
     return tokens <= EVERY_ROW_LIMIT and reached > 0.5
 
@@ -326,7 +353,9 @@ def dropless_experts(x, chosen, gates, w_gate, w_up, w_down, held,
     expert is computed over every row instead and a row keeps the
     outputs of those it chose: the same sum, in three batched products
     that read each held expert once whatever the choice, so that a step
-    takes the same time whichever experts its tokens hit."""
+    takes the same time whichever experts its tokens hit.  A call whose
+    sorted pairs' rows would pass :data:`GROUPED_ROW_BYTES` runs its
+    rows in equal runs, one after another (:func:`_grouped_chunks`)."""
     tokens, k = chosen.shape
     first, count = held[0], int(w_gate.shape[0])
     if expert_axis is not None:
@@ -341,12 +370,68 @@ def dropless_experts(x, chosen, gates, w_gate, w_up, w_down, held,
     if every_row:
         y = _every_row(x, key, gates, w_gate, w_up, w_down)
     else:
-        y = _grouped_rows(x, key, local, gates, sizes, w_gate, w_up, w_down)
+        y = _grouped_chunks(x, key, local, gates, sizes, w_gate, w_up,
+                            w_down)
     counts = jnp.stack([routed.sum(), local.sum(), (sizes > 0).sum(),
                         jnp.int32(1)]).astype(jnp.int32)
     if expert_axis is not None:
         y = jax.lax.psum(y, expert_axis)
     return y, counts
+
+
+def identity_experts(x, chosen, gates, n_real, valid=None):
+    """The identity (zero-compute) experts' part of an expert layer: a
+    chosen id of ``n_real`` or more is one of them and adds ``gate *
+    x``, so a token gets ``(sum of those gates) * x``: no product, no
+    weight, no entry among the sorted pairs of :func:`dropless_experts`
+    (which leaves out every id it does not hold, these among them).
+    Every member of an expert-parallel deployment computes this term
+    whole for its own tokens; it is added once.  Returns ``(y [T, d],
+    count)``, ``count`` the :data:`ZERO_COUNT` of the call (pad rows,
+    not ``valid``, choose nothing)."""
+    zero = chosen >= n_real
+    if valid is not None:
+        zero = zero & valid[:, None]
+    gate = jnp.where(zero, gates, 0).sum(-1, keepdims=True)
+    return (gate * x.astype(jnp.float32)).astype(x.dtype), \
+        zero.sum().astype(jnp.int32)
+
+
+# the bytes one grouped product's gathered rows may take: a call's T * k
+# pairs are gathered, computed and scattered back as [pairs, d] arrays
+# whether or not their expert is held (a 6144-token prompt choosing 12
+# at d 6144: 906 MB a copy, 3 GB of temporaries compiled for a v5e), so
+# a call past this runs its rows in equal runs.  Every bucket of the
+# cells that choose 8 or 10 a token stays whole (382 MB at the most)
+GROUPED_ROW_BYTES = 384 * 2 ** 20
+
+
+def _grouped_chunks(x, key, local, gates, sizes, w_gate, w_up, w_down):
+    """:func:`_grouped_rows` over the call's rows, whole where its
+    pairs' rows are at most :data:`GROUPED_ROW_BYTES` and else in the
+    fewest equal runs of rows that are (a run sorts and groups its own
+    pairs; the held experts' weights are read once a run)."""
+    tokens, k = key.shape
+    limit = GROUPED_ROW_BYTES // (x.shape[1] * x.dtype.itemsize)
+    runs = next(n for n in range(1, tokens + 1)
+                if tokens % n == 0 and tokens // n * k <= limit
+                or n == tokens)
+    if runs == 1:
+        return _grouped_rows(x, key, local, gates, sizes, w_gate, w_up,
+                             w_down)
+    count = sizes.shape[0]
+
+    def one(run):
+        x_r, key_r, local_r, gates_r = run
+        sizes_r = jnp.zeros(count + 1, jnp.int32).at[
+            key_r.reshape(-1)].add(1)[:count]
+        return _grouped_rows(x_r, key_r, local_r, gates_r, sizes_r, w_gate,
+                             w_up, w_down)
+
+    y = jax.lax.map(one, tuple(a.reshape((runs, tokens // runs)
+                                         + a.shape[1:])
+                               for a in (x, key, local, gates)))
+    return y.reshape(x.shape)
 
 
 def _grouped_rows(x, key, local, gates, sizes, w_gate, w_up, w_down):

@@ -220,6 +220,12 @@ _PAGED_KERNELS = {
                (_s((64, 128, 640), BF16), _s((64, 640), BF16),
                 _s(_LATENT_POOL, BF16), _s((64, 256), jnp.int32),
                 _s((64,), jnp.int32))),
+    # longcat-serve-agent64: 64 rows of 64 heads, 512-block tables, the
+    # 8 sublayers' pools of 16,000 blocks as one
+    "latent_64_heads": (_latent_kernel, "latent_decode_attention",
+                        (_s((64, 64, 640), BF16), _s((64, 640), BF16),
+                         _s((8 * 16000, 16, 640), BF16),
+                         _s((64, 512), jnp.int32), _s((64,), jnp.int32))),
 }
 
 
