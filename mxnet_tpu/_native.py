@@ -11,7 +11,9 @@ says so (a warning, and :func:`status`).
 from __future__ import annotations
 
 import collections
+import contextlib
 import ctypes
+import fcntl
 import os
 import subprocess
 import threading
@@ -104,6 +106,24 @@ def _build():
     return None
 
 
+@contextlib.contextmanager
+def _one_process():
+    """Hold ``native/build/.lock`` for this process alone: the workers
+    of a parallel test run share the checkout, and a library that one of
+    them is still linking must be neither loaded nor built over by the
+    others (both end in a segmentation fault)."""
+    build = os.path.dirname(_SO_PATH)
+    try:
+        os.makedirs(build, exist_ok=True)
+        fd = open(os.path.join(build, ".lock"), "w")
+    except OSError:             # a checkout that cannot be written to
+        yield
+        return
+    with fd:
+        fcntl.flock(fd, fcntl.LOCK_EX)
+        yield
+
+
 def _load():
     """(configured CDLL, None), or (None, why it cannot be loaded)."""
     try:
@@ -128,16 +148,18 @@ def lib():
         if os.environ.get("MXTPU_NO_NATIVE"):
             _status = "disabled by MXTPU_NO_NATIVE"
             return None
-        found = os.path.exists(_SO_PATH)
-        why = None if found else _build()
-        if why is None:
-            _lib, why = _load()
-            if _lib is None and found:
-                # stale .so missing newer symbols: rebuild once, then retry
-                found = False
-                why = _build()
-                if why is None:
-                    _lib, why = _load()
+        with _one_process():
+            found = os.path.exists(_SO_PATH)
+            why = None if found else _build()
+            if why is None:
+                _lib, why = _load()
+                if _lib is None and found:
+                    # stale .so missing newer symbols: rebuild once, then
+                    # retry
+                    found = False
+                    why = _build()
+                    if why is None:
+                        _lib, why = _load()
         if _lib is None:
             _status = "unavailable, pure-Python fallbacks in use (%s)" % why
             warnings.warn("native runtime " + _status)

@@ -437,12 +437,20 @@ class PagedKVCache(object):
             return sorted(self._tables)
 
     def block_table(self, seq_id, max_blocks):
-        """Padded ``int32[max_blocks]`` table for a decode dispatch.
+        """Padded ``int32[max_blocks]`` table for a decode dispatch, a
+        new array every call.
 
         Pad entries point at block 0 — harmless, because decode
         attention masks scores past the context length before softmax
         (``-1e30`` → exp underflows to exact ``0.0``), so whatever those
         rows hold never reaches the output bits.
+
+        The generation loop calls this once a sequence, when it joins
+        the decode batch, and keeps the row (a sequence reserved whole
+        at admission is never grown, so its row holds until
+        :meth:`free`); a decode step calls it for no one.  The other
+        callers are ``warmup()`` and code that drives a backend by
+        hand, which build the table of every call.
         """
         table = self._tables.get(seq_id)
         if table is None:
@@ -460,7 +468,10 @@ class PagedKVCache(object):
         decode call belongs to, found through the first block of its
         table (the slot rides with the block table); ``num_slots`` for a
         pad row (position 0), which the decode program reads as "writes
-        nowhere"."""
+        nowhere".  ``LMBackend.decode`` calls this once for every table
+        array it is handed and keeps the answer with the table: a
+        sequence holds its slot as long as its blocks, so the rows of
+        an unchanged table have unchanged slots."""
         tables = np.asarray(block_tables)
         out = np.full(len(tables), self.num_slots, dtype=np.int32)
         with self._lock:

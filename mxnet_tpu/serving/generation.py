@@ -28,10 +28,18 @@ scheduler was already styled after:
   new request with the typed 429
   :class:`~mxnet_tpu.ops.kv_cache.CacheExhaustedError` through the
   stock admission accounting.  Weights and pools stay on the device
-  between dispatches: a call hands over token ids, positions, block
-  tables and lengths and gets logits back
+  between dispatches: a call hands over token ids, positions and
+  lengths and gets logits back
   (``generation_host_to_device_bytes_total`` /
-  ``generation_device_to_host_bytes_total`` are the evidence).  The
+  ``generation_device_to_host_bytes_total`` are the evidence).  **The
+  decode batch's block table is kept, not rebuilt**: a sequence's
+  padded row is made once, when it joins the batch (its whole horizon
+  is reserved then, so the row cannot change), the lane puts the live
+  rows together only when the batch gained or lost a sequence, and
+  between such steps a decode call is handed the very array the last
+  one got, which the backend knows again by identity: the table
+  crosses to the device when it changes, not with every step
+  (``generation_block_table_rows_built_total``).  The
   pool write follows the dispatch that produced its K/V (a decode
   step's inside :meth:`LMBackend.decode`, right behind the program) and
   targets only the calling sequences' own reserved slots, so a
@@ -386,12 +394,23 @@ def with_greedy_ids(decode):
     return program
 
 
+class _Table(_collections.namedtuple("_Table", "host device slots live")):
+    """A decode batch's block table as :meth:`LMBackend.decode` was
+    handed it (``host``, known again by identity), its copy on the
+    device and, for a model with recurrent state, the rows' state slots
+    there (``live`` of them a sequence's): what is derived from a table
+    once and serves every step that is handed the same one."""
+
+    __slots__ = ()
+
+
 class _Step(_collections.namedtuple(
         "_Step", "fed logits ids k v counts cold")):
-    """One decode step on the device's queue: what it was fed (numpy;
-    a queued step's tokens are known once the step before it is read)
-    and its outputs, device arrays whose copies to the host are on
-    their way."""
+    """One decode step on the device's queue: what it was fed (numpy,
+    the caller's own arrays, kept by reference and never written; a
+    queued step's tokens are known once the step before it is read) and
+    its outputs, device arrays whose copies to the host are on their
+    way."""
 
     __slots__ = ()
 
@@ -504,6 +523,9 @@ class LMBackend(Backend):
         self.run_ahead = False
         self._ahead = None
         self.greedy_ids = None
+        # the block table the last decode call was handed, with what
+        # was derived from it (:meth:`_on_device`)
+        self._table = None
         self._ahead_used = _M_AHEAD_USED.labels(model)
         self._ahead_dropped = _M_AHEAD_DROPPED.labels(model)
         self._state_moved = _M_STATE_MOVED.labels(model)
@@ -598,19 +620,39 @@ class LMBackend(Backend):
         return (self._fetch("prefill", logits, counts)[0], k, v, cold,
                 *state)
 
-    def _dispatch_decode(self, tokens, positions, block_tables,
-                         context_lens, slots=None):
+    def _on_device(self, block_tables, positions):
+        """The :class:`_Table` of ``block_tables``: the one kept from
+        the last call if this is the very array that call was handed
+        (what was handed over is never written again, so the same
+        object is the same table), else made now: the table crosses to
+        the device, and the rows' state slots are looked up (a model
+        with recurrent state), once a table and not once a step."""
+        table = self._table
+        if table is None or table.host is not block_tables:
+            import jax
+
+            slots, live = None, 0
+            if self.cache.state:
+                slots = self.cache.state_slots(block_tables, positions)
+                live = int((slots < self.cache.num_slots).sum())
+            self.moved("decode", h2d=_host_nbytes((block_tables, slots)))
+            table = self._table = _Table(
+                block_tables, *jax.device_put((block_tables, slots)), live)
+        return table
+
+    def _dispatch_decode(self, tokens, positions, table, context_lens):
         """Put one decode step on the device's queue and return it: the
         program, the copies of what it gives back, and right behind it
         the write of its K/V rows into the pool, so that whatever is
         dispatched next reads a pool that holds this step.  ``tokens``
-        is numpy, or the device's ids of the step before.  ``slots``
-        (a model with recurrent state): the rows' state slots; the
-        program is handed the state pool donated and the cache is
-        re-bound to what it gives back."""
+        is numpy, or the device's ids of the step before; ``table`` the
+        batch's :class:`_Table` (the program reads its device copy, the
+        pool write its host copy).  A model with recurrent state is
+        handed the state pool donated, with the rows' state slots, and
+        the cache is re-bound to what it gives back."""
         args = (tokens, positions, self.cache.k_pages, self.cache.v_pages,
-                block_tables, context_lens)
-        if slots is None:
+                table.device, context_lens)
+        if table.slots is None:
             fn, cold = self._jit(("decode", len(positions)),
                                  self._decode_program)
             logits, ids, k, v, counts = fn(self.params, *args)
@@ -619,7 +661,8 @@ class LMBackend(Backend):
                                  self._decode_program, donate=(7,))
             try:
                 logits, ids, k, v, counts, pools = fn(
-                    self.params, *args, self.cache.state_pools, slots)
+                    self.params, *args, self.cache.state_pools,
+                    table.slots)
             except Exception as exc:
                 lost = self.cache.state_lost(exc)
                 if lost is None:
@@ -627,13 +670,12 @@ class LMBackend(Backend):
                 raise lost from exc
             self.cache.swap_state(pools)
             self._state_moved.inc(
-                2 * int((slots < self.cache.num_slots).sum())
-                * self.cache.state.bytes)
+                2 * table.live * self.cache.state.bytes)
         self._copy_back(logits, ids, counts)
         self.moved("decode", h2d=_host_nbytes(
             (*self.params.values(), *args))
-            + self.cache.write_tokens(block_tables, positions, k, v))
-        return _Step((tokens, positions, block_tables, context_lens),
+            + self.cache.write_tokens(table.host, positions, k, v))
+        return _Step((tokens, positions, table.host, context_lens),
                      logits, ids, k, v, counts, cold)
 
     def drop_ahead(self):
@@ -665,8 +707,14 @@ class LMBackend(Backend):
         the same bucket and tables, ``tokens`` the device's own ids of
         this step, ``positions + 1``, ``context_lens + 1``, then its
         write.  The next call gets that step's results if its four
-        arguments equal what was queued; any other call drops it and is
-        dispatched afresh, as is any call after an error.  A dropped
+        arguments equal what was queued (the same ``block_tables``
+        object is taken as equal, unread); any other call drops it and
+        is dispatched afresh, as is any call after an error.  Equal
+        arguments are not always the same batch: a row's successor may
+        get its blocks, stand at its position + 1 and feed its token.
+        So a caller whose batch gained or lost a row calls
+        :meth:`drop_ahead` before it decodes (the generation loop does,
+        with the new table array it makes then).  A dropped
         step's write is harmless: a row that goes on rewrites the slot
         with the same values; one that does not owns the slot until its
         blocks are freed, whoever gets them next writes a position
@@ -685,27 +733,41 @@ class LMBackend(Backend):
         raised is :class:`RecurrentStateHazard`; the caller re-prefills
         its sequences or fails them.
 
+        **The arguments are not written after the call.**  The queued
+        step keeps references to them, and a jitted call on the CPU
+        platform may read an aligned numpy argument where it lies: a
+        caller makes new arrays for the next step and leaves these as
+        they are.  In return the same ``block_tables`` object (an
+        ``int32`` array) is the same table: its 4 bytes an entry are
+        neither compared with the queued step's nor sent again; its
+        copy on the device, and the rows' state slots, are kept with it
+        until a call brings another array, and
+        ``generation_host_to_device_bytes_total`` books the table only
+        then.  A caller that builds a table per call gets what it got
+        before: every table is sent, and compared by value.
+
         A subclass that overrides this method with these four
         arguments and calls it (the benchmark's wrapper does) sees one
         call a step, numpy arguments, and a numpy ``out[0]`` that
         belongs to those arguments."""
         fed = tuple(_np.asarray(a, dtype=_np.int32) for a in
                     (tokens, positions, block_tables, context_lens))
-        slots = self.cache.state_slots(fed[2], fed[1]) \
-            if self.cache.state else None
+        table = self._on_device(fed[2], fed[1])
         step, self._ahead = self._ahead, None
-        if step is not None and all(map(_np.array_equal, step.fed, fed)):
+        if step is not None and all(
+                a is b or _np.array_equal(a, b)
+                for a, b in zip(step.fed, fed)):
             self._ahead_used.inc()
         else:
             if step is not None:
                 self._ahead_dropped.inc()
-            step = self._dispatch_decode(*fed, slots)
+            step = self._dispatch_decode(fed[0], fed[1], table, fed[3])
         ahead = None
         try:
             if self.run_ahead:
                 ahead = self._dispatch_decode(
-                    step.ids, fed[1] + 1, fed[2], fed[3] + 1, slots)
-            if slots is not None:
+                    step.ids, fed[1] + 1, table, fed[3] + 1)
+            if table.slots is not None:
                 # the drill of a step that fails behind its dispatch
                 chaos.visit("serving.decode", name="%s:fetch" % self.model)
             logits, ids = self._fetch("decode", step.logits, step.counts,
@@ -713,7 +775,7 @@ class LMBackend(Backend):
         except Exception as exc:
             if ahead is not None:
                 self._ahead_dropped.inc()
-            if (slots is not None and self.run_ahead
+            if (table.slots is not None and self.run_ahead
                     and not isinstance(exc, CachePoolLostError)):
                 raise RecurrentStateHazard(
                     "model %r: a decode step failed after the step "
@@ -734,10 +796,21 @@ class LMBackend(Backend):
 
 
 class _Sequence(object):
-    """One live generation: its request, cache identity, and progress."""
+    """One live generation: its request, cache identity, and progress.
+
+    ``table`` is the sequence's padded block-table row, ``int32
+    [max_blocks_per_seq]``, made once from the allocation it got when
+    it joined the decode batch.  The whole horizon (prompt +
+    ``max_new_tokens``) is reserved then and never grown, so the row
+    holds until ``cache.free``, and with it the state slot the cache
+    gave the sequence beside its blocks (found through the row's first
+    block: ``PagedKVCache.state_slots``).  The row is never written: a
+    re-prefilled sequence (hot swap, :class:`RecurrentStateHazard`) is
+    a new ``_Sequence`` with a new ``seq_id``, new blocks, a new slot
+    and a new row."""
 
     __slots__ = ("req", "seq_id", "length", "last_token", "backend_ref",
-                 "new_tokens", "t_last_token")
+                 "new_tokens", "t_last_token", "table")
 
     def __init__(self, req, seq_id, backend_ref):
         self.req = req
@@ -747,6 +820,8 @@ class _Sequence(object):
         self.last_token = 0      # input to the next decode step
         self.new_tokens = 0
         self.t_last_token = time.monotonic()
+        self.table = backend_ref.cache.block_table(
+            seq_id, backend_ref.max_blocks_per_seq)
 
 
 class _GenLane(object):
@@ -755,16 +830,23 @@ class _GenLane(object):
 
     __slots__ = ("entry", "queue", "active", "owed", "thread", "steps",
                  "tokens", "rows", "slots", "max_step_rows", "seq_counter",
-                 "tenant_handles",
+                 "tenant_handles", "seated", "tables",
                  "m_req", "m_prefill", "m_itl", "m_depth", "m_occ",
                  "m_active", "m_requests", "m_tokens", "m_steps",
-                 "m_context", "m_compiles", "m_errors", "m_reprefills")
+                 "m_context", "m_compiles", "m_errors", "m_reprefills",
+                 "m_table_rows")
 
     def __init__(self, entry, weight_fn=None):
         self.entry = entry
         self.queue = _tenancy.FairQueue(weight_fn)
         self.tenant_handles = {}
         self.active = []
+        # the decode batch's block table, ``int32 [bucket,
+        # max_blocks_per_seq]``, and the sequences whose rows it holds,
+        # in order: replaced together, by new objects, when ``active``
+        # is no longer that list (``_decode_step``)
+        self.seated = []
+        self.tables = None
         # wake-ups of stream readers whose requests ended since the last
         # device call: called beside the next one (or when none follows)
         self.owed = set()
@@ -846,6 +928,12 @@ class GenerationScheduler(object):
                 "Cached tokens the decode steps attended over: the live "
                 "sequences' context lengths, summed over steps",
                 ["model"]),
+            "table_rows": reg.counter(
+                "generation_block_table_rows_built_total",
+                "Block-table rows built: one a sequence that joined the "
+                "decode batch (admitted, or re-prefilled after a hot swap "
+                "or a state hazard); a decode step builds none",
+                ["model"]),
             "compiles": reg.counter(
                 "generation_compiles_total",
                 "Cold (compiling) prefill/decode shapes; flat after "
@@ -921,7 +1009,8 @@ class GenerationScheduler(object):
                           ("context", "m_context"),
                           ("compiles", "m_compiles"),
                           ("errors", "m_errors"),
-                          ("reprefills", "m_reprefills")):
+                          ("reprefills", "m_reprefills"),
+                          ("table_rows", "m_table_rows")):
             setattr(lane, attr, self._fam[key].labels(name))
         with self._cond:
             self._lanes[name] = lane
@@ -1117,6 +1206,9 @@ class GenerationScheduler(object):
             self._retire(lane, backend)
             if not lane.active:
                 self._wake_owed(lane)   # no device call to do it beside
+                # an idle lane keeps no sequence, nor through one the
+                # backend it ran on (a swap may have replaced it)
+                lane.seated, lane.tables = [], None
             if _metrics.metrics_enabled():
                 lane.m_active.set(len(lane.active))
 
@@ -1281,6 +1373,8 @@ class GenerationScheduler(object):
                 self._fail_live(lane, exc)
             raise
         seq = _Sequence(req, seq_id, backend)
+        if _metrics.metrics_enabled():
+            lane.m_table_rows.inc()
         seq.length = t
         if resume is None:
             first = int(_np.argmax(logits))
@@ -1381,17 +1475,27 @@ class GenerationScheduler(object):
         live = lane.active
         n = len(live)
         bucket = lane.entry.pick_bucket(n)
+        if lane.seated != live or len(lane.tables) != bucket:
+            # the batch gained or lost a sequence: its table is put
+            # together anew from the rows the sequences brought, as a
+            # new array (the one handed over before is never written:
+            # :meth:`LMBackend.decode`); any other step hands the
+            # backend the very array the last one did
+            tables = _np.zeros((bucket, backend.max_blocks_per_seq),
+                               dtype=_np.int32)
+            tables[:n] = [seq.table for seq in live]
+            lane.seated, lane.tables = list(live), tables
+            # a step queued for the batch as it was answers no one: a
+            # new row may hold the old row's blocks, position and token
+            backend.drop_ahead()
+        tables = lane.tables
+        # the three vectors of a step are new arrays every step; a pad
+        # row reads position 0 and context 1
         tokens = _np.zeros(bucket, dtype=_np.int32)
         positions = _np.zeros(bucket, dtype=_np.int32)
-        context = _np.ones(bucket, dtype=_np.int32)
-        tables = _np.zeros((bucket, backend.max_blocks_per_seq),
-                           dtype=_np.int32)
-        for i, seq in enumerate(live):
-            tokens[i] = seq.last_token
-            positions[i] = seq.length
-            context[i] = seq.length + 1
-            tables[i] = backend.cache.block_table(
-                seq.seq_id, backend.max_blocks_per_seq)
+        tokens[:n] = [seq.last_token for seq in live]
+        positions[:n] = [seq.length for seq in live]
+        context = positions + 1
         req_uids = ([s.req.trace for s in live]
                     if _tracing.tracing_enabled() else ())
         out = None

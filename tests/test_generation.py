@@ -318,9 +318,10 @@ def test_pool_writes_alias_the_pool(lm):
 
 def test_transfer_counters_read_logits_out_and_kilobytes_in(lm):
     """``generation_*_bytes_total``: a decode call at bucket ``B`` copies
-    ``B x V x 4`` bytes back and hands over only ids, positions, tables,
-    lengths and slot indices; a numpy array slipping back among the
-    weights shows at once."""
+    ``B x V x 4`` bytes back and hands over only ids, positions,
+    lengths and slot indices, and the block table when it is not the
+    array the call before was handed; a numpy array slipping back among
+    the weights shows at once."""
     from mxnet_tpu.observability import metrics as om
 
     be = _backend(lm, model="bytes")
@@ -342,10 +343,15 @@ def test_transfer_counters_read_logits_out_and_kilobytes_in(lm):
     assert d2h.labels("bytes", "decode").value == bucket * (VOCAB + 1) * 4
     per_call = bucket * 4 * (5 + be.max_blocks_per_seq)
     assert h2d.labels("bytes", "decode").value == per_call
+    # the same table object again is the same table: it stays where it
+    # is and is not booked; a copy of it is another table, and crosses
     be.params["pred_bias"] = np.asarray(be.params["pred_bias"])
     be.decode(*call)
     assert h2d.labels("bytes", "decode").value \
-        == 2 * per_call + VOCAB * 4
+        == 2 * per_call - tables.nbytes + VOCAB * 4
+    be.decode(call[0], call[1], tables.copy(), call[3])
+    assert h2d.labels("bytes", "decode").value \
+        == 3 * per_call - tables.nbytes + 2 * VOCAB * 4
 
 
 def test_lost_pool_fails_live_sequences_and_serves_again(lm, monkeypatch):

@@ -626,3 +626,32 @@ def test_generated_cpp_ops_compile_and_run():
         capture_output=True, text=True, env=env, timeout=300)
     assert r.returncode == 0, (r.stdout, r.stderr)
     assert "GEN_OPS ok" in r.stdout, r.stdout
+
+
+def test_one_process_at_a_time_finds_builds_and_loads_the_library():
+    """The workers of a parallel test run share one checkout: while one
+    of them holds ``native/build/.lock`` (it may be linking the library)
+    another waits, and neither loads a half-written library nor starts
+    a second ``make`` over the first."""
+    import subprocess
+    import sys
+    import time
+
+    holder = subprocess.Popen(
+        [sys.executable, "-c",
+         "import sys, time\n"
+         "from mxnet_tpu import _native\n"
+         "with _native._one_process():\n"
+         "    print('held', flush=True)\n"
+         "    time.sleep(1.0)\n"],
+        stdout=subprocess.PIPE, text=True,
+        env=dict(os.environ, MXTPU_NO_NATIVE="1", JAX_PLATFORMS="cpu"))
+    try:
+        assert holder.stdout.readline().strip() == "held"
+        t0 = time.monotonic()
+        with _native._one_process():
+            waited = time.monotonic() - t0
+        assert waited > 0.5, "the lock let two processes in at once"
+    finally:
+        holder.wait(timeout=60)
+    assert holder.returncode == 0
