@@ -27,9 +27,22 @@ stamps (``native/src/profiler.cc NowUs``), so Python spans and native
 engine ops merge onto ONE aligned timeline in
 ``exporters.export_chrome_trace``.
 
-Recording is off by default; the profiler façade
-(``profiler_set_state('run')``) or :func:`enable_tracing` turns it on.
-When off, ``span()`` is a no-op context manager (constant-time guard).
+Spans record while :func:`enable_tracing` holds **or a JAX profiler
+session is live** (``jax.profiler.start_trace`` … ``stop_trace``:
+``profiler_set_state('run')``, the front end's ``/profile?ms=N``, a
+benchmark's traced run), and at no other time: there is no other
+switch.  Under a live session a span is also entered as a
+``jax.profiler.TraceAnnotation`` named ``"mx:" + name``, with its span
+id, its parent's and (where the span has a ``request`` attribute) the
+request's token as metadata, so the same span lies in the profiler's
+own trace, in the host plane, **on the clock of the device's
+operations**.  The ring's own timestamps stay CLOCK_MONOTONIC: the ring
+and the profiler's trace are two records of the same spans, not one
+timeline.  A span with explicit timestamps (:func:`record_span`) cannot
+be back-dated in the profiler's trace; it leaves a mark there at the
+moment it is recorded, with ``start_us`` / ``end_us`` as metadata.
+With no session and tracing not enabled, ``span()`` is a no-op context
+manager (constant-time guard) and nothing is recorded.
 """
 
 from __future__ import annotations
@@ -37,6 +50,7 @@ from __future__ import annotations
 import collections
 import itertools
 import os
+import sys
 import threading
 import time
 
@@ -53,7 +67,11 @@ _M_DROPPED = _metrics.counter(
     "spans_dropped_total",
     "Trace spans evicted from the ring buffer before export")
 
+#: Prefix of a span's name in the profiler's trace.
+ANNOTATION_PREFIX = "mx:"
+
 _enabled = False
+_annotation = None   # jax.profiler.TraceAnnotation, bound at first use
 _lock = threading.Lock()
 _ids = itertools.count(1)
 _buffer = None       # created lazily so the env cap is read at first use
@@ -98,12 +116,34 @@ def enable_tracing():
 
 
 def disable_tracing():
+    """Take :func:`enable_tracing` back.  Spans still record while a
+    profiler session is live."""
     global _enabled
     _enabled = False
 
 
+def _bind_session():
+    """Is a JAX profiler session live?  Stands in as ``_session_live``
+    until JAX is found imported, then binds
+    ``TraceAnnotation.is_enabled`` (under 0.1 us a call) in its place:
+    this module imports nothing of JAX, and without JAX there is no
+    session to be live."""
+    global _annotation, _session_live
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return False
+    _annotation = profiler.TraceAnnotation
+    _session_live = _annotation.is_enabled
+    return _session_live()
+
+
+_session_live = _bind_session
+
+
 def tracing_enabled():
-    return _enabled
+    """Do spans record now: :func:`enable_tracing` was called, or a JAX
+    profiler session is live.  The one predicate of every site."""
+    return _enabled or _session_live()
 
 
 def _stack():
@@ -119,7 +159,7 @@ def capture_context():
     :func:`attach_context` on another thread to parent spans across the
     hop — this pair is what ``engine.push`` threads through to worker
     threads."""
-    if not _enabled:
+    if not tracing_enabled():
         return None
     st = getattr(_tls, "stack", None)
     return st[-1] if st else 0
@@ -154,7 +194,7 @@ def capture_wire_context():
     is open.  The token is a plain string so it rides in the kvstore
     JSON frame header as an OPTIONAL field — old peers that do not know
     it decode the frame unchanged."""
-    if not _enabled:
+    if not tracing_enabled():
         return None
     st = getattr(_tls, "stack", None)
     if not st:
@@ -183,7 +223,7 @@ class attach_wire_context(object):
         self._pushed = False
 
     def __enter__(self):
-        if not _enabled or not isinstance(self._tok, str):
+        if not isinstance(self._tok, str) or not tracing_enabled():
             return self
         try:
             pid_s, span_s = self._tok.split(":", 1)
@@ -207,11 +247,13 @@ class span(object):
 
     ``cat`` groups spans in the trace viewer (engine / prefetch /
     kvstore / frontend...); extra keyword attrs land in the chrome-trace
-    ``args``.  No-op (constant-time guard) while tracing is off.
+    ``args``.  Records while :func:`tracing_enabled` (and then, under a
+    live profiler session, into the profiler's trace too: the module
+    docstring); a no-op (constant-time guard) otherwise.
     """
 
     __slots__ = ("_name", "_cat", "_attrs", "_t0", "_id", "_parent",
-                 "_live")
+                 "_live", "_note")
 
     def __init__(self, name, cat="frontend", **attrs):
         self._name = name
@@ -227,13 +269,20 @@ class span(object):
         return self
 
     def __enter__(self):
-        if not _enabled:
+        session = _session_live()
+        if not (session or _enabled):
             return self
         self._live = True
         st = _stack()
         self._parent = st[-1] if st else 0
         self._id = next(_ids)
         st.append(self._id)
+        if session:
+            self._note = _annotate(self._name, self._id, self._parent,
+                                   self._attrs)
+            self._note.__enter__()
+        else:
+            self._note = None
         self._t0 = int(time.monotonic() * 1e6)
         return self
 
@@ -242,6 +291,8 @@ class span(object):
             return False
         self._live = False
         end = int(time.monotonic() * 1e6)
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
         st = _stack()
         if st and st[-1] == self._id:
             st.pop()
@@ -254,6 +305,14 @@ class span(object):
         return False
 
 
+def _annotate(name, span_id, parent, attrs, **more):
+    """The span as the profiler's trace gets it (a session is live)."""
+    if "request" in attrs:
+        more["request"] = attrs["request"]
+    return _annotation(ANNOTATION_PREFIX + name, span_id=span_id,
+                       parent_id=parent, **more)
+
+
 def record_span(name, cat="frontend", start_us=None, end_us=None,
                 parent=None, **attrs):
     """Record a span with EXPLICIT timestamps — for intervals measured
@@ -262,8 +321,12 @@ def record_span(name, cat="frontend", start_us=None, end_us=None,
     dispatch).  ``parent`` may be a local span id, a wire token (kept as
     a remote parent, stitched at export), or ``None`` to parent under
     the calling thread's current stack top.  Returns the new span id, or
-    ``None`` while tracing is off (constant-time guard)."""
-    if not _enabled:
+    ``None`` while tracing is off (constant-time guard).  Under a live
+    profiler session the span also leaves a mark in the profiler's
+    trace, at the moment of this call, with its timestamps as
+    metadata."""
+    session = _session_live()
+    if not (session or _enabled):
         return None
     now = int(time.monotonic() * 1e6)
     if end_us is None:
@@ -283,6 +346,10 @@ def record_span(name, cat="frontend", start_us=None, end_us=None,
         except ValueError:
             parent = 0
     sid = next(_ids)
+    if session:
+        with _annotate(name, sid, parent, attrs, start_us=int(start_us),
+                       end_us=int(end_us)):
+            pass
     buf = _buf()
     if len(buf) == buf.maxlen:
         _M_DROPPED.inc()

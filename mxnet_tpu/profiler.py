@@ -2,7 +2,7 @@
 ``src/engine/profiler.cc``) — now a façade over
 :mod:`mxnet_tpu.observability`.
 
-Three lanes, merged under one API:
+Three lanes under one API:
  - **device**: the jax/XLA profiler (xplane) — ``profiler_set_state('run')``
    starts a trace viewable in TensorBoard/Perfetto.  This is the TPU
    equivalent of the reference's GPU op timing.
@@ -10,10 +10,29 @@ Three lanes, merged under one API:
    records per-op start/end/thread for host-side engine work — the direct
    equivalent of the reference's ``OprExecStat`` → ``DumpProfile`` path
    (``src/engine/profiler.h:20-141``, hook ``threaded_engine.h:294-308``).
- - **frontend spans**: ``scope()`` and every instrumented runtime seam
-   record through :func:`observability.span` into the cross-thread ring
-   buffer; ``dump_profile`` merges them with the native dump into ONE
-   chrome://tracing JSON (shared CLOCK_MONOTONIC µs timeline).
+ - **the program's spans**: ``scope()`` and every instrumented runtime
+   seam record through :func:`observability.span`.
+
+**The rule: a live profiler session records the program's spans.**
+Between ``jax.profiler.start_trace`` and ``stop_trace`` — this module's
+``profiler_set_state('run')``, a serving replica's ``/profile?ms=N``, a
+benchmark's traced run — every ``observability.span`` records, with
+nothing else to turn on, and goes to two places.  (1) Into the
+profiler's own trace, as a ``TraceAnnotation`` named ``mx:<span>`` with
+its span id, parent id and request token as metadata: there it lies in
+the host plane **on the device's clock**, over the device's operations,
+and a gap on the device reads off what the serving loop was doing in it
+(``mx:decode.wait``, ``mx:decode.copy``, ``mx:generation.idle``…; the
+span table is in ``docs/how_to/observability.md``).  (2) Into the
+cross-thread ring buffer, stamped CLOCK_MONOTONIC µs like the native
+engine's events, which ``dump_profile`` merges with the native dump
+into one chrome://tracing JSON beside the xplane directory.  **These are
+two timelines, not one**: the chrome JSON has the host's spans and
+engine ops on the host's clock and no device operation; the xplane has
+the device's operations and the ``mx:`` spans on the profiler's.  To lay
+host work over device work, open the xplane.  With no session (and
+``observability.enable_tracing()`` not called) a span is a constant-time
+guard and records nothing.
 """
 
 from __future__ import annotations
@@ -52,8 +71,10 @@ def profiler_set_config(mode="symbolic", filename="profile.json"):
 
 
 def profiler_set_state(state="stop"):
-    """'run' starts the xplane trace, the native engine recording, and
-    frontend span recording; 'stop' ends all three (parity:
+    """'run' starts the xplane trace and the native engine recording,
+    and with the session the program's spans record (the module
+    docstring's rule; ``enable_tracing`` is set besides, for a build
+    whose profiler starts no session); 'stop' ends all three (parity:
     ``profiler.py:profiler_set_state``).  Idempotent and thread-safe:
     concurrent or repeated 'run' calls start ONE session."""
     import jax

@@ -270,22 +270,24 @@ class _StreamWriter(object):
             if self._event.wait(wait):
                 self._event.clear()
                 _M_STREAM_WAKEUPS.inc()
-            with self._lock:
-                streams = list(self._streams)
-                if self._closed and not streams:
-                    return
-            now = time.monotonic()
-            tokens = 0
-            for stream in streams:
-                tokens += self._serve(stream, now)
-            if tokens:
-                _M_STREAM_TOKENS.inc(tokens)
-            live = [s for s in streams if not s.over.is_set()]
-            if len(live) < len(streams):
+            with _tracing.span("stream.flush", cat="serving") as sp:
                 with self._lock:
-                    self._streams = [s for s in self._streams
-                                     if not s.over.is_set()]
-            owed = any(s.owed for s in live)
+                    streams = list(self._streams)
+                    if self._closed and not streams:
+                        return
+                now = time.monotonic()
+                tokens = 0
+                for stream in streams:
+                    tokens += self._serve(stream, now)
+                if tokens:
+                    _M_STREAM_TOKENS.inc(tokens)
+                live = [s for s in streams if not s.over.is_set()]
+                if len(live) < len(streams):
+                    with self._lock:
+                        self._streams = [s for s in self._streams
+                                         if not s.over.is_set()]
+                owed = any(s.owed for s in live)
+                sp.set(streams=len(streams), chunks=tokens)
 
     def _serve(self, stream, now):
         """Send ``stream`` what it is owed; returns the token chunks put

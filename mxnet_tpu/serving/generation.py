@@ -574,9 +574,30 @@ class LMBackend(Backend):
         transfer buffer), with a decode step's greedy ids; what the
         program counted rides back beside them and is booked, and so
         are the bytes copied.  The caller's ``beside_device`` runs
-        first: the device is at work by now."""
-        if self.beside_device is not None:
-            self.beside_device()
+        first: the device is at work by now.  A decode step's fetch is
+        three spans (``decode.deliver``, ``decode.wait``,
+        ``decode.copy``); a prefill's is one, its caller's."""
+        if phase != "decode":
+            if self.beside_device is not None:
+                self.beside_device()
+            return self._copy(phase, logits, counts, ids)
+        with _tracing.span("decode.deliver", cat="serving",
+                           model=self.model):
+            if self.beside_device is not None:
+                self.beside_device()
+        if _tracing.tracing_enabled():
+            # only a recorded step is asked when its outputs exist apart
+            # from their copy: unrecorded, the copy below waits for both
+            import jax
+
+            with _tracing.span("decode.wait", cat="serving",
+                               model=self.model):
+                jax.block_until_ready(ids)
+        with _tracing.span("decode.copy", cat="serving", model=self.model):
+            return self._copy(phase, logits, counts, ids)
+
+    def _copy(self, phase, logits, counts, ids):
+        """:meth:`_fetch`'s copies into ordinary memory, booked."""
         logits = _np.array(logits)
         d2h = logits.nbytes
         if ids is not None:
@@ -613,12 +634,16 @@ class LMBackend(Backend):
         args = (tokens, _np.asarray(length, dtype=_np.int32))
         fn, cold = self._jit(("prefill",) + tokens.shape,
                              self.definition.prefill)
-        logits, k, v, counts, *state = fn(self.params, *args)
+        with _tracing.span("prefill.dispatch", cat="serving",
+                           model=self.model):
+            logits, k, v, counts, *state = fn(self.params, *args)
         self._copy_back(counts)
         self.moved("prefill", h2d=_host_nbytes(
             (*self.params.values(), *args)))
-        return (self._fetch("prefill", logits, counts)[0], k, v, cold,
-                *state)
+        with _tracing.span("prefill.fetch", cat="serving",
+                           model=self.model):
+            logits = self._fetch("prefill", logits, counts)[0]
+        return (logits, k, v, cold, *state)
 
     def _on_device(self, block_tables, positions):
         """The :class:`_Table` of ``block_tables``: the one kept from
@@ -752,21 +777,28 @@ class LMBackend(Backend):
         belongs to those arguments."""
         fed = tuple(_np.asarray(a, dtype=_np.int32) for a in
                     (tokens, positions, block_tables, context_lens))
-        table = self._on_device(fed[2], fed[1])
-        step, self._ahead = self._ahead, None
+        step = self._ahead
         if step is not None and all(
                 a is b or _np.array_equal(a, b)
                 for a, b in zip(step.fed, fed)):
+            table = self._on_device(fed[2], fed[1])
+            self._ahead = None
             self._ahead_used.inc()
         else:
-            if step is not None:
-                self._ahead_dropped.inc()
-            step = self._dispatch_decode(fed[0], fed[1], table, fed[3])
+            with _tracing.span("decode.dispatch", cat="serving",
+                               model=self.model, ahead=0):
+                table = self._on_device(fed[2], fed[1])
+                self._ahead = None
+                if step is not None:
+                    self._ahead_dropped.inc()
+                step = self._dispatch_decode(fed[0], fed[1], table, fed[3])
         ahead = None
         try:
             if self.run_ahead:
-                ahead = self._dispatch_decode(
-                    step.ids, fed[1] + 1, table, fed[3] + 1)
+                with _tracing.span("decode.dispatch", cat="serving",
+                                   model=self.model, ahead=1):
+                    ahead = self._dispatch_decode(
+                        step.ids, fed[1] + 1, table, fed[3] + 1)
             if table.slots is not None:
                 # the drill of a step that fails behind its dispatch
                 chaos.visit("serving.decode", name="%s:fetch" % self.model)
@@ -1173,7 +1205,9 @@ class GenerationScheduler(object):
             with self._cond:
                 while (not lane.queue and not lane.active
                        and not self._killed and not self._stopping):
-                    self._cond.wait(0.05)
+                    with _tracing.span("generation.idle", cat="serving",
+                                       model=name):
+                        self._cond.wait(0.05)
                     self.last_beat = time.monotonic()
                 if self._killed or (self._stopping and not lane.queue
                                     and not lane.active):
@@ -1187,7 +1221,8 @@ class GenerationScheduler(object):
         waiting requests up to the decode capacity, then run one decode
         step — the Orca schedule."""
         entry = lane.entry
-        with entry.dispatch_lock:
+        with _tracing.span("generation.iterate", cat="serving",
+                           model=name) as sp, entry.dispatch_lock:
             backend = entry.backend
             self._retire_stale_backend(name, lane, backend)
             self._retire(lane, backend)
@@ -1201,6 +1236,7 @@ class GenerationScheduler(object):
             for req in admitted:
                 self._prefill_one(name, lane, backend, req)
             self._retire(lane, backend)
+            sp.set(admitted=len(admitted), rows=len(lane.active))
             if lane.active:
                 self._decode_step(name, lane, backend)
             self._retire(lane, backend)
@@ -1284,6 +1320,14 @@ class GenerationScheduler(object):
         cache allocation (typed 429 on exhaustion), ONE prefill
         dispatch, first token out.  Caller holds dispatch_lock."""
         now = time.monotonic()
+        if resume is None and _tracing.tracing_enabled():
+            # how long the request waited for this moment, under its own
+            # root span and not the loop's
+            _tracing.record_span(
+                "generation.queue", cat="serving",
+                start_us=req.t_admit * 1e6, end_us=now * 1e6,
+                parent=req.trace or 0, model=name, tenant=req.tenant,
+                request=req.trace)
         if req.cancelled:
             req._finish("cancelled")
             return
@@ -1339,8 +1383,8 @@ class GenerationScheduler(object):
             try:
                 with _tracing.span("generation.prefill", cat="serving",
                                    model=name, bucket=bucket, length=t,
-                                   attempt=attempt,
-                                   parent=req.trace) as sp:
+                                   attempt=attempt, parent=req.trace,
+                                   request=req.trace) as sp:
                     try:
                         chaos.visit("serving.dispatch",
                                     name="%s:prefill:%d" % (name, bucket))
@@ -1362,37 +1406,39 @@ class GenerationScheduler(object):
         logits, k, v, cold, *state = out
         if cold and _metrics.metrics_enabled():
             lane.m_compiles.inc()
-        # the pool write follows the dispatch that succeeded and targets
-        # only this sequence's own reserved slots (and its state slot)
-        try:
-            backend.moved("prefill", h2d=backend.cache.write_prefill(
-                seq_id, k, v, t, *state))
-        except Exception as exc:
-            backend.cache.free(seq_id)
-            if isinstance(exc, CachePoolLostError):
-                self._fail_live(lane, exc)
-            raise
-        seq = _Sequence(req, seq_id, backend)
-        if _metrics.metrics_enabled():
-            lane.m_table_rows.inc()
-        seq.length = t
-        if resume is None:
-            first = int(_np.argmax(logits))
-            req._push(first)
-            wake = req._deliver()  # a first token waits for nothing
-            if wake is not None:
-                wake()
-            seq.last_token = first
-            seq.new_tokens = 1
-        else:
-            # resumed sequence: tokens so far already streamed; the next
-            # decode step continues from the last generated token
-            seq.last_token = int(req.generated[-1])
-            seq.new_tokens = resume.new_tokens
-        req.seq_id = seq_id
-        lane.active.append(seq)
-        if _metrics.metrics_enabled():
-            lane.m_prefill.observe(time.monotonic() - t0, req.trace)
+        with _tracing.span("generation.prefill_write", cat="serving",
+                           model=name, request=req.trace):
+            # the pool write follows the dispatch that succeeded and targets
+            # only this sequence's own reserved slots (and its state slot)
+            try:
+                backend.moved("prefill", h2d=backend.cache.write_prefill(
+                    seq_id, k, v, t, *state))
+            except Exception as exc:
+                backend.cache.free(seq_id)
+                if isinstance(exc, CachePoolLostError):
+                    self._fail_live(lane, exc)
+                raise
+            seq = _Sequence(req, seq_id, backend)
+            if _metrics.metrics_enabled():
+                lane.m_table_rows.inc()
+            seq.length = t
+            if resume is None:
+                first = int(_np.argmax(logits))
+                req._push(first)
+                wake = req._deliver()  # a first token waits for nothing
+                if wake is not None:
+                    wake()
+                seq.last_token = first
+                seq.new_tokens = 1
+            else:
+                # resumed sequence: tokens so far already streamed; the next
+                # decode step continues from the last generated token
+                seq.last_token = int(req.generated[-1])
+                seq.new_tokens = resume.new_tokens
+            req.seq_id = seq_id
+            lane.active.append(seq)
+            if _metrics.metrics_enabled():
+                lane.m_prefill.observe(time.monotonic() - t0, req.trace)
 
     @staticmethod
     def _beside(lane, backend, call, *args, run_ahead=False):
@@ -1475,29 +1521,30 @@ class GenerationScheduler(object):
         live = lane.active
         n = len(live)
         bucket = lane.entry.pick_bucket(n)
-        if lane.seated != live or len(lane.tables) != bucket:
-            # the batch gained or lost a sequence: its table is put
-            # together anew from the rows the sequences brought, as a
-            # new array (the one handed over before is never written:
-            # :meth:`LMBackend.decode`); any other step hands the
-            # backend the very array the last one did
-            tables = _np.zeros((bucket, backend.max_blocks_per_seq),
-                               dtype=_np.int32)
-            tables[:n] = [seq.table for seq in live]
-            lane.seated, lane.tables = list(live), tables
-            # a step queued for the batch as it was answers no one: a
-            # new row may hold the old row's blocks, position and token
-            backend.drop_ahead()
-        tables = lane.tables
-        # the three vectors of a step are new arrays every step; a pad
-        # row reads position 0 and context 1
-        tokens = _np.zeros(bucket, dtype=_np.int32)
-        positions = _np.zeros(bucket, dtype=_np.int32)
-        tokens[:n] = [seq.last_token for seq in live]
-        positions[:n] = [seq.length for seq in live]
-        context = positions + 1
-        req_uids = ([s.req.trace for s in live]
-                    if _tracing.tracing_enabled() else ())
+        with _tracing.span("decode.build", cat="serving", model=name):
+            if lane.seated != live or len(lane.tables) != bucket:
+                # the batch gained or lost a sequence: its table is put
+                # together anew from the rows the sequences brought, as a
+                # new array (the one handed over before is never written:
+                # :meth:`LMBackend.decode`); any other step hands the
+                # backend the very array the last one did
+                tables = _np.zeros((bucket, backend.max_blocks_per_seq),
+                                   dtype=_np.int32)
+                tables[:n] = [seq.table for seq in live]
+                lane.seated, lane.tables = list(live), tables
+                # a step queued for the batch as it was answers no one: a
+                # new row may hold the old row's blocks, position and token
+                backend.drop_ahead()
+            tables = lane.tables
+            # the three vectors of a step are new arrays every step; a pad
+            # row reads position 0 and context 1
+            tokens = _np.zeros(bucket, dtype=_np.int32)
+            positions = _np.zeros(bucket, dtype=_np.int32)
+            tokens[:n] = [seq.last_token for seq in live]
+            positions[:n] = [seq.length for seq in live]
+            context = positions + 1
+            req_uids = ([s.req.trace for s in live]
+                        if _tracing.tracing_enabled() else ())
         out = None
         last_exc = None
         for attempt in range(default_retries() + 1):
@@ -1548,34 +1595,35 @@ class GenerationScheduler(object):
                     "model %r: decode step failed after %d attempts: %s"
                     % (name, default_retries() + 1, last_exc)))
             return
-        cold = out[3]
-        # what is served is what the next step is fed: the ids the
-        # decode program chose from these very logits
-        tokens_out = backend.greedy_ids
-        now = time.monotonic()
-        lane.steps += 1
-        lane.rows += n
-        lane.slots += bucket
-        lane.max_step_rows = max(lane.max_step_rows, n)
-        if _metrics.metrics_enabled():
-            lane.m_steps.inc()
-            lane.m_context.inc(int(context[:n].sum()))
-            lane.m_occ.set(n / float(bucket))
-            if cold:
-                lane.m_compiles.inc()
-        for i, seq in enumerate(live):
-            seq.length += 1
-            tok = int(tokens_out[i])
-            seq.req._push(tok)
-            seq.last_token = tok
-            seq.new_tokens += 1
-            lane.tokens += 1
+        with _tracing.span("decode.publish", cat="serving", model=name):
+            cold = out[3]
+            # what is served is what the next step is fed: the ids the
+            # decode program chose from these very logits
+            tokens_out = backend.greedy_ids
+            now = time.monotonic()
+            lane.steps += 1
+            lane.rows += n
+            lane.slots += bucket
+            lane.max_step_rows = max(lane.max_step_rows, n)
             if _metrics.metrics_enabled():
-                lane.m_tokens.inc()
-                if seq.req._h_tokens is not None:
-                    seq.req._h_tokens.inc()
-                lane.m_itl.observe(now - seq.t_last_token, seq.req.trace)
-            seq.t_last_token = now
+                lane.m_steps.inc()
+                lane.m_context.inc(int(context[:n].sum()))
+                lane.m_occ.set(n / float(bucket))
+                if cold:
+                    lane.m_compiles.inc()
+            for i, seq in enumerate(live):
+                seq.length += 1
+                tok = int(tokens_out[i])
+                seq.req._push(tok)
+                seq.last_token = tok
+                seq.new_tokens += 1
+                lane.tokens += 1
+                if _metrics.metrics_enabled():
+                    lane.m_tokens.inc()
+                    if seq.req._h_tokens is not None:
+                        seq.req._h_tokens.inc()
+                    lane.m_itl.observe(now - seq.t_last_token, seq.req.trace)
+                seq.t_last_token = now
 
     # -- lifecycle ----------------------------------------------------
 

@@ -28,7 +28,10 @@ import time
 _HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(_HERE)
 sys.path.insert(0, ROOT)
-sys.path.insert(0, os.path.join(ROOT, "examples", "image_classification"))
+# appended, not put first: the directory holds a ``benchmark.py``, which
+# at the head of the path would hide the repo's ``benchmark`` package
+# from whatever imports it later in this process
+sys.path.append(os.path.join(ROOT, "examples", "image_classification"))
 
 import numpy as np
 
