@@ -6,10 +6,12 @@ cover natively (§7.10).  Long sequences in the reference are handled only by
 bucketing and model-parallel LSTM; here they are handled the TPU way:
 
 * ``flash_attention`` — blockwise-softmax attention.  On TPU the forward is a
-  Pallas kernel (one VMEM pass per query block, online softmax, MXU matmuls)
-  and the backward is a pair of Pallas kernels (a dk/dv pass and a dq pass,
-  both O(block) VMEM, reusing the forward's saved log-sum-exp); elsewhere a
-  numerically identical jax fallback runs.
+  Pallas kernel (online softmax, MXU matmuls) and the backward is a pair of
+  Pallas kernels (a dk/dv pass and a dq pass, reusing the forward's saved
+  log-sum-exp), all O(block) VMEM; each walks, inside the program, only
+  the tiles of the score square a causal row can see (``causal_walk``;
+  gauge ``flash_attention_walked_share``).  Elsewhere a numerically
+  identical jax fallback runs.
 * ``ring_attention`` — context-parallel attention for sequences sharded along
   a mesh ``seq`` axis: K/V blocks rotate around the ring via ``ppermute``
   while each device's query block folds them into an online softmax.  Used
@@ -37,6 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import platform as _platform
+from ..observability import metrics as _metrics
 from .fused.parity import case_rng, register_parity
 from .registry import ParamSpec as P, register
 
@@ -45,16 +48,20 @@ __all__ = ["flash_attention", "ring_attention", "stable_causal_attention",
            "NEG_INF"]
 
 NEG_INF = -1e30
-# Mosaic tiles the last two block dims as (8 sublanes, 128 lanes); per-row
-# vectors (lse, delta) cross pallas_call boundaries broadcast over a
-# 128-lane trailing dim (the layout jax's own TPU flash kernel uses).
+# Mosaic tiles the last two block dims as (8 sublanes, 128 lanes).  Inside
+# a kernel a per-row vector (m, l, lse, delta) is held across a 128-lane
+# trailing dim (the layout jax's own TPU flash kernel uses); across
+# pallas_call boundaries it is a [1, T] row, 128 times less to write
+# and read, and a kernel turns it on its way in or out.
 _LANE = 128
 
 
-def _causal_mask(bq, bk, q_offset, k_offset):
-    """Boolean [bq, bk] mask: query global pos >= key global pos."""
-    qi = q_offset + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    ki = k_offset + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+def _causal_mask(bq, bk, q_offset, k_offset, keys_first=False):
+    """Boolean [bq, bk] mask: query global pos >= key global pos
+    (``keys_first``: the same, [bk, bq])."""
+    shape, rows, cols = ((bk, bq), 1, 0) if keys_first else ((bq, bk), 0, 1)
+    qi = q_offset + lax.broadcasted_iota(jnp.int32, shape, rows)
+    ki = k_offset + lax.broadcasted_iota(jnp.int32, shape, cols)
     return qi >= ki
 
 
@@ -159,8 +166,8 @@ def latent_prefill_attention(q, k, v, sm_scale):
     v_dim]`` narrower than the keys.  Never holds ``[H, T, T]`` scores
     where the flash kernel runs (a TPU, T >= 1024); below that, and
     elsewhere, the exact softmax."""
-    with jax.named_scope("latent_prefill_attention"):
-        return _flash_dispatch(q, k, v, True, float(sm_scale), False)
+    return _flash_dispatch(q, k, v, True, float(sm_scale), False,
+                           scope="latent_prefill_attention")
 
 
 def gqa_prefill_attention(q, k, v, sm_scale):
@@ -172,10 +179,159 @@ def gqa_prefill_attention(q, k, v, sm_scale):
     where the flash kernel runs (a TPU, T >= 1024); below that, and
     elsewhere, the exact softmax."""
     per = q.shape[1] // k.shape[1]
-    with jax.named_scope("gqa_prefill_attention"):
-        return _flash_dispatch(q, jnp.repeat(k, per, axis=1),
-                               jnp.repeat(v, per, axis=1), True,
-                               float(sm_scale), False)
+    return _flash_dispatch(q, jnp.repeat(k, per, axis=1),
+                           jnp.repeat(v, per, axis=1), True,
+                           float(sm_scale), False,
+                           scope="gqa_prefill_attention")
+
+
+# ----------------------------------------------------------------------
+# the walk: which chunks of the other axis a run of rows (or keys) meets
+# ----------------------------------------------------------------------
+#
+# A flash program keeps a block of each axis in VMEM and walks one of
+# them in chunks *inside* the program, a run of rows (or keys) of the
+# other at a time.  Under a causal mask the walk is bounded by the
+# diagonal: a run of query rows meets the key chunks that begin at or
+# before its last row, and only those the diagonal cuts are masked (the
+# ones wholly below it take no iota, no compare and no select); a run of
+# keys meets the query chunks from the diagonal down.  A walk is a tuple
+# of ``(chunk, masked)`` tiles, known when the kernel is built: the
+# programs of a grid make only a few different walks (on the diagonal,
+# below it, at a ragged tail), each is unrolled in the kernel under a
+# test of ``program_id``, and the whole schedule is the compiler's (a
+# ``lax.fori_loop`` with its trip count from ``program_id`` walked the
+# same tiles 1.6-2.3 times slower on the v5e: PERF.md §6, PR 38).
+
+
+def _chunks_under(x, chunk, n, partly=False):
+    """Of ``n`` chunks of ``chunk`` from 0: how many end at or below
+    ``x`` (``partly``: begin below it)."""
+    if partly:
+        x = x + chunk - 1
+    return min(max(x, 0) // chunk, n)
+
+
+def _key_walk(row0, rows, chunk, n, causal, kv_len=None):
+    """The walk of the query rows ``[row0, row0 + rows)`` over ``n`` key
+    chunks, rows counted from the first key: the chunks every row sees
+    in full, then those cut by the diagonal (or by a ragged tail at
+    ``kv_len``), which take the mask."""
+    whole = end = n
+    if causal:
+        whole = _chunks_under(row0 + 1, chunk, n)
+        end = _chunks_under(row0 + rows, chunk, n, partly=True)
+    if kv_len is not None:
+        whole = min(whole, _chunks_under(kv_len, chunk, n))
+        end = min(end, _chunks_under(kv_len, chunk, n, partly=True))
+    return tuple((c, c >= whole) for c in range(end))
+
+
+def _query_walk(col0, cols, chunk, n, causal):
+    """The walk of the keys ``[col0, col0 + cols)`` over ``n`` query
+    chunks, keys counted from the first query row: from the diagonal
+    down, first the chunks it cuts, then those that see every key."""
+    begin = whole = 0
+    if causal:
+        begin = _chunks_under(col0, chunk, n)
+        whole = _chunks_under(col0 + cols - 1, chunk, n, partly=True)
+    return tuple((c, c < whole) for c in range(begin, n))
+
+
+def causal_walk(T, Tk, run, chunk, causal=True, keys_resident=False):
+    """``(walked, masked, pairs)``: of the ``pairs`` (run, chunk) tiles
+    of a ``T x Tk`` score square, how many a kernel computes and how
+    many of those it masks.  Query runs over key chunks (the forward and
+    the dQ pass), or with ``keys_resident`` key runs over query chunks
+    (the dK/dV pass).  Counted with the walks the kernels unroll."""
+    if keys_resident:
+        n = -(-T // chunk)
+        walks = [_query_walk(col0, run, chunk, n, causal)
+                 for col0 in range(0, Tk, run)]
+    else:
+        n = -(-Tk // chunk)
+        walks = [_key_walk(row0, run, chunk, n, causal,
+                           Tk if Tk % chunk else None)
+                 for row0 in range(0, T, run)]
+    return (sum(len(w) for w in walks),
+            sum(masked for w in walks for _, masked in w), len(walks) * n)
+
+
+_M_WALKED = _metrics.gauge(
+    "flash_attention_walked_share",
+    "Share of the score square's (run, chunk) tiles the flash kernel "
+    "built last computes (the rest lie above the causal diagonal), by "
+    "kernel: fwd, dkdv, dq", ["kernel"])
+
+
+def _grid_walks(n_q, n_k, block_q, block_k, walks_of, causal, tailed=False):
+    """The different walks the programs of an ``n_q x n_k`` grid make:
+    ``[(walks, lead, last, test)]``.  ``walks_of(lead, last)`` gives a
+    program's walks (one a run) from ``lead``, its first query row
+    counted from its first key, and whether its key block is the last
+    (which matters where the keys are ``tailed``: padded past a ragged
+    end); ``test(qi, ki)`` is true in the programs that make ``walks``
+    (python ints in, a python bool out).  Programs that walk nothing
+    are in no group; a group the diagonal cuts has one ``lead``."""
+    groups = {}
+    for i in range(n_q):
+        for j in range(n_k):
+            lead = i * block_q - j * block_k
+            last = j == n_k - 1 if tailed else None
+            walks = walks_of(lead, last)
+            if any(walks):
+                cut = causal and any(masked for w in walks for _, masked in w)
+                groups.setdefault((walks, lead if cut else None, last),
+                                  []).append(lead)
+
+    def test(leads, last):
+        lo, hi = min(leads), max(leads)
+
+        def at(qi, ki):
+            lead = qi * block_q - ki * block_k
+            # walks move one way with lead: a group is a range of it
+            ok = lead == lo if lo == hi else (lead >= lo) & (lead <= hi)
+            if last is None:
+                return ok
+            return ok & (ki == n_k - 1 if last else ki != n_k - 1)
+        return at
+
+    return [(walks, min(leads), last, test(leads, last))
+            for (walks, _, last), leads in groups.items()]
+
+
+def _lanes(x, n):
+    """``[rows, n]`` of a per-row value held across ``_LANE`` lanes."""
+    if n <= _LANE:
+        return x[:, :n]
+    if n % _LANE == 0:
+        return jnp.concatenate([x] * (n // _LANE), axis=1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _row(x):
+    """The ``[1, rows]`` row of a per-row value held across ``_LANE``
+    lanes: the form it crosses a ``pallas_call`` boundary in."""
+    rows = x.shape[0]
+    if rows % _LANE:
+        return x.T[:1, :]
+    # lane j of rows 128 g + j is kept and the sublanes summed: a select
+    # and an add a vreg where the transpose costs three times that
+    eye = (lax.broadcasted_iota(jnp.int32, (_LANE, _LANE), 0)
+           == lax.broadcasted_iota(jnp.int32, (_LANE, _LANE), 1))
+    return jnp.concatenate(
+        [jnp.sum(jnp.where(eye, x[g:g + _LANE, :], 0.0), axis=0,
+                 keepdims=True) for g in range(0, rows, _LANE)], axis=1)
+
+
+def _fit(T, block, *units):
+    """``(block, units)`` for an axis of ``T`` cut by ``units``: a short
+    axis is one block, of the whole units it fills or, under one unit,
+    of itself; a unit that does not divide the block becomes the
+    block."""
+    most = max(units)
+    block = max(8, T) if T <= most else min(block, -(-T // most) * most)
+    return block, tuple(u if block % u == 0 else block for u in units)
 
 
 # ----------------------------------------------------------------------
@@ -184,19 +340,22 @@ def gqa_prefill_attention(q, k, v, sm_scale):
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
-                  sm_scale, causal, block_q, block_k, n_k, kv_len):
+                  sm_scale, causal, rows, chunk, n_q, n_k, walks, tail):
     """One (batch*head, q-block, k-block) program of the online softmax.
 
     The k-block grid dimension is sequential ("arbitrary"); VMEM scratch
     (m/l/acc) carries the running max, denominator, and weighted sum across
     k steps, so VMEM holds only one q-block and one k/v-block at a time —
-    sequence length is bounded by HBM, not the 16 MB VMEM (the previous
-    kernel staged all of K/V per program and capped out near T=8K).
+    sequence length is bounded by HBM, not the 16 MB VMEM.  Inside the
+    program each run of ``rows`` query rows walks the key block in
+    chunks of ``chunk`` (``walks``, of ``_grid_walks``): the scores in
+    flight are ``[rows, chunk]``, never the whole block pair.  ``m`` and
+    ``l`` are held across ``_LANE`` lanes, the form the log-sum-exp
+    leaves in.
 
     ``rest`` is ``(lse_ref, m_scr, l_scr, acc_scr)`` when the caller asked
     for the log-sum-exp residual (the VJP forward) and just the three
-    scratch refs otherwise — the primal/inference path skips the extra
-    [bq, 128] HBM write entirely."""
+    scratch refs otherwise."""
     import jax.experimental.pallas as pl
 
     if len(rest) == 4:
@@ -204,9 +363,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
     else:
         lse_ref = None
         m_scr, l_scr, acc_scr = rest
-
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    block_k, dv = k_ref.shape[1], v_ref.shape[2]
+    qi = pl.program_id(1) if n_q > 1 else 0
+    ki = pl.program_id(2) if n_k > 1 else 0
 
     @pl.when(ki == 0)
     def _init():
@@ -214,89 +373,105 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    if causal:
-        # skip blocks entirely above the diagonal
-        run = ki * block_k <= qi * block_q + block_q - 1
-    else:
-        run = True
-
-    @pl.when(run)
-    def _compute():
+    def fold(run, q, at, keep):
         # matmuls take the INPUT dtype (bf16 rides the MXU at full rate;
         # an fp32 pre-cast would quarter it) and accumulate fp32; all
         # softmax math stays fp32
-        q = q_ref[0]  # [bq, D]
-        k = k_ref[0]  # [bk, D]
-        v = v_ref[0]
+        v = v_ref[0, at, :]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [bq, bk]
-        mask = None
-        if causal:
-            mask = _causal_mask(block_q, block_k, qi * block_q, ki * block_k)
-        if kv_len % block_k:
-            # ragged tail: padded key columns contribute nothing
-            col = ki * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            valid = col < kv_len
-            mask = valid if mask is None else (mask & valid)
-        if mask is not None:
-            s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        if mask is not None:
-            p = jnp.where(mask, p, 0.0)
+            q, k_ref[0, at, :], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale  # [rows, chunk]
+        if keep is not None:
+            # a row's walk begins at a key it sees (column 0), so its
+            # running max is finite before any chunk masks it whole,
+            # and exp() sends the masked scores to exact 0
+            s = jnp.where(keep, s, NEG_INF)
+        m_prev = m_scr[run, :]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, chunk))
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        l_scr[run, :] = l_scr[run, :] * alpha + jnp.sum(
+            p, axis=1, keepdims=True)
+        acc_scr[run, :] = (
+            acc_scr[run, :] * _lanes(alpha, dv) + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        m_scr[run, :] = m_new
+
+    def walk(runs, lead, last):
+        # the keys the block holds: ``tail`` in the last, padded past them
+        held = tail if last else block_k
+        for a, tiles in enumerate(runs):
+            run = pl.ds(a * rows, rows)
+            q = q_ref[0, run, :]
+            for c, masked in tiles:
+                keep = None
+                if masked and causal:
+                    keep = _causal_mask(rows, chunk, lead + a * rows,
+                                        c * chunk)
+                if (c + 1) * chunk > held:
+                    # ragged tail: padded key columns contribute nothing
+                    valid = c * chunk + lax.broadcasted_iota(
+                        jnp.int32, (rows, chunk), 1) < held
+                    keep = valid if keep is None else keep & valid
+                fold(run, q, pl.ds(c * chunk, chunk), keep)
+
+    for runs, lead, last, test in walks:
+        pl.when(test(qi, ki))(functools.partial(walk, runs, lead, last))
 
     @pl.when(ki == n_k - 1)
     def _finish():
         l = l_scr[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / _lanes(l, dv)).astype(o_ref.dtype)
         if lse_ref is not None:
             # log-sum-exp residual for the backward kernels (padded rows
-            # get -inf + 0; they are sliced off before use).  Broadcast
-            # across a 128-lane trailing dim: Mosaic requires the last two
-            # block dims to tile (8, 128), so a per-row vector rides as
-            # [bq, 128] (the layout jax's own TPU flash kernel uses for
-            # its l/m residuals).
-            lse = m_scr[...] + jnp.log(l)
-            lse_ref[0] = jnp.broadcast_to(lse[:, None], lse_ref[0].shape)
+            # get -inf + 0; they are sliced off before use)
+            lse_ref[0] = _row(m_scr[...] + jnp.log(l))
 
 
-def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q=1024, block_k=2048,
-                      interpret=False, return_lse=False):
+def _flash_blocks(Tk, D):
+    """``(block_q, block_k, rows, chunk)`` of the forward: the blocks a
+    program keeps, and the run of query rows and the chunk of keys it
+    walks them by.  From ``tools/flash_sweep.py`` on the attached v5e
+    (PERF.md §6, PR 38, holds its tables): a head of 64 is quickest by
+    tiles of 256 x 256 (at T = 1024 a head computes 10 of 16 and masks
+    4), heads of 192/128 by tiles of 512 x 512 over key blocks of 2048:
+    the wider the head, the more of a tile's time is the array's and
+    the less a larger tile's spills cost; a head of 256 reads the same
+    either way."""
+    tile = 256 if D <= 64 else 512
+    block_k = 2048
+    if D > 192:
+        # a 256-wide head: the blocks of q, k and v and the accumulator
+        # stay well inside the v5e's 16 MiB of scoped VMEM at 1024
+        block_k = 1024
+    if Tk > block_k and Tk % block_k:
+        # a ragged key tail pads to whole blocks: smaller ones pad less
+        block_k = 1024
+    return 1024, block_k, tile, tile
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "sm_scale", "interpret", "return_lse", "blocks", "scope"))
+def _flash_fwd_pallas(q, k, v, causal, sm_scale, interpret=False,
+                      return_lse=False, blocks=None, scope=None):
     """Pallas forward on [B, H, T, D].  T is padded to block multiples.
+    Jitted so that a model's layers share one trace and one lowering of
+    the kernel; ``scope`` names it in a device trace.
 
-    Default blocks re-tuned r5 on a v5e (a sweep at b8h16d64, by a
-    probe that is gone): (1024, 2048) beats the old (512, 1024) by 4-14% across
-    T=1024..8192 (e.g. 16.6 -> 14.9 ms at T4096); the backward kernels
-    keep (1024, 1024) — their dk/dv pass at block_k=2048 exceeds what
-    the compiler will schedule."""
+    ``blocks`` is ``(block_q, block_k, rows, chunk)``; left out, as
+    every caller but the sweep and the tests leaves it, ``_flash_blocks``
+    chooses from what it can see (``Tk``, ``D``)."""
     import jax.experimental.pallas as pl
 
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, D = q.shape
     Tk, Dv = k.shape[2], v.shape[3]   # values may be narrower than keys
-    block_q = min(block_q, max(8, T))
-    if D > 192:
-        # a 256-wide head: the blocks of q, k and v and the accumulator
-        # beside the [bq, bk] scores pass the v5e's 16 MiB of scoped
-        # VMEM at block_k=2048 (17.23 MiB at T=4096), at 1024 they fit
-        block_k = min(block_k, 1024)
-    if Tk > block_k and Tk % block_k:
-        # a ragged key tail adds a second [bq, bk] mask to the causal
-        # one; at block_k=2048 the v5e compiler refuses that kernel
-        # (17.98 MiB of 16 MiB scoped VMEM at T=2176), at 1024 it fits
-        block_k = min(block_k, 1024)
-    block_k = min(block_k, max(8, Tk))
+    block_q, block_k, rows, chunk = blocks or _flash_blocks(Tk, D)
+    block_q, (rows,) = _fit(T, block_q, rows)
+    block_k, (chunk,) = _fit(Tk, block_k, chunk)
     # ragged shapes: pad to block multiples.  Padded q rows are sliced off
     # the output; padded key columns are masked inside the kernel (kv_len).
     Tp = -(-T // block_q) * block_q
@@ -310,43 +485,63 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q=1024, block_k=2048,
     qf = q.reshape(B * H, Tp, D)
     kf = k.reshape(B * H, Tkp, D)
     vf = v.reshape(B * H, Tkp, Dv)
-    n_k = Tkp // block_k
-    grid = (B * H, Tp // block_q, n_k)
+    n_q, n_k = Tp // block_q, Tkp // block_k
+    walked, _, pairs = causal_walk(T, Tk, rows, chunk, causal)
+    _M_WALKED.labels("fwd").set(walked / pairs)
+
+    tail = Tk - (n_k - 1) * block_k    # the keys the last block holds
+
+    def walks_of(lead, last):
+        return tuple(
+            _key_walk(lead + row0, rows, chunk, block_k // chunk, causal,
+                      tail if last else None)
+            for row0 in range(0, block_q, rows))
+
     kernel = functools.partial(
-        _flash_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_k=block_k, n_k=n_k, kv_len=Tk)
+        _flash_kernel, sm_scale=sm_scale, causal=causal, rows=rows,
+        chunk=chunk, n_q=n_q, n_k=n_k, tail=tail,
+        walks=_grid_walks(n_q, n_k, block_q, block_k, walks_of, causal,
+                          Tkp != Tk))
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+    def kv_block(b, i, j):
+        # a key block wholly above the diagonal is not walked: name the
+        # last one that is, which is already there, and nothing is copied
+        if causal:
+            j = jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
+        return b, j, 0
+
     out_shape = [jax.ShapeDtypeStruct((B * H, Tp, Dv), q.dtype)]
     out_specs = [pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0))]
     if return_lse:
-        out_shape.append(
-            jax.ShapeDtypeStruct((B * H, Tp, _LANE), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((B * H, 1, Tp), jnp.float32))
         out_specs.append(
-            pl.BlockSpec((1, block_q, _LANE), lambda b, i, j: (b, i, 0)))
-    res = pl.pallas_call(
-        kernel,
-        out_shape=out_shape,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, Dv), jnp.float32),
-        ],
-        interpret=interpret,
-        **kwargs,
-    )(qf, kf, vf)
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)))
+    with jax.named_scope(scope or "flash_attention"):
+        res = pl.pallas_call(
+            kernel,
+            out_shape=out_shape,
+            grid=(B * H, n_q, n_k),
+            in_specs=[
+                pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, block_k, D), kv_block),
+                pl.BlockSpec((1, block_k, Dv), kv_block),
+            ],
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((block_q, _LANE), jnp.float32),
+                pltpu.VMEM((block_q, _LANE), jnp.float32),
+                pltpu.VMEM((block_q, Dv), jnp.float32),
+            ],
+            interpret=interpret,
+            **kwargs,
+        )(qf, kf, vf)
     out = res[0].reshape(B, H, Tp, Dv)[:, :, :T]
     if return_lse:
-        return out, res[1][:, :, 0].reshape(B, H, Tp)[:, :, :T]
+        return out, res[1].reshape(B, H, Tp)[:, :, :T]
     return out
 
 
@@ -360,19 +555,21 @@ def _flash(q, k, v, causal, sm_scale, interpret):
     return _flash_dispatch(q, k, v, causal, sm_scale, interpret)
 
 
-def _flash_dispatch(q, k, v, causal, sm_scale, interpret):
+def _flash_dispatch(q, k, v, causal, sm_scale, interpret, scope=None):
     """The forward's choice of body: ``interpret`` asks for the kernel
     whatever the length (under the interpreter off the chip); else on a
     TPU the kernel from 1024 tokens (the VJP forward's threshold too;
     past 8K the blocked kernel is the only option, exact attention
-    OOMs), and the exact softmax below that and elsewhere."""
+    OOMs), and the exact softmax below that and elsewhere.  ``scope``
+    names the caller's attention in a device trace, whichever body."""
     on_chip = _platform.pallas_mode() == "chip"
     if interpret:
         return _flash_fwd_pallas(q, k, v, causal, sm_scale,
-                                 interpret=not on_chip)
+                                 interpret=not on_chip, scope=scope)
     if on_chip and (q.shape[2] >= 1024 or k.shape[2] >= 1024):
-        return _flash_fwd_pallas(q, k, v, causal, sm_scale)
-    return _attention_fwd_ref(q, k, v, causal, sm_scale)
+        return _flash_fwd_pallas(q, k, v, causal, sm_scale, scope=scope)
+    with jax.named_scope(scope or "flash_attention"):
+        return _attention_fwd_ref(q, k, v, causal, sm_scale)
 
 
 def _flash_fwd_vjp(q, k, v, causal, sm_scale, interpret):
@@ -401,77 +598,83 @@ def _flash_fwd_vjp(q, k, v, causal, sm_scale, interpret):
 # ----------------------------------------------------------------------
 
 
-def _bwd_p_ds(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, qi, kj, *,
-              sm_scale, causal, block_q, block_k, kv_len):
-    """Shared backward tile math for one (q-block, k-block) pair: the
-    attention weights ``p`` and score gradients ``ds`` plus the fp32
-    block operands.  Both bwd kernels call this, so the mask and scale
-    logic can never diverge between dq and dk/dv."""
+def _bwd_p_ds(q, do, lse, delta, k, v, cut_at, *, sm_scale,
+              keys_first=False):
+    """Shared backward tile math for one (query rows, keys) tile: the
+    attention weights ``p`` and score gradients ``ds``.  Both bwd
+    kernels call this, so the mask and scale logic can never diverge
+    between dq and dk/dv.  ``cut_at`` is the tile's ``(first row, first
+    key)`` where the diagonal cuts it, and None where every row sees
+    every key.  With ``keys_first`` both come out transposed, ``[keys,
+    rows]``, from the operands the other way round: what the dK/dV pass
+    multiplies from the left, so that neither of its products has to
+    turn a tile.  (A ragged key tail needs no mask here: the padded
+    rows of ``k`` and ``v`` are zeros, so they add nothing to ``dq``,
+    and their own ``dk``/``dv`` rows are sliced off.)"""
     # matmul operands stay in the input dtype (bf16 at full MXU rate),
-    # accumulating fp32; softmax statistics math is fp32 throughout
-    qb = q_ref[0]    # [bq, D]
-    dob = do_ref[0]  # [bq, D]
-    kb = k_ref[0]    # [bk, D]
-    vb = v_ref[0]
-    # [bq, _LANE] lane-broadcast vectors; any-lane reduce recovers them
-    lseb = jnp.max(lse_ref[0], axis=1)   # [bq] (+inf on padded q rows)
-    dlt = jnp.max(delta_ref[0], axis=1)  # [bq]
+    # accumulating fp32; softmax statistics math is fp32 throughout.
+    # lse (+inf on padded q rows) and delta are per-row vectors: held
+    # across _LANE lanes, or [1, rows] with keys_first
+    if keys_first:
+        (x, dx), (y, dy) = (k, v), (q, do)
+    else:
+        (x, dx), (y, dy) = (q, do), (k, v)
+        lse, delta = _lanes(lse, k.shape[0]), _lanes(delta, k.shape[0])
     s = jax.lax.dot_general(
-        qb, kb, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale  # [bq, bk]
-    p = jnp.exp(s - lseb[:, None])
-    mask = None
-    if causal:
-        mask = _causal_mask(block_q, block_k, qi * block_q, kj * block_k)
-    if kv_len % block_k:
-        # ragged tail: padded key columns contribute nothing
-        col = kj * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        valid = col < kv_len
-        mask = valid if mask is None else (mask & valid)
-    if mask is not None:
-        p = jnp.where(mask, p, 0.0)
+        x, y, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale
+    p = jnp.exp(s - lse)
+    if cut_at is not None:
+        p = jnp.where(_causal_mask(q.shape[0], k.shape[0], *cut_at,
+                                   keys_first), p, 0.0)
     dp = jax.lax.dot_general(
-        dob, vb, (((1,), (1,)), ((), ())),
+        dx, dy, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    ds = p * (dp - dlt[:, None]) * sm_scale
-    return p, ds, qb, dob, kb
+    ds = p * (dp - delta) * sm_scale
+    return p, ds
 
 
 def _flash_bwd_dkdv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                            dk_ref, dv_ref, dk_scr, dv_scr, *,
-                           sm_scale, causal, block_q, block_k, n_q, kv_len):
+                           sm_scale, cols, chunk, n_q, n_k, walks):
     """One (batch*head, k-block, q-block) program: k-blocks are parallel,
     q-blocks sequential; VMEM scratch accumulates dk/dv for the resident
-    k-block while q/do/lse/delta blocks stream past."""
+    k-block while q/do/lse/delta blocks stream past (lse and delta as
+    ``[1, block_q]`` rows).  Inside, each run of ``cols`` keys walks the
+    query block in chunks of ``chunk`` from the diagonal down (``walks``,
+    of ``_grid_walks``), the scores held keys first."""
     import jax.experimental.pallas as pl
 
-    kj = pl.program_id(1)
-    qi = pl.program_id(2)
+    kj = pl.program_id(1) if n_k > 1 else 0
+    qi = pl.program_id(2) if n_q > 1 else 0
 
     @pl.when(qi == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    if causal:
-        # q-blocks entirely above the diagonal contribute nothing
-        run = qi * block_q + block_q - 1 >= kj * block_k
-    else:
-        run = True
+    def walk(runs, lead):
+        for b, tiles in enumerate(runs):
+            run = pl.ds(b * cols, cols)
+            k = k_ref[0, run, :]
+            v = v_ref[0, run, :]
+            for c, masked in tiles:
+                at = pl.ds(c * chunk, chunk)
+                q = q_ref[0, at, :]
+                do = do_ref[0, at, :]
+                p, ds = _bwd_p_ds(
+                    q, do, lse_ref[0, :, at], delta_ref[0, :, at], k, v,
+                    (lead + c * chunk, b * cols) if masked else None,
+                    sm_scale=sm_scale, keys_first=True)  # [cols, chunk]
+                dv_scr[run, :] += jnp.dot(
+                    p.astype(do.dtype), do,
+                    preferred_element_type=jnp.float32)
+                dk_scr[run, :] += jnp.dot(
+                    ds.astype(q.dtype), q,
+                    preferred_element_type=jnp.float32)
 
-    @pl.when(run)
-    def _compute():
-        p, ds, qb, dob, _ = _bwd_p_ds(
-            q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, qi, kj,
-            sm_scale=sm_scale, causal=causal, block_q=block_q,
-            block_k=block_k, kv_len=kv_len)
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_scr[...] += jax.lax.dot_general(
-            ds.astype(qb.dtype), qb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    for runs, lead, _, test in walks:
+        pl.when(test(qi, kj))(functools.partial(walk, runs, lead))
 
     @pl.when(qi == n_q - 1)
     def _finish():
@@ -481,53 +684,79 @@ def _flash_bwd_dkdv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 
 def _flash_bwd_dq_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dq_scr, *,
-                         sm_scale, causal, block_q, block_k, n_k, kv_len):
+                         sm_scale, rows, chunk, n_q, n_k, walks):
     """One (batch*head, q-block, k-block) program: q-blocks parallel,
-    k-blocks sequential; scratch accumulates dq for the resident q-block."""
+    k-blocks sequential; scratch accumulates dq for the resident q-block
+    (lse and delta as ``[1, block_q]`` rows).  Inside, each run of
+    ``rows`` query rows walks the key block in chunks of ``chunk`` up to
+    the diagonal (``walks``)."""
     import jax.experimental.pallas as pl
 
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    qi = pl.program_id(1) if n_q > 1 else 0
+    kj = pl.program_id(2) if n_k > 1 else 0
 
     @pl.when(kj == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    if causal:
-        run = kj * block_k <= qi * block_q + block_q - 1
-    else:
-        run = True
+    def walk(runs, lead):
+        for a, tiles in enumerate(runs):
+            run = pl.ds(a * rows, rows)
+            q = q_ref[0, run, :]
+            do = do_ref[0, run, :]
+            # the [1, rows] rows they come as, turned to [rows, _LANE]
+            lse, delta = (jnp.broadcast_to(ref[0, :, run], (_LANE, rows)).T
+                          for ref in (lse_ref, delta_ref))
+            for c, masked in tiles:
+                at = pl.ds(c * chunk, chunk)
+                k = k_ref[0, at, :]
+                _, ds = _bwd_p_ds(
+                    q, do, lse, delta, k, v_ref[0, at, :],
+                    (lead + a * rows, c * chunk) if masked else None,
+                    sm_scale=sm_scale)
+                dq_scr[run, :] += jax.lax.dot_general(
+                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
 
-    @pl.when(run)
-    def _compute():
-        _, ds, _, _, kb = _bwd_p_ds(
-            q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, qi, kj,
-            sm_scale=sm_scale, causal=causal, block_q=block_q,
-            block_k=block_k, kv_len=kv_len)
-        dq_scr[...] += jax.lax.dot_general(
-            ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    for runs, lead, _, test in walks:
+        pl.when(test(qi, kj))(functools.partial(walk, runs, lead))
 
     @pl.when(kj == n_k - 1)
     def _finish():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
+# ``(block_q, block_k, dkdv, dq)`` of the backward, the last two the
+# ``(query rows, keys)`` tile of each pass: 256 x 256 in both, the
+# quickest of the sweep at a head of 64, the one width a cell trains at
+# (PERF.md §6, PR 38; wider heads are not measured)
+_BWD_BLOCKS = (1024, 1024, (256, 256), (256, 256))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "sm_scale", "interpret", "blocks"))
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale,
-                      block_q=1024, block_k=1024, interpret=False):
+                      interpret=False, blocks=None):
     """Two-pass Pallas flash backward on [B, H, T, D]: a dk/dv kernel and
     a dq kernel, each O(block) VMEM — the backward twin of
-    ``_flash_fwd_pallas`` (ends the plain-jax recompute that MFU-capped
-    the transformer bench; the measured figure lives in the
-    ``model_flops_utilization`` gauge / bench.py's ``mfu`` key, not
-    here — see docs/PERF.md "MFU is measured, not quoted")."""
+    ``_flash_fwd_pallas``, jitted as it is (ends the plain-jax recompute
+    that MFU-capped the transformer bench; the measured figure lives in
+    the ``model_flops_utilization`` gauge / bench.py's ``mfu`` key, not
+    here — see docs/PERF.md "MFU is measured, not quoted").
+
+    ``blocks`` is ``(block_q, block_k, dkdv, dq)``, the last two the
+    ``(query rows, keys)`` tile each pass walks by: the dK/dV pass runs
+    of that many keys over chunks of that many query rows, the dQ pass
+    the other way.  Left out, as every caller but the sweep and the
+    tests leaves it, it is ``_BWD_BLOCKS``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, D = q.shape
-    Tk = k.shape[2]
-    bq = min(block_q, max(8, T))
-    bk = min(block_k, max(8, Tk))
+    Tk, Dv = k.shape[2], v.shape[3]
+    bq, bk, dkdv, dq_tile = blocks or _BWD_BLOCKS
+    bq, (chunk_q, rows) = _fit(T, bq, dkdv[0], dq_tile[0])
+    bk, (cols, chunk_k) = _fit(Tk, bk, dkdv[1], dq_tile[1])
     Tp = -(-T // bq) * bq
     Tkp = -(-Tk // bk) * bk
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
@@ -547,58 +776,83 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale,
         v = jnp.pad(v, pad3)
     BH = B * H
     qf = q.reshape(BH, Tp, D)
-    dof = do.reshape(BH, Tp, D)
+    dof = do.reshape(BH, Tp, Dv)
     kf = k.reshape(BH, Tkp, D)
-    vf = v.reshape(BH, Tkp, D)
-    # per-row vectors cross as [BH, Tp, _LANE] lane-broadcasts (tiling rule)
-    lsef = jnp.broadcast_to(lse.reshape(BH, Tp, 1), (BH, Tp, _LANE))
-    deltaf = jnp.broadcast_to(delta.reshape(BH, Tp, 1), (BH, Tp, _LANE))
+    vf = v.reshape(BH, Tkp, Dv)
+    # per-row vectors cross as [BH, 1, Tp] rows: what the dK/dV pass,
+    # whose scores lie keys first, takes as they are
+    lsef, deltaf = lse.reshape(BH, 1, Tp), delta.reshape(BH, 1, Tp)
     n_q = Tp // bq
     n_k = Tkp // bk
+    for name, walk in (
+            ("dkdv", causal_walk(T, Tk, cols, chunk_q, causal, True)),
+            ("dq", causal_walk(T, Tk, rows, chunk_k, causal))):
+        _M_WALKED.labels(name).set(walk[0] / walk[2])
 
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
 
+    def key_runs(lead, _):
+        return tuple(_query_walk(col0 - lead, cols, chunk_q, bq // chunk_q,
+                                 causal) for col0 in range(0, bk, cols))
+
+    def row_runs(lead, _):
+        return tuple(_key_walk(lead + row0, rows, chunk_k, bk // chunk_k,
+                               causal) for row0 in range(0, bq, rows))
+
+    # blocks wholly above the diagonal are not walked: their index names
+    # the nearest block that is, and nothing is copied for them
+    def q_block(b, j, i):
+        return b, jnp.maximum(i, j * bk // bq) if causal else i, 0
+
+    def q_row(b, j, i):
+        return b, 0, q_block(b, j, i)[1]
+
+    def kv_block(b, i, j):
+        return b, jnp.minimum(j, ((i + 1) * bq - 1) // bk) if causal else j, 0
+
     dkdv_kernel = functools.partial(
-        _flash_bwd_dkdv_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=bq, block_k=bk, n_q=n_q, kv_len=Tk)
+        _flash_bwd_dkdv_kernel, sm_scale=sm_scale, cols=cols,
+        chunk=chunk_q, n_q=n_q, n_k=n_k,
+        walks=_grid_walks(n_q, n_k, bq, bk, key_runs, causal))
     dk, dv = pl.pallas_call(
         dkdv_kernel,
         out_shape=[jax.ShapeDtypeStruct((BH, Tkp, D), k.dtype),
-                   jax.ShapeDtypeStruct((BH, Tkp, D), v.dtype)],
+                   jax.ShapeDtypeStruct((BH, Tkp, Dv), v.dtype)],
         grid=(BH, n_k, n_q),
         in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0)),      # q
-            pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0)),      # do
-            pl.BlockSpec((1, bq, _LANE), lambda b, j, i: (b, i, 0)),  # lse
-            pl.BlockSpec((1, bq, _LANE), lambda b, j, i: (b, i, 0)),  # delta
+            pl.BlockSpec((1, bq, D), q_block),                        # q
+            pl.BlockSpec((1, bq, Dv), q_block),                       # do
+            pl.BlockSpec((1, 1, bq), q_row),                          # lse
+            pl.BlockSpec((1, 1, bq), q_row),                          # delta
             pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),      # k
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),      # v
+            pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0)),     # v
         ],
         out_specs=[pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-                   pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0))],
+                   pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0))],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
+                        pltpu.VMEM((bk, Dv), jnp.float32)],
         interpret=interpret,
         **kwargs,
     )(qf, dof, lsef, deltaf, kf, vf)
 
     dq_kernel = functools.partial(
-        _flash_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=bq, block_k=bk, n_k=n_k, kv_len=Tk)
+        _flash_bwd_dq_kernel, sm_scale=sm_scale, rows=rows, chunk=chunk_k,
+        n_q=n_q, n_k=n_k,
+        walks=_grid_walks(n_q, n_k, bq, bk, row_runs, causal))
     dq = pl.pallas_call(
         dq_kernel,
         out_shape=jax.ShapeDtypeStruct((BH, Tp, D), q.dtype),
         grid=(BH, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),      # k
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),      # v
+            pl.BlockSpec((1, bk, D), kv_block),                       # k
+            pl.BlockSpec((1, bk, Dv), kv_block),                      # v
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),      # q
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),      # do
-            pl.BlockSpec((1, bq, _LANE), lambda b, i, j: (b, i, 0)),  # lse
-            pl.BlockSpec((1, bq, _LANE), lambda b, i, j: (b, i, 0)),  # delta
+            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),     # do
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),      # lse
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),      # delta
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
@@ -608,7 +862,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale,
 
     dq = dq.reshape(B, H, Tp, D)[:, :, :T]
     dk = dk.reshape(B, H, Tkp, D)[:, :, :Tk]
-    dv = dv.reshape(B, H, Tkp, D)[:, :, :Tk]
+    dv = dv.reshape(B, H, Tkp, Dv)[:, :, :Tk]
     return dq, dk, dv
 
 

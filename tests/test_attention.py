@@ -10,8 +10,9 @@ import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx
-from mxnet_tpu.ops.attention import (_attention_fwd_ref, flash_attention,
-                                     ring_attention)
+from mxnet_tpu.ops import attention as att
+from mxnet_tpu.ops.attention import (_attention_fwd_ref, causal_walk,
+                                     flash_attention, ring_attention)
 
 
 def _rand_qkv(b=2, h=2, t=128, d=32, seed=0):
@@ -57,7 +58,8 @@ def test_flash_gradients_match_reference(causal):
 
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("t,tk", [(64, 64), (1024, 1024), (72, 72),
-                                  (128, 96)])
+                                  (128, 96), (256, 256), (512, 512),
+                                  (640, 640)])
 def test_flash_pallas_backward_kernels(causal, t, tk):
     """The Pallas bwd kernels themselves (dk/dv pass + dq pass) in
     interpret mode — the path TPU hardware runs.  Without interpret=True
@@ -82,6 +84,135 @@ def test_flash_pallas_backward_kernels(causal, t, tk):
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
+
+
+# (T, Tk, D, Dv, causal, forward blocks (block_q, block_k, rows, chunk),
+#  backward blocks (block_q, block_k, dK/dV tile, dQ tile)): the walk at
+# sizes the interpreter affords, by explicit small blocks
+_WALKS = {
+    # one block of each axis: the walk is unrolled; 1, 2 and 4 chunks
+    "unrolled-1-chunk": (16, 16, 16, 16, True, (64, 64, 16, 16),
+                         (64, 64, (16, 16), (16, 16))),
+    "unrolled-2-chunks": (32, 32, 16, 16, True, (64, 64, 16, 16),
+                          (64, 64, (16, 16), (16, 16))),
+    "unrolled-4-chunks": (64, 64, 16, 16, True, (64, 64, 16, 16),
+                          (64, 64, (16, 16), (16, 16))),
+    # several blocks: the bounds come from program_id
+    "grid-4x2-blocks": (128, 128, 16, 16, True, (32, 64, 16, 16),
+                        (32, 64, (16, 16), (16, 16))),
+    "grid-8-chunks-a-block": (128, 128, 16, 16, True, (64, 128, 32, 16),
+                              (128, 64, (16, 32), (32, 16))),
+    # runs and chunks of different sizes: the diagonal cuts mid-chunk
+    "diagonal-mid-chunk": (64, 64, 16, 16, True, (64, 64, 16, 32),
+                           (64, 64, (32, 16), (16, 32))),
+    "diagonal-mid-chunk-grid": (96, 96, 16, 16, True, (32, 64, 8, 32),
+                                (32, 32, (32, 8), (8, 32))),
+    # ragged T: 67 and 200 as they are, 2176 = 2 x 1024 + 128 as 68
+    "ragged-67": (67, 67, 16, 16, True, (32, 64, 16, 16),
+                  (32, 32, (16, 16), (16, 16))),
+    "ragged-200": (200, 200, 8, 8, True, (64, 128, 32, 32),
+                   (64, 64, (32, 16), (16, 32))),
+    "ragged-2176-scaled": (68, 68, 16, 16, True, (32, 64, 8, 8),
+                           (32, 32, (8, 8), (8, 8))),
+    "ragged-67-full": (67, 67, 16, 16, False, (32, 64, 16, 16),
+                       (32, 32, (16, 16), (16, 16))),
+    "full-4-chunks": (64, 64, 16, 16, False, (64, 64, 16, 16),
+                      (64, 64, (16, 16), (16, 16))),
+    "cross-longer-queries": (128, 96, 16, 16, False, (64, 64, 16, 32),
+                             (64, 32, (16, 32), (32, 16))),
+    "cross-longer-keys": (48, 80, 16, 16, False, (16, 32, 16, 16),
+                          (16, 32, (16, 16), (16, 16))),
+    "causal-longer-keys": (96, 128, 16, 16, True, (64, 64, 16, 32),
+                           (64, 32, (16, 32), (32, 16))),
+    "causal-ragged-shorter-keys": (128, 80, 16, 16, True, (64, 64, 16, 32),
+                                   (64, 32, (16, 32), (32, 16))),
+    "narrow-values": (64, 64, 32, 16, True, (32, 64, 16, 16),
+                      (32, 32, (16, 16), (16, 16))),
+    "narrow-values-ragged": (72, 72, 24, 8, True, (32, 32, 16, 8),
+                             (32, 32, (8, 16), (16, 8))),
+    "the-rule": (72, 72, 16, 16, True, None, None),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_WALKS))
+def test_flash_walk_matches_reference(case, dtype):
+    """The three kernels by explicit blocks (interpret mode): output,
+    log-sum-exp and all three gradients against the exact softmax and
+    its VJP."""
+    T, Tk, D, Dv, causal, fwd_blocks, bwd_blocks = _WALKS[case]
+    rng = np.random.RandomState(len(case))
+    q, k, v, do = (jnp.asarray(rng.normal(size=(1, 2, t, d)), dtype)
+                   for t, d in ((T, D), (Tk, D), (Tk, Dv), (T, Dv)))
+    scale = D ** -0.5
+    tol = 2e-5 if dtype == "float32" else 3e-2
+    ref, vjp = jax.vjp(
+        lambda q, k, v: _attention_fwd_ref(q, k, v, causal, scale), q, k, v)
+    _, ref_lse = _attention_fwd_ref(q, k, v, causal, scale, return_lse=True)
+    out, lse = att._flash_fwd_pallas(q, k, v, causal, scale, interpret=True,
+                                     return_lse=True, blocks=fwd_blocks)
+    assert out.dtype == q.dtype and out.shape == (1, 2, T, Dv)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                               rtol=tol, atol=tol)
+    grads = att._flash_bwd_pallas(q, k, v, ref, ref_lse, do, causal, scale,
+                                  interpret=True, blocks=bwd_blocks)
+    for got, want in zip(grads, vjp(do)):
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=5 * tol, atol=5 * tol)
+
+
+@pytest.mark.parametrize("args,walked,masked,pairs", [
+    # by hand at 1024 / 256: query run i of 4 meets key chunks 0..i, the
+    # last of them on the diagonal: 1 + 2 + 3 + 4 of 16, 4 masked
+    ((1024, 1024, 256, 256), 10, 4, 16),
+    ((1024, 1024, 256, 256, False), 16, 0, 16),
+    # the dK/dV pass from the other side: key run j meets query chunks j..3
+    ((1024, 1024, 256, 256, True, True), 10, 4, 16),
+    # runs of 512 over chunks of 256: run 0 meets chunks 0 and 1 and is
+    # cut by both; run 1 sees 0 and 1 whole and is cut by 2 and 3
+    ((1024, 1024, 512, 256), 6, 4, 8),
+    ((1024, 1024, 256, 128), 20, 8, 32),
+    ((1024, 1024, 128, 128), 36, 8, 64),
+    ((1024, 1024, 1024, 1024), 1, 1, 1),
+    # ragged keys, no mask but the tail's: 5 chunks a run, the last cut
+    ((128, 136, 64, 32, False), 10, 2, 10),
+    # a prefill bucket of 2048 by runs and chunks of 256: 36 of 64
+    ((2048, 2048, 256, 256), 36, 8, 64),
+    ((2048, 2048, 256, 256, True, True), 36, 8, 64),
+])
+def test_causal_walk_by_hand(args, walked, masked, pairs):
+    assert causal_walk(*args) == (walked, masked, pairs)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_walked_share_gauge_reads_what_the_walk_says(causal):
+    """``flash_attention_walked_share{kernel}`` is set when a kernel is
+    built, to the share ``causal_walk`` counts for its blocks."""
+    from mxnet_tpu.observability import metrics
+
+    q, k, v = _rand_qkv(b=1, h=1, t=64, d=16)
+    out, lse = att._flash_fwd_pallas(
+        q, k, v, causal, 0.25, interpret=True, return_lse=True,
+        blocks=(32, 64, 16, 16))
+    att._flash_bwd_pallas(q, k, v, out, lse, out, causal, 0.25,
+                          interpret=True,
+                          blocks=(32, 32, (32, 16), (16, 8)))
+    want = {"fwd": causal_walk(64, 64, 16, 16, causal),
+            "dkdv": causal_walk(64, 64, 16, 32, causal, True),
+            "dq": causal_walk(64, 64, 16, 8, causal)}
+    if causal:
+        assert want["fwd"][:2] == (10, 4) and want["dq"][0] == 20
+    text = metrics.dump_metrics()
+    for kernel, (walked, _, pairs) in want.items():
+        gauge = att._M_WALKED.labels(kernel)
+        assert gauge.value == pytest.approx(walked / pairs)
+        assert 'flash_attention_walked_share{kernel="%s"}' % kernel in text
+    assert (want["fwd"][0] < want["fwd"][2]) == causal
 
 
 @pytest.mark.parametrize("causal", [False, True])

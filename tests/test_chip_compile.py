@@ -90,10 +90,12 @@ def _flash_bwd(q, k, v, o, lse, do):
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 @pytest.mark.parametrize("shape", [
-    (8, 16, 2048, 64),      # the LM train step's attention
+    (8, 16, 1024, 64),      # gpt2m-train's attention: one block a head,
+                            # the walk unrolled in it
+    (8, 16, 2048, 64),      # two blocks an axis: on and below the diagonal
     (1, 16, 32768, 64),     # the long-context configuration
     (4, 8, 2176, 64),       # ragged T: refused at block_k=2048 before PR 21
-], ids=["T2048", "T32768", "T2176-ragged"])
+], ids=["T1024", "T2048", "T32768", "T2176-ragged"])
 def test_flash_kernels_compile(topo, on_tpu, shape, direction):
     one = SingleDeviceSharding(topo.devices[0])
     x, lse = _s(shape, BF16), _s(shape[:3], F32)
