@@ -6,7 +6,8 @@ pools (:func:`paged_decode_attention`), the latent-attention model over
 one pool of latent rows in the absorbed form
 (:func:`latent_paged_decode_attention`), a grouped-query model over key
 and value pools whose rows hold its few key-value heads
-(:func:`gqa_paged_decode_attention`).  Each public function holds
+(:func:`gqa_paged_decode_attention`; two bodies, by whether a head is
+whole lane tiles).  Each public function holds
 its whole choice of body: where a Pallas kernel runs
 (:func:`~mxnet_tpu.ops.platform.pallas_mode`) and a page of every pool
 is whole tiles (:func:`_walk_tiles`), the block-table walk
@@ -177,12 +178,13 @@ def gqa_paged_decode_attention(q, k_step, v_step, k_pages, v_pages,
     as a score of its own, so the pools are read as they lie.  On a TPU,
     with pages that are whole tiles, the block-table walk
     (:func:`_walk_pages`) reads the blocks that hold live tokens and no
-    other; elsewhere XLA gathers every table block and masks."""
+    other, under the body :func:`_gqa_walk_body` names for the head's
+    width; elsewhere XLA gathers every table block and masks."""
     mode = _platform.pallas_mode()
     if mode and _walk_tiles(k_pages, v_pages):
-        return _gqa_decode_pallas(q, k_step, v_step, k_pages, v_pages,
-                                  block_tables, context_lens,
-                                  float(sm_scale), mode == "interpret")
+        return _gqa_walk_body(q.shape[-1])(
+            q, k_step, v_step, k_pages, v_pages, block_tables, context_lens,
+            float(sm_scale), mode == "interpret")
     with jax.named_scope("paged_decode_gqa_attention"):
         return _gqa_decode_xla(q, k_step, v_step, k_pages, v_pages,
                                block_tables, context_lens, sm_scale)
@@ -616,6 +618,95 @@ def _gqa_decode_pallas(q, k_step, v_step, k_pages, v_pages, block_tables,
             interpret=interpret)
 
 
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+def _gqa_packed_decode_pallas(q, k_step, v_step, k_pages, v_pages,
+                              block_tables, context_lens, sm_scale,
+                              interpret=False):
+    """The grouped-query decode over the walk where a key-value head is
+    narrower than a lane tile (``D`` 64: two heads a tile, eight in a
+    512-wide row), so that a head's keys are no slice of lanes a kernel
+    can take.  Every query head is spread over the whole cached row
+    instead, its own key-value head's lanes holding it and the others
+    zero: the scores of all ``Hq`` heads are then one product with the
+    chunk's key rows as they lie (the zeros add nothing, exactly), and
+    ``p . values`` one product with its value rows, of which a head
+    keeps its own key-value head's lanes.  Both ride the MXU in the
+    pools' dtype with the chunk's rows as the stationary operand, which
+    they are in the sliced body too: the ``Hkv`` times more
+    multiplications stream through tiles that are loaded anyway.
+    Scores, softmax and accumulator stay float32.  Jitted so that a
+    model's layers share one trace and one lowering of the kernel."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    bsz, heads, dim = q.shape
+    groups = k_step.shape[1]
+    per, width = heads // groups, groups * dim
+    f32 = jnp.float32
+    own = lax.broadcasted_iota(jnp.int32, (heads, width), 1) // dim \
+        == lax.broadcasted_iota(jnp.int32, (heads, width), 0) // per
+    q_wide = jnp.where(own, jnp.tile(q, (1, 1, groups)), 0)
+
+    def init(row_refs, state):
+        q_ref, k_ref, v_ref = row_refs
+        m_ref, l_ref, acc_ref = state
+        m_ref[...] = jnp.sum(q_ref[0].astype(f32) * k_ref[0].astype(f32),
+                             axis=1, keepdims=True) * sm_scale
+        l_ref[...] = jnp.ones_like(l_ref)
+        acc_ref[...] = jnp.broadcast_to(v_ref[0].astype(f32), acc_ref.shape)
+
+    def chunk(row_refs, held, state, live):
+        m_ref, l_ref, acc_ref = state
+        keys, values = held[0][...], held[1][...]               # [T, W]
+        tokens = keys.shape[0]
+        s = lax.dot_general(
+            row_refs[0][0].astype(keys.dtype), keys,
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=f32) * sm_scale              # [Hq, T]
+        if live is not None:
+            at = lax.broadcasted_iota(jnp.int32, (1, tokens), 1)
+            s = jnp.where(at < live, s, NEG_INF)
+            at = lax.broadcasted_iota(jnp.int32, (tokens, 1), 0)
+            values = jnp.where(at < live, values, 0)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + lax.dot_general(
+            p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
+            preferred_element_type=f32)
+        m_ref[...] = m_new
+
+    def finish(row_refs, state, out_ref):
+        _, l_ref, acc_ref = state
+        out_ref[0] = (acc_ref[...] / l_ref[...]).astype(out_ref.dtype)
+
+    rows = (q_wide, k_step.reshape(bsz, 1, width),
+            v_step.reshape(bsz, 1, width))
+    # the scope names the kernel in a trace; it has to be the innermost
+    with jax.named_scope("paged_decode_gqa_attention"):
+        wide = _walk_pages(
+            init, chunk, finish, rows, (), (k_pages, v_pages),
+            block_tables, context_lens,
+            jax.ShapeDtypeStruct((bsz, heads, width), q.dtype),
+            [pltpu.VMEM((heads, 1), f32), pltpu.VMEM((heads, 1), f32),
+             pltpu.VMEM((heads, width), f32)],
+            interpret=interpret)
+    # a head's output is its own key-value head's lanes of its row
+    wide = wide.reshape(bsz, groups, per, groups, dim)
+    return jnp.stack([wide[:, g, :, g, :] for g in range(groups)],
+                     axis=1).reshape(bsz, heads, dim)
+
+
+def _gqa_walk_body(dim):
+    """The walk's grouped-query body for heads ``dim`` wide: a
+    key-value head that is whole lane tiles is a slice of a cached row
+    (:func:`_gqa_decode_pallas`), a narrower one is not
+    (:func:`_gqa_packed_decode_pallas`)."""
+    return _gqa_decode_pallas if dim % _LANE == 0 \
+        else _gqa_packed_decode_pallas
+
+
 # ----------------------------------------------------------------------
 # parity grids: each kernel against its XLA body (ragged tails on
 # purpose; the widest case of each is the served shape, which
@@ -712,7 +803,7 @@ def _gqa_case(case):
     tol = (3e-2, 3e-2) if dtype == "bfloat16" else (1e-4, 1e-4)
     scale = 1.0 / float(dim) ** 0.5
     return (functools.partial(_gqa_decode_xla, sm_scale=scale),
-            functools.partial(_gqa_decode_pallas, sm_scale=scale,
+            functools.partial(_gqa_walk_body(dim), sm_scale=scale,
                               interpret=_interpret()),
             (q, k_step, v_step, k_pages, v_pages, tables, lens), tol)
 
@@ -725,4 +816,10 @@ register_parity(
         # the served heads (16 queries over 2 key-value heads of 256,
         # 512-wide bfloat16 rows, blocks of 16)
         ("bfloat16", 16, 2, 256, 16, 4, (1, 16, 17, 18, 64)),
+        # key-value heads narrower than a lane tile (the packed body):
+        # the served heads of 64 (32 queries over 8 key-value heads,
+        # 512-wide bfloat16 rows, blocks of 16), and three queries a
+        # head of 32
+        ("bfloat16", 32, 8, 64, 16, 4, (1, 16, 17, 18, 64)),
+        ("float32", 12, 4, 32, 8, 3, (2, 24, 11)),
     ))

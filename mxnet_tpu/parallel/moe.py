@@ -201,7 +201,7 @@ def moe_ffn(params, x, *, capacity_factor=2.0, expert_axis="expert",
 
 
 def route_group_limited(logits, bias, *, top_k, n_group=1, topk_group=1,
-                        scale=1.0, normalize=True):
+                        scale=1.0, normalize=True, eps=1e-20):
     """DeepSeek-V3's ``noaux_tc`` choice.  ``logits`` float32 ``[T, E]``
     over ALL the experts of the model, ``bias`` ``[E]`` (the
     ``e_score_correction_bias``).  Scores are ``s = sigmoid(logits)``;
@@ -209,8 +209,9 @@ def route_group_limited(logits, bias, *, top_k, n_group=1, topk_group=1,
     neighbouring experts scores the sum of its two largest, the
     ``topk_group`` best groups are kept, and the ``top_k`` largest among
     them are chosen.  The gates are ``s`` (without the bias) at the
-    chosen experts, divided by their sum if ``normalize``, times
-    ``scale``.  Returns ``(experts int32 [T, top_k], gates float32 [T,
+    chosen experts, divided by their sum plus ``eps`` if ``normalize``
+    (1e-20 as DeepSeek-V3 publishes it, 1e-6 in LFM2), times ``scale``.
+    Returns ``(experts int32 [T, top_k], gates float32 [T,
     top_k])``; every token keeps all its ``top_k`` experts."""
     tokens, experts = logits.shape
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
@@ -226,7 +227,7 @@ def route_group_limited(logits, bias, *, top_k, n_group=1, topk_group=1,
     chosen = jax.lax.top_k(choice, top_k)[1].astype(jnp.int32)
     gates = jnp.take_along_axis(s, chosen, axis=1)
     if normalize:
-        gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+        gates = gates / (gates.sum(-1, keepdims=True) + eps)
     return chosen, gates * scale
 
 

@@ -621,16 +621,31 @@ _PAGED_KERNELS["gqa"] = (
     + (_s((128, 512), jnp.int32), _s((128,), jnp.int32)))
 
 
-def test_gqa_paged_decode_kernel_compiles_at_the_cells_shape(topo, on_tpu):
-    """The grouped-query body of the block-table walk at the new cell's
-    size: one custom call under its scope's name, the pools handed to it
-    as they lie."""
-    fn, name, args = _PAGED_KERNELS["gqa"]
+# lfm2-serve-chat64: 64 rows of 32 query heads over 8 key-value heads
+# of 64 (two a lane tile: the walk's packed body), 512-block tables, the
+# three attention layers' 9,600-block pools as one
+_PAGED_KERNELS["gqa_heads_of_64"] = (
+    lambda *args: paged.gqa_paged_decode_attention(*args, 0.125),
+    "paged_decode_gqa_attention",
+    (_s((64, 32, 64), BF16),) + (_s((64, 8, 64), BF16),) * 2
+    + (_s((3 * 9600, 16, 512), BF16),) * 2
+    + (_s((64, 512), jnp.int32), _s((64,), jnp.int32)))
+
+
+@pytest.mark.parametrize("kernel,beside", [("gqa", 2 ** 21),
+                                           ("gqa_heads_of_64", 2 ** 23)])
+def test_gqa_paged_decode_kernel_compiles_at_the_cells_shape(topo, on_tpu,
+                                                             kernel, beside):
+    """Both grouped-query bodies of the block-table walk at their cells'
+    sizes: one custom call under the scope's name, the pools handed to
+    it as they lie (the packed body's spread queries and its outputs'
+    own lanes are 2 MB each beside it)."""
+    fn, name, args = _PAGED_KERNELS[kernel]
     compiled = _compile(fn, args, SingleDeviceSharding(topo.devices[0]))
     text = compiled.as_text()
     assert _named_calls(text, name) == 1
-    assert _big_moves(text, 2 ** 20) == []
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 21
+    assert _big_moves(text, beside // 2) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < beside
 
 
 def test_gated_delta_decode_step_updates_the_state_where_it_lies(topo,

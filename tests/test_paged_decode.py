@@ -93,6 +93,10 @@ def _gqa_case(ctx, dtype="bfloat16", heads=16, groups=2, dim=256,
             jnp.asarray(bt), jnp.asarray(ctx, jnp.int32)]
 
 
+def _pallas_calls(fn, *args):
+    return str(jax.make_jaxpr(fn)(*args)).count("pallas_call")
+
+
 def _kv_xla(*args):
     return att._kv_decode_xla(*args, SCALE)
 
@@ -145,14 +149,38 @@ def test_latent_kernel_equals_the_xla_body(ctx, dtype, chunking):
     np.testing.assert_allclose(_f32(got), _f32(ref), rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-@pytest.mark.parametrize("ctx", sorted(RAGGED))
-def test_gqa_kernel_equals_the_xla_body(ctx, dtype, chunking):
-    """Grouped queries: 8 query heads share a key-value head's lanes of
-    a 512-wide row."""
-    args = _gqa_case(RAGGED[ctx], dtype)
-    ref = att._gqa_decode_xla(*args, 0.0625)
-    got = att._gqa_decode_pallas(*args, 0.0625, interpret=True)
+# the two grouped-query bodies of the walk: a key-value head that is
+# whole lane tiles (16 queries over 2 heads of 256) and one narrower
+# than a tile (32 queries over 8 heads of 64: two heads a tile)
+GQA_HEADS = {"2x256": dict(heads=16, groups=2, dim=256, scale=0.0625),
+             "8x64": dict(heads=32, groups=8, dim=64, scale=0.125)}
+
+
+def _gqa_heads(name):
+    heads = dict(GQA_HEADS[name])
+    return heads, heads.pop("scale")
+
+
+# every context in both dtypes for the sliced body; for the packed one
+# every context in float32 and the mixed batch in bfloat16 (the file
+# stays inside its share of the tier-1 clock)
+_GQA_CASES = [(ctx, dtype, "2x256") for ctx in sorted(RAGGED)
+              for dtype in ("bfloat16", "float32")] \
+    + [(ctx, "float32", "8x64") for ctx in sorted(RAGGED)] \
+    + [("mixed", "bfloat16", "8x64")]
+
+
+@pytest.mark.parametrize("ctx,dtype,heads", _GQA_CASES)
+def test_gqa_kernel_equals_the_xla_body(ctx, dtype, heads, chunking):
+    """Grouped queries over 512-wide rows: 8 query heads share a
+    key-value head's 256 lanes, or 4 share its 64 (no cached token, one
+    live block, ragged tails, a full table)."""
+    heads, scale = _gqa_heads(heads)
+    args = _gqa_case(RAGGED[ctx], dtype, **heads)
+    body = att._gqa_walk_body(heads["dim"])
+    assert (body is att._gqa_decode_pallas) == (heads["dim"] == 256)
+    ref = att._gqa_decode_xla(*args, scale)
+    got = body(*args, scale, interpret=True)
     assert got.dtype == ref.dtype and got.shape == ref.shape
     tol = 2e-2 if dtype == "bfloat16" else 2e-5
     np.testing.assert_allclose(_f32(got), _f32(ref), rtol=tol, atol=tol)
@@ -179,18 +207,43 @@ def test_gqa_xla_body_is_plain_attention_with_repeated_heads():
                                        atol=1e-5)
 
 
-def test_gqa_kernel_reads_no_dead_block(chunking):
+@pytest.mark.parametrize("heads", sorted(GQA_HEADS))
+def test_gqa_kernel_reads_no_dead_block(heads, chunking):
+    heads, scale = _gqa_heads(heads)
+    body = att._gqa_walk_body(heads["dim"])
     ctx = RAGGED["mixed"]
-    args = _gqa_case(ctx)
-    clean = att._gqa_decode_pallas(*args, 0.0625, interpret=True)
+    args = _gqa_case(ctx, **heads)
+    clean = body(*args, scale, interpret=True)
     bt = np.asarray(args[5])
     for at in (3, 4):
         args[at] = jnp.asarray(_poison(_f32(args[at]), ctx, bt),
                                args[at].dtype)
-    got = att._gqa_decode_pallas(*args, 0.0625, interpret=True)
+    got = body(*args, scale, interpret=True)
     assert np.isfinite(_f32(got)).all()
     np.testing.assert_array_equal(_f32(got), _f32(clean))
-    assert np.isnan(_f32(att._gqa_decode_xla(*args, 0.0625))).any()
+    assert np.isnan(_f32(att._gqa_decode_xla(*args, scale))).any()
+
+
+def test_where_pallas_runs_heads_of_64_take_the_packed_body(monkeypatch):
+    """The platform test patched to the interpreter: the public entry
+    point walks the pools under the packed body for heads narrower than
+    a lane tile (one ``pallas_call``, the XLA body's result within the
+    kernel's class), and keeps the XLA body off the chip."""
+    from mxnet_tpu.ops import platform
+
+    heads, scale = _gqa_heads("8x64")
+    args = _gqa_case((5, 20, 40), **heads)
+
+    def fn(*a):
+        return att.gqa_paged_decode_attention(*a, scale)
+
+    # (a function's trace is kept: each count traces one of its own)
+    assert _pallas_calls(lambda *a: fn(*a), *args) == 0
+    monkeypatch.setattr(platform, "pallas_mode", lambda: "interpret")
+    assert _pallas_calls(lambda *a: fn(*a), *args) == 1
+    np.testing.assert_allclose(
+        _f32(fn(*args)), _f32(att._gqa_decode_xla(*args, scale)),
+        rtol=2e-2, atol=2e-2)
 
 
 def test_kv_kernel_takes_a_bfloat16_pool():
@@ -292,10 +345,6 @@ def test_chunk_pages_follow_from_the_page_bytes():
         jax.ShapeDtypeStruct((9, 16, 576), jnp.bfloat16))
     assert not att._walk_tiles(
         jax.ShapeDtypeStruct((9, 8, 640), jnp.bfloat16))
-
-
-def _pallas_calls(fn, *args):
-    return str(jax.make_jaxpr(fn)(*args)).count("pallas_call")
 
 
 def test_off_the_chip_the_xla_bodies_run():

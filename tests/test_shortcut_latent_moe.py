@@ -759,7 +759,7 @@ def test_the_cell_and_its_metrics_are_declared():
     spec = Spec(ROOT)
     cell = spec.cell("longcat-serve-agent64")
     assert cell["chips"] == 1 and cell["config"] == "longcat-flash-ep32"
-    assert len(cell["why"]) <= 200 and len(spec.cells) == 6
+    assert len(cell["why"]) <= 200 and len(spec.cells) >= 6
     names = {m["name"] for m in spec.cell_metrics(cell["name"], "per_layer")}
     new = {m["name"] for m in spec.doc["per_layer"]
            if m["name"].endswith(".longcat")}

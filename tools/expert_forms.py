@@ -1,0 +1,65 @@
+"""Both forms of the dropless expert layer (``parallel/moe.py``: every
+held expert over every row, or grouped products over the sorted pairs)
+at a decode step's shapes, on the chip it is started on: wall time of
+one layer, the median of 60 calls and the pace of 20 queued calls, for
+8 to 128 rows over 32 experts of LFM2's widths, 4 a token.
+
+    chiprun --chips 1 -- python3 tools/expert_forms.py
+
+Writes ``chiprun_out/expert_forms.json``; says for each shape what
+``few_rows_hit_most`` would choose (PERF.md section 6, PR 39)."""
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def main():
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.parallel import moe
+
+    d, h, n, k = 2048, 1792, 32, 4
+    key = jax.random.PRNGKey(0)
+    ks = jax.random.split(key, 6)
+    w_gate = (0.02 * jax.random.normal(ks[0], (n, d, h), jnp.float32)).astype(jnp.bfloat16)
+    w_up = (0.02 * jax.random.normal(ks[1], (n, d, h), jnp.float32)).astype(jnp.bfloat16)
+    w_down = (0.02 * jax.random.normal(ks[2], (n, h, d), jnp.float32)).astype(jnp.bfloat16)
+    router = 0.02 * jax.random.normal(ks[3], (n, d), jnp.float32)
+    bias = 0.01 * jax.random.normal(ks[4], (n,), jnp.float32)
+    out = {}
+    for rows in (8, 16, 32, 64, 128):
+        x = jax.random.normal(ks[5], (rows, d), jnp.float32).astype(jnp.bfloat16)
+        for every in (False, True):
+            def layer(x, w_gate, w_up, w_down):
+                logits = jnp.einsum("nc,ec->ne", x.astype(jnp.float32), router)
+                chosen, gates = moe.route_group_limited(logits, bias, top_k=k, eps=1e-6)
+                return moe.dropless_experts(x, chosen, gates, w_gate, w_up, w_down, (0, n), every_row=every)
+            fn = jax.jit(layer)
+            y, counts = fn(x, w_gate, w_up, w_down); y.block_until_ready()
+            times = []
+            for _ in range(60):
+                t0 = time.perf_counter()
+                y, counts = fn(x, w_gate, w_up, w_down); y.block_until_ready()
+                times.append(time.perf_counter() - t0)
+            # twenty calls queued, one wait: the device's own pace
+            t0 = time.perf_counter()
+            for _ in range(20):
+                y, counts = fn(x, w_gate, w_up, w_down)
+            y.block_until_ready()
+            queued = (time.perf_counter() - t0) / 20
+            name = "%d_rows_%s" % (rows, "every_row" if every else "grouped")
+            out[name] = {"median_ms": 1e3 * float(np.median(times)), "queued_ms": 1e3 * queued,
+                         "hit": int(counts[2]), "rule": bool(moe.few_rows_hit_most(rows, k, n))}
+            print(name, out[name], flush=True)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/expert_forms.json", "w") as f:
+        json.dump(out, f, indent=1)
+
+
+if __name__ == "__main__":
+    main()
