@@ -334,7 +334,8 @@ def _feed_forward(params, i, x, cfg, valid=None):
             params[p + "experts_down_weight"], cfg["held"], valid=valid,
             every_row=_moe.few_rows_hit_most(
                 h.shape[0], cfg["num_experts_per_tok"],
-                cfg["n_routed_experts"]))
+                cfg["n_routed_experts"]),
+            n_experts=cfg["n_routed_experts"])
         shared = _moe.swiglu(h, params[p + "shared_gate_weight"],
                              params[p + "shared_up_weight"],
                              params[p + "shared_down_weight"])

@@ -297,7 +297,8 @@ def _feed_forward(params, i, x, cfg, valid=None):
             params[p + "experts_down_weight"], cfg["held"], valid=valid,
             every_row=_moe.few_rows_hit_most(
                 h.shape[0], cfg["num_experts_per_tok"],
-                cfg["num_experts"]))
+                cfg["num_experts"]),
+            n_experts=cfg["num_experts"])
 
 
 def _head(params, x, cfg):

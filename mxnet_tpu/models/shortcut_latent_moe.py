@@ -183,7 +183,8 @@ def _experts(params, p, h, cfg, valid=None):
             params[p + "experts_up_weight"],
             params[p + "experts_down_weight"], cfg["held"], valid=valid,
             every_row=_moe.few_rows_hit_most(
-                h.shape[0], cfg["moe_topk"], router_width(cfg)))
+                h.shape[0], cfg["moe_topk"], router_width(cfg)),
+            n_experts=router_width(cfg))
         same, zero = _moe.identity_experts(
             h, chosen, gates, cfg["n_routed_experts"], valid)
     return routed + same, jnp.concatenate([counts, zero[None]])

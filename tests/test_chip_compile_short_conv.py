@@ -16,7 +16,8 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from test_chip_compile import (  # noqa: F401  (topo, on_tpu: fixtures)
-    _big_moves, _named_calls, _traffic, on_tpu, topo)
+    _big_moves, _grouped_tiles_are_the_rules, _named_calls, _traffic, on_tpu,
+    topo)
 
 BF16, F32 = jnp.bfloat16, jnp.float32
 
@@ -118,4 +119,5 @@ def test_short_conv_prefill_buckets_compile(topo, on_tpu, bucket):
     assert (flash >= 3, scores) == ((True, False) if bucket >= 1024
                                     else (False, True))
     assert text.count("ragged-dot") >= 3 * 12
+    _grouped_tiles_are_the_rules(text, bucket * 4, 32, 2048, 1792)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30

@@ -15,7 +15,8 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from test_chip_compile import (  # noqa: F401  (topo, on_tpu: fixtures)
-    _big_moves, _holds, _named_calls, _traffic, on_tpu, topo)
+    _big_moves, _grouped_tiles_are_the_rules, _holds, _named_calls, _traffic,
+    on_tpu, topo)
 
 BF16, F32 = jnp.bfloat16, jnp.float32
 
@@ -54,6 +55,7 @@ def test_shortcut_prefill_buckets_compile(topo, on_tpu, bucket):
     products are the chip's grouped kernels; rows ``[2, T, 640]`` go to
     the pool."""
     from mxnet_tpu.models import shortcut_latent_moe as sm
+    from mxnet_tpu.parallel import moe
 
     one = SingleDeviceSharding(topo.devices[0])
     _, cfg, params = _shortcut_shapes(one, num_layers=1)
@@ -70,6 +72,10 @@ def test_shortcut_prefill_buckets_compile(topo, on_tpu, bucket):
         assert _named_calls(text, "latent_prefill_attention") == 0
         assert _holds(text, scores)
     assert "ragged-dot" in text
+    # a prompt of 3072 or 4096 tokens sorts its pairs in two runs of rows
+    run = bucket // moe.grouped_runs(bucket, 12, 6144 * 2)
+    assert run == {3072: 1536, 4096: 2048}.get(bucket, bucket)
+    _grouped_tiles_are_the_rules(text, run * 12, 16, 6144, 2048)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2 ** 30
 
 
@@ -137,4 +143,6 @@ def test_shortcut_prefill_at_full_depth_fits_beside_weights_and_pool(
     assert _named_calls(text, "latent_prefill_attention") == 8
     assert "f32[64,6144,6144]" not in text
     assert compiled.out_info[1].shape == (8, 6144, 640)
+    # three runs of 2048 rows: the tiles are those of a run's pairs
+    _grouped_tiles_are_the_rules(text, 2048 * 12, 16, 6144, 2048)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9

@@ -7,7 +7,8 @@ one layer, the median of 60 calls and the pace of 20 queued calls, for
     chiprun --chips 1 -- python3 tools/expert_forms.py
 
 Writes ``chiprun_out/expert_forms.json``; says for each shape what
-``few_rows_hit_most`` would choose (PERF.md section 6, PR 39)."""
+``few_rows_hit_most`` would choose (PERF.md section 6: PR 39 against
+the compiler's 512-row tile, PR 40 against ``moe.grouped_tiling``'s)."""
 import json
 import os
 import sys
