@@ -13,8 +13,8 @@ __all__ = ["LMDefinition"]
 
 class LMDefinition(collections.namedtuple(
         "LMDefinition", "cfg forward prefill decode cache_row book "
-                        "prepare cache_layers state",
-        defaults=(None, None))):
+                        "prepare cache_layers state cache_groups",
+        defaults=(None, None, None))):
     """One model, as the generation lane serves it.
 
     - ``cfg``: a dict with at least ``seq_len`` (the context limit the
@@ -55,6 +55,25 @@ class LMDefinition(collections.namedtuple(
       the call and written where they lie: row ``i`` reads version
       ``positions[i] % 2`` of slot ``slots[i]`` and writes the other;
       a slot id of ``slots`` or more is a pad row.
+    - ``cache_groups``: ``None`` where every cached layer keeps its
+      rows as long as the sequence lives (one pool, one table), else
+      the **layer groups** of the cache (:mod:`~mxnet_tpu.ops.kv_cache`),
+      ``((layers, window), ...)``: ``layers`` the rows of ``k_rows``
+      that are the group's (each of ``cache_layers`` in one group; a
+      model may order its rows by group, and a group whose rows lie
+      together is written without a gather), ``window`` ``None`` or the
+      tokens a layer of the group looks back over, the current one
+      counted (a sliding-window layer).  The cache then keeps a pool a
+      group, and the programs see the groups: ``decode`` takes
+      ``k_pages`` and ``v_pages`` as tuples, one pool ``[group's
+      layers, group's blocks, block_size, width]`` a group, and
+      ``block_tables`` ``int32 [B, table_width]`` with the groups'
+      tables side by side in a row, each ``ceil(seq_len / block_size)``
+      wide but for a window group's, whose table is a ring of at most
+      ``window / block_size + 1`` entries (token ``p`` in entry ``(p //
+      block_size) mod ring``).  Put the group without a window first:
+      the cache's gauges of one pool (``occupancy``) are the first
+      group's.
     """
 
     __slots__ = ()

@@ -45,7 +45,7 @@ from .registry import ParamSpec as P, register
 
 __all__ = ["flash_attention", "ring_attention", "stable_causal_attention",
            "latent_prefill_attention", "stable_scores", "stable_softmax",
-           "NEG_INF"]
+           "band_tiles", "NEG_INF"]
 
 NEG_INF = -1e30
 # Mosaic tiles the last two block dims as (8 sublanes, 128 lanes).  Inside
@@ -56,13 +56,18 @@ NEG_INF = -1e30
 _LANE = 128
 
 
-def _causal_mask(bq, bk, q_offset, k_offset, keys_first=False):
+def _causal_mask(bq, bk, q_offset, k_offset, keys_first=False,
+                 window=None):
     """Boolean [bq, bk] mask: query global pos >= key global pos
-    (``keys_first``: the same, [bk, bq])."""
+    (``keys_first``: the same, [bk, bq]); with a ``window``, also less
+    than ``window`` past it: a row sees itself and the ``window - 1``
+    keys before it (a band)."""
     shape, rows, cols = ((bk, bq), 1, 0) if keys_first else ((bq, bk), 0, 1)
     qi = q_offset + lax.broadcasted_iota(jnp.int32, shape, rows)
     ki = k_offset + lax.broadcasted_iota(jnp.int32, shape, cols)
-    return qi >= ki
+    if window is None:
+        return qi >= ki
+    return (qi >= ki) & (qi - ki < window)
 
 
 # ----------------------------------------------------------------------
@@ -70,12 +75,13 @@ def _causal_mask(bq, bk, q_offset, k_offset, keys_first=False):
 # ----------------------------------------------------------------------
 
 
-def _attention_fwd_ref(q, k, v, causal, sm_scale, return_lse=False):
+def _attention_fwd_ref(q, k, v, causal, sm_scale, return_lse=False,
+                       window=None):
     """Exact softmax attention on [B, H, T, D] tensors, fp32 softmax."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * sm_scale
     if causal:
-        mask = _causal_mask(q.shape[2], k.shape[2], 0, 0)
+        mask = _causal_mask(q.shape[2], k.shape[2], 0, 0, window=window)
         s = jnp.where(mask[None, None], s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
@@ -170,19 +176,31 @@ def latent_prefill_attention(q, k, v, sm_scale):
                            scope="latent_prefill_attention")
 
 
-def gqa_prefill_attention(q, k, v, sm_scale):
+def gqa_prefill_attention(q, k, v, sm_scale, window=None):
     """Causal grouped-query attention of a prefill: ``q`` ``[B, Hq, T,
     D]``, ``k``/``v`` ``[B, Hkv, T, D]``, key-value head ``g`` serving
     the query heads ``g * Hq / Hkv`` and the ``Hq / Hkv - 1`` after it
     (each key-value head is repeated for its queries: 16 MB a pool at
     4096 tokens of 2 heads of 256).  Never holds ``[H, T, T]`` scores
     where the flash kernel runs (a TPU, T >= 1024); below that, and
-    elsewhere, the exact softmax."""
+    elsewhere, the exact softmax.
+
+    ``window`` (static; a sliding-window layer): a row sees itself and
+    the ``window - 1`` keys before it.  The kernel then walks the band
+    alone (:func:`_key_walk`) under a scope of its own,
+    ``gqa_window_prefill_attention``, whatever the prompt's length: a
+    prompt no longer than the window has the whole triangle in its
+    band, and is the causal kernel under that name."""
     per = q.shape[1] // k.shape[1]
+    scope = "gqa_prefill_attention"
+    if window is not None:
+        scope = "gqa_window_prefill_attention"
+        if window >= q.shape[2]:
+            window = None
     return _flash_dispatch(q, jnp.repeat(k, per, axis=1),
                            jnp.repeat(v, per, axis=1), True,
-                           float(sm_scale), False,
-                           scope="gqa_prefill_attention")
+                           float(sm_scale), False, scope=scope,
+                           window=window)
 
 
 # ----------------------------------------------------------------------
@@ -202,6 +220,12 @@ def gqa_prefill_attention(q, k, v, sm_scale):
 # test of ``program_id``, and the whole schedule is the compiler's (a
 # ``lax.fori_loop`` with its trip count from ``program_id`` walked the
 # same tiles 1.6-2.3 times slower on the v5e: PERF.md §6, PR 38).
+#
+# A sliding window makes the triangle a band (row ``r`` sees the keys
+# ``r - window + 1 .. r``): a run's walk then *begins* at the first
+# chunk its first row sees, and the chunks the band's lower edge cuts
+# take the mask as those on the diagonal do.  The forward alone walks a
+# band; the backward passes refuse a window.
 
 
 def _chunks_under(x, chunk, n, partly=False):
@@ -212,19 +236,28 @@ def _chunks_under(x, chunk, n, partly=False):
     return min(max(x, 0) // chunk, n)
 
 
-def _key_walk(row0, rows, chunk, n, causal, kv_len=None):
+def _key_walk(row0, rows, chunk, n, causal, kv_len=None, window=None):
     """The walk of the query rows ``[row0, row0 + rows)`` over ``n`` key
     chunks, rows counted from the first key: the chunks every row sees
     in full, then those cut by the diagonal (or by a ragged tail at
-    ``kv_len``), which take the mask."""
+    ``kv_len``), which take the mask.  Under a ``window`` the chunks no
+    row of the run sees any more (they end at or before ``row0 -
+    window``) are left out, and those the band's lower edge cuts (they
+    begin at or before the last row's ``- window``) take the mask."""
     whole = end = n
+    begin = cut = 0
+    if window is not None:
+        if not causal:
+            raise ValueError("a window is a causal band")
+        begin = _chunks_under(row0 - window + 1, chunk, n)
+        cut = _chunks_under(row0 + rows - window, chunk, n, partly=True)
     if causal:
         whole = _chunks_under(row0 + 1, chunk, n)
         end = _chunks_under(row0 + rows, chunk, n, partly=True)
     if kv_len is not None:
         whole = min(whole, _chunks_under(kv_len, chunk, n))
         end = min(end, _chunks_under(kv_len, chunk, n, partly=True))
-    return tuple((c, c >= whole) for c in range(end))
+    return tuple((c, c >= whole or c < cut) for c in range(begin, end))
 
 
 def _query_walk(col0, cols, chunk, n, causal):
@@ -238,20 +271,24 @@ def _query_walk(col0, cols, chunk, n, causal):
     return tuple((c, c < whole) for c in range(begin, n))
 
 
-def causal_walk(T, Tk, run, chunk, causal=True, keys_resident=False):
+def causal_walk(T, Tk, run, chunk, causal=True, keys_resident=False,
+                window=None):
     """``(walked, masked, pairs)``: of the ``pairs`` (run, chunk) tiles
     of a ``T x Tk`` score square, how many a kernel computes and how
     many of those it masks.  Query runs over key chunks (the forward and
     the dQ pass), or with ``keys_resident`` key runs over query chunks
-    (the dK/dV pass).  Counted with the walks the kernels unroll."""
+    (the dK/dV pass).  Counted with the walks the kernels unroll;
+    ``window`` is the forward's band (the backward walks have none)."""
     if keys_resident:
+        if window is not None:
+            raise NotImplementedError("the backward walks have no window")
         n = -(-T // chunk)
         walks = [_query_walk(col0, run, chunk, n, causal)
                  for col0 in range(0, Tk, run)]
     else:
         n = -(-Tk // chunk)
         walks = [_key_walk(row0, run, chunk, n, causal,
-                           Tk if Tk % chunk else None)
+                           Tk if Tk % chunk else None, window)
                  for row0 in range(0, T, run)]
     return (sum(len(w) for w in walks),
             sum(masked for w in walks for _, masked in w), len(walks) * n)
@@ -262,6 +299,14 @@ _M_WALKED = _metrics.gauge(
     "Share of the score square's (run, chunk) tiles the flash kernel "
     "built last computes (the rest lie above the causal diagonal), by "
     "kernel: fwd, dkdv, dq", ["kernel"])
+
+
+_M_WINDOW_TILES = _metrics.gauge(
+    "flash_attention_window_tiles",
+    "(run, chunk) tiles of the banded (sliding-window) forward built "
+    "last for a prompt of this length: those it walks, and those of "
+    "them it masks (on the diagonal or on the band's lower edge)",
+    ["tiles", "tokens"])
 
 
 def _grid_walks(n_q, n_k, block_q, block_k, walks_of, causal, tailed=False):
@@ -340,7 +385,8 @@ def _fit(T, block, *units):
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
-                  sm_scale, causal, rows, chunk, n_q, n_k, walks, tail):
+                  sm_scale, causal, rows, chunk, n_q, n_k, walks, tail,
+                  window=None):
     """One (batch*head, q-block, k-block) program of the online softmax.
 
     The k-block grid dimension is sequential ("arbitrary"); VMEM scratch
@@ -384,7 +430,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         if keep is not None:
             # a row's walk begins at a key it sees (column 0), so its
             # running max is finite before any chunk masks it whole,
-            # and exp() sends the masked scores to exact 0
+            # and exp() sends the masked scores to exact 0.  (Under a
+            # window a run's first chunk may hold no key its last rows
+            # still see: what they add there, exp(0) a key, the first
+            # chunk with a key they do see wipes, alpha being exp(-1e30
+            # - m) = 0 exactly, and every row sees itself.)
             s = jnp.where(keep, s, NEG_INF)
         m_prev = m_scr[run, :]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -408,7 +458,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
                 keep = None
                 if masked and causal:
                     keep = _causal_mask(rows, chunk, lead + a * rows,
-                                        c * chunk)
+                                        c * chunk, window=window)
                 if (c + 1) * chunk > held:
                     # ragged tail: padded key columns contribute nothing
                     valid = c * chunk + lax.broadcasted_iota(
@@ -452,17 +502,35 @@ def _flash_blocks(Tk, D):
     return 1024, block_k, tile, tile
 
 
+def band_tiles(T, D, window):
+    """``(walked, masked, causal)``: the (run, chunk) tiles the forward
+    walks over a prompt of ``T`` tokens with heads of ``D`` under a
+    sliding ``window``, those of them it masks, and what a causal walk
+    of the same prompt walks; by the tiles :func:`_flash_blocks` gives
+    the kernel, whichever body runs."""
+    block_q, block_k, rows, chunk = _flash_blocks(T, D)
+    _, (rows,) = _fit(T, block_q, rows)
+    _, (chunk,) = _fit(T, block_k, chunk)
+    walked, masked, _ = causal_walk(
+        T, T, rows, chunk, window=window if window < T else None)
+    return walked, masked, causal_walk(T, T, rows, chunk)[0]
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "sm_scale", "interpret", "return_lse", "blocks", "scope"))
+    "causal", "sm_scale", "interpret", "return_lse", "blocks", "scope",
+    "window"))
 def _flash_fwd_pallas(q, k, v, causal, sm_scale, interpret=False,
-                      return_lse=False, blocks=None, scope=None):
+                      return_lse=False, blocks=None, scope=None,
+                      window=None):
     """Pallas forward on [B, H, T, D].  T is padded to block multiples.
     Jitted so that a model's layers share one trace and one lowering of
     the kernel; ``scope`` names it in a device trace.
 
     ``blocks`` is ``(block_q, block_k, rows, chunk)``; left out, as
     every caller but the sweep and the tests leaves it, ``_flash_blocks``
-    chooses from what it can see (``Tk``, ``D``)."""
+    chooses from what it can see (``Tk``, ``D``).  ``window``: the band
+    of a sliding-window layer (self-attention: ``T == Tk``); its tiles
+    are counted in the gauge under the kernel ``fwd_window``."""
     import jax.experimental.pallas as pl
 
     from jax.experimental.pallas import tpu as pltpu
@@ -486,20 +554,29 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, interpret=False,
     kf = k.reshape(B * H, Tkp, D)
     vf = v.reshape(B * H, Tkp, Dv)
     n_q, n_k = Tp // block_q, Tkp // block_k
-    walked, _, pairs = causal_walk(T, Tk, rows, chunk, causal)
-    _M_WALKED.labels("fwd").set(walked / pairs)
+    walked, masked, pairs = causal_walk(T, Tk, rows, chunk, causal,
+                                        window=window)
+    if window is None:
+        _M_WALKED.labels("fwd").set(walked / pairs)
+    else:
+        if return_lse or T != Tk:
+            raise NotImplementedError(
+                "a window is the forward's, over a prompt's own keys")
+        _M_WALKED.labels("fwd_window").set(walked / pairs)
+        _M_WINDOW_TILES.labels("walked", str(T)).set(walked)
+        _M_WINDOW_TILES.labels("masked", str(T)).set(masked)
 
     tail = Tk - (n_k - 1) * block_k    # the keys the last block holds
 
     def walks_of(lead, last):
         return tuple(
             _key_walk(lead + row0, rows, chunk, block_k // chunk, causal,
-                      tail if last else None)
+                      tail if last else None, window)
             for row0 in range(0, block_q, rows))
 
     kernel = functools.partial(
         _flash_kernel, sm_scale=sm_scale, causal=causal, rows=rows,
-        chunk=chunk, n_q=n_q, n_k=n_k, tail=tail,
+        chunk=chunk, n_q=n_q, n_k=n_k, tail=tail, window=window,
         walks=_grid_walks(n_q, n_k, block_q, block_k, walks_of, causal,
                           Tkp != Tk))
     kwargs = {}
@@ -512,6 +589,9 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, interpret=False,
         # last one that is, which is already there, and nothing is copied
         if causal:
             j = jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
+        if window is not None:
+            # nor is one wholly left of the band
+            j = jnp.maximum(j, (i * block_q - window + 1) // block_k)
         return b, j, 0
 
     out_shape = [jax.ShapeDtypeStruct((B * H, Tp, Dv), q.dtype)]
@@ -555,21 +635,26 @@ def _flash(q, k, v, causal, sm_scale, interpret):
     return _flash_dispatch(q, k, v, causal, sm_scale, interpret)
 
 
-def _flash_dispatch(q, k, v, causal, sm_scale, interpret, scope=None):
+def _flash_dispatch(q, k, v, causal, sm_scale, interpret, scope=None,
+                    window=None):
     """The forward's choice of body: ``interpret`` asks for the kernel
     whatever the length (under the interpreter off the chip); else on a
     TPU the kernel from 1024 tokens (the VJP forward's threshold too;
     past 8K the blocked kernel is the only option, exact attention
     OOMs), and the exact softmax below that and elsewhere.  ``scope``
-    names the caller's attention in a device trace, whichever body."""
+    names the caller's attention in a device trace, whichever body;
+    ``window`` makes the causal triangle a band, in either body (no
+    caller with a backward pass gives one)."""
     on_chip = _platform.pallas_mode() == "chip"
     if interpret:
         return _flash_fwd_pallas(q, k, v, causal, sm_scale,
-                                 interpret=not on_chip, scope=scope)
+                                 interpret=not on_chip, scope=scope,
+                                 window=window)
     if on_chip and (q.shape[2] >= 1024 or k.shape[2] >= 1024):
-        return _flash_fwd_pallas(q, k, v, causal, sm_scale, scope=scope)
+        return _flash_fwd_pallas(q, k, v, causal, sm_scale, scope=scope,
+                                 window=window)
     with jax.named_scope(scope or "flash_attention"):
-        return _attention_fwd_ref(q, k, v, causal, sm_scale)
+        return _attention_fwd_ref(q, k, v, causal, sm_scale, window=window)
 
 
 def _flash_fwd_vjp(q, k, v, causal, sm_scale, interpret):

@@ -60,6 +60,32 @@ decode program itself updates the state pool, donated to it
 (:meth:`PagedKVCache.swap_state`); a prefill's state is written by
 :meth:`PagedKVCache.write_prefill`.
 
+**A model whose layers keep their rows for different lengths of time
+has layer groups.**  A sliding-window layer needs the last ``window``
+tokens of a sequence, a global layer all of them; one pool, in which a
+block id means the same tokens in every layer and lives until the
+sequence ends, would keep a window layer's rows as long as a global
+layer's.  The model names its groups (``groups``: ``(layers,
+window)`` each, ``layers`` the rows of its programs' ``k_rows`` that
+are the group's, ``window`` ``None`` or a token count), and every group
+has a pool, a free list and a table a sequence of its own, under one
+:meth:`PagedKVCache.allocate` and one :meth:`PagedKVCache.free`.  In a
+window group a sequence's blocks are a **ring** of ``min(ceil(horizon /
+block_size), window / block_size + 1)`` entries, reserved whole at
+admission like the rest of its horizon: token ``p`` lies in entry ``(p
+// block_size) mod ring``, nothing is freed or replaced while the
+sequence lives, and a sequence's table row (:meth:`PagedKVCache.
+block_table`: the groups' tables side by side, ``[table_width]``) is
+still made once.  The entry a step writes never holds a key the step
+(or one dispatched again behind it) still reads: the ring is a block
+longer than the window.  A sequence whose horizon is under the window
+costs a window group what it costs a global one.  ``k_pages`` /
+``v_pages``, ``num_blocks`` and ``stats()["occupancy"]`` are the
+**first** group's (a model puts its global layers there: their blocks
+grow with the context); ``stats()["groups"]`` has every group's.  A
+model with one group (every layer, no window) has the one pool, table
+and free list it always had.
+
 The cache is **backend state**: ``serving.generation.LMBackend`` owns
 one, the ``ModelRegistry`` swap machinery replaces cache and weights
 together, and the generation lane re-prefills live sequences after a
@@ -199,6 +225,23 @@ _M_SESS_BLOCKS = _metrics.histogram(
     buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256))
 
 
+_M_GROUP_BLOCKS = _metrics.gauge(
+    "serving_kv_cache_group_used_blocks",
+    "KV-cache blocks currently allocated in one layer group's pool (a "
+    "model with several groups only), by model and group",
+    ["model", "group"])
+_M_GROUP_PEAK = _metrics.gauge(
+    "serving_kv_cache_group_occupancy_peak",
+    "Largest fraction of one layer group's blocks in use since the cache "
+    "was built (a model with several groups only), by model and group",
+    ["model", "group"])
+_M_RING_WRAPS = _metrics.counter(
+    "serving_kv_cache_ring_wraps_total",
+    "Ring entries of a window group's tables that a decode step began "
+    "to write over (the block they held has left the window), summed "
+    "over window groups, by model", ["model"])
+
+
 _M_SLOTS = _metrics.gauge(
     "serving_state_slots_used",
     "Recurrent-state slots held by live sequences, by model", ["model"])
@@ -238,6 +281,73 @@ def _scatter_pages(k_pages, v_pages, k, v, blocks, offsets):
 _write_pages = jax.jit(_scatter_pages, donate_argnums=(0, 1))
 
 
+def _group_rows(rows, layers):
+    """The rows ``[L, N, ...]`` of a program's that are one group's
+    ``layers``: a slice where they lie together (a model may order its
+    rows by group), else a gather."""
+    if rows is None:
+        return None
+    if layers == tuple(range(layers[0], layers[-1] + 1)):
+        return rows[layers[0]:layers[-1] + 1]
+    return rows[np.asarray(layers)]
+
+
+def _scatter_groups(k_pools, v_pools, k, v, blocks, offsets, layers):
+    """:func:`_scatter_pages` a layer group: ``k``/``v`` ``[L, N, ...]``
+    over the layers of all groups, ``layers[g]`` the rows that are group
+    ``g``'s, into its pools at its own slots ``(blocks[g], offsets[g])``."""
+    done = [_scatter_pages(kp, vp, _group_rows(k, ls), _group_rows(v, ls),
+                           b, o)
+            for kp, vp, b, o, ls in zip(k_pools, v_pools, blocks, offsets,
+                                        layers)]
+    return tuple(d[0] for d in done), tuple(d[1] for d in done)
+
+
+# one program per N for all the groups of a model that has several
+_write_groups = jax.jit(_scatter_groups, donate_argnums=(0, 1),
+                        static_argnames=("layers",))
+
+
+class _Group(object):
+    """One layer group of a cache: its pools, free list and tables."""
+
+    def __init__(self, layers, window, num_blocks, block_size, max_tokens):
+        self.layers = tuple(int(i) for i in layers)
+        self.window = None if window is None else int(window)
+        self.num_blocks = int(num_blocks)
+        self.k_pages = self.v_pages = None
+        self.free = list(range(self.num_blocks - 1, -1, -1))
+        self.tables = {}       # seq_id -> [block ids]
+        self.owner = {}        # block id -> seq_id, for every block in use
+        self.peak = 0          # most blocks in use at once, so far
+        whole = None if max_tokens is None \
+            else -(-int(max_tokens) // block_size)
+        self.ring = None
+        if window is not None:
+            if self.window < block_size or self.window % block_size:
+                raise MXNetError("a window of %d tokens is not whole "
+                                 "blocks of %d" % (self.window, block_size))
+            if whole is None:
+                raise MXNetError("a cache with a window group needs "
+                                 "max_tokens, the longest sequence")
+            self.ring = self.window // block_size + 1
+        #: the group's columns of a sequence's table row
+        self.width = whole if self.ring is None else min(self.ring, whole)
+
+    def blocks_for(self, num_tokens, block_size):
+        blocks = -(-max(num_tokens, 1) // block_size)
+        return blocks if self.ring is None else min(blocks, self.ring)
+
+    def entry(self, positions, block_size):
+        """The table entry that holds each of ``positions`` (numpy)."""
+        index = positions // block_size
+        return index if self.ring is None else index % self.ring
+
+    @property
+    def used(self):
+        return self.num_blocks - len(self.free)
+
+
 def _scatter_state(pools, rows, at):
     """A prefill's state rows (``[state layers, ...]`` an array of
     ``rows``) into rows ``at`` ``int32 [state layers]`` of the state
@@ -262,21 +372,34 @@ class PagedKVCache(object):
 
     def __init__(self, num_layers, num_heads=None, head_dim=None,
                  block_size=None, num_blocks=None, dtype=np.float32,
-                 model="default", row=None, state=None, state_slots=None):
+                 model="default", row=None, state=None, state_slots=None,
+                 groups=None, max_tokens=None):
         self.block_size = int(block_size or default_block_size())
-        self.num_blocks = int(num_blocks or default_num_blocks())
-        if self.block_size <= 0 or self.num_blocks <= 0:
-            raise MXNetError("PagedKVCache needs positive block_size/"
-                             "num_blocks (got %d/%d)"
-                             % (self.block_size, self.num_blocks))
         self.num_layers = int(num_layers)
+        # one group of every layer unless the model names several;
+        # ``num_blocks`` is then a number a group
+        groups = tuple(groups or ((tuple(range(self.num_layers)), None),))
+        sizes = num_blocks if isinstance(num_blocks, (tuple, list)) \
+            else [num_blocks or default_num_blocks()] * len(groups)
+        if len(sizes) != len(groups) or self.block_size <= 0 \
+                or min(int(n) for n in sizes) <= 0:
+            raise MXNetError("PagedKVCache needs positive block_size/"
+                             "num_blocks, one num_blocks a layer group "
+                             "(got %d/%r for %d group(s))"
+                             % (self.block_size, num_blocks, len(groups)))
+        if sorted(i for layers, _ in groups for i in layers) \
+                != list(range(self.num_layers)):
+            raise MXNetError("the layer groups %r do not name each of %d "
+                             "layers once" % (groups, self.num_layers))
+        self._groups = [_Group(layers, window, n, self.block_size,
+                               max_tokens)
+                        for (layers, window), n in zip(groups, sizes)]
+        self.num_blocks = self._groups[0].num_blocks
         # the row is the model's to define; heads and head size alone
         # describe the plain key row + value row
         self.row = row or CacheRow("kv", int(num_heads) * int(head_dim),
                                    dtype, 2)
         self.model = model
-        self._shape = (self.num_layers, self.num_blocks, self.block_size,
-                       int(self.row.width))
         self._dtype = np.dtype(self.row.dtype)
         _M_ROW_BYTES.labels(model).set(self.row.bytes)
         _M_CACHE_LAYERS.labels(model).set(self.num_layers)
@@ -293,10 +416,8 @@ class PagedKVCache(object):
         self._lock = threading.Lock()
         self._free_slots = list(range(self.num_slots - 1, -1, -1))
         self._slots = {}       # seq_id -> state slot
-        self._free = list(range(self.num_blocks - 1, -1, -1))
-        self._tables = {}      # seq_id -> [block ids]
-        self._owner = {}       # block id -> seq_id, for every block in use
         self._lengths = {}     # seq_id -> tokens written
+        self._wraps = _M_RING_WRAPS.labels(model)
         self._occ = _M_OCC.labels(model)
         self._used = _M_BLOCKS.labels(model)
         self._exhausted = _M_EXHAUSTED.labels(model)
@@ -322,12 +443,45 @@ class PagedKVCache(object):
             weakref.finalize(self, _memory.untag, "recurrent_state",
                              self._ledger_key)
 
+    # the first group's pools under the names the one pool always had
+    # (what reads them: the benchmark's driver, tests, chip_smoke.py);
+    # the programs are handed :meth:`program_pools`
+
+    @property
+    def k_pages(self):
+        return self._groups[0].k_pages
+
+    @property
+    def v_pages(self):
+        return self._groups[0].v_pages
+
+    @property
+    def table_width(self):
+        """Columns of a sequence's table row (:meth:`block_table`) where
+        it is sized by the cache: every group's side by side (known with
+        ``max_tokens`` only)."""
+        return sum(g.width for g in self._groups)
+
+    def program_pools(self):
+        """``(k_pages, v_pages)`` as the model's decode program takes
+        them: the arrays of a cache with one group, a tuple of arrays,
+        one a group, of a cache with several."""
+        if len(self._groups) == 1:
+            return self.k_pages, self.v_pages
+        return (tuple(g.k_pages for g in self._groups),
+                tuple(g.v_pages for g in self._groups))
+
+    def _pool_shape(self, group):
+        return (len(group.layers), group.num_blocks, self.block_size,
+                int(self.row.width))
+
     def _zero_pools(self):
         # drop the old pair first: two pools never exist at once
-        self.k_pages = self.v_pages = None
-        self.k_pages = jnp.zeros(self._shape, self._dtype)
-        if self.row.pools == 2:
-            self.v_pages = jnp.zeros(self._shape, self._dtype)
+        for g in self._groups:
+            g.k_pages = g.v_pages = None
+            g.k_pages = jnp.zeros(self._pool_shape(g), self._dtype)
+            if self.row.pools == 2:
+                g.v_pages = jnp.zeros(self._pool_shape(g), self._dtype)
 
     def _zero_state(self):
         self.state_pools = None
@@ -340,8 +494,8 @@ class PagedKVCache(object):
 
     @property
     def pool_bytes(self):
-        return self.row.pools * int(np.prod(self._shape)) \
-            * self._dtype.itemsize
+        return self.row.pools * self._dtype.itemsize * sum(
+            int(np.prod(self._pool_shape(g))) for g in self._groups)
 
     @property
     def state_bytes(self):
@@ -351,73 +505,88 @@ class PagedKVCache(object):
 
     # -- allocation --------------------------------------------------
 
-    def _blocks_for(self, num_tokens):
-        return -(-max(num_tokens, 1) // self.block_size)
-
     def allocate(self, seq_id, num_tokens):
-        """Reserve capacity for ``num_tokens`` total tokens of ``seq_id``.
+        """Reserve capacity for ``num_tokens`` total tokens of ``seq_id``
+        in every layer group (a window group: its ring, at most).
 
         Idempotent growth: call again with a larger total to extend.
         Raises :class:`CacheExhaustedError` (and allocates nothing) if
-        the free list cannot cover the extension — a failed grow never
-        strands partially-allocated blocks.
+        a group's free list cannot cover the extension — a failed grow
+        never strands partially-allocated blocks.
         """
         chaos.visit("serving.kv_alloc", name=str(seq_id))
-        need_total = self._blocks_for(num_tokens)
         with self._lock:
-            table = self._tables.get(seq_id, [])
-            grow = need_total - len(table)
+            grows = [g.blocks_for(num_tokens, self.block_size)
+                     - len(g.tables.get(seq_id, ())) for g in self._groups]
+            short = next((i for i, (g, grow) in enumerate(
+                zip(self._groups, grows)) if grow > len(g.free)), None)
             no_slot = bool(self.state) and seq_id not in self._slots \
                 and not self._free_slots
-            if grow > len(self._free) or no_slot:
+            if short is not None or no_slot:
                 self._exhausted.inc()
-                used = self.num_blocks - len(self._free)
+                g = self._groups[short or 0]
+                of_group = "" if len(self._groups) == 1 \
+                    else " in layer group %d" % (short or 0)
                 err = CacheExhaustedError(
                     "kv cache exhausted: seq %r needs a state slot, none "
                     "free of %d" % (seq_id, self.num_slots) if no_slot else
-                    "kv cache exhausted: seq %r needs %d more block(s), "
-                    "%d free of %d" % (seq_id, grow, len(self._free),
-                                       self.num_blocks))
+                    "kv cache exhausted: seq %r needs %d more block(s)%s, "
+                    "%d free of %d" % (seq_id, grows[short], of_group,
+                                       len(g.free), g.num_blocks))
                 # occupancy hints the serving front-end forwards in the
                 # 429 error body so clients can back off proportionally
-                err.kv_cache_occupancy = used / float(self.num_blocks)
-                err.kv_cache_blocks_free = len(self._free)
-                err.kv_cache_blocks_total = self.num_blocks
+                # (of the group that ran out)
+                err.kv_cache_occupancy = g.used / float(g.num_blocks)
+                err.kv_cache_blocks_free = len(g.free)
+                err.kv_cache_blocks_total = g.num_blocks
                 raise err
-            if grow > 0:
-                fresh = [self._free.pop() for _ in range(grow)]
-                self._tables[seq_id] = table + fresh
-                self._owner.update(dict.fromkeys(fresh, seq_id))
-                self._lengths.setdefault(seq_id, 0)
-                self._allocs.inc(grow)
+            for g, grow in zip(self._groups, grows):
+                if grow > 0:
+                    fresh = [g.free.pop() for _ in range(grow)]
+                    g.tables[seq_id] = g.tables.get(seq_id, []) + fresh
+                    g.owner.update(dict.fromkeys(fresh, seq_id))
+                    g.peak = max(g.peak, g.used)
+                    self._lengths.setdefault(seq_id, 0)
+                    self._allocs.inc(grow)
             if self.state and seq_id not in self._slots:
                 self._slots[seq_id] = self._free_slots.pop()
             self._set_gauges_locked()
 
     def free(self, seq_id):
-        """Return ``seq_id``'s blocks (and its state slot) to the pool;
-        returns the freed block ids (empty for an unknown sequence —
-        freeing is always safe to call from retire paths)."""
+        """Return ``seq_id``'s blocks, of every layer group (and its
+        state slot), to the pools; returns the block ids freed in the
+        first group (empty for an unknown sequence — freeing is always
+        safe to call from retire paths)."""
         with self._lock:
-            table = self._tables.pop(seq_id, None) or []
             self._lengths.pop(seq_id, None)
             slot = self._slots.pop(seq_id, None)
             if slot is not None:
                 self._free_slots.append(slot)
-            for block in table:
-                del self._owner[block]
-            if table:
-                self._free.extend(reversed(table))
-                self._frees.inc(len(table))
-                self._sess_blocks.observe(len(table))
+            freed = []
+            for g in self._groups:
+                table = g.tables.pop(seq_id, None) or []
+                freed.append(table)
+                for block in table:
+                    del g.owner[block]
+                g.free.extend(reversed(table))
+            count = sum(len(table) for table in freed)
+            if count:
+                self._frees.inc(count)
+                self._sess_blocks.observe(count)
             self._set_gauges_locked()
-            return list(table)
+            return list(freed[0])
 
     def _set_gauges_locked(self):
-        used = self.num_blocks - len(self._free)
+        first = self._groups[0]
+        if len(self._groups) > 1:
+            for i, g in enumerate(self._groups):
+                _M_GROUP_BLOCKS.labels(self.model, str(i)).set(g.used)
+                _M_GROUP_PEAK.labels(self.model, str(i)).set(
+                    g.peak / float(g.num_blocks))
+        used = first.used
         self._used.set(used)
         self._occ.set(used / float(self.num_blocks))
-        self._headroom.set(len(self._free) / float(self.num_blocks))
+        self._headroom.set(len(first.free) / float(self.num_blocks))
         if used:
             written = sum(self._lengths.values())
             self._frag.set(1.0 - written / float(used * self.block_size))
@@ -434,7 +603,7 @@ class PagedKVCache(object):
 
     def sequences(self):
         with self._lock:
-            return sorted(self._tables)
+            return sorted(self._groups[0].tables)
 
     def block_table(self, seq_id, max_blocks):
         """Padded ``int32[max_blocks]`` table for a decode dispatch, a
@@ -452,9 +621,22 @@ class PagedKVCache(object):
         callers are ``warmup()`` and code that drives a backend by
         hand, which build the table of every call.
         """
-        table = self._tables.get(seq_id)
+        table = self._groups[0].tables.get(seq_id)
         if table is None:
             raise MXNetError("unknown sequence %r" % (seq_id,))
+        if len(self._groups) > 1:
+            # the groups' tables side by side, each as wide as the
+            # cache made it (a window group's: its ring)
+            if max_blocks != self.table_width:
+                raise MXNetError(
+                    "a table row of this cache's %d layer groups is %d "
+                    "wide, not %d" % (len(self._groups), self.table_width,
+                                      max_blocks))
+            out, at = np.zeros(max_blocks, dtype=np.int32), 0
+            for g in self._groups:
+                out[at:at + len(g.tables[seq_id])] = g.tables[seq_id]
+                at += g.width
+            return out
         if len(table) > max_blocks:
             raise MXNetError(
                 "sequence %r spans %d blocks > table width %d"
@@ -476,7 +658,7 @@ class PagedKVCache(object):
         out = np.full(len(tables), self.num_slots, dtype=np.int32)
         with self._lock:
             for row in np.flatnonzero(np.asarray(positions)):
-                owner = self._owner.get(int(tables[row, 0]))
+                owner = self._groups[0].owner.get(int(tables[row, 0]))
                 if owner is None:
                     raise MXNetError(
                         "state_slots: row %d reads block %d, which is "
@@ -505,14 +687,25 @@ class PagedKVCache(object):
     # -- writes ------------------------------------------------------
 
     def _write_locked(self, k, v, blocks, offsets):
-        """Scatter into the donated pools and re-bind them; returns the
-        host bytes handed to the device (the slot indices)."""
+        """Scatter into the donated pools and re-bind them: ``blocks``
+        and ``offsets`` are a list of vectors, one a layer group.
+        Returns the host bytes handed to the device (the slot
+        indices)."""
+        groups = self._groups
         try:
-            self.k_pages, self.v_pages = _write_pages(
-                self.k_pages, self.v_pages, k, v, blocks, offsets)
+            if len(groups) == 1:
+                g = groups[0]
+                g.k_pages, g.v_pages = _write_pages(
+                    g.k_pages, g.v_pages, k, v, blocks[0], offsets[0])
+            else:
+                k_pools, v_pools = _write_groups(
+                    *self.program_pools(), k, v, tuple(blocks),
+                    tuple(offsets), layers=tuple(g.layers for g in groups))
+                for g, k_pool, v_pool in zip(groups, k_pools, v_pools):
+                    g.k_pages, g.v_pages = k_pool, v_pool
         except Exception as exc:
-            if not any(p is not None and p.is_deleted()
-                       for p in (self.k_pages, self.v_pages)):
+            if not any(p is not None and p.is_deleted() for g in groups
+                       for p in (g.k_pages, g.v_pages)):
                 raise       # refused before donation: pool untouched
             self._zero_pools()
             self._lengths = dict.fromkeys(self._lengths, 0)
@@ -521,13 +714,17 @@ class PagedKVCache(object):
                 "%s); the pool was rebuilt zeroed and every live "
                 "sequence lost its pages"
                 % (self.model, type(exc).__name__, exc)) from exc
-        return blocks.nbytes + offsets.nbytes
+        return sum(a.nbytes for a in blocks) \
+            + sum(a.nbytes for a in offsets)
 
     def write_prefill(self, seq_id, k, v, length, state=None):
         """Store prompt K/V: ``k``/``v`` device arrays ``[L, T, heads *
         dim]`` (or ``[L, T, heads, dim]``) as the prefill dispatch
         produced them, ``T`` its bucket; positions ``< length`` are
-        written, the bucket's pad positions are dropped.  ``state``
+        written, the bucket's pad positions are dropped; a window group
+        takes the prompt's last blocks, as many as the sequence's ring
+        has entries (the tokens before them have left every window a
+        decode step will look through).  ``state``
         (a model with recurrent layers): the prompt's state rows taken
         at ``length``, written to version ``length % 2`` of the
         sequence's slot, where the decode step at position ``length``
@@ -541,18 +738,24 @@ class PagedKVCache(object):
         """
         bucket, length = int(k.shape[1]), int(length)
         with self._lock:
-            blocks = np.full(bucket, self.num_blocks, dtype=np.int32)
-            offsets = np.zeros(bucket, dtype=np.int32)
-            table = self._tables.get(seq_id)
-            if (table is None or length > bucket
-                    or len(table) < self._blocks_for(length)):
-                raise MXNetError(
-                    "write_prefill(%r, %d tokens of a bucket of %d) "
-                    "exceeds allocation" % (seq_id, length, bucket))
             positions = np.arange(length)
-            blocks[:length] = np.asarray(table, dtype=np.int32)[
-                positions // self.block_size]
-            offsets[:length] = positions % self.block_size
+            blocks, offsets = [], []
+            for g in self._groups:
+                at = np.full(bucket, g.num_blocks, dtype=np.int32)
+                table = g.tables.get(seq_id)
+                if (table is None or length > bucket or len(table)
+                        < g.blocks_for(length, self.block_size)):
+                    raise MXNetError(
+                        "write_prefill(%r, %d tokens of a bucket of %d) "
+                        "exceeds allocation" % (seq_id, length, bucket))
+                # the first token kept: a ring holds the last blocks
+                first = max(0, -(-length // self.block_size)
+                            - len(table)) * self.block_size
+                at[first:length] = np.asarray(table, dtype=np.int32)[
+                    g.entry(positions[first:], self.block_size)]
+                blocks.append(at)
+                offsets.append(np.zeros(bucket, dtype=np.int32))
+                offsets[-1][:length] = positions % self.block_size
             staged = self._write_locked(k, v, blocks, offsets)
             if self.state:
                 staged += self._write_state_locked(
@@ -598,44 +801,79 @@ class PagedKVCache(object):
             raise MXNetError(
                 "write_tokens: tables %r, positions %r, %d rows"
                 % (tables.shape, positions.shape, bucket))
-        index = positions // self.block_size
-        if positions.min() < 0 or index.max() >= tables.shape[1]:
+        groups = self._groups
+        # a cache with one group takes a table of any width (its
+        # caller's); the groups of several lie side by side in a row
+        widths = [tables.shape[1]] if len(groups) == 1 \
+            else [g.width for g in groups]
+        if sum(widths) != tables.shape[1]:
             raise MXNetError(
-                "write_tokens: positions %d..%d outside a table of %d "
-                "blocks" % (positions.min(), positions.max(),
-                            tables.shape[1]))
+                "write_tokens: a table row of this cache's %d layer "
+                "groups is %d wide, not %d"
+                % (len(groups), sum(widths), tables.shape[1]))
         live = np.flatnonzero(positions)
-        blocks = np.full(bucket, self.num_blocks, dtype=np.int32)
-        blocks[live] = tables[live, index[live]]
         offsets = positions % self.block_size
-        with self._lock:
-            owners = [self._owner.get(b) for b in blocks[live].tolist()]
-            if None in owners:
-                row = live[owners.index(None)]
+        blocks, column, wraps = [], 0, 0
+        for g, width in zip(groups, widths):
+            index = g.entry(positions, self.block_size)
+            if positions.min() < 0 or index.max() >= width:
                 raise MXNetError(
-                    "write_tokens: row %d at position %d exceeds "
-                    "allocation (block %d is free)"
-                    % (row, positions[row], blocks[row]))
-            staged = self._write_locked(k, v, blocks, offsets)
+                    "write_tokens: positions %d..%d outside a table of %d "
+                    "blocks" % (positions.min(), positions.max(), width))
+            at = np.full(bucket, g.num_blocks, dtype=np.int32)
+            at[live] = tables[live, column + index[live]]
+            blocks.append(at)
+            column += width
+            if g.ring is not None:
+                # a step that begins a block whose entry held another
+                wraps += int(((offsets[live] == 0) & (
+                    positions[live] // self.block_size >= g.ring)).sum())
+        with self._lock:
+            for g, at in zip(groups, blocks):
+                owners = [g.owner.get(b) for b in at[live].tolist()]
+                if None in owners:
+                    row = live[owners.index(None)]
+                    raise MXNetError(
+                        "write_tokens: row %d at position %d exceeds "
+                        "allocation (block %d is free)"
+                        % (row, positions[row], at[row]))
+            staged = self._write_locked(k, v, blocks,
+                                        [offsets] * len(groups))
             for seq_id, pos in zip(owners, positions[live].tolist()):
                 if pos >= self._lengths[seq_id]:
                     self._lengths[seq_id] = pos + 1
+        if wraps:
+            self._wraps.inc(wraps)
         return staged
 
     # -- introspection ----------------------------------------------
 
     def stats(self):
+        """The gauges as a dict.  ``blocks``, ``used``, ``free``,
+        ``occupancy``, ``headroom`` and ``fragmentation`` are the
+        **first** layer group's (a model with window layers puts its
+        global layers there: the blocks that grow with the context);
+        ``groups`` has ``blocks``, ``used``, ``occupancy``, ``peak``
+        (the largest occupancy so far), ``window`` and ``layers`` of
+        every group, the first among them."""
         with self._lock:
-            used = self.num_blocks - len(self._free)
+            first = self._groups[0]
+            used = first.used
             written = sum(self._lengths.values())
             return {"blocks": self.num_blocks, "used": used,
-                    "free": len(self._free),
+                    "free": len(first.free),
                     "occupancy": used / float(self.num_blocks),
-                    "headroom": len(self._free) / float(self.num_blocks),
+                    "headroom": len(first.free) / float(self.num_blocks),
                     "fragmentation": (1.0 - written
                                       / float(used * self.block_size))
                                      if used else 0.0,
-                    "sequences": len(self._tables),
+                    "sequences": len(first.tables),
+                    "groups": [
+                        {"layers": len(g.layers), "window": g.window,
+                         "blocks": g.num_blocks, "used": g.used,
+                         "occupancy": g.used / float(g.num_blocks),
+                         "peak": g.peak / float(g.num_blocks)}
+                        for g in self._groups],
                     "block_size": self.block_size,
                     "pool_bytes": self.pool_bytes,
                     "state_slots": self.num_slots,

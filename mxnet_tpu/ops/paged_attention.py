@@ -160,7 +160,8 @@ def _latent_decode_xla(q, row_step, pages, block_tables, context_lens,
 
 
 def gqa_paged_decode_attention(q, k_step, v_step, k_pages, v_pages,
-                               block_tables, context_lens, sm_scale):
+                               block_tables, context_lens, sm_scale,
+                               window=None):
     """One decode step of grouped-query attention over key and value
     pools.
 
@@ -179,8 +180,40 @@ def gqa_paged_decode_attention(q, k_step, v_step, k_pages, v_pages,
     with pages that are whole tiles, the block-table walk
     (:func:`_walk_pages`) reads the blocks that hold live tokens and no
     other, under the body :func:`_gqa_walk_body` names for the head's
-    width; elsewhere XLA gathers every table block and masks."""
+    width; elsewhere XLA gathers every table block and masks.
+
+    ``window`` (static; a sliding-window layer): the row at position
+    ``p = context_lens - 1`` sees the cached keys ``p - window + 1 .. p
+    - 1`` and itself, and ``block_tables`` is a **ring**: token ``j``
+    lies in entry ``(j // block_size) mod ring`` of its row, ``ring``
+    the table's width, which has to hold the window and a block more
+    (``window / block_size + 1`` entries, or the row's whole horizon
+    where that is shorter: the block a step writes never holds a key
+    it still reads).  The walk visits the
+    ring's live entries alone, ``window / block_size + 1`` blocks at the
+    most whatever the context, in ring order (a softmax has none), and
+    masks by the key's position, so that the keys that have left the
+    window and still lie in their block do not count.  A row whose
+    whole horizon fits the ring never wraps, and its table reads as any
+    other.  Under the scope ``paged_decode_gqa_window``."""
     mode = _platform.pallas_mode()
+    if window is not None:
+        blk = k_pages.shape[1]
+        if window % blk or window < blk:
+            raise ValueError("a window of %d tokens is not whole blocks "
+                             "of %d" % (window, blk))
+        if mode and _walk_tiles(k_pages, v_pages):
+            if q.shape[-1] % _LANE:
+                raise NotImplementedError(
+                    "the ring walk is built for heads of whole lane tiles")
+            return _gqa_decode_pallas(
+                q, k_step, v_step, k_pages, v_pages, block_tables,
+                context_lens, float(sm_scale), mode == "interpret",
+                window=int(window))
+        with jax.named_scope("paged_decode_gqa_window"):
+            return _gqa_decode_xla(q, k_step, v_step, k_pages, v_pages,
+                                   block_tables, context_lens, sm_scale,
+                                   window)
     if mode and _walk_tiles(k_pages, v_pages):
         return _gqa_walk_body(q.shape[-1])(
             q, k_step, v_step, k_pages, v_pages, block_tables, context_lens,
@@ -190,8 +223,20 @@ def gqa_paged_decode_attention(q, k_step, v_step, k_pages, v_pages,
                                block_tables, context_lens, sm_scale)
 
 
+def _ring_positions(context_lens, ring, blk):
+    """``int32 [B, ring * blk]``: the position of the token that lies
+    in each slot of a row's ring as of a step at position ``p =
+    context_lens - 1``: entry ``e`` holds the newest block ``b <= (p -
+    1) // blk`` with ``b mod ring == e`` (negative: none yet)."""
+    top = (context_lens - 2) // blk                  # the newest block
+    entry = lax.broadcasted_iota(jnp.int32, (1, ring, 1), 1)
+    block = top[:, None, None] - (top[:, None, None] - entry) % ring
+    at = block * blk + lax.broadcasted_iota(jnp.int32, (1, 1, blk), 2)
+    return at.reshape(context_lens.shape[0], ring * blk)
+
+
 def _gqa_decode_xla(q, k_step, v_step, k_pages, v_pages, block_tables,
-                    context_lens, sm_scale):
+                    context_lens, sm_scale, window=None):
     bsz, max_blocks = block_tables.shape
     groups, dim = k_step.shape[1:]
     kmax = max_blocks * k_pages.shape[1]
@@ -201,8 +246,17 @@ def _gqa_decode_xla(q, k_step, v_step, k_pages, v_pages, block_tables,
     qg = q.reshape(bsz, groups, -1, dim)                # [B, G, R, D]
     s = jnp.einsum("bgrd,bkgd->bgrk", qg.astype(keys.dtype), keys,
                    preferred_element_type=f32) * sm_scale
-    pos = lax.broadcasted_iota(jnp.int32, (1, 1, 1, kmax), 3)
-    s = jnp.where(pos < (context_lens - 1)[:, None, None, None], s, NEG_INF)
+    cached = (context_lens - 1)[:, None, None, None]
+    if window is None:
+        pos = lax.broadcasted_iota(jnp.int32, (1, 1, 1, kmax), 3)
+        seen = pos < cached
+    else:
+        pos = _ring_positions(context_lens, max_blocks,
+                              k_pages.shape[1])[:, None, None, :]
+        seen = (pos >= 0) & (pos < cached) & (cached - pos < window)
+        # what a masked slot holds is anyone's: no bit of it may count
+        values = jnp.where(seen[:, 0, 0, :, None, None], values, 0)
+    s = jnp.where(seen, s, NEG_INF)
     s_self = jnp.einsum("bgrd,bgd->bgr", qg.astype(f32),
                         k_step.astype(f32)) * sm_scale
     m = jnp.maximum(jnp.max(s, axis=-1), s_self)
@@ -258,11 +312,15 @@ def _walk_chunk_pages(pools, max_blocks):
 
 
 def _walk_kernel(tables_ref, lens_ref, *refs, n_rows, n_pools, pages, blk,
-                 max_blocks, init, chunk, finish):
+                 max_blocks, init, chunk, finish, window=None):
     """One row of the batch.  ``refs``: the row's operands and those
     every row shares, the pools (in HBM), the output, then scratch: a
     two-slot chunk buffer a pool, the copies' semaphores ``[pool,
-    slot]`` and the body's state."""
+    slot]`` and the body's state.  With a ``window`` the table is a
+    ring of ``max_blocks`` entries: the walk begins at the block that
+    holds the oldest key the row still sees, reads block ``b`` from
+    entry ``b mod max_blocks``, and hands the body of its first chunk
+    the tokens to skip there."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -276,6 +334,11 @@ def _walk_kernel(tables_ref, lens_ref, *refs, n_rows, n_pools, pages, blk,
     tokens = pages * blk
     cached = jnp.maximum(lens_ref[b] - 1, 0)
     live_pages = (cached + blk - 1) // blk
+    first_page = oldest = 0
+    if window is not None:
+        oldest = jnp.maximum(cached - window + 1, 0)
+        first_page = oldest // blk
+        live_pages = live_pages - first_page
     n_chunks = (live_pages + pages - 1) // pages
 
     def copies(c, slot, wait):
@@ -286,7 +349,9 @@ def _walk_kernel(tables_ref, lens_ref, *refs, n_rows, n_pools, pages, blk,
 
             @pl.when(page < live_pages)
             def _():
-                at = 0 if wait else tables_ref[b * max_blocks + page]
+                entry = page if window is None \
+                    else lax.rem(first_page + page, max_blocks)
+                at = 0 if wait else tables_ref[b * max_blocks + entry]
                 for n in range(n_pools):
                     copy = pltpu.make_async_copy(
                         pool_refs[n].at[at],
@@ -308,18 +373,34 @@ def _walk_kernel(tables_ref, lens_ref, *refs, n_rows, n_pools, pages, blk,
             copies(c + 1, 1 - slot, False)
 
         copies(c, slot, True)
-        live = cached - c * tokens
+        live = cached - (c * tokens if window is None
+                         else first_page * blk + c * tokens)
         held = [buf.at[slot] for buf in bufs]
+        if window is None:
+            @pl.when(live >= tokens)
+            def _():
+                chunk(row_refs, held, state, None)
 
-        @pl.when(live >= tokens)
+            @pl.when(live < tokens)
+            def _():
+                # the row's last chunk: its tail was not copied and
+                # holds whatever the buffer held
+                chunk(row_refs, held, state, live)
+
+            return carry
+
+        # the walk's first chunk begins with the keys of its block
+        # that have left the window
+        skip = jnp.where(c == 0, oldest - first_page * blk, 0)
+        whole = (live >= tokens) & (skip == 0)
+
+        @pl.when(whole)
         def _():
             chunk(row_refs, held, state, None)
 
-        @pl.when(live < tokens)
+        @pl.when(jnp.logical_not(whole))
         def _():
-            # the row's last chunk: its tail was not copied and holds
-            # whatever the buffer held
-            chunk(row_refs, held, state, live)
+            chunk(row_refs, held, state, (skip, live))
 
         return carry
 
@@ -328,7 +409,7 @@ def _walk_kernel(tables_ref, lens_ref, *refs, n_rows, n_pools, pages, blk,
 
 
 def _walk_pages(init, chunk, finish, rows, shared, pools, block_tables,
-                context_lens, out, state, interpret=False):
+                context_lens, out, state, interpret=False, window=None):
     """Run ``init`` / ``chunk`` / ``finish`` over every row's live
     blocks.
 
@@ -341,7 +422,10 @@ def _walk_pages(init, chunk, finish, rows, shared, pools, block_tables,
     ``chunk(row_refs, held, state, live)`` folds ``pages * block_size``
     tokens (``held``: one ``[tokens, W]`` ref a pool) into the state;
     ``live`` is None where every token counts and else the number that
-    do, the rest being unspecified bits."""
+    do, the rest being unspecified bits.  With a ``window`` (tokens)
+    ``block_tables`` is a ring a row (:func:`gqa_paged_decode_attention`)
+    and ``live`` is None or ``(skip, end)``: the chunk's tokens ``skip
+    .. end - 1`` count (:func:`_counted`)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -351,7 +435,7 @@ def _walk_pages(init, chunk, finish, rows, shared, pools, block_tables,
     kernel = functools.partial(
         _walk_kernel, n_rows=len(rows) + len(shared), n_pools=len(pools),
         pages=pages, blk=blk, max_blocks=max_blocks, init=init, chunk=chunk,
-        finish=finish)
+        finish=finish, window=window)
 
     def one_row(x):
         return pl.BlockSpec((1,) + x.shape[1:],
@@ -536,15 +620,36 @@ def _latent_decode_pallas(q, row_step, pages, block_tables, context_lens,
             interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+def _counted(live, shape, axis):
+    """Which of a chunk's tokens, counted along ``axis`` of ``shape``,
+    count: those before ``live``, or from ``live[0]`` to before
+    ``live[1]`` where the walk is a window's."""
+    at = lax.broadcasted_iota(jnp.int32, shape, axis)
+    if isinstance(live, tuple):
+        return (at >= live[0]) & (at < live[1])
+    return at < live
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "interpret", "window"))
 def _gqa_decode_pallas(q, k_step, v_step, k_pages, v_pages, block_tables,
-                       context_lens, sm_scale, interpret=False):
+                       context_lens, sm_scale, interpret=False,
+                       window=None):
     """The grouped-query decode over the walk: a chunk's key rows hold
     every key-value head side by side (``D`` a whole number of lane
     tiles), so a head's keys are a slice of lanes and its ``Hq / Hkv``
     queries one small product with them on the MXU, in the pools' dtype;
     scores, softmax and accumulator stay float32.  Jitted so that a
-    model's layers share one trace and one lowering of the kernel."""
+    model's layers share one trace and one lowering of the kernel.
+
+    A key-value head's queries are a run of rows of the ``[Hq, .]``
+    state, whole sublane tiles where ``Hq / Hkv`` is a multiple of 8.
+    Where it is not and there are several key-value heads (7 queries a
+    head), every head's run is padded to whole tiles with dead rows
+    (queries of zeros: a uniform softmax nobody reads), which costs the
+    MXU nothing (it takes 8 rows a pass either way), the VPU an eighth
+    more softmax, and XLA a pad of the queries and a slice of the
+    output, ``[B, Hq, D]`` each.  ``window``: the walk over a ring."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -552,6 +657,16 @@ def _gqa_decode_pallas(q, k_step, v_step, k_pages, v_pages, block_tables,
     groups = k_step.shape[1]
     per = heads // groups
     f32 = jnp.float32
+    dead = -per % 8 if groups > 1 else 0
+    if dead:
+        q = jnp.pad(q.reshape(bsz, groups, per, dim),
+                    ((0, 0), (0, 0), (0, dead), (0, 0)))
+        out = _gqa_decode_pallas(
+            q.reshape(bsz, groups * (per + dead), dim), k_step, v_step,
+            k_pages, v_pages, block_tables, context_lens, sm_scale,
+            interpret, window)
+        return out.reshape(bsz, groups, per + dead, dim)[:, :, :per] \
+            .reshape(bsz, heads, dim)
 
     def group(ref, g):                  # a group's rows of [Hq, .] state
         return ref.at[pl.ds(g * per, per)]
@@ -574,8 +689,7 @@ def _gqa_decode_pallas(q, k_step, v_step, k_pages, v_pages, block_tables,
         keys, values = held[0][...], held[1][...]               # [T, W]
         tokens = keys.shape[0]
         if live is not None:
-            at = lax.broadcasted_iota(jnp.int32, (tokens, 1), 0)
-            values = jnp.where(at < live, values, 0)
+            values = jnp.where(_counted(live, (tokens, 1), 0), values, 0)
         for g in range(groups):
             lanes = slice(g * dim, (g + 1) * dim)
             q_g = row_refs[0][0, g * per:(g + 1) * per, :]
@@ -584,8 +698,7 @@ def _gqa_decode_pallas(q, k_step, v_step, k_pages, v_pages, block_tables,
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=f32) * sm_scale          # [R, T]
             if live is not None:
-                at = lax.broadcasted_iota(jnp.int32, (1, tokens), 1)
-                s = jnp.where(at < live, s, NEG_INF)
+                s = jnp.where(_counted(live, (1, tokens), 1), s, NEG_INF)
             m_prev = group(m_ref, g)[...]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -608,14 +721,15 @@ def _gqa_decode_pallas(q, k_step, v_step, k_pages, v_pages, block_tables,
     rows = (q.astype(f32), k_step.reshape(bsz, 1, width),
             v_step.reshape(bsz, 1, width))
     # the scope names the kernel in a trace; it has to be the innermost
-    with jax.named_scope("paged_decode_gqa_attention"):
+    with jax.named_scope("paged_decode_gqa_attention" if window is None
+                         else "paged_decode_gqa_window"):
         return _walk_pages(
             init, chunk, finish, rows, (), (k_pages, v_pages),
             block_tables, context_lens,
             jax.ShapeDtypeStruct((bsz, heads, dim), q.dtype),
             [pltpu.VMEM((heads, 1), f32), pltpu.VMEM((heads, 1), f32),
              pltpu.VMEM((heads, dim), f32)],
-            interpret=interpret)
+            interpret=interpret, window=window)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))

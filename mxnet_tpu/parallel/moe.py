@@ -328,9 +328,14 @@ def few_rows_hit_most(tokens, k, n_experts):
     return tokens <= EVERY_ROW_LIMIT and reached > 0.5
 
 
+#: the gate's activation, by the name a caller gives it: SwiGLU's and
+#: ReGLU's (``relu(W_gate x) * W_up x``)
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def dropless_experts(x, chosen, gates, w_gate, w_up, w_down, held,
                      valid=None, expert_axis=None, every_row=False,
-                     n_experts=None):
+                     n_experts=None, activation="silu"):
     """The routed part of an expert layer, for the experts held here.
 
     ``x`` ``[T, d]``; ``chosen``/``gates`` ``[T, k]`` from the router,
@@ -364,7 +369,9 @@ def dropless_experts(x, chosen, gates, w_gate, w_up, w_down, held,
     with the call's shapes it says how many rows an expert is expected
     to get, and so what share of the rows the grouped kernel computes
     is kept (the gauge ``moe_grouped_walked_share``; the products'
-    tiles are :func:`grouped_tiling`'s)."""
+    tiles are :func:`grouped_tiling`'s).  ``activation`` (static) names
+    the gate's: ``"silu"`` (SwiGLU) or ``"relu"`` (ReGLU)."""
+    act = ACTIVATIONS[activation]
     tokens, k = chosen.shape
     first, count = held[0], int(w_gate.shape[0])
     if expert_axis is not None:
@@ -377,10 +384,10 @@ def dropless_experts(x, chosen, gates, w_gate, w_up, w_down, held,
     sizes = jnp.zeros(count + 1, jnp.int32).at[key.reshape(-1)].add(
         1)[:count]
     if every_row:
-        y = _every_row(x, key, gates, w_gate, w_up, w_down)
+        y = _every_row(x, key, gates, w_gate, w_up, w_down, act)
     else:
         y = _grouped_chunks(x, key, local, gates, sizes, w_gate, w_up,
-                            w_down, n_experts or count)
+                            w_down, n_experts or count, act)
     counts = jnp.stack([routed.sum(), local.sum(), (sizes > 0).sum(),
                         jnp.int32(1)]).astype(jnp.int32)
     if expert_axis is not None:
@@ -426,7 +433,7 @@ def grouped_runs(tokens, k, row_bytes):
 
 
 def _grouped_chunks(x, key, local, gates, sizes, w_gate, w_up, w_down,
-                    n_experts):
+                    n_experts, act=jax.nn.silu):
     """:func:`_grouped_rows` over the call's rows, whole where its
     pairs' rows are at most :data:`GROUPED_ROW_BYTES` and else in the
     fewest equal runs of rows that are (a run sorts and groups its own
@@ -444,14 +451,14 @@ def _grouped_chunks(x, key, local, gates, sizes, w_gate, w_up, w_down,
         expected / (visits * tile))
     if runs == 1:
         return _grouped_rows(x, key, local, gates, sizes, w_gate, w_up,
-                             w_down)
+                             w_down, act)
 
     def one(run):
         x_r, key_r, local_r, gates_r = run
         sizes_r = jnp.zeros(count + 1, jnp.int32).at[
             key_r.reshape(-1)].add(1)[:count]
         return _grouped_rows(x_r, key_r, local_r, gates_r, sizes_r, w_gate,
-                             w_up, w_down)
+                             w_up, w_down, act)
 
     y = jax.lax.map(one, tuple(a.reshape((runs, tokens // runs)
                                          + a.shape[1:])
@@ -539,7 +546,8 @@ def grouped_tiling(pairs, contraction, output, itemsize=2):
     return None
 
 
-def _grouped_rows(x, key, local, gates, sizes, w_gate, w_up, w_down):
+def _grouped_rows(x, key, local, gates, sizes, w_gate, w_up, w_down,
+                  act=jax.nn.silu):
     """The held experts' gated sum as grouped products over the pairs
     sorted by ``key`` (an expert's place here; ``G`` for a pair that is
     not computed here), each under the tiles :func:`grouped_tiling`
@@ -557,7 +565,7 @@ def _grouped_rows(x, key, local, gates, sizes, w_gate, w_up, w_down):
                                       preferred_element_type=out)
 
     rows = x[order // k]
-    h = (jax.nn.silu(grouped(rows, w_gate, jnp.float32))
+    h = (act(grouped(rows, w_gate, jnp.float32))
          * grouped(rows, w_up, jnp.float32)).astype(x.dtype)
     y = grouped(h, w_down, x.dtype)
     # back to (token, choice) order; the rows of absent experts were
@@ -568,7 +576,7 @@ def _grouped_rows(x, key, local, gates, sizes, w_gate, w_up, w_down):
                       preferred_element_type=jnp.float32).astype(x.dtype)
 
 
-def _every_row(x, key, gates, w_gate, w_up, w_down):
+def _every_row(x, key, gates, w_gate, w_up, w_down, act=jax.nn.silu):
     """The same sum with every held expert computed over every row: a
     row's gate for an expert it did not choose is 0."""
     count = w_gate.shape[0]
@@ -578,7 +586,7 @@ def _every_row(x, key, gates, w_gate, w_up, w_down):
     def every(spec, a, w, out):
         return jnp.einsum(spec, a, w, preferred_element_type=out)
 
-    h = (jax.nn.silu(every("td,gdh->gth", x, w_gate, jnp.float32))
+    h = (act(every("td,gdh->gth", x, w_gate, jnp.float32))
          * every("td,gdh->gth", x, w_up, jnp.float32)).astype(x.dtype)
     y = every("gth,ghd->gtd", h, w_down, x.dtype)
     return every("gtd,tg->td", y, gate_of.astype(x.dtype),

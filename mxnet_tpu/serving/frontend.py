@@ -361,7 +361,10 @@ class _StreamWriter(object):
 
 class ServingFrontend(object):
     """Handle for a running front-end: ``.port``, ``.url``,
-    ``.close()``.  Also a context manager."""
+    ``.close()``.  Also a context manager.  A closed front end lets go
+    of its server and of ``target``: the handle may outlive them (a
+    caller that keeps it keeps no scheduler, and so no backend's
+    weights and cache pools, on the device)."""
 
     def __init__(self, httpd, thread, target, writer):
         self._httpd = httpd
@@ -372,10 +375,14 @@ class ServingFrontend(object):
         self.url = "http://%s:%d" % (httpd.server_address[0], self.port)
 
     def close(self):
+        if self._httpd is None:
+            return
         self._httpd.shutdown()
         self._httpd.server_close()
         self._thread.join(timeout=5)
         self._writer.close()
+        # the server's handler class closes over the target
+        self._httpd = self._thread = self.target = None
 
     def __enter__(self):
         return self

@@ -502,12 +502,17 @@ class LMBackend(Backend):
             num_layers=definition.cache_layers or self.cfg["num_layers"],
             row=definition.cache_row, block_size=block_size,
             num_blocks=num_blocks, model=model, state=definition.state,
-            state_slots=state_slots)
+            state_slots=state_slots, groups=definition.cache_groups,
+            max_tokens=self.cfg["seq_len"])
         # every sequence gets a fixed-width block table: the decode jit
         # signature depends only on the batch bucket, never on how long
-        # any sequence has run — the zero-recompile contract
-        self.max_blocks_per_seq = -(-self.cfg["seq_len"]
-                                    // self.cache.block_size)
+        # any sequence has run — the zero-recompile contract.  (Of a
+        # model with layer groups the row holds every group's table,
+        # side by side: ``num_blocks`` is then a number a group.)
+        self.max_blocks_per_seq = self.cache.table_width
+        # the windows of the model's sliding-window layer groups
+        self.windows = tuple(window for _, window in
+                             definition.cache_groups or () if window)
         self._jits = {}
         self._jit_lock = threading.Lock()
         self._decode_program = with_greedy_ids(definition.decode)
@@ -675,7 +680,7 @@ class LMBackend(Backend):
         pool write its host copy).  A model with recurrent state is
         handed the state pool donated, with the rows' state slots, and
         the cache is re-bound to what it gives back."""
-        args = (tokens, positions, self.cache.k_pages, self.cache.v_pages,
+        args = (tokens, positions, *self.cache.program_pools(),
                 table.device, context_lens)
         if table.slots is None:
             fn, cold = self._jit(("decode", len(positions)),
@@ -865,8 +870,8 @@ class _GenLane(object):
                  "tenant_handles", "seated", "tables",
                  "m_req", "m_prefill", "m_itl", "m_depth", "m_occ",
                  "m_active", "m_requests", "m_tokens", "m_steps",
-                 "m_context", "m_compiles", "m_errors", "m_reprefills",
-                 "m_table_rows")
+                 "m_context", "m_window", "m_compiles", "m_errors",
+                 "m_reprefills", "m_table_rows")
 
     def __init__(self, entry, weight_fn=None):
         self.entry = entry
@@ -960,6 +965,15 @@ class GenerationScheduler(object):
                 "Cached tokens the decode steps attended over: the live "
                 "sequences' context lengths, summed over steps",
                 ["model"]),
+            "window": reg.counter(
+                "generation_decode_window_tokens_total",
+                "Cached tokens a sliding-window layer's decode attended "
+                "over: the smaller of a live sequence's context length "
+                "and the window, summed over steps (and over the model's "
+                "windows, where it has several); against "
+                "generation_decode_context_tokens_total, what a walk of "
+                "the whole context would have read, it is what the "
+                "window saves", ["model"]),
             "table_rows": reg.counter(
                 "generation_block_table_rows_built_total",
                 "Block-table rows built: one a sequence that joined the "
@@ -1039,6 +1053,7 @@ class GenerationScheduler(object):
                           ("requests", "m_requests"),
                           ("tokens", "m_tokens"), ("steps", "m_steps"),
                           ("context", "m_context"),
+                          ("window", "m_window"),
                           ("compiles", "m_compiles"),
                           ("errors", "m_errors"),
                           ("reprefills", "m_reprefills"),
@@ -1608,6 +1623,9 @@ class GenerationScheduler(object):
             if _metrics.metrics_enabled():
                 lane.m_steps.inc()
                 lane.m_context.inc(int(context[:n].sum()))
+                for window in getattr(backend, "windows", ()):
+                    lane.m_window.inc(
+                        int(_np.minimum(context[:n], window).sum()))
                 lane.m_occ.set(n / float(bucket))
                 if cold:
                     lane.m_compiles.inc()
