@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import platform as _platform
+from ..observability import metrics as _metrics
 from .attention import NEG_INF, stable_scores, stable_softmax
 from .fused.parity import case_rng, register_parity
 
@@ -284,13 +285,41 @@ def _gqa_decode_xla(q, k_step, v_step, k_pages, v_pages, block_tables,
 # A block that holds no live token costs neither a copy nor arithmetic.
 # The current token is the walk's initial state (``init``), so the pool
 # is read as of before the step.
+#
+# The copies stay in flight across chunks and rows: under a row's last
+# chunk the walk fetches the first chunk of the row after it (the
+# programs run in order on one core, and the chunk buffers and their
+# semaphores are scratch that outlives a program), so that only row 0
+# begins with nothing in flight.  A chunk whose pages are all live is
+# started without a branch a page and awaited once a pool; a row's last
+# chunk goes page by page, since its tail must not be read, and where
+# no more than half of it is live the body folds that half alone.
 
 #: bytes of pool pages (all pools of the cache together) a chunk of the
 #: walk holds; a second chunk is in flight behind it.  A page is 2 x 64
-#: KB (GPT-2 medium's float32 key and value rows) or 20 KB (a 640-wide
-#: bfloat16 latent row): one page a loop step would leave the loop's
-#: own cost and a copy's latency larger than the arithmetic on it.
-_WALK_CHUNK_BYTES = 1 << 20
+#: KB (GPT-2 medium's float32 key and value rows), 2 x 16 KB (512-wide
+#: bfloat16 rows) or 20 KB (a 640-wide bfloat16 latent row): one page a
+#: loop step would leave the loop's own cost and a copy's latency larger
+#: than the arithmetic on it.  2 MiB since a row's first chunk is
+#: fetched under the row before it (1 MiB while every larger chunk paid
+#: for itself at the head of every row): over contexts as they are
+#: served the best of 1, 2 and 4 MiB at four of the seven served shapes
+#: and within 1.5% of it at two; the ring gains 6% more from 4 MiB,
+#: which costs five shapes 7-12% (``tools/walk_sweep.py``).
+_WALK_CHUNK_BYTES = 2 << 20
+
+_M_CHUNK_TOKENS = _metrics.gauge(
+    "paged_decode_walk_chunk_tokens",
+    "Cached tokens a chunk of the block-table walk built last holds, by "
+    "the kernel's scope", ["kernel"])
+_M_CHUNK_WAITS = _metrics.gauge(
+    "paged_decode_walk_waits_per_chunk",
+    "Waits the walk built last makes for a chunk whose pages are all "
+    "live (one a pool), by the kernel's scope", ["kernel"])
+_M_ROWS_AHEAD = _metrics.gauge(
+    "paged_decode_walk_rows_ahead",
+    "Rows ahead of the one it folds whose first chunk the walk built "
+    "last keeps in flight, by the kernel's scope", ["kernel"])
 
 
 def _walk_tiles(*pools):
@@ -316,11 +345,18 @@ def _walk_kernel(tables_ref, lens_ref, *refs, n_rows, n_pools, pages, blk,
     """One row of the batch.  ``refs``: the row's operands and those
     every row shares, the pools (in HBM), the output, then scratch: a
     two-slot chunk buffer a pool, the copies' semaphores ``[pool,
-    slot]`` and the body's state.  With a ``window`` the table is a
-    ring of ``max_blocks`` entries: the walk begins at the block that
-    holds the oldest key the row still sees, reads block ``b`` from
-    entry ``b mod max_blocks``, and hands the body of its first chunk
-    the tokens to skip there."""
+    slot]``, the slot the next row begins in, and the body's state.
+    With a ``window`` the table is a ring of ``max_blocks`` entries:
+    the walk begins at the block that holds the oldest key the row
+    still sees, reads block ``b`` from entry ``b mod max_blocks``, and
+    hands the body of its first chunk the tokens to skip there.
+
+    Row ``b``'s first chunk is in flight when its program begins
+    (started under the last chunk of the nearest row before it that
+    walks at all, or by a row between them that walks nothing; row 0
+    starts its own), so the slot a row begins in is walk state: its
+    last chunk sits in one slot while the next row's first lands in the
+    other."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -328,90 +364,149 @@ def _walk_kernel(tables_ref, lens_ref, *refs, n_rows, n_pools, pages, blk,
     pool_refs = refs[n_rows:n_rows + n_pools]
     out_ref = refs[n_rows + n_pools]
     scratch = refs[n_rows + n_pools + 1:]
-    bufs, sem, state = scratch[:n_pools], scratch[n_pools], \
-        scratch[n_pools + 1:]
+    bufs, sem, slot_ref, state = scratch[:n_pools], scratch[n_pools], \
+        scratch[n_pools + 1], scratch[n_pools + 2:]
     b = pl.program_id(0)
     tokens = pages * blk
-    cached = jnp.maximum(lens_ref[b] - 1, 0)
-    live_pages = (cached + blk - 1) // blk
-    first_page = oldest = 0
-    if window is not None:
-        oldest = jnp.maximum(cached - window + 1, 0)
-        first_page = oldest // blk
-        live_pages = live_pages - first_page
-    n_chunks = (live_pages + pages - 1) // pages
 
-    def copies(c, slot, wait):
-        # the live pages of chunk ``c``, each copied from where its
-        # table entry says it lies; a wait needs the copy's size only
-        for i in range(pages):
-            page = c * pages + i
+    def walk_of(row):
+        # what the walk of ``row`` reads: its cached tokens, the oldest
+        # it sees, the page that holds it, its live pages and chunks
+        cached = jnp.maximum(lens_ref[row] - 1, 0)
+        live_pages = (cached + blk - 1) // blk
+        first_page = oldest = 0
+        if window is not None:
+            oldest = jnp.maximum(cached - window + 1, 0)
+            first_page = oldest // blk
+            live_pages = live_pages - first_page
+        return cached, oldest, first_page, live_pages, \
+            (live_pages + pages - 1) // pages
 
-            @pl.when(page < live_pages)
-            def _():
-                entry = page if window is None \
-                    else lax.rem(first_page + page, max_blocks)
-                at = 0 if wait else tables_ref[b * max_blocks + entry]
-                for n in range(n_pools):
-                    copy = pltpu.make_async_copy(
-                        pool_refs[n].at[at],
-                        bufs[n].at[slot, pl.ds(i * blk, blk)],
-                        sem.at[n, slot])
-                    copy.wait() if wait else copy.start()
+    def start(row, first_page, live_pages, c, slot):
+        # chunk ``c`` of ``row``: each live page from where its table
+        # entry says it lies
+        entry = c * pages
+        if window is not None:
+            entry = lax.rem(first_page + entry, max_blocks)
 
-    init(row_refs, state)
+        def page(i, to):
+            at = entry + i
+            if window is not None:      # the ring wraps inside a chunk
+                at = jnp.where(at >= max_blocks, at - max_blocks, at)
+            at = tables_ref[row * max_blocks + at]
+            for n in range(n_pools):
+                pltpu.make_async_copy(
+                    pool_refs[n].at[at], bufs[n].at[slot, pl.ds(to, blk)],
+                    sem.at[n, slot]).start()
 
-    @pl.when(n_chunks > 0)
-    def _():
-        copies(0, 0, False)
+        live = live_pages - c * pages
 
-    def step(c, carry):
-        slot = lax.rem(c, 2)
-
-        @pl.when(c + 1 < n_chunks)
+        @pl.when(live >= pages)
         def _():
-            copies(c + 1, 1 - slot, False)
+            for i in range(pages):
+                page(i, i * blk)
 
-        copies(c, slot, True)
-        live = cached - (c * tokens if window is None
-                         else first_page * blk + c * tokens)
+        @pl.when(live < pages)
+        def _():
+            lax.fori_loop(0, live, lambda i, _: page(
+                i, pl.multiple_of(i * blk, blk)), None)
+
+    def wait(live_pages, c, slot):
+        # a wait needs the copy's size alone: a whole chunk's copies
+        # are awaited as one of the slot's size a pool
+        live = live_pages - c * pages
+
+        def a_page(i, _):
+            for n in range(n_pools):
+                pltpu.make_async_copy(
+                    pool_refs[n].at[0], bufs[n].at[slot, pl.ds(0, blk)],
+                    sem.at[n, slot]).wait()
+
+        @pl.when(live >= pages)
+        def _():
+            for n in range(n_pools):
+                pltpu.make_async_copy(bufs[n].at[slot], bufs[n].at[slot],
+                                      sem.at[n, slot]).wait()
+
+        @pl.when(live < pages)
+        def _():
+            lax.fori_loop(0, live, a_page, None)
+
+    def fold(slot, skip, live):
+        # the chunk in ``slot``, of whose tokens those from ``skip`` to
+        # before ``live`` count: folded whole where all do, else
+        # masked; a last chunk no more than half live as its first
+        # half alone, at half the arithmetic
         held = [buf.at[slot] for buf in bufs]
-        if window is None:
-            @pl.when(live >= tokens)
-            def _():
-                chunk(row_refs, held, state, None)
-
-            @pl.when(live < tokens)
-            def _():
-                # the row's last chunk: its tail was not copied and
-                # holds whatever the buffer held
-                chunk(row_refs, held, state, live)
-
-            return carry
-
-        # the walk's first chunk begins with the keys of its block
-        # that have left the window
-        skip = jnp.where(c == 0, oldest - first_page * blk, 0)
-        whole = (live >= tokens) & (skip == 0)
+        half = pages // 2 * blk
+        whole = live >= tokens
+        if window is not None:
+            whole = whole & (skip == 0)
+        counted = live if window is None else (skip, live)
 
         @pl.when(whole)
         def _():
             chunk(row_refs, held, state, None)
 
-        @pl.when(jnp.logical_not(whole))
+        @pl.when(jnp.logical_not(whole) & (live > half))
         def _():
-            chunk(row_refs, held, state, (skip, live))
+            chunk(row_refs, held, state, counted)
 
+        if half:
+            @pl.when(live <= half)
+            def _():
+                chunk(row_refs, [ref.at[pl.ds(0, half)] for ref in held],
+                      state, counted)
+
+    def either(pred, this, that):
+        return [jnp.where(pred, x, y) for x, y in zip(this, that)]
+
+    cached, oldest, first_page, live_pages, n_chunks = walk_of(b)
+    own = (b, first_page, live_pages)
+    # the row whose first chunk this one fetches: none after the last
+    ahead = jnp.minimum(b + 1, pl.num_programs(0) - 1)
+    _, _, ahead_first, ahead_live, ahead_chunks = walk_of(ahead)
+    ahead_chunks = jnp.where(ahead > b, ahead_chunks, 0)
+    ahead = (ahead, ahead_first, ahead_live)
+    slot0 = jnp.where(b == 0, 0, slot_ref[0])
+    slot_ref[0] = lax.rem(slot0 + n_chunks, 2)
+
+    # row 0 starts its own first chunk; a row that walks nothing hands
+    # the turn on
+    walks = n_chunks > 0
+
+    @pl.when(jnp.where(walks, b == 0, ahead_chunks > 0))
+    def _():
+        start(*either(walks, own, ahead), 0, slot0)
+
+    init(row_refs, state)
+
+    def step(c, carry):
+        slot = lax.rem(slot0 + c, 2)
+        last = c + 1 == n_chunks
+
+        @pl.when(jnp.logical_not(last) | (ahead_chunks > 0))
+        def _():
+            start(*either(last, ahead, own), jnp.where(last, 0, c + 1),
+                  1 - slot)
+
+        wait(live_pages, c, slot)
+        # the walk's first chunk begins with the keys of its block that
+        # have left the window
+        fold(slot, 0 if window is None
+             else jnp.where(c == 0, oldest - first_page * blk, 0),
+             cached - first_page * blk - c * tokens)
         return carry
 
     lax.fori_loop(0, n_chunks, step, 0)
     finish(row_refs, state, out_ref)
 
 
-def _walk_pages(init, chunk, finish, rows, shared, pools, block_tables,
-                context_lens, out, state, interpret=False, window=None):
+def _walk_pages(scope, init, chunk, finish, rows, shared, pools,
+                block_tables, context_lens, out, state, interpret=False,
+                window=None):
     """Run ``init`` / ``chunk`` / ``finish`` over every row's live
-    blocks.
+    blocks, as one kernel under the name ``scope``.
 
     ``rows``: per-row operands ``[B, r, c]``, handed to the bodies as
     ``[1, r, c]`` refs, followed by the ``shared`` ones, whole;
@@ -432,6 +527,9 @@ def _walk_pages(init, chunk, finish, rows, shared, pools, block_tables,
     bsz, max_blocks = block_tables.shape
     blk = pools[0].shape[1]
     pages = _walk_chunk_pages(pools, max_blocks)
+    _M_CHUNK_TOKENS.labels(scope).set(pages * blk)
+    _M_CHUNK_WAITS.labels(scope).set(len(pools))
+    _M_ROWS_AHEAD.labels(scope).set(1)
     kernel = functools.partial(
         _walk_kernel, n_rows=len(rows) + len(shared), n_pools=len(pools),
         pages=pages, blk=blk, max_blocks=max_blocks, init=init, chunk=chunk,
@@ -451,15 +549,18 @@ def _walk_pages(init, chunk, finish, rows, shared, pools, block_tables,
         out_specs=one_row(out),
         scratch_shapes=[pltpu.VMEM((2, pages * blk, p.shape[2]), p.dtype)
                         for p in pools]
-        + [pltpu.SemaphoreType.DMA((len(pools), 2))] + list(state))
+        + [pltpu.SemaphoreType.DMA((len(pools), 2)),
+           pltpu.SMEM((1,), jnp.int32)] + list(state))
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary",))
-    return pl.pallas_call(
-        kernel, out_shape=out, grid_spec=grid_spec, interpret=interpret,
-        **kwargs)(block_tables.reshape(-1).astype(jnp.int32),
-                  context_lens.astype(jnp.int32), *rows, *shared, *pools)
+    # the scope names the kernel in a trace; it has to be the innermost
+    with jax.named_scope(scope):
+        return pl.pallas_call(
+            kernel, out_shape=out, grid_spec=grid_spec, interpret=interpret,
+            **kwargs)(block_tables.reshape(-1).astype(jnp.int32),
+                      context_lens.astype(jnp.int32), *rows, *shared, *pools)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
@@ -548,15 +649,13 @@ def _kv_decode_pallas(q, k_step, v_step, k_pages, v_pages, block_tables,
 
     rows = tuple(x.astype(f32).reshape(bsz, 1, width)
                  for x in (q, k_step, v_step))
-    # the scope names the kernel in a trace; it has to be the innermost
-    with jax.named_scope("paged_decode_attention"):
-        out = _walk_pages(
-            init, chunk, finish, rows, (selector,), (k_pages, v_pages),
-            block_tables, context_lens,
-            jax.ShapeDtypeStruct((bsz, 1, width), f32),
-            [pltpu.VMEM((1, heads), f32), pltpu.VMEM((1, heads), f32),
-             pltpu.VMEM((1, width), f32)],
-            interpret=interpret)
+    out = _walk_pages(
+        "paged_decode_attention", init, chunk, finish, rows, (selector,),
+        (k_pages, v_pages), block_tables, context_lens,
+        jax.ShapeDtypeStruct((bsz, 1, width), f32),
+        [pltpu.VMEM((1, heads), f32), pltpu.VMEM((1, heads), f32),
+         pltpu.VMEM((1, width), f32)],
+        interpret=interpret)
     return out.reshape(bsz, heads, dim)
 
 
@@ -608,16 +707,14 @@ def _latent_decode_pallas(q, row_step, pages, block_tables, context_lens,
         _, l_ref, acc_ref = state
         out_ref[0] = (acc_ref[...] / l_ref[...]).astype(out_ref.dtype)
 
-    # the scope names the kernel in a trace; it has to be the innermost
-    with jax.named_scope("latent_decode_attention"):
-        return _walk_pages(
-            init, chunk, finish, (q, row_step[:, None, :]), (), (pages,),
-            block_tables, context_lens,
-            jax.ShapeDtypeStruct((bsz, heads, kv_rank), q.dtype),
-            [pltpu.VMEM((heads, 1), jnp.float32),
-             pltpu.VMEM((heads, 1), jnp.float32),
-             pltpu.VMEM((heads, kv_rank), jnp.float32)],
-            interpret=interpret)
+    return _walk_pages(
+        "latent_decode_attention", init, chunk, finish,
+        (q, row_step[:, None, :]), (), (pages,), block_tables, context_lens,
+        jax.ShapeDtypeStruct((bsz, heads, kv_rank), q.dtype),
+        [pltpu.VMEM((heads, 1), jnp.float32),
+         pltpu.VMEM((heads, 1), jnp.float32),
+         pltpu.VMEM((heads, kv_rank), jnp.float32)],
+        interpret=interpret)
 
 
 def _counted(live, shape, axis):
@@ -720,16 +817,14 @@ def _gqa_decode_pallas(q, k_step, v_step, k_pages, v_pages, block_tables,
     # tiles whatever the pools' dtype packs
     rows = (q.astype(f32), k_step.reshape(bsz, 1, width),
             v_step.reshape(bsz, 1, width))
-    # the scope names the kernel in a trace; it has to be the innermost
-    with jax.named_scope("paged_decode_gqa_attention" if window is None
-                         else "paged_decode_gqa_window"):
-        return _walk_pages(
-            init, chunk, finish, rows, (), (k_pages, v_pages),
-            block_tables, context_lens,
-            jax.ShapeDtypeStruct((bsz, heads, dim), q.dtype),
-            [pltpu.VMEM((heads, 1), f32), pltpu.VMEM((heads, 1), f32),
-             pltpu.VMEM((heads, dim), f32)],
-            interpret=interpret, window=window)
+    return _walk_pages(
+        "paged_decode_gqa_attention" if window is None
+        else "paged_decode_gqa_window", init, chunk, finish, rows, (),
+        (k_pages, v_pages), block_tables, context_lens,
+        jax.ShapeDtypeStruct((bsz, heads, dim), q.dtype),
+        [pltpu.VMEM((heads, 1), f32), pltpu.VMEM((heads, 1), f32),
+         pltpu.VMEM((heads, dim), f32)],
+        interpret=interpret, window=window)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
@@ -797,15 +892,13 @@ def _gqa_packed_decode_pallas(q, k_step, v_step, k_pages, v_pages,
 
     rows = (q_wide, k_step.reshape(bsz, 1, width),
             v_step.reshape(bsz, 1, width))
-    # the scope names the kernel in a trace; it has to be the innermost
-    with jax.named_scope("paged_decode_gqa_attention"):
-        wide = _walk_pages(
-            init, chunk, finish, rows, (), (k_pages, v_pages),
-            block_tables, context_lens,
-            jax.ShapeDtypeStruct((bsz, heads, width), q.dtype),
-            [pltpu.VMEM((heads, 1), f32), pltpu.VMEM((heads, 1), f32),
-             pltpu.VMEM((heads, width), f32)],
-            interpret=interpret)
+    wide = _walk_pages(
+        "paged_decode_gqa_attention", init, chunk, finish, rows, (),
+        (k_pages, v_pages), block_tables, context_lens,
+        jax.ShapeDtypeStruct((bsz, heads, width), q.dtype),
+        [pltpu.VMEM((heads, 1), f32), pltpu.VMEM((heads, 1), f32),
+         pltpu.VMEM((heads, width), f32)],
+        interpret=interpret)
     # a head's output is its own key-value head's lanes of its row
     wide = wide.reshape(bsz, groups, per, groups, dim)
     return jnp.stack([wide[:, g, :, g, :] for g in range(groups)],

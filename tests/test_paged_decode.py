@@ -332,19 +332,47 @@ def test_the_tail_of_a_chunk_is_never_read(kernel):
 
 
 def test_chunk_pages_follow_from_the_page_bytes():
-    """A chunk holds what of a power of two pages fits its bytes: 8
-    pages of GPT-2's two float32 pools (128 tokens), 32 of the latent
-    pool's (512), never more than the table."""
+    """A chunk holds what of a power of two pages fits its 2 MiB: 16
+    pages of GPT-2's two float32 pools (256 tokens), 64 of the latent
+    pool's and of two pools of 512-wide bfloat16 rows (1024), never
+    more than the table."""
     kv = jax.ShapeDtypeStruct((24 * 680, 16, 1024), jnp.float32)
     latent = jax.ShapeDtypeStruct((6 * 9600, 16, 640), jnp.bfloat16)
-    assert att._walk_chunk_pages((kv, kv), 64) == 8
-    assert att._walk_chunk_pages((latent,), 256) == 32
+    gqa = jax.ShapeDtypeStruct((4 * 20224, 16, 512), jnp.bfloat16)
+    assert att._walk_chunk_pages((kv, kv), 64) == 16
+    assert att._walk_chunk_pages((latent,), 256) == 64
+    assert att._walk_chunk_pages((gqa, gqa), 1024) == 64
+    assert att._walk_chunk_pages((gqa, gqa), 257) == 64
     assert att._walk_chunk_pages((latent,), 4) == 4
     assert att._walk_tiles(kv, latent)
     assert not att._walk_tiles(
         jax.ShapeDtypeStruct((9, 16, 576), jnp.bfloat16))
     assert not att._walk_tiles(
         jax.ShapeDtypeStruct((9, 8, 640), jnp.bfloat16))
+
+
+def test_the_walk_says_how_it_copies():
+    """The three gauges are set when a kernel is built, under its
+    scope's name: a chunk's tokens, one wait a pool for a whole chunk,
+    one row ahead."""
+    from mxnet_tpu.observability import metrics
+
+    jax.clear_caches()      # the gauges are set when a kernel is traced
+    args = _gqa_case((5, 40))
+    att._gqa_decode_pallas(*args, 0.0625, interpret=True)
+    att._latent_decode_pallas(*_latent_case((5, 40)), 0.07, 512,
+                              interpret=True)
+    text = metrics.dump_metrics()
+    for line in (
+            'paged_decode_walk_chunk_tokens{kernel="paged_decode_gqa_'
+            'attention"} 128',
+            'paged_decode_walk_waits_per_chunk{kernel="paged_decode_gqa_'
+            'attention"} 2',
+            'paged_decode_walk_rows_ahead{kernel="paged_decode_gqa_'
+            'attention"} 1',
+            'paged_decode_walk_waits_per_chunk{kernel="latent_decode_'
+            'attention"} 1'):
+        assert line in text, line
 
 
 def test_off_the_chip_the_xla_bodies_run():

@@ -126,8 +126,9 @@ def _device_events(logdir):
                      "measures on the chip and nowhere else")
 
 
-def run(cases, iters):
-    """Device microseconds a call of every case: ``{label: {kind: us}}``."""
+def run(cases, iters, kind=_kind):
+    """Device microseconds a call of every case: ``{label: {kind: us}}``,
+    ``kind(name)`` a device event's (None: counted under ``all`` alone)."""
     mark = jax.jit(lambda x: x + 1.0)
     flag = jnp.zeros(MARK, jnp.float32)
     sound = []
@@ -154,7 +155,7 @@ def run(cases, iters):
         if shape in name:
             groups.append({})
         elif groups:
-            for key in (_kind(name), "all"):
+            for key in (kind(name), "all"):
                 if key:
                     groups[-1][key] = groups[-1].get(key, 0) + dur
     if len(groups) != len(cases) + 1:
