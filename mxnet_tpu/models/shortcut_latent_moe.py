@@ -171,7 +171,7 @@ def _route(params, p, h, cfg):
 
 
 def _experts(params, p, h, cfg, valid=None):
-    """The shortcut branch ``MoE(h)`` and its five counts: the held
+    """The shortcut branch ``MoE(h)`` and its six counts: the held
     experts' gated sum plus the identity experts' ``(sum of gates) *
     h``."""
     with jax.named_scope("shortcut_experts"):

@@ -112,11 +112,15 @@ def _run_example(name, call, timeout, func="run"):
     two of its tests are left.  So there are as many files as workers
     (eight files ran 725 s against 537-588 s for six: two workers walked
     two each), each with three gates or more (a file of two takes the
-    next file with it), the long gates first.  The child keeps XLA's default thread pools: a gate is
-    mostly one thread (``autoencoder``: 189 s alone on eight cores with
-    247 s of CPU time, 215 s held to two cores, 270 s beside five other
-    workers; ``cnn_text_classification``: 55, 59 and 69 s), so there is
-    nothing to bound.
+    next file with it), the long gates first.  A gate is mostly one
+    thread (``autoencoder``: 189 s alone on eight cores with 247 s of
+    CPU time, 215 s held to two cores; PR 24), and the child's products
+    run on that thread alone (``--xla_cpu_multi_thread_eigen=false``):
+    beside five other workers on a shared eight-core machine XLA's
+    pool of a thread a core made a file of gates take 780 s, a gate
+    dying at its 600 s, where it takes 429 s without the pool (PR 43;
+    the six files were 490-605 s of a tier-1 run that was cut at its
+    clock).
     """
     code = (
         "import sys, json\n"
@@ -131,7 +135,8 @@ def _run_example(name, call, timeout, func="run"):
         "print('STATS ' + json.dumps({k: float(v) for k, v in stats.items()}))\n"
         % (_REPO, os.path.join(_REPO, "examples", name), func, call)
     )
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS=os.environ.get(
+        "XLA_FLAGS", "") + " --xla_cpu_multi_thread_eigen=false")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env, timeout=timeout, cwd=_REPO)
     assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-2000:])

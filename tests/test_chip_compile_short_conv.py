@@ -119,5 +119,5 @@ def test_short_conv_prefill_buckets_compile(topo, on_tpu, bucket):
     assert (flash >= 3, scores) == ((True, False) if bucket >= 1024
                                     else (False, True))
     assert text.count("ragged-dot") >= 3 * 12
-    _grouped_tiles_are_the_rules(text, bucket * 4, 32, 2048, 1792)
+    _grouped_tiles_are_the_rules(text, bucket * 4, 32, 32, 2048, 1792)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30

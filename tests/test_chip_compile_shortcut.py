@@ -72,10 +72,10 @@ def test_shortcut_prefill_buckets_compile(topo, on_tpu, bucket):
         assert _named_calls(text, "latent_prefill_attention") == 0
         assert _holds(text, scores)
     assert "ragged-dot" in text
-    # a prompt of 3072 or 4096 tokens sorts its pairs in two runs of rows
-    run = bucket // moe.grouped_runs(bucket, 12, 6144 * 2)
-    assert run == {3072: 1536, 4096: 2048}.get(bucket, bucket)
-    _grouped_tiles_are_the_rules(text, run * 12, 16, 6144, 2048)
+    # a run keeps a 24th of the prompt's pairs: no runs of tokens
+    assert moe.grouped_kept_rows(bucket * 12, 16, 768, 6144 * 2) \
+        == bucket // 2
+    _grouped_tiles_are_the_rules(text, bucket * 12, 16, 768, 6144, 2048)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2 ** 30
 
 
@@ -126,10 +126,11 @@ def test_shortcut_decode_step_walks_a_pool_a_sublayer(topo, on_tpu):
 def test_shortcut_prefill_at_full_depth_fits_beside_weights_and_pool(
         topo, on_tpu):
     """The largest prefill bucket (6144 tokens) at full depth: a flash
-    kernel a sublayer, no score matrix, and temporaries that, with the
-    held experts' pairs in three runs of rows, stay under 1.5 GB (whole,
-    73,728 sorted pairs are 3 GB of them): 12.97 GB of weights and pool
-    leave the chip room for them."""
+    kernel a sublayer, no score matrix, and temporaries that, with a run
+    of the held experts' pairs 3,072 rows of the 73,728, are no more
+    than the 1,121,119,232 bytes they were with the pairs in three runs
+    of tokens (PR 42; whole, 73,728 sorted pairs were 3 GB of them):
+    12.97 GB of weights and pool leave the chip room for them."""
     from mxnet_tpu.models import shortcut_latent_moe as sm
 
     one = SingleDeviceSharding(topo.devices[0])
@@ -143,6 +144,5 @@ def test_shortcut_prefill_at_full_depth_fits_beside_weights_and_pool(
     assert _named_calls(text, "latent_prefill_attention") == 8
     assert "f32[64,6144,6144]" not in text
     assert compiled.out_info[1].shape == (8, 6144, 640)
-    # three runs of 2048 rows: the tiles are those of a run's pairs
-    _grouped_tiles_are_the_rules(text, 2048 * 12, 16, 6144, 2048)
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    _grouped_tiles_are_the_rules(text, 6144 * 12, 16, 768, 6144, 2048)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1121119232

@@ -117,7 +117,7 @@ def test_window_prefill_buckets_compile(topo, on_tpu, bucket):
         jax.ShapeDtypeStruct((), jnp.int32, sharding=one)).compile()
     text = compiled.as_text()
     assert compiled.out_info[1].shape == (16, bucket, 512)
-    assert compiled.out_info[3].shape == (7,)
+    assert compiled.out_info[3].shape == (8,)
     scores = "f32[28,%d,%d]" % (bucket, bucket) in text \
         or "f32[1,28,%d,%d]" % (bucket, bucket) in text
     kernels = (text.count("%gqa_prefill_attention") >= 4,
@@ -125,9 +125,12 @@ def test_window_prefill_buckets_compile(topo, on_tpu, bucket):
     assert (kernels, scores) == (((True, True), False) if bucket >= 1024
                                  else ((False, False), True))
     assert text.count("ragged-dot") >= 3 * 16
-    _grouped_tiles_are_the_rules(text, bucket * 6, 16, 2560, 768)
+    _grouped_tiles_are_the_rules(text, bucket * 6, 16, 64, 2560, 768)
     # 11.5 GB of weights and pools are resident beside it
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.1 * 2 ** 30
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2.1 * 2 ** 30
+    if bucket == 12288:     # no more than with every pair's rows (PR 42)
+        assert temp <= 1540893184
 
 
 def test_both_groups_pool_write_is_in_place_on_the_chip(topo):

@@ -143,7 +143,8 @@ def test_full_forward_is_the_reference_on_a_share(reference):
     cfg = program_config(share)
     params = lm.init_params(cfg, 1, jnp.float32, SCALE, BIAS_SCALE)
     toks = _tokens(12, seed=1)
-    got = np.asarray(lm.full_logits(params, toks[None], cfg))
+    got = np.asarray(jax.jit(lambda p, t: lm.full_logits(p, t, cfg))(
+        params, toks[None]))
     want = np.asarray(reference.logits(share, params, toks[None]))
     np.testing.assert_allclose(got, want, atol=1e-4, rtol=0)
 
@@ -298,7 +299,7 @@ def test_no_token_is_dropped_at_any_skew(model, reference, tokens,
     chosen = jnp.tile(jnp.array([[3, 0, 1, 2]], jnp.int32), (tokens, 1))
     gates = jnp.tile(jnp.array([[1.0, 0.5, 0.25, 0.75]]), (tokens, 1))
     part, counts = _share_part(w, h, chosen, gates, 0, 16, every_row)
-    assert list(counts) == [4 * tokens, 4 * tokens, 4, 1]
+    assert list(counts) == [4 * tokens, 4 * tokens, 4, 1, 0]
     want = sum(
         g * np.asarray(reference._swiglu(
             reference._Math("float32"), h, w["experts_gate_weight"][e],
@@ -651,6 +652,7 @@ def test_readers_of_the_new_metrics(capsys):
     counters = {"moe_layer_steps_total": 10.0,
                 "moe_local_experts_hit_total": 140.0,
                 "moe_local_assignments_total": 320.0,
+                "moe_grouped_extra_runs_total": 1.0,
                 "generation_decode_steps_total": 2.0,
                 "generation_decode_context_tokens_total": 2 * 96000.0,
                 "generation_tokens_total": 128.0}
@@ -672,12 +674,15 @@ def test_readers_of_the_new_metrics(capsys):
     assert "latent decode roofline: bound by memory" in out
     assert read("moe_tokens_per_held_expert", ctx) == pytest.approx(2.0)
     assert read("moe_held_experts_hit_share", ctx) == pytest.approx(87.5)
+    # one layer call of the ten overflowed into a second run (PR 43)
+    assert read("moe_grouped_extra_runs_per_layer", ctx) == pytest.approx(0.1)
     # the parent's program has no such counter, a --trace 0 run no trace
     bare = {"trace": _trace(events), "peaks": peaks,
             "compiles_in_window": {"generation_decode_steps_total": 2.0}}
     for metric in ("moe_expert_roofline.serve", "mla_decode_roofline.serve",
                    "moe_tokens_per_held_expert",
-                   "moe_held_experts_hit_share"):
+                   "moe_held_experts_hit_share",
+                   "moe_grouped_extra_runs_per_layer"):
         assert read(metric, bare) is None
     other = {"trace": _trace(events[-1:]), "peaks": peaks,
              "compiles_in_window": counters}
@@ -766,6 +771,8 @@ def test_new_cell_rehearsed_on_the_cpu(tiny_benchmark, trace, capsys):
         assert metrics["staged_gb_per_step"]["value"] == 0
         assert 0 < metrics["moe_tokens_per_held_expert"]["value"] < 16
         assert 0 < metrics["moe_held_experts_hit_share"]["value"] <= 100
+        # every tiny expert is held: the grouped form runs whole
+        assert metrics["moe_grouped_extra_runs_per_layer"]["value"] == 0
         assert metrics["kv_occupancy_peak"]["value"] > 0
         # short prompts, 4-16 new tokens: what a row attends over a step
         assert 4 < metrics["decode_context_tokens_mean"]["value"] < 64

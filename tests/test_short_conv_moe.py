@@ -124,7 +124,8 @@ def test_full_forward_is_the_reference_on_a_share(reference):
     assert "l0_router_weight" not in params and "l1_ffn_up_weight" in params
     toks = _tokens(24, 3)
     want = np.asarray(reference.logits(tiny, params, toks[None]))[0]
-    got = np.asarray(sc.full_logits(params, toks[None], cfg))[0]
+    got = np.asarray(jax.jit(lambda p, t: sc.full_logits(p, t, cfg))(
+        params, toks[None]))[0]
     assert np.abs(want).max() > 1.0
     np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
 
@@ -201,18 +202,26 @@ def test_convolution_over_a_prompt_equals_the_one_step_form(model, reference,
                                atol=2e-5, rtol=0)
 
 
+@pytest.fixture(scope="module")
+def prefill_program(model):
+    """One jitted prefill for the module: a bucket compiles once."""
+    cfg, _ = model
+    return jax.jit(lambda p, t, n: sc.prefill(p, t, n, cfg))
+
+
 @pytest.mark.parametrize("length", [1, 2, 5, 11])
-def test_a_prefills_pad_rows_leave_the_state_as_at_length(model, length):
+def test_a_prefills_pad_rows_leave_the_state_as_at_length(
+        model, prefill_program, length):
     """The tail a prefill returns is the gated inputs of tokens ``length
     - 2`` and ``length - 1`` whatever the bucket and whatever the pad
     holds (zeros before the start of a prompt shorter than the taps)."""
     cfg, params = model
     toks = _tokens(16, 5)
-    exact = sc.prefill(params, jnp.asarray(toks[:length]), length, cfg)
+    exact = prefill_program(params, jnp.asarray(toks[:length]), length)
     for bucket in (12, 16):
         padded = np.full(bucket, 33, np.int32)          # pad is not zero
         padded[:length] = toks[:length]
-        got = sc.prefill(params, jnp.asarray(padded), length, cfg)
+        got = prefill_program(params, jnp.asarray(padded), length)
         np.testing.assert_allclose(got[0], exact[0], atol=1e-5, rtol=0)
         assert len(got[4]) == 1 and got[4][0].shape == (5, 2, 32)
         np.testing.assert_allclose(got[4][0], exact[4][0], atol=1e-5,

@@ -159,7 +159,8 @@ def test_full_forward_is_the_reference_on_a_share(family, reference):
     cfg = family.program_config(share)
     params = sm.init_params(cfg, 1, jnp.float32, SCALE, BIAS_SCALE)
     toks = _tokens(12, seed=1)
-    got = np.asarray(sm.full_logits(params, toks[None], cfg))[0]
+    got = np.asarray(jax.jit(lambda p, t: sm.full_logits(p, t, cfg))(
+        params, toks[None]))[0]
     want = _reference_logits(reference, share, params, toks)
     np.testing.assert_allclose(got, want, atol=1e-4, rtol=0)
 
@@ -326,7 +327,7 @@ def test_a_token_of_identity_choices_alone_gets_its_gates_times_h(
     chosen = jnp.array([[16, 23, 19, 20], [3, 17, 0, 22]], jnp.int32)
     gates = jnp.array([[0.5, 0.25, 1.0, 0.125], [0.5, 0.25, 1.0, 2.0]])
     part, counts = _share_part(w, h, chosen, gates, 0, 16, every_row)
-    assert list(counts) == [8, 2, 2, 1]
+    assert list(counts) == [8, 2, 2, 1, 0]
     assert not part[0].any() and part[1].any()
     same, zero = moe.identity_experts(h, chosen, gates, REAL)
     assert int(zero) == 6
@@ -342,10 +343,10 @@ def test_a_token_of_identity_choices_alone_gets_its_gates_times_h(
     alone = moe.route_softmax_topk
     try:
         moe.route_softmax_topk = lambda *a, **k: (chosen, gates)
-        m, five = sm._experts(params, "l1_", h, cfg)
+        m, six = sm._experts(params, "l1_", h, cfg)
     finally:
         moe.route_softmax_topk = alone
-    assert list(np.asarray(five)) == [8, 2, 2, 1, 6]
+    assert list(np.asarray(six)) == [8, 2, 2, 1, 0, 6]
     np.testing.assert_allclose(np.asarray(m)[0], 1.875 * np.asarray(h)[0],
                                rtol=1e-6)
 
@@ -393,7 +394,8 @@ def test_no_token_is_dropped_at_any_skew(model, reference, tokens,
                                          every_row, monkeypatch):
     """All tokens to one real expert (their other choices two more and
     one identity expert): every pair is computed, at any number of
-    tokens, whole or in runs of rows."""
+    tokens, whole or in runs of 64 sorted pairs, with every run beyond
+    the first counted."""
     cfg, params = model
     w = _weights(params)
     h = jax.random.normal(jax.random.PRNGKey(8), (tokens, cfg["hidden_size"]))
@@ -404,12 +406,17 @@ def test_no_token_is_dropped_at_any_skew(model, reference, tokens,
             reference._Math("float32"), h, w["experts_gate_weight"][e],
             w["experts_up_weight"][e], w["experts_down_weight"][e]))
         for e, g in zip([3, 0, 2], [1.0, 0.5, 0.75]))
+    row_bytes = h.shape[1] * h.dtype.itemsize
     for pairs in (None, 64):
         if pairs:       # the rows of 64 pairs: a longer call runs in runs
-            monkeypatch.setattr(moe, "GROUPED_ROW_BYTES",
-                                pairs * h.shape[1] * h.dtype.itemsize)
+            monkeypatch.setattr(moe, "GROUPED_ROW_BYTES", pairs * row_bytes)
+        kept = moe.grouped_kept_rows(4 * tokens, 16, 16, row_bytes)
+        assert kept == min(4 * tokens, pairs or 4 * tokens)
+        extra = 0 if every_row else -(-3 * tokens // kept) - 1
+        assert extra == (0 if every_row or not pairs else
+                         {1: 0, 24: 1, 200: 9}[tokens])
         part, counts = _share_part(w, h, chosen, gates, 0, 16, every_row)
-        assert list(counts) == [4 * tokens, 3 * tokens, 3, 1]
+        assert list(counts) == [4 * tokens, 3 * tokens, 3, 1, extra]
         np.testing.assert_allclose(part, want, atol=2e-5, rtol=0)
     valid = jnp.arange(tokens) < max(1, tokens // 2)
     part_v, counts_v = _share_part(w, h, chosen, gates, 0, 16, every_row,
@@ -421,17 +428,22 @@ def test_no_token_is_dropped_at_any_skew(model, reference, tokens,
 def test_which_calls_compute_every_row():
     """The cell's decode step (64 rows, 12 of 768: 8 real of 512)
     computes every held expert over every row; a prefill keeps the
-    grouped form, in runs of rows from 32,768 pairs on."""
+    grouped form, a run of it the held experts' rows twice over: no
+    bucket of the expert cells comes near ``GROUPED_ROW_BYTES``, which
+    bounded 32,768 pairs' rows before the cut."""
     assert moe.few_rows_hit_most(64, 12, 768)
     assert moe.few_rows_hit_most(64, 8, 512)
     assert not moe.few_rows_hit_most(16, 12, 768)
     assert not moe.few_rows_hit_most(512, 12, 768)
-    # the cell's buckets (bfloat16 rows of 6144): whole up to 2048 tokens
+    # the cell's buckets (bfloat16 rows of 6144): 16 of 768 outputs
+    # held, a 24th of the pairs kept; all held would bound 32,768 rows
     limit = moe.GROUPED_ROW_BYTES // (6144 * 2)
     assert 2048 * 12 <= limit < 3072 * 12
-    # every bucket of the other two expert cells stays whole
-    assert 3328 * 8 * 7168 * 2 <= moe.GROUPED_ROW_BYTES
-    assert 4096 * 10 * 2048 * 2 <= moe.GROUPED_ROW_BYTES
+    assert moe.grouped_kept_rows(6144 * 12, 16, 768, 6144 * 2) == 3072
+    assert moe.grouped_kept_rows(6144 * 12, 768, 768, 6144 * 2) == limit
+    # the largest bucket of the other two expert cells
+    assert moe.grouped_kept_rows(3328 * 8, 16, 256, 7168 * 2) == 3328
+    assert moe.grouped_kept_rows(4096 * 10, 128, 512, 2048 * 2) == 20480
 
 
 # ----------------------------------------------------------------------
@@ -866,6 +878,8 @@ def test_new_cell_rehearsed_on_the_cpu(tiny_benchmark, trace, capsys):
             < 16
         assert 0 < metrics["moe_held_experts_hit_share.longcat"]["value"] \
             <= 100
+        # every tiny expert is held: the grouped form runs whole
+        assert metrics["moe_grouped_extra_runs_per_layer"]["value"] == 0
         # 8 of the tiny router's 24 outputs are identity experts
         assert 10 < metrics["moe_zero_expert_share.longcat"]["value"] < 60
         assert metrics["kv_occupancy_peak"]["value"] > 0

@@ -1,7 +1,8 @@
 """The grouped expert product (``jax.lax.ragged_dot``, what
-``parallel/moe.py:_grouped_rows`` calls three times a layer) by its
-tiling, on the chip it is started on: one product a (cell, prefill
-bucket, product, tiling), the pace of 20 queued calls (the least of
+``parallel/moe.py:_grouped_run`` calls three times a layer and run) by
+its tiling, on the chip it is started on: one product a (cell, prefill
+bucket, product, tiling) at the rows one run holds
+(``moe.grouped_kept_rows``), the pace of 20 queued calls (the least of
 three rounds), group sizes drawn as the cell's router draws them.
 
     chiprun --chips 1 -- python3 tools/grouped_tiles.py
@@ -39,14 +40,17 @@ CELLS = {
                               6144, 2048),
     "qwen3next-serve-reason128": ("serve-reason-closed128-8k.json", 128,
                                   512, 10, 2048, 512),
+    "smallthinker-serve-mixed48": ("serve-mixed-closed48-14k.json", 16, 64,
+                                   6, 2560, 768),
 }
 
 
 def products(cell):
-    """``[(bucket, pairs, held, width, contraction, output, out
+    """``[(bucket, rows, held, width, contraction, output, out
     dtype)]``: the grouped products a prefill of each bucket of the
     cell's traffic file runs a layer (gate and up are one shape), at
-    the pairs of one run of rows (``moe.grouped_runs``)."""
+    the rows one run keeps of the bucket's sorted pairs
+    (``moe.grouped_kept_rows``)."""
     from mxnet_tpu.parallel import moe
 
     traffic, held, width, k, d, h = CELLS[cell]
@@ -54,22 +58,24 @@ def products(cell):
         buckets = json.load(f)["prefill_buckets"]
     out = []
     for bucket in buckets:
-        pairs = bucket // moe.grouped_runs(bucket, k, d * 2) * k
-        out.append((bucket, pairs, held, width, d, h, "float32"))
-        out.append((bucket, pairs, held, width, h, d, "bfloat16"))
+        rows = moe.grouped_kept_rows(bucket * k, held, width, d * 2)
+        out.append((bucket, rows, held, width, d, h, "float32"))
+        out.append((bucket, rows, held, width, h, d, "bfloat16"))
     return out
 
 
-def draw_sizes(rng, pairs, held, width):
-    """Group sizes as a seeded router gives them: every pair falls on
-    one of ``width`` experts with a chance that is even but for the
-    spread a seeded selection bias gives (an expert takes 0.77-1.25 of
-    its share, PERF.md section 4), and the first ``held`` are here."""
+def draw_sizes(rng, pairs, held, width, rows):
+    """Group sizes of a call's first run as a seeded router gives them:
+    every one of the call's ``pairs`` falls on one of ``width`` experts
+    with a chance that is even but for the spread a seeded selection
+    bias gives (an expert takes 0.77-1.25 of its share, PERF.md section
+    4), the first ``held`` are here, and the run keeps ``rows`` of
+    theirs."""
     import numpy as np
 
     share = np.exp(0.1 * rng.standard_normal(width))
-    return rng.multinomial(pairs, share / share.sum())[:held].astype(
-        np.int32)
+    ends = np.cumsum(rng.multinomial(pairs, share / share.sum())[:held])
+    return np.diff(np.minimum(ends, rows), prepend=0).astype(np.int32)
 
 
 def _width(text, whole):
@@ -99,33 +105,34 @@ def main():
     from mxnet_tpu.parallel import moe
 
     only = {int(b) for b in args.buckets.split(",") if b}
-    rows = []
+    table = []
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     device = jax.devices()[0]
     print("device", device.platform, device.device_kind, flush=True)
     rng = np.random.default_rng(args.seed)
     weights, seen = {}, set()
     for cell in args.cells.split(","):
-        for bucket, pairs, held, width, kk, nn, out in products(cell):
-            if only and bucket not in only or (cell, pairs, kk) in seen:
-                continue    # LongCat's long prompts run in runs of one size
-            seen.add((cell, pairs, kk))
+        for bucket, rows, held, width, kk, nn, out in products(cell):
+            if only and bucket not in only or (cell, rows, kk) in seen:
+                continue
+            seen.add((cell, rows, kk))
             if (held, kk, nn) not in weights:
                 weights.clear()     # one matrix on the device at a time
                 weights[held, kk, nn] = (0.02 * jax.random.normal(
                     jax.random.PRNGKey(0), (held, kk, nn),
                     jnp.float32)).astype(jnp.bfloat16)
             w = weights[held, kk, nn]
-            a = jax.random.normal(jax.random.PRNGKey(1), (pairs, kk),
+            a = jax.random.normal(jax.random.PRNGKey(1), (rows, kk),
                                   jnp.float32).astype(jnp.bfloat16)
-            sizes = jnp.asarray(draw_sizes(rng, pairs, held, width))
-            chosen = moe.grouped_tiling(pairs, kk, nn)
+            sizes = jnp.asarray(draw_sizes(
+                rng, bucket * CELLS[cell][3], held, width, rows))
+            chosen = moe.grouped_tiling(rows, kk, nn)
             tilings, plain = [None, chosen], None
             for tm in (int(t) for t in args.tm.split(",")):
                 for tk in (_width(t, kk) for t in args.tk.split(",")):
                     for tn in (_width(t, nn) for t in args.tn.split(",")):
                         t = (tm, tk, tn)
-                        if pairs % tm == 0 and kk % tk == 0 \
+                        if rows % tm == 0 and kk % tk == 0 \
                                 and nn % tn == 0 and t not in tilings:
                             tilings.append(t)
             for tiling in tilings:
@@ -136,7 +143,7 @@ def main():
                     with set_xla_metadata(**told):
                         return jax.lax.ragged_dot(
                             a, w, sizes, preferred_element_type=out)
-                row = {"cell": cell, "bucket": bucket, "pairs": pairs,
+                row = {"cell": cell, "bucket": bucket, "rows": rows,
                        "held": held, "k": kk, "n": nn, "out": out,
                        "held_rows": int(sizes.sum()),
                        "tiling": "default" if tiling is None
@@ -145,7 +152,7 @@ def main():
                 try:
                     fn = jax.jit(product)
                     # the held experts' rows: what lies behind them is
-                    # never read (``_grouped_rows`` masks it)
+                    # never read (a pair behind them is nobody's)
                     y = np.asarray(fn(a, w, sizes),
                                    np.float32)[:row["held_rows"]]
                     if tiling is None:
@@ -164,10 +171,10 @@ def main():
                     row["queued_ms"] = 1e3 * best
                 except Exception as e:  # the compiler's refusal is a result
                     row["error"] = str(e).replace("\n", " ")[:200]
-                rows.append(row)
+                table.append(row)
                 print(json.dumps(row), flush=True)
                 with open(args.out, "w") as f:
-                    json.dump(rows, f, indent=0)
+                    json.dump(table, f, indent=0)
 
 
 if __name__ == "__main__":
