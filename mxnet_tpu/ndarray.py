@@ -435,14 +435,23 @@ def load_frombuffer(buf):
 
 @functools.lru_cache(maxsize=None)
 def _jitted_apply(op_name, attrs_key, n_args, n_aux, is_train, with_rng):
+    """One compiled program per (op, static attributes, input signature).
+    Unbounded, yet bounded in practice: the attributes an op declares
+    ``operand=True`` (an optimizer's lr, wd, step count) are arguments of
+    the program, not part of ``attrs_key``, so the cache holds the distinct
+    static configurations a process uses and does not grow with its steps."""
     op = get_op(op_name)
     attrs = dict(attrs_key)
+    n_tensors = n_args + n_aux + bool(with_rng)
 
-    def run(*tensors):
-        args = tensors[:n_args]
-        auxs = tensors[n_args : n_args + n_aux]
-        rng = tensors[-1] if with_rng else None
-        outputs, new_aux = op.apply(attrs, args, auxs, is_train=is_train, rng=rng)
+    def run(*inputs):
+        args = inputs[:n_args]
+        auxs = inputs[n_args : n_args + n_aux]
+        rng = inputs[n_args + n_aux] if with_rng else None
+        call_attrs = op.with_operands(
+            attrs, **dict(zip(op.operand_params, inputs[n_tensors:])))
+        outputs, new_aux = op.apply(call_attrs, args, auxs,
+                                    is_train=is_train, rng=rng)
         return tuple(outputs) + tuple(new_aux)
 
     return jax.jit(run)
@@ -451,7 +460,7 @@ def _jitted_apply(op_name, attrs_key, n_args, n_aux, is_train, with_rng):
 def invoke(op_name, args, kwargs=None, out=None, is_train=False):
     """Imperative op invoke (parity: ``MXImperativeInvoke``,
     reference ``src/c_api/c_api_ndarray.cc:322``): look up the op, jit-cache by
-    (op, attrs), run on the arrays' device, wrap outputs."""
+    (op, static attrs), run on the arrays' device, wrap outputs."""
     op = get_op(op_name)
     kwargs = dict(kwargs or {})
     kwargs.pop("name", None)
@@ -482,8 +491,16 @@ def invoke(op_name, args, kwargs=None, out=None, is_train=False):
     tensors = [as_jax(a) for a in arg_list] + [as_jax(a) for a in aux_list]
     if op.needs_rng:
         tensors.append(_random.next_key())
+    # declared operands ride behind the tensors as Python scalars, which jit
+    # traces as weak-typed 0-d float32 / int32: the program is keyed on the
+    # other attributes only, and promotes dtypes as a Python attribute would
+    static = attrs
+    if op.operand_params:
+        static = {k: v for k, v in attrs.items()
+                  if k not in op.operand_params}
+        tensors.extend(attrs[k] for k in op.operand_params)
     fn = _jitted_apply(
-        op_name, op.attrs_key(attrs), len(arg_list), n_aux, is_train,
+        op_name, op.attrs_key(static), len(arg_list), n_aux, is_train,
         op.needs_rng
     )
     if op.mesh_aware:

@@ -18,6 +18,9 @@ An op declares:
   moving stats).  The compute fn returns their new values after the outputs.
 * ``params``      — attribute spec (name -> ParamSpec), the ``dmlc::Parameter``
   equivalent: typed, defaulted, string-parseable (for JSON graph loading).
+  An attribute declared ``operand=True`` may reach ``fn`` as a traced scalar:
+  the imperative path hands it to the compiled program as an argument instead
+  of compiling it in, and a fused caller sets it with ``Op.with_operands``.
 * ``fn(attrs, *tensors, is_train=..., rng=...)`` — the compute rule on jax
   arrays.  ``rng`` is a jax PRNG key for stochastic ops (Dropout, samplers).
 """
@@ -67,15 +70,22 @@ def _parse_shape(s):
 
 
 class ParamSpec:
-    """One attribute of an op (the ``DMLC_DECLARE_FIELD`` equivalent)."""
+    """One attribute of an op (the ``DMLC_DECLARE_FIELD`` equivalent).
 
-    __slots__ = ("name", "type", "default", "required", "enum")
+    ``operand=True`` says the compute rule only does arithmetic on the
+    value (no Python branch, shape or dtype depends on it), so it may be
+    a traced scalar: set it on a number that changes from call to call
+    (a learning rate, a step count), never on one the rule branches on."""
 
-    def __init__(self, type="str", default=None, required=False, enum=None):
+    __slots__ = ("name", "type", "default", "required", "enum", "operand")
+
+    def __init__(self, type="str", default=None, required=False, enum=None,
+                 operand=False):
         self.type = type
         self.default = default
         self.required = required
         self.enum = enum
+        self.operand = operand
 
     def parse(self, value):
         if value is None:
@@ -124,6 +134,11 @@ class Op:
         self.aux_names = list(aux_names)
         self.num_outputs = num_outputs  # int or callable(attrs) -> int
         self.params = params or {}
+        # the attributes that may arrive as traced scalars, in declaration
+        # order: THE list every caller reads (``ndarray.invoke`` passes them
+        # as operands, the fused trainers overwrite them per step)
+        self.operand_params = tuple(
+            k for k, spec in self.params.items() if spec.operand)
         self.needs_mode = needs_mode
         self.needs_rng = needs_rng
         # variable_args: op takes N homogeneous inputs (Concat, add_n, ...)
@@ -162,6 +177,26 @@ class Op:
     def attrs_key(self, attrs: Dict):
         """Hashable canonical form of attrs (jit-cache key component)."""
         return tuple(sorted((k, _hashable(v)) for k, v in attrs.items()))
+
+    def is_operand(self, name: str) -> bool:
+        return name in self.operand_params
+
+    def with_operands(self, attrs: Dict, **values) -> Dict:
+        """``attrs`` with the operands among ``values`` set, to Python
+        numbers or traced scalars alike.  A name the op does not have is
+        skipped (a trainer offers its step count to every update op); one
+        it has but did not declare ``operand=True`` is refused, because
+        the compute rule may branch on it."""
+        out = dict(attrs)
+        for k, v in values.items():
+            if k in self.operand_params:
+                out[k] = v
+            elif k in self.params:
+                raise MXNetError(
+                    "%s: attribute %r is not declared operand=True and "
+                    "cannot be set per call (operands: %s)"
+                    % (self.name, k, list(self.operand_params)))
+        return out
 
     def n_outputs(self, attrs) -> int:
         if callable(self.num_outputs):

@@ -975,9 +975,14 @@ def _prep_grad(grad, attrs):
     return g
 
 
+# operand=True: lr, wd (and adam's t) change from step to step, and the
+# rules below only do arithmetic on them — eager calls pass them as scalar
+# operands of one compiled program, the fused trainers trace them.
+# clip_gradient stays an attribute (_prep_grad branches on it), as do the
+# constants of one optimizer object (rescale_grad, momentum, betas, ...).
 _OPT_COMMON = {
-    "lr": P("float", 0.01, required=True),
-    "wd": P("float", 0.0),
+    "lr": P("float", 0.01, required=True, operand=True),
+    "wd": P("float", 0.0, operand=True),
     "rescale_grad": P("float", 1.0),
     "clip_gradient": P("float", -1.0),
 }
@@ -1010,7 +1015,7 @@ def _sgd_mom_update(attrs, w, g, mom):
         beta1=P("float", 0.9),
         beta2=P("float", 0.999),
         epsilon=P("float", 1e-8),
-        t=P("int", 1),
+        t=P("int", 1, operand=True),
     ),
 )
 def _adam_update(attrs, w, g, mean, var):
@@ -1018,11 +1023,13 @@ def _adam_update(attrs, w, g, mean, var):
     b1, b2 = attrs["beta1"], attrs["beta2"]
     new_mean = b1 * mean + (1 - b1) * g
     new_var = b2 * var + (1 - b2) * jnp.square(g)
-    # t may be a traced scalar (ShardedTrainer and dist_tpu pass the
-    # on-device step counter so long runs don't recompile per step).
-    # Compute the bias correction explicitly in f32 so static-t (python
-    # float64 powers) and traced-t callers get BITWISE-identical updates
-    # — the dist_tpu-vs-dist_sync exact-parity contract depends on it.
+    # t is a declared operand: eager calls pass the step count as a
+    # scalar argument, ShardedTrainer and dist_tpu the on-device counter,
+    # so long runs don't recompile per step.  The bias correction is
+    # computed explicitly in f32 so that all of them get BITWISE-identical
+    # updates — the dist_tpu-vs-dist_sync exact-parity contract depends on
+    # it.  (A t compiled in as a constant can differ in the last place:
+    # XLA folds beta**3 to b*b*b where the run-time power is pow's.)
     t = jnp.asarray(attrs["t"], jnp.float32)
     b1f, b2f = jnp.float32(b1), jnp.float32(b2)
     lr = attrs["lr"] * jnp.sqrt(1 - b2f**t) / (1 - b1f**t)

@@ -157,22 +157,20 @@ class Optimizer(object):
 
     def _fused_spec_for(self, op_name, **static):
         """Build a ``dist_tpu`` fused-step spec from a registered update
-        op: ``(op, attrs, n_states, needs_t)``.  ``attrs`` is fully parsed
-        with lr/wd (and t) as placeholders the fused program overwrites
-        with traced values — so the update arithmetic is THE registered
-        op's, the same one :meth:`update` calls (one registry, zero
-        drift)."""
+        op: ``(op, attrs, n_states)``.  ``attrs`` is fully parsed, the
+        op's declared operands (lr, wd, adam's t) at their defaults: the
+        fused program sets them to traced values at every push
+        (``Op.with_operands``) — so the update arithmetic is THE
+        registered op's, the same one :meth:`update` calls (one registry,
+        zero drift)."""
         from .ops.registry import get_op
 
         op = get_op(op_name)
-        full = dict(static, lr=0.0, wd=0.0,
-                    rescale_grad=self.rescale_grad,
+        full = dict(static, rescale_grad=self.rescale_grad,
                     clip_gradient=self.clip_gradient or -1.0)
-        needs_t = "t" in op.params
-        if needs_t:
-            full["t"] = 1
+        full.update((k, op.params[k].default) for k in op.operand_params)
         attrs = op.parse_attrs(full)
-        return op, attrs, op.n_outputs(attrs) - 1, needs_t
+        return op, attrs, op.n_outputs(attrs) - 1
 
     def fused_spec(self):
         """The fused reduce+update spec for the ``dist_tpu`` kvstore.

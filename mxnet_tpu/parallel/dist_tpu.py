@@ -45,7 +45,7 @@ class FusedTPUStore:
         self._mesh = None
         self._weights = {}   # key -> jnp array (global replicated when dist)
         self._states = {}    # key -> tuple of jnp arrays
-        self._spec = None    # (update_op, static_attrs, n_states, needs_t)
+        self._spec = None    # (update_op, static_attrs, n_states)
         self._jits = {}      # (kind, shape, dtype) -> compiled step
 
     # -- plumbing ------------------------------------------------------
@@ -125,10 +125,8 @@ class FusedTPUStore:
             g = jnp.sum(gstack, axis=0)
             if kind == "accum":
                 return (w + g,)
-            update_op, static_attrs, _, needs_t = spec
-            attrs = dict(static_attrs, lr=lr, wd=wd)
-            if needs_t:
-                attrs["t"] = t
+            update_op, static_attrs, _ = spec
+            attrs = update_op.with_operands(static_attrs, lr=lr, wd=wd, t=t)
             outs, _ = update_op.apply(attrs, [w, g, *state])
             return tuple(outs)
 

@@ -381,11 +381,10 @@ class PipelinedTrainer:
             if self._needs_count:
                 t_new = states["num_update"] + 1
                 new_states["num_update"] = t_new
-                attrs = dict(attrs)
-                if self._needs_t:
-                    attrs["t"] = t_new
+                traced = {"t": t_new}
                 if self._lr_fn is not None:
-                    attrs["lr"] = self._lr_fn(t_new)
+                    traced["lr"] = self._lr_fn(t_new)
+                attrs = self._update_op.with_operands(attrs, **traced)
             new_params, slots = self._apply_updates(
                 stacked_params, grads, states.get("slots", ()), attrs)
             if slots:

@@ -149,7 +149,7 @@ def resolve_update_op(optimizer, optimizer_params, momentum, learning_rate,
     static.update(opt_kwargs)
     attrs = update_op.parse_attrs(static)
     n_states = update_op.n_outputs(attrs) - 1
-    return update_op, attrs, n_states, "t" in update_op.params
+    return update_op, attrs, n_states, update_op.is_operand("t")
 
 
 def sgd_mom_tree_stock(attrs, params, grads, moms, ok=None):
@@ -562,7 +562,6 @@ class ShardedTrainer:
         use_mom = self._use_momentum
         update_op = self._update_op
         opt_attrs = self._opt_attrs
-        needs_t = self._needs_t
         needs_count = self._needs_count
         lr_fn = self._lr_fn
         diff = [n for n in self.param_names if n in self._diff_set]
@@ -647,11 +646,10 @@ class ShardedTrainer:
             if needs_count:
                 t_new = moms[_STEP_COUNT] + 1
                 new_moms[_STEP_COUNT] = t_new
-                attrs = dict(opt_attrs)
-                if needs_t:
-                    attrs["t"] = t_new
+                traced = {"t": t_new}
                 if lr_fn is not None:
-                    attrs["lr"] = lr_fn(t_new)
+                    traced["lr"] = lr_fn(t_new)
+                attrs = update_op.with_operands(opt_attrs, **traced)
             if use_tree:
                 tree_p, tree_m = fused_sgd_mom_tree(
                     attrs, {n: params[n] for n in diff}, grads,

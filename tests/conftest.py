@@ -74,6 +74,36 @@ def _bound_compiler_state():
     jax.clear_caches()
 
 
+@pytest.fixture(scope="module")
+def topo():
+    """A described TPU v5e 2x2, for the ``test_chip_compile*.py`` files'
+    compiles; the persistent compilation cache is off around them (an
+    entry written by such a compile cannot be read back without a chip)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip("cannot describe a v5e topology here: %s" % exc)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Make the platform test (``ops.platform.pallas_mode``) answer as
+    on the chip: every rule takes its TPU branch, real kernels and not
+    interpret mode."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -103,24 +133,15 @@ def _run_example(name, call, timeout, func="run"):
     each must work from a cold start, like a user run.
 
     ``timeout`` (seconds) is the gate's own budget, written beside it:
-    about three times what the gate takes under the tier-1 command and
-    never over 600 (``test_docs.py`` holds every file to that), so a
-    gate that hangs fails as itself and the rest of the suite still
-    runs.  The gates live in ``test_example_gates_*.py``, dealt so the
-    files take about the same time: tier-1 (``-n 6 --dist loadfile``)
-    hands a whole file to one worker, and a worker its next file when
-    two of its tests are left.  So there are as many files as workers
-    (eight files ran 725 s against 537-588 s for six: two workers walked
-    two each), each with three gates or more (a file of two takes the
-    next file with it), the long gates first.  A gate is mostly one
-    thread (``autoencoder``: 189 s alone on eight cores with 247 s of
-    CPU time, 215 s held to two cores; PR 24), and the child's products
-    run on that thread alone (``--xla_cpu_multi_thread_eigen=false``):
-    beside five other workers on a shared eight-core machine XLA's
-    pool of a thread a core made a file of gates take 780 s, a gate
-    dying at its 600 s, where it takes 429 s without the pool (PR 43;
-    the six files were 490-605 s of a tier-1 run that was cut at its
-    clock).
+    about three times what the gate takes under the tier-1 command, at
+    least 60 and never over 300 (``test_docs.py`` holds every gate to
+    that), so a gate that hangs fails as itself and the rest of the
+    suite still runs.  The gates live in the six
+    ``test_example_gates_*.py`` files, three to five a file: tier-1
+    (``-n 6 --dist loadfile``) hands a whole file to one worker, and a
+    file takes 45-120 s of it, the longest gate 60-80 s (PR 44; they
+    took 380-570 s a file while every eager optimizer step of every
+    parameter was a compile of its own).
     """
     code = (
         "import sys, json\n"
@@ -135,8 +156,7 @@ def _run_example(name, call, timeout, func="run"):
         "print('STATS ' + json.dumps({k: float(v) for k, v in stats.items()}))\n"
         % (_REPO, os.path.join(_REPO, "examples", name), func, call)
     )
-    env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS=os.environ.get(
-        "XLA_FLAGS", "") + " --xla_cpu_multi_thread_eigen=false")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env, timeout=timeout, cwd=_REPO)
     assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-2000:])

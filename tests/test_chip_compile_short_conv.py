@@ -2,9 +2,9 @@
 compiled for the described v5e at ``lfm2-serve-chat64``'s sizes and the
 published widths: the decode bucket of 64 and the prefill buckets where
 the attention changes body.  A file of its own beside
-``test_chip_compile.py`` (whose fixtures and helpers it uses) because a
-file is the unit of distribution of the tier-1 run and these compiles
-take a few minutes."""
+``test_chip_compile.py`` (the kernels' compiles) because a file is the
+unit of distribution of the tier-1 run and these compiles take a few
+minutes."""
 
 import os
 
@@ -15,9 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from test_chip_compile import (  # noqa: F401  (topo, on_tpu: fixtures)
-    _big_moves, _grouped_tiles_are_the_rules, _named_calls, _traffic, on_tpu,
-    topo)
+from chip_compile_helpers import (
+    _big_moves, _grouped_tiles_are_the_rules, _named_calls, _traffic)
 
 BF16, F32 = jnp.bfloat16, jnp.float32
 

@@ -1,9 +1,9 @@
 """The shortcut-connected latent model (``models/shortcut_latent_moe.py``)
 compiled for the described v5e at ``longcat-serve-agent64``'s sizes and
 the published widths: every prefill bucket and the decode bucket of 64.
-A file of its own beside ``test_chip_compile.py`` (whose fixtures and
-helpers it uses) because a file is the unit of distribution of the
-tier-1 run and these seven compiles take three minutes."""
+A file of its own beside ``test_chip_compile.py`` (the kernels'
+compiles) because a file is the unit of distribution of the tier-1 run
+and these seven compiles take three minutes."""
 
 import os
 
@@ -14,9 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from test_chip_compile import (  # noqa: F401  (topo, on_tpu: fixtures)
-    _big_moves, _grouped_tiles_are_the_rules, _holds, _named_calls, _traffic,
-    on_tpu, topo)
+from chip_compile_helpers import (
+    _big_moves, _grouped_tiles_are_the_rules, _holds, _named_calls, _traffic)
 
 BF16, F32 = jnp.bfloat16, jnp.float32
 

@@ -4,9 +4,9 @@
 decode bucket of 48 over the two layer groups' pools, the prefill
 buckets where the attention changes body and where the band leaves the
 triangle, and the pool write of both groups in one program.  A file of
-its own beside ``test_chip_compile.py`` (whose fixtures and helpers it
-uses) because a file is the unit of distribution of the tier-1 run and
-these compiles take a few minutes."""
+its own beside ``test_chip_compile.py`` (the kernels' compiles) because
+a file is the unit of distribution of the tier-1 run and these compiles
+take a few minutes."""
 
 import os
 
@@ -17,9 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from test_chip_compile import (  # noqa: F401  (topo, on_tpu: fixtures)
-    _big_moves, _grouped_tiles_are_the_rules, _named_calls, _traffic, on_tpu,
-    topo)
+from chip_compile_helpers import (
+    _big_moves, _grouped_tiles_are_the_rules, _named_calls, _traffic)
 
 BF16 = jnp.bfloat16
 

@@ -143,8 +143,9 @@ def test_suite_fits_its_clock():
     unit of distribution and one hung child must not eat the clock.  So
     every ``subprocess.run(`` / ``_run_example(`` under tests/ passes a
     ``timeout``, no ``timeout=<number>`` anywhere under tests/ (a call's
-    or a helper's default) is over 600 s, and no file holds more than
-    eight ``_run_example`` gates."""
+    or a helper's default) is over 600 s, no ``_run_example`` gate's is
+    over 300 s (the longest gate takes 60-80 s: ``_run_example``), and no
+    file holds more than eight ``_run_example`` gates."""
     import ast
     import re
 
@@ -164,6 +165,13 @@ def test_suite_fits_its_clock():
                     and ast.unparse(c.func) in ("subprocess.run",
                                                 "_run_example")
                     and not any(kw.arg == "timeout" for kw in c.keywords)]
+            bad += ["%s:%d _run_example(timeout=%s) is over 300 s"
+                    % (rel, c.lineno, ast.unparse(kw.value))
+                    for c in ast.walk(tree) if isinstance(c, ast.Call)
+                    and ast.unparse(c.func) == "_run_example"
+                    for kw in c.keywords if kw.arg == "timeout"
+                    and not (isinstance(kw.value, ast.Constant)
+                             and kw.value.value <= 300)]
             gates = [f.name for f in ast.walk(tree)
                      if isinstance(f, ast.FunctionDef)
                      and f.name.startswith("test_")

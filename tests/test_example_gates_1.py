@@ -14,7 +14,7 @@ def test_autoencoder_example():
     0.1000, 10 + 25 read 0.1034, 4 + 30 read 0.1052 (PR 24)."""
     stats = _run_example("autoencoder.py",
                          "pretrain_epochs=10, finetune_epochs=35, log=False",
-                         timeout=600)
+                         timeout=60)
     assert stats["ae_mse"] < 0.9 * stats["pca_mse"], stats
 
 

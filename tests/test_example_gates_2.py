@@ -18,7 +18,7 @@ def test_dec_clustering_example():
     on its own k-means init.  The example's own 45 pretraining epochs
     stay: they read 0.743 against the 0.7 bar, 30 read 0.722 and 20
     read 0.710 (PR 24)."""
-    stats = _run_example("dec_clustering.py", "log=False", timeout=600)
+    stats = _run_example("dec_clustering.py", "log=False", timeout=60)
     assert stats["dec_acc"] > stats["raw_acc"] + 0.3, stats
     assert stats["dec_acc"] >= stats["init_acc"] - 0.02, stats
     assert stats["dec_acc"] > 0.7, stats
@@ -31,7 +31,7 @@ def test_speech_demo_example():
     through an LSTM acoustic model, posteriors written back to ark and
     verified; frame accuracy >= 0.9."""
     stats = _run_example("speech_demo.py", "epochs=6, log=False",
-                         timeout=90)
+                         timeout=60)
     assert stats["frame_acc"] >= 0.9, stats
 
 

@@ -16,7 +16,7 @@ def test_speech_recognition_example():
     (PR 24)."""
     stats = _run_example("speech_recognition.py",
                          "num_epochs=14, stop_cer=0.08, log=False",
-                         timeout=600)
+                         timeout=240)
     assert stats["cer"] < 0.12, stats
 
 
@@ -27,7 +27,7 @@ def test_quantize_transformer_example():
     Chip throughput rows come from the same example's --benchmark mode
     via tools/bench_table.py."""
     stats = _run_example("quantize_transformer.py",
-                         "epochs=4, n_train=512, log=False", timeout=180)
+                         "epochs=4, n_train=512, log=False", timeout=60)
     assert stats["fp32_acc"] > 0.9, stats
     assert stats["int8_acc"] >= stats["fp32_acc"] - 0.01, stats
 

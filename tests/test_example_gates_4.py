@@ -18,7 +18,7 @@ def test_kaggle_ndsb1_example():
     stats = _run_example(
         "kaggle_ndsb1.py",
         "epochs=12, n_per_class=40, n_test=48, width_mult=0.5, log=False",
-        timeout=540)
+        timeout=240)
     assert stats["val_acc"] > 0.8, stats
     assert stats["test_acc"] > 0.7, stats
     assert stats["n_submission_rows"] == 48, stats
@@ -29,7 +29,7 @@ def test_cnn_text_classification_example():
     signature trigrams position-invariantly.  3 epochs read 1.0 in
     five runs of five, as 4 and 5 do."""
     stats = _run_example("cnn_text_classification.py",
-                         "epochs=3, log=False", timeout=210)
+                         "epochs=3, log=False", timeout=60)
     assert stats["val_acc"] > 0.95, stats
 
 
@@ -39,7 +39,7 @@ def test_rnn_time_major_example():
     demo point, minus the cuDNN speed asymmetry XLA erases), and both
     train to near the synthetic Markov chain's true entropy."""
     stats = _run_example("rnn_time_major.py", "epochs=6, log=False",
-                         timeout=240)
+                         timeout=60)
     assert stats["parity_gap"] < 1e-5, stats
     assert stats["ppl_tnc"] < 1.35 * stats["true_ppl"], stats
     assert stats["ppl_ntc"] < 1.35 * stats["true_ppl"], stats
@@ -48,5 +48,5 @@ def test_rnn_time_major_example():
 def test_nce_loss_example():
     """NCE with k=8 sampled negatives learns the full-vocab ranking: the
     true next token ranks (near-)first across the whole vocabulary."""
-    stats = _run_example("nce_loss.py", "steps=300, log=False", timeout=120)
+    stats = _run_example("nce_loss.py", "steps=300, log=False", timeout=60)
     assert stats["mrr"] > 0.8, stats

@@ -11,7 +11,7 @@ def test_recommender_mf_example():
     """Matrix-factorization recommender: learned embeddings beat the
     global-mean and per-item-mean baselines by a wide margin."""
     stats = _run_example("recommender_mf.py",
-                         "epochs=10, batch=128, log=False", timeout=540)
+                         "epochs=10, batch=128, log=False", timeout=60)
     assert stats["rmse"] < 0.7 * stats["rmse_item"], stats
     assert stats["rmse"] < 1.0, stats
 
@@ -24,7 +24,7 @@ def test_kaggle_ndsb2_example():
     CRPS of 0.0383 in five runs of five, further under both bars than
     the 0.0454 of 12 epochs."""
     stats = _run_example("kaggle_ndsb2.py", "epochs=8, log=False",
-                         timeout=300)
+                         timeout=90)
     assert stats["crps"] < 0.8 * stats["crps_const"], stats
     assert stats["crps"] < 0.055, stats
 
@@ -35,7 +35,7 @@ def test_stochastic_depth_example():
     converges, the gate actually closes at ~death_rate during training,
     and eval uses the deterministic expectation path."""
     stats = _run_example("stochastic_depth.py",
-                         "epochs=8, death_rate=0.3, log=False", timeout=180)
+                         "epochs=8, death_rate=0.3, log=False", timeout=90)
     assert stats["val_acc"] > 0.9, stats
     # 2 blocks x 8 epochs x 12 batches = 192 draws; Bernoulli(0.3)
     # mean is within ~3 sigma bounds below
@@ -47,7 +47,7 @@ def test_quantization_conv_example():
     """Conv-path PTQ: _contrib_quantized_conv + quantized FC carry a
     small convnet to fp32-matching accuracy on the int8 MXU path."""
     stats = _run_example("quantization.py", "epochs=8, log=False",
-                         timeout=120, func="run_conv")
+                         timeout=60, func="run_conv")
     assert stats["fp32_acc"] > 0.9, stats
     assert stats["int8_acc"] > stats["fp32_acc"] - 0.05, stats
 

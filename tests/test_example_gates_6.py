@@ -11,7 +11,7 @@ def test_fcn_xs_example():
     """FCN with Deconvolution upsampling + Crop skip fusion segments
     per-pixel: accuracy and foreground IoU bars.  4 epochs read 0.964
     and 0.776 in five runs of five (0.966 and 0.790 at 6 epochs)."""
-    stats = _run_example("fcn_xs.py", "epochs=4, log=False", timeout=390)
+    stats = _run_example("fcn_xs.py", "epochs=4, log=False", timeout=60)
     assert stats["pix_acc"] > 0.93, stats
     assert stats["fg_miou"] > 0.6, stats
 
@@ -22,7 +22,7 @@ def test_bi_lstm_sort_example():
     accuracy bar with margin: 0.930 in five runs of five (0.936 at 6
     epochs, 0.948 at 8)."""
     stats = _run_example("bi_lstm_sort.py", "epochs=5, log=False",
-                         timeout=360)
+                         timeout=90)
     assert stats["elem_acc"] > 0.85, stats
 
 
@@ -31,7 +31,7 @@ def test_multi_task_example():
     converge.  4 epochs read 1.0 and 1.0 in five runs of five, as 6
     do."""
     stats = _run_example("multi_task.py", "epochs=4, log=False",
-                         timeout=180)
+                         timeout=60)
     assert stats["cls_acc"] > 0.9, stats
     assert stats["parity_acc"] > 0.9, stats
 
@@ -42,7 +42,7 @@ def test_quantize_resnet_example():
     must stay within a point of fp32 (chip-measured throughput rows come
     from the same example's --benchmark mode via tools/bench_table.py)."""
     stats = _run_example("quantize_resnet.py",
-                         "epochs=4, n_train=512, log=False", timeout=210)
+                         "epochs=4, n_train=512, log=False", timeout=90)
     assert stats["fp32_acc"] > 0.9, stats
     assert stats["int8_acc"] >= stats["fp32_acc"] - 0.01, stats
 
