@@ -44,6 +44,7 @@ import numpy as np
 from ..ops.attention import gqa_prefill_attention
 from ..ops.gated_delta import gated_delta_chunked, gated_delta_update
 from ..ops.kv_cache import CacheRow, StateRows
+from ..ops import short_conv as _conv
 from ..ops.paged_attention import gqa_paged_decode_attention
 from ..parallel import moe as _moe
 from .lm import LMDefinition
@@ -94,13 +95,10 @@ def _sizes(cfg):
 
 def _tail_shape(cfg):
     """How the ``kernel - 1`` rows of ``channels`` values a sequence
-    keeps for the convolution lie in the state pool: as rows of 512
-    lanes where the channels are whole such rows (a pool ``[.., 3,
-    8192]`` would pad its 3 rows to a tile of 16 on a TPU)."""
-    rows, channels = cfg["linear_conv_kernel_dim"] - 1, _sizes(cfg)[2]
-    if channels % 512 == 0:
-        return (rows * channels // 512, 512)
-    return (rows, channels)
+    keeps for the convolution lie in the state pool
+    (:func:`~mxnet_tpu.ops.short_conv.tail_shape`)."""
+    return _conv.tail_shape(cfg["linear_conv_kernel_dim"] - 1,
+                            _sizes(cfg)[2])
 
 
 def state_rows(cfg, dtype=jnp.bfloat16):
@@ -337,20 +335,13 @@ def _delta_prefill(params, p, x, valid, length, cfg):
     start), as they lie in the pool."""
     h = _norm(x, params[p + "mixer_norm_gamma"], cfg)
     into, z, beta, g = _delta_inputs(params, p, h, cfg)
-    taps = cfg["linear_conv_kernel_dim"]
-    w = params[p + "conv_weight"].astype(jnp.float32)
-    padded = jnp.pad(into, ((taps - 1, 0), (0, 0)))
-    t = x.shape[0]
-    conv = sum(padded[j:j + t].astype(jnp.float32) * w[:, j]
-               for j in range(taps))
+    conv, tail = _conv.conv_prefill(into, params[p + "conv_weight"], length)
     conv = jax.nn.silu(conv).astype(x.dtype)
     q, k, v = _delta_heads(conv, cfg)
     if valid is not None:           # the bucket's pad leaves the state
         beta = jnp.where(valid[:, None], beta, 0.0)
         g = jnp.where(valid[:, None], g, 0.0)
     o, state = gated_delta_chunked(q, k, v, g, beta)
-    tail = jax.lax.dynamic_slice_in_dim(
-        padded, t if length is None else length, taps - 1)
     return _delta_out(params, p, o, z, cfg), state, \
         tail.reshape(_tail_shape(cfg))
 
@@ -363,13 +354,10 @@ def _delta_decode(params, p, x, pool, read, write, tail, cfg):
     and the tail, advanced."""
     h = _norm(x, params[p + "mixer_norm_gamma"], cfg)
     into, z, beta, g = _delta_inputs(params, p, h, cfg)
-    window = jnp.concatenate([tail, into[:, None, :].astype(tail.dtype)],
-                             axis=1)
-    w = params[p + "conv_weight"].astype(jnp.float32)
-    conv = jnp.einsum("bjc,cj->bc", window.astype(jnp.float32), w)
+    conv, tail = _conv.conv_step(tail, into, params[p + "conv_weight"])
     q, k, v = _delta_heads(jax.nn.silu(conv).astype(x.dtype), cfg)
     o, pool = gated_delta_update(q, k, v, g, beta, pool, read, write)
-    return _delta_out(params, p, o, z, cfg), pool, window[:, 1:]
+    return _delta_out(params, p, o, z, cfg), pool, tail
 
 
 def _feed_forward(params, i, x, cfg, valid=None):

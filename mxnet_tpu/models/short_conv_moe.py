@@ -43,6 +43,7 @@ import numpy as np
 
 from ..ops.attention import gqa_prefill_attention
 from ..ops.kv_cache import CacheRow, StateRows
+from ..ops import short_conv as _conv
 from ..ops.paged_attention import gqa_paged_decode_attention
 from ..parallel import moe as _moe
 from . import latent_moe as _lm
@@ -102,13 +103,9 @@ def _is_dense(cfg, layer):
 
 def _tail_shape(cfg):
     """How the ``conv_L_cache - 1`` rows of ``hidden_size`` values a
-    sequence keeps for the convolution lie in the state pool: as rows of
-    512 lanes where the channels are whole such rows (a pool ``[.., 2,
-    2048]`` would pad its 2 rows to a tile on a TPU)."""
-    rows, channels = cfg["conv_L_cache"] - 1, cfg["hidden_size"]
-    if channels % 512 == 0:
-        return (rows * channels // 512, 512)
-    return (rows, channels)
+    sequence keeps for the convolution lie in the state pool
+    (:func:`~mxnet_tpu.ops.short_conv.tail_shape`)."""
+    return _conv.tail_shape(cfg["conv_L_cache"] - 1, cfg["hidden_size"])
 
 
 def state_rows(cfg, dtype=jnp.bfloat16):
@@ -207,13 +204,8 @@ def _conv_prefill(params, p, x, length, cfg):
     the start), as they lie in the pool."""
     with jax.named_scope("short_conv_prefill"):
         u, gate = _conv_inputs(params, p, x, cfg)
-        taps, t = cfg["conv_L_cache"], x.shape[0]
-        w = params[p + "conv_weight"].astype(jnp.float32)
-        padded = jnp.pad(u, ((taps - 1, 0), (0, 0)))
-        conv = sum(padded[j:j + t].astype(jnp.float32) * w[:, j]
-                   for j in range(taps))
-        tail = jax.lax.dynamic_slice_in_dim(
-            padded, t if length is None else length, taps - 1)
+        conv, tail = _conv.conv_prefill(u, params[p + "conv_weight"],
+                                        length)
         return _conv_out(params, p, gate, conv), \
             tail.reshape(_tail_shape(cfg))
 
@@ -224,11 +216,8 @@ def _conv_decode(params, p, x, tail, cfg):
     stream and the tail, advanced."""
     with jax.named_scope("short_conv_decode"):
         u, gate = _conv_inputs(params, p, x, cfg)
-        window = jnp.concatenate([tail, u[:, None, :].astype(tail.dtype)],
-                                 axis=1)
-        conv = jnp.einsum("bjc,cj->bc", window.astype(jnp.float32),
-                          params[p + "conv_weight"].astype(jnp.float32))
-        return _conv_out(params, p, gate, conv), window[:, 1:]
+        conv, tail = _conv.conv_step(tail, u, params[p + "conv_weight"])
+        return _conv_out(params, p, gate, conv), tail
 
 
 def _attention_projections(params, p, x, positions, cfg):

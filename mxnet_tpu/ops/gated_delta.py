@@ -16,7 +16,8 @@ rounded.  The serving path runs it over a pool of states
 the pool and written to another, once each; on a TPU a Pallas kernel
 does that with the pool left where it lies (one program a row, the
 state's rows named by scalar-prefetched indices, the pool aliased to the
-output), elsewhere XLA gathers, steps and scatters.  A prefill runs it over
+output: :mod:`~mxnet_tpu.ops.state_pool`), elsewhere XLA gathers, steps
+and scatters.  A prefill runs it over
 chunks of :data:`CHUNK` tokens (:func:`gated_delta_chunked`): inside a
 chunk the rule is solved as one triangular system (the WY form of the
 chunk's rank-one updates), and only the chunk-to-chunk carry of the
@@ -38,6 +39,7 @@ import jax.numpy as jnp
 
 from . import platform as _platform
 from .fused.parity import case_rng, register_parity
+from .state_pool import rows_through_pool
 
 __all__ = ["CHUNK", "gated_delta_step", "gated_delta_update",
            "gated_delta_chunked"]
@@ -110,51 +112,23 @@ def _update_kernel(read_ref, write_ref, decay_ref, beta_ref, q_ref, k_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _update_pallas(q, k, v, g, beta, pool, read, write, interpret=False):
-    """The update with the pool left in place: the grid runs over the
-    batch's rows, a row's state block is fetched from ``pool[read[i]]``
-    and stored to ``pool[write[i]]`` by the pipeline's own copies (the
-    next row's fetch in flight behind this row's arithmetic), and the
-    pool is aliased to the output, so that the rows no one writes stay
-    as they are.  Jitted so that a model's layers share one trace and
-    one lowering of the kernel."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
+    """The update with the pool left in place
+    (:func:`~mxnet_tpu.ops.state_pool.rows_through_pool`: one program a
+    row, its state fetched from ``pool[read[i]]`` and stored to
+    ``pool[write[i]]``, the pool aliased to the output).  Jitted so
+    that a model's layers share one trace and one lowering of the
+    kernel."""
     f32 = jnp.float32
-    bsz, heads, dk = q.shape
+    bsz, heads, _ = q.shape
     dv = v.shape[-1]
     decay = jnp.exp(g.astype(f32)).reshape(-1)
     q_t = q.astype(f32).transpose(0, 2, 1)                  # [B, dk, H]
     k_t = k.astype(f32).transpose(0, 2, 1)
-
-    def row(*block):
-        return pl.BlockSpec((1,) + block,
-                            lambda i, *_: (i,) + (0,) * len(block))
-
-    state_at = (1, heads, dk, dv)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(bsz,),
-        in_specs=[row(dk, heads), row(dk, heads), row(heads, dv),
-                  pl.BlockSpec(state_at,
-                               lambda i, rd, *_: (rd[i], 0, 0, 0))],
-        out_specs=[row(heads, dv),
-                   pl.BlockSpec(state_at,
-                                lambda i, rd, wr, *_: (wr[i], 0, 0, 0))])
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",))
-    # the scope names the kernel in a trace; it has to be the innermost
-    with jax.named_scope("gated_delta_decode"):
-        o, pool = pl.pallas_call(
-            functools.partial(_update_kernel, heads=heads),
-            out_shape=[jax.ShapeDtypeStruct((bsz, heads, dv), f32),
-                       jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
-            grid_spec=grid_spec, input_output_aliases={7: 1},
-            interpret=interpret, **kwargs)(
-            read.astype(jnp.int32), write.astype(jnp.int32), decay,
-            beta.astype(f32).reshape(-1), q_t, k_t, v.astype(f32), pool)
+    o, pool = rows_through_pool(
+        functools.partial(_update_kernel, heads=heads),
+        [decay, beta.astype(f32).reshape(-1)], [q_t, k_t, v.astype(f32)],
+        pool, read, write, [jax.ShapeDtypeStruct((bsz, heads, dv), f32)],
+        scope="gated_delta_decode", interpret=interpret)
     return o, pool
 
 

@@ -335,8 +335,10 @@ def few_rows_hit_most(tokens, k, n_experts):
 
 
 #: the gate's activation, by the name a caller gives it: SwiGLU's and
-#: ReGLU's (``relu(W_gate x) * W_up x``)
-ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+#: ReGLU's (``relu(W_gate x) * W_up x``), and that of an expert of two
+#: matrices without a gate, ``W_down relu(W_up x)^2``
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+               "relu2": lambda v: jnp.square(jax.nn.relu(v))}
 
 
 def dropless_experts(x, chosen, gates, w_gate, w_up, w_down, held,
@@ -378,10 +380,17 @@ def dropless_experts(x, chosen, gates, w_gate, w_up, w_down, held,
     ``moe_grouped_kept_rows`` and ``moe_grouped_walked_share``; the
     products' tiles are :func:`grouped_tiling`'s).  ``activation``
     (static) names
-    the gate's: ``"silu"`` (SwiGLU) or ``"relu"`` (ReGLU)."""
+    the gate's: ``"silu"`` (SwiGLU) or ``"relu"`` (ReGLU).
+
+    ``w_gate=None`` is an **expert of two matrices**, ``W_down
+    act(W_up x)`` with no gate (``activation="relu2"``: Nemotron's
+    squared ReLU), in every form: two products a run where the gated
+    expert has three.  ``d`` need not be the model's width: a layer
+    whose experts work in a narrower latent projects to it before the
+    call and back after it."""
     act = ACTIVATIONS[activation]
     tokens, k = chosen.shape
-    first, count = held[0], int(w_gate.shape[0])
+    first, count = held[0], int(w_up.shape[0])
     if expert_axis is not None:
         first = jax.lax.axis_index(expert_axis) * count
     local = (chosen >= first) & (chosen < first + count)
@@ -462,9 +471,12 @@ def _grouped(x, key, local, gates, sizes, w_gate, w_up, w_down, n_experts,
     rows are set here, for the call's pairs and the ``n_experts`` the
     choice was made over."""
     pairs, count = key.size, sizes.shape[0]
+    # the widest row a run holds one of a pair: the input's or the
+    # hidden activation's (wider only where the experts work in a
+    # latent narrower than they are)
     kept = grouped_kept_rows(pairs, count, n_experts,
-                             x.shape[1] * x.dtype.itemsize)
-    tiling = grouped_tiling(kept, *w_gate.shape[1:], x.dtype.itemsize)
+                             max(w_up.shape[1:]) * x.dtype.itemsize)
+    tiling = grouped_tiling(kept, *w_up.shape[1:], x.dtype.itemsize)
     tile = tiling[0] if tiling else DEFAULT_TILE_ROWS
     expected, visits = grouped_visits(pairs, count, n_experts, tile)
     labels = str(pairs), str(count)
@@ -580,8 +592,15 @@ def _grouped_run(x, k, picked, sizes, w_gate, w_up, w_down, act):
                                       preferred_element_type=out)
 
     rows = x[picked // k]
-    h = (act(grouped(rows, w_gate, jnp.float32))
-         * grouped(rows, w_up, jnp.float32)).astype(x.dtype)
+    if w_gate is None:
+        # no gate: the product is rounded as it leaves the kernel (a
+        # float32 copy of a run's hidden rows is twice its largest
+        # array) and the activation is taken in float32 from there
+        h = act(grouped(rows, w_up, x.dtype).astype(jnp.float32)
+                ).astype(x.dtype)
+    else:
+        h = (act(grouped(rows, w_gate, jnp.float32))
+             * grouped(rows, w_up, jnp.float32)).astype(x.dtype)
     return grouped(h, w_down, x.dtype)
 
 
@@ -660,15 +679,18 @@ def _grouped_cut(x, key, local, gates, sizes, w_gate, w_up, w_down, act,
 def _every_row(x, key, gates, w_gate, w_up, w_down, act=jax.nn.silu):
     """The same sum with every held expert computed over every row: a
     row's gate for an expert it did not choose is 0."""
-    count = w_gate.shape[0]
+    count = w_up.shape[0]
     gate_of = jnp.where(key[:, :, None] == jnp.arange(count),
                         gates[:, :, None], 0).sum(1)             # [T, G]
 
     def every(spec, a, w, out):
         return jnp.einsum(spec, a, w, preferred_element_type=out)
 
-    h = (act(every("td,gdh->gth", x, w_gate, jnp.float32))
-         * every("td,gdh->gth", x, w_up, jnp.float32)).astype(x.dtype)
+    if w_gate is None:
+        h = act(every("td,gdh->gth", x, w_up, jnp.float32)).astype(x.dtype)
+    else:
+        h = (act(every("td,gdh->gth", x, w_gate, jnp.float32))
+             * every("td,gdh->gth", x, w_up, jnp.float32)).astype(x.dtype)
     y = every("gth,ghd->gtd", h, w_down, x.dtype)
     return every("gtd,tg->td", y, gate_of.astype(x.dtype),
                  jnp.float32).astype(x.dtype)

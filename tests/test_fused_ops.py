@@ -49,7 +49,8 @@ def _pallas_calls(fn, *args):
 def test_every_hot_path_kernel_is_registered():
     assert KERNELS == ["flash_prefill_attention", "gated_delta_decode",
                        "latent_decode_attention", "paged_decode_attention",
-                       "paged_decode_gqa_attention", "sgd_mom_tree"]
+                       "paged_decode_gqa_attention", "sgd_mom_tree",
+                       "ssm_decode"]
     regs = fpar.parity_registrations()
     # the tree step is plain jax on every backend; the rest are Pallas
     assert [k for k in KERNELS if not regs[k].pallas] == ["sgd_mom_tree"]
