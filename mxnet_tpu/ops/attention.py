@@ -6,12 +6,12 @@ cover natively (§7.10).  Long sequences in the reference are handled only by
 bucketing and model-parallel LSTM; here they are handled the TPU way:
 
 * ``flash_attention`` — blockwise-softmax attention.  On TPU the forward is a
-  Pallas kernel (online softmax, MXU matmuls) and the backward is a pair of
-  Pallas kernels (a dk/dv pass and a dq pass, reusing the forward's saved
-  log-sum-exp), all O(block) VMEM; each walks, inside the program, only
-  the tiles of the score square a causal row can see (``causal_walk``;
-  gauge ``flash_attention_walked_share``).  Elsewhere a numerically
-  identical jax fallback runs.
+  Pallas kernel (online softmax, MXU matmuls) and the backward is one more
+  (dq, dk and dv from one walk of the scores, reusing the forward's saved
+  log-sum-exp), O(block) VMEM but for a head's dq; each walks, inside the
+  program, only the tiles of the score square a causal row can see
+  (``causal_walk``; gauge ``flash_attention_walked_share``).  Elsewhere a
+  numerically identical jax fallback runs.
 * ``ring_attention`` — context-parallel attention for sequences sharded along
   a mesh ``seq`` axis: K/V blocks rotate around the ring via ``ppermute``
   while each device's query block folds them into an online softmax.  Used
@@ -225,7 +225,7 @@ def gqa_prefill_attention(q, k, v, sm_scale, window=None):
 # ``r - window + 1 .. r``): a run's walk then *begins* at the first
 # chunk its first row sees, and the chunks the band's lower edge cuts
 # take the mask as those on the diagonal do.  The forward alone walks a
-# band; the backward passes refuse a window.
+# band; the backward refuses a window.
 
 
 def _chunks_under(x, chunk, n, partly=False):
@@ -275,13 +275,13 @@ def causal_walk(T, Tk, run, chunk, causal=True, keys_resident=False,
                 window=None):
     """``(walked, masked, pairs)``: of the ``pairs`` (run, chunk) tiles
     of a ``T x Tk`` score square, how many a kernel computes and how
-    many of those it masks.  Query runs over key chunks (the forward and
-    the dQ pass), or with ``keys_resident`` key runs over query chunks
-    (the dK/dV pass).  Counted with the walks the kernels unroll;
-    ``window`` is the forward's band (the backward walks have none)."""
+    many of those it masks.  Query runs over key chunks (the forward),
+    or with ``keys_resident`` key runs over query chunks (the
+    backward).  Counted with the walks the kernels unroll; ``window`` is
+    the forward's band (the backward's walk has none)."""
     if keys_resident:
         if window is not None:
-            raise NotImplementedError("the backward walks have no window")
+            raise NotImplementedError("the backward's walk has no window")
         n = -(-T // chunk)
         walks = [_query_walk(col0, run, chunk, n, causal)
                  for col0 in range(0, Tk, run)]
@@ -298,7 +298,7 @@ _M_WALKED = _metrics.gauge(
     "flash_attention_walked_share",
     "Share of the score square's (run, chunk) tiles the flash kernel "
     "built last computes (the rest lie above the causal diagonal), by "
-    "kernel: fwd, dkdv, dq", ["kernel"])
+    "kernel: fwd, bwd", ["kernel"])
 
 
 _M_WINDOW_TILES = _metrics.gauge(
@@ -475,7 +475,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         l = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_scr[...] / _lanes(l, dv)).astype(o_ref.dtype)
         if lse_ref is not None:
-            # log-sum-exp residual for the backward kernels (padded rows
+            # log-sum-exp residual for the backward kernel (padded rows
             # get -inf + 0; they are sliced off before use)
             lse_ref[0] = _row(m_scr[...] + jnp.log(l))
 
@@ -659,16 +659,16 @@ def _flash_dispatch(q, k, v, causal, sm_scale, interpret, scope=None,
 
 def _flash_fwd_vjp(q, k, v, causal, sm_scale, interpret):
     """Forward for the VJP: same dispatch as the primal, but every path
-    also emits the per-row log-sum-exp so the backward kernels never have
-    to re-derive the softmax statistics."""
+    also emits the per-row log-sum-exp so the backward never has to
+    re-derive the softmax statistics."""
     on_chip = _platform.pallas_mode() == "chip"
     if interpret:
         out, lse = _flash_fwd_pallas(q, k, v, causal, sm_scale,
                                      interpret=not on_chip,
                                      return_lse=True)
     elif on_chip and (q.shape[2] >= 1024 or k.shape[2] >= 1024):
-        # same T>=1024 crossover as the primal: the Pallas bwd kernels
-        # consume the kernel's lse directly, and skipping the [T, T]
+        # same T>=1024 crossover as the primal: the Pallas bwd kernel
+        # consumes the kernel's lse directly, and skipping the [T, T]
         # XLA softmax materialization pays off
         out, lse = _flash_fwd_pallas(q, k, v, causal, sm_scale,
                                      return_lse=True)
@@ -679,59 +679,63 @@ def _flash_fwd_vjp(q, k, v, causal, sm_scale, interpret):
 
 
 # ----------------------------------------------------------------------
-# Pallas TPU backward kernels (dk/dv pass + dq pass)
+# Pallas TPU backward kernel (dq, dk and dv from one pass)
 # ----------------------------------------------------------------------
 
 
-def _bwd_p_ds(q, do, lse, delta, k, v, cut_at, *, sm_scale,
-              keys_first=False):
-    """Shared backward tile math for one (query rows, keys) tile: the
-    attention weights ``p`` and score gradients ``ds``.  Both bwd
-    kernels call this, so the mask and scale logic can never diverge
-    between dq and dk/dv.  ``cut_at`` is the tile's ``(first row, first
-    key)`` where the diagonal cuts it, and None where every row sees
-    every key.  With ``keys_first`` both come out transposed, ``[keys,
-    rows]``, from the operands the other way round: what the dK/dV pass
-    multiplies from the left, so that neither of its products has to
-    turn a tile.  (A ragged key tail needs no mask here: the padded
-    rows of ``k`` and ``v`` are zeros, so they add nothing to ``dq``,
-    and their own ``dk``/``dv`` rows are sliced off.)"""
+def _bwd_p_ds(q, do, lse, delta, k, v, cut_at, *, sm_scale):
+    """The backward's tile math for one (keys, query rows) tile: the
+    attention weights ``p`` and score gradients ``ds``, both ``[keys,
+    rows]``: what dK's and dV's products multiply from the left and
+    dQ's from the right, so that none of the three has to turn a tile.
+    ``lse`` (+inf on padded q rows) and ``delta`` are ``[1, rows]``
+    rows.  ``cut_at`` is the tile's ``(first row, first key)`` where the
+    diagonal cuts it, and None where every row sees every key.  (A
+    ragged key tail needs no mask here: the padded rows of ``k`` and
+    ``v`` are zeros, so they add nothing to ``dq``, and their own
+    ``dk``/``dv`` rows are sliced off.)"""
     # matmul operands stay in the input dtype (bf16 at full MXU rate),
-    # accumulating fp32; softmax statistics math is fp32 throughout.
-    # lse (+inf on padded q rows) and delta are per-row vectors: held
-    # across _LANE lanes, or [1, rows] with keys_first
-    if keys_first:
-        (x, dx), (y, dy) = (k, v), (q, do)
-    else:
-        (x, dx), (y, dy) = (q, do), (k, v)
-        lse, delta = _lanes(lse, k.shape[0]), _lanes(delta, k.shape[0])
+    # accumulating fp32; softmax statistics math is fp32 throughout
     s = jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())),
+        k, q, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * sm_scale
     p = jnp.exp(s - lse)
     if cut_at is not None:
         p = jnp.where(_causal_mask(q.shape[0], k.shape[0], *cut_at,
-                                   keys_first), p, 0.0)
+                                   keys_first=True), p, 0.0)
     dp = jax.lax.dot_general(
-        dx, dy, (((1,), (1,)), ((), ())),
+        v, do, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     ds = p * (dp - delta) * sm_scale
     return p, ds
 
 
-def _flash_bwd_dkdv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                           dk_ref, dv_ref, dk_scr, dv_scr, *,
-                           sm_scale, cols, chunk, n_q, n_k, walks):
-    """One (batch*head, k-block, q-block) program: k-blocks are parallel,
-    q-blocks sequential; VMEM scratch accumulates dk/dv for the resident
-    k-block while q/do/lse/delta blocks stream past (lse and delta as
-    ``[1, block_q]`` rows).  Inside, each run of ``cols`` keys walks the
-    query block in chunks of ``chunk`` from the diagonal down (``walks``,
-    of ``_grid_walks``), the scores held keys first."""
+def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+                      dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *,
+                      sm_scale, cols, chunk, n_q, n_k, walks):
+    """One (batch*head, k-block, q-block) program of the backward: both
+    block axes are sequential.  VMEM scratch accumulates dk/dv for the
+    resident k-block while q/do/lse/delta blocks stream past (lse and
+    delta as ``[1, block_q]`` rows), and dq for every query row of the
+    (batch*head) across all of its programs: zeroed at its first, rounded
+    into ``dq_ref`` (the whole head's block) at its last.  Inside, each
+    run of ``cols`` keys walks the query block in chunks of ``chunk``
+    from the diagonal down (``walks``, of ``_grid_walks``), the scores
+    held keys first; a tile's ``p`` and ``ds`` are made once and feed all
+    three gradients.  dq is kept turned, ``[D, T]``: its product is
+    ``k^T ds``, the tile as it lies and the run's keys turned once a
+    run (``ds^T k`` turns a tile a product: 0.13 ms of a 0.74 ms call
+    at T = 1024, PERF.md §6, PR 46), and the head's sum is turned back
+    once, on its way out."""
     import jax.experimental.pallas as pl
 
+    block_q = q_ref.shape[1]
     kj = pl.program_id(1) if n_k > 1 else 0
     qi = pl.program_id(2) if n_q > 1 else 0
+
+    @pl.when((kj == 0) & (qi == 0))
+    def _init_head():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
 
     @pl.when(qi == 0)
     def _init():
@@ -740,9 +744,12 @@ def _flash_bwd_dkdv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 
     def walk(runs, lead):
         for b, tiles in enumerate(runs):
+            if not tiles:
+                continue
             run = pl.ds(b * cols, cols)
             k = k_ref[0, run, :]
             v = v_ref[0, run, :]
+            kt = k.T
             for c, masked in tiles:
                 at = pl.ds(c * chunk, chunk)
                 q = q_ref[0, at, :]
@@ -750,13 +757,19 @@ def _flash_bwd_dkdv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                 p, ds = _bwd_p_ds(
                     q, do, lse_ref[0, :, at], delta_ref[0, :, at], k, v,
                     (lead + c * chunk, b * cols) if masked else None,
-                    sm_scale=sm_scale, keys_first=True)  # [cols, chunk]
+                    sm_scale=sm_scale)                    # [cols, chunk]
+                ds = ds.astype(q.dtype)
                 dv_scr[run, :] += jnp.dot(
                     p.astype(do.dtype), do,
                     preferred_element_type=jnp.float32)
                 dk_scr[run, :] += jnp.dot(
-                    ds.astype(q.dtype), q,
-                    preferred_element_type=jnp.float32)
+                    ds, q, preferred_element_type=jnp.float32)
+                # the chunk's rows in the head: where dq's scratch has them
+                rows = c * chunk
+                if n_q > 1:
+                    rows = pl.multiple_of(qi * block_q + rows, chunk)
+                dq_scr[:, pl.ds(rows, chunk)] += jnp.dot(
+                    kt, ds, preferred_element_type=jnp.float32)
 
     for runs, lead, _, test in walks:
         pl.when(test(qi, kj))(functools.partial(walk, runs, lead))
@@ -766,82 +779,55 @@ def _flash_bwd_dkdv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
-
-def _flash_bwd_dq_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, dq_scr, *,
-                         sm_scale, rows, chunk, n_q, n_k, walks):
-    """One (batch*head, q-block, k-block) program: q-blocks parallel,
-    k-blocks sequential; scratch accumulates dq for the resident q-block
-    (lse and delta as ``[1, block_q]`` rows).  Inside, each run of
-    ``rows`` query rows walks the key block in chunks of ``chunk`` up to
-    the diagonal (``walks``)."""
-    import jax.experimental.pallas as pl
-
-    qi = pl.program_id(1) if n_q > 1 else 0
-    kj = pl.program_id(2) if n_k > 1 else 0
-
-    @pl.when(kj == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-
-    def walk(runs, lead):
-        for a, tiles in enumerate(runs):
-            run = pl.ds(a * rows, rows)
-            q = q_ref[0, run, :]
-            do = do_ref[0, run, :]
-            # the [1, rows] rows they come as, turned to [rows, _LANE]
-            lse, delta = (jnp.broadcast_to(ref[0, :, run], (_LANE, rows)).T
-                          for ref in (lse_ref, delta_ref))
-            for c, masked in tiles:
-                at = pl.ds(c * chunk, chunk)
-                k = k_ref[0, at, :]
-                _, ds = _bwd_p_ds(
-                    q, do, lse, delta, k, v_ref[0, at, :],
-                    (lead + a * rows, c * chunk) if masked else None,
-                    sm_scale=sm_scale)
-                dq_scr[run, :] += jax.lax.dot_general(
-                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-
-    for runs, lead, _, test in walks:
-        pl.when(test(qi, kj))(functools.partial(walk, runs, lead))
-
-    @pl.when(kj == n_k - 1)
-    def _finish():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+    @pl.when((kj == n_k - 1) & (qi == n_q - 1))
+    def _finish_head():
+        # a block of rows at a time: the turned block is a temporary
+        for rows in range(0, n_q * block_q, block_q):
+            dq_ref[0, rows:rows + block_q, :] = dq_scr[
+                :, rows:rows + block_q].T.astype(dq_ref.dtype)
 
 
-# ``(block_q, block_k, dkdv, dq)`` of the backward, the last two the
-# ``(query rows, keys)`` tile of each pass: 256 x 256 in both, the
-# quickest of the sweep at a head of 64, the one width a cell trains at
-# (PERF.md §6, PR 38; wider heads are not measured)
-_BWD_BLOCKS = (1024, 1024, (256, 256), (256, 256))
+# ``(block_q, block_k, (query rows, keys))`` of the backward: the blocks a
+# program keeps and the tile it walks them by, runs of that many keys
+# over chunks of that many query rows: 256 x 256, the quickest of the
+# sweep at a head of 64, the one width a cell trains at (PERF.md §6,
+# PR 46; wider heads are not measured)
+_BWD_BLOCKS = (1024, 1024, (256, 256))
+# what a kernel may take of VMEM unasked on the v5e; the backward asks
+# for more where the head's dq does not fit in it beside the blocks
+_VMEM_UNASKED = 16 * 2 ** 20
 
 
 @functools.partial(jax.jit, static_argnames=(
     "causal", "sm_scale", "interpret", "blocks"))
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale,
                       interpret=False, blocks=None):
-    """Two-pass Pallas flash backward on [B, H, T, D]: a dk/dv kernel and
-    a dq kernel, each O(block) VMEM — the backward twin of
-    ``_flash_fwd_pallas``, jitted as it is (ends the plain-jax recompute
-    that MFU-capped the transformer bench; the measured figure lives in
-    the ``model_flops_utilization`` gauge / bench.py's ``mfu`` key, not
-    here — see docs/PERF.md "MFU is measured, not quoted").
+    """One-pass Pallas flash backward on [B, H, T, D]: one kernel makes
+    dq, dk and dv from one walk of the scores (five products a tile) —
+    the backward twin of ``_flash_fwd_pallas``, jitted as it is (ends
+    the plain-jax recompute that MFU-capped the transformer bench; the
+    measured figure lives in the ``model_flops_utilization`` gauge /
+    bench.py's ``mfu`` key, not here — see docs/PERF.md "MFU is
+    measured, not quoted").  VMEM holds a block of each axis and, in
+    float32 and turned, the dq of a whole head, ``[D, T]`` (256 KB at
+    T = 1024, D = 64, 8 MB at T = 32768), beside its block on the way
+    out: the call asks for the VMEM that takes (``vmem_limit_bytes``:
+    more than a kernel gets unasked from T = 11 K or so) and compiles
+    for a v5e up to T = 131072 at a head of 64; past the chip's VMEM
+    the compiler refuses the kernel.
 
-    ``blocks`` is ``(block_q, block_k, dkdv, dq)``, the last two the
-    ``(query rows, keys)`` tile each pass walks by: the dK/dV pass runs
-    of that many keys over chunks of that many query rows, the dQ pass
-    the other way.  Left out, as every caller but the sweep and the
-    tests leaves it, it is ``_BWD_BLOCKS``."""
+    ``blocks`` is ``(block_q, block_k, (query rows, keys))``, the last
+    the tile the walk goes by: runs of that many keys over chunks of
+    that many query rows.  Left out, as every caller but the sweep and
+    the tests leaves it, it is ``_BWD_BLOCKS``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, D = q.shape
     Tk, Dv = k.shape[2], v.shape[3]
-    bq, bk, dkdv, dq_tile = blocks or _BWD_BLOCKS
-    bq, (chunk_q, rows) = _fit(T, bq, dkdv[0], dq_tile[0])
-    bk, (cols, chunk_k) = _fit(Tk, bk, dkdv[1], dq_tile[1])
+    bq, bk, tile = blocks or _BWD_BLOCKS
+    bq, (chunk,) = _fit(T, bq, tile[0])
+    bk, (cols,) = _fit(Tk, bk, tile[1])
     Tp = -(-T // bq) * bq
     Tkp = -(-Tk // bk) * bk
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
@@ -864,28 +850,32 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale,
     dof = do.reshape(BH, Tp, Dv)
     kf = k.reshape(BH, Tkp, D)
     vf = v.reshape(BH, Tkp, Dv)
-    # per-row vectors cross as [BH, 1, Tp] rows: what the dK/dV pass,
-    # whose scores lie keys first, takes as they are
+    # per-row vectors cross as [BH, 1, Tp] rows: what the kernel, whose
+    # scores lie keys first, takes as they are
     lsef, deltaf = lse.reshape(BH, 1, Tp), delta.reshape(BH, 1, Tp)
     n_q = Tp // bq
     n_k = Tkp // bk
-    for name, walk in (
-            ("dkdv", causal_walk(T, Tk, cols, chunk_q, causal, True)),
-            ("dq", causal_walk(T, Tk, rows, chunk_k, causal))):
-        _M_WALKED.labels(name).set(walk[0] / walk[2])
+    walked, _, pairs = causal_walk(T, Tk, cols, chunk, causal, True)
+    _M_WALKED.labels("bwd").set(walked / pairs)
 
     kwargs = {}
     if not interpret:
+        # the blocks and dq's block twice (the pipeline's two buffers;
+        # a row of VMEM is whole lanes, whatever the head's width), the
+        # three accumulators once, and room for the tiles in flight
+        def lanes(width):
+            return -(-width // _LANE) * _LANE
+
+        held = lanes(D) + lanes(Dv)
+        vmem = (2 * q.dtype.itemsize * (Tp * lanes(D) + (bq + 2 * bk) * held)
+                + 16 * bq + 4 * (Tp * D + bk * held) + 4 * 2 ** 20)
         kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(vmem, _VMEM_UNASKED))
 
     def key_runs(lead, _):
-        return tuple(_query_walk(col0 - lead, cols, chunk_q, bq // chunk_q,
+        return tuple(_query_walk(col0 - lead, cols, chunk, bq // chunk,
                                  causal) for col0 in range(0, bk, cols))
-
-    def row_runs(lead, _):
-        return tuple(_key_walk(lead + row0, rows, chunk_k, bk // chunk_k,
-                               causal) for row0 in range(0, bq, rows))
 
     # blocks wholly above the diagonal are not walked: their index names
     # the nearest block that is, and nothing is copied for them
@@ -895,16 +885,17 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale,
     def q_row(b, j, i):
         return b, 0, q_block(b, j, i)[1]
 
-    def kv_block(b, i, j):
-        return b, jnp.minimum(j, ((i + 1) * bq - 1) // bk) if causal else j, 0
+    def k_block(b, j, i):
+        return b, j, 0
 
-    dkdv_kernel = functools.partial(
-        _flash_bwd_dkdv_kernel, sm_scale=sm_scale, cols=cols,
-        chunk=chunk_q, n_q=n_q, n_k=n_k,
+    kernel = functools.partial(
+        _flash_bwd_kernel, sm_scale=sm_scale, cols=cols, chunk=chunk,
+        n_q=n_q, n_k=n_k,
         walks=_grid_walks(n_q, n_k, bq, bk, key_runs, causal))
-    dk, dv = pl.pallas_call(
-        dkdv_kernel,
-        out_shape=[jax.ShapeDtypeStruct((BH, Tkp, D), k.dtype),
+    dq, dk, dv = pl.pallas_call(
+        kernel,
+        out_shape=[jax.ShapeDtypeStruct((BH, Tp, D), q.dtype),
+                   jax.ShapeDtypeStruct((BH, Tkp, D), k.dtype),
                    jax.ShapeDtypeStruct((BH, Tkp, Dv), v.dtype)],
         grid=(BH, n_k, n_q),
         in_specs=[
@@ -912,38 +903,18 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale,
             pl.BlockSpec((1, bq, Dv), q_block),                       # do
             pl.BlockSpec((1, 1, bq), q_row),                          # lse
             pl.BlockSpec((1, 1, bq), q_row),                          # delta
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),      # k
-            pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0)),     # v
+            pl.BlockSpec((1, bk, D), k_block),                        # k
+            pl.BlockSpec((1, bk, Dv), k_block),                       # v
         ],
-        out_specs=[pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-                   pl.BlockSpec((1, bk, Dv), lambda b, j, i: (b, j, 0))],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+        out_specs=[pl.BlockSpec((1, Tp, D), lambda b, j, i: (b, 0, 0)),
+                   pl.BlockSpec((1, bk, D), k_block),
+                   pl.BlockSpec((1, bk, Dv), k_block)],
+        scratch_shapes=[pltpu.VMEM((D, Tp), jnp.float32),
+                        pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, Dv), jnp.float32)],
         interpret=interpret,
         **kwargs,
     )(qf, dof, lsef, deltaf, kf, vf)
-
-    dq_kernel = functools.partial(
-        _flash_bwd_dq_kernel, sm_scale=sm_scale, rows=rows, chunk=chunk_k,
-        n_q=n_q, n_k=n_k,
-        walks=_grid_walks(n_q, n_k, bq, bk, row_runs, causal))
-    dq = pl.pallas_call(
-        dq_kernel,
-        out_shape=jax.ShapeDtypeStruct((BH, Tp, D), q.dtype),
-        grid=(BH, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, bk, D), kv_block),                       # k
-            pl.BlockSpec((1, bk, Dv), kv_block),                      # v
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),      # q
-            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),     # do
-            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),      # lse
-            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),      # delta
-        ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        interpret=interpret,
-        **kwargs,
-    )(kf, vf, qf, dof, lsef, deltaf)
 
     dq = dq.reshape(B, H, Tp, D)[:, :, :T]
     dk = dk.reshape(B, H, Tkp, D)[:, :, :Tk]
@@ -1002,7 +973,7 @@ def _flash_bwd_scan(q, k, v, o, lse, do, causal, sm_scale):
 
 
 def _flash_bwd_vjp(causal, sm_scale, interpret, res, do):
-    """Backward dispatch: Pallas two-pass kernels on TPU (and under
+    """Backward dispatch: the one-pass Pallas kernel on TPU (and under
     ``interpret=True`` for CPU testing); plain-jax blockwise scan
     elsewhere."""
     q, k, v, o, lse = res
@@ -1022,8 +993,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, interpret=False):
     """Softmax attention over [B, H, T, D] tensors.
 
     On TPU both directions run as Pallas flash kernels (O(T) memory): the
-    online-softmax forward plus a dk/dv pass and a dq pass that reuse the
-    forward's log-sum-exp.  ``interpret=True`` forces the Pallas kernels in
+    online-softmax forward plus one backward pass that makes dq, dk and dv
+    from the forward's log-sum-exp.  ``interpret=True`` forces the Pallas kernels in
     interpreter mode (CPU testing).
     """
     if sm_scale is None:

@@ -61,7 +61,7 @@ def test_flash_gradients_match_reference(causal):
                                   (128, 96), (256, 256), (512, 512),
                                   (640, 640)])
 def test_flash_pallas_backward_kernels(causal, t, tk):
-    """The Pallas bwd kernels themselves (dk/dv pass + dq pass) in
+    """The Pallas bwd kernel itself (dq, dk and dv from one pass) in
     interpret mode — the path TPU hardware runs.  Without interpret=True
     the CPU grad dispatch takes the plain-jax scan fallback and the
     kernels would only ever execute on the chip.  Covers multi-block
@@ -87,8 +87,10 @@ def test_flash_pallas_backward_kernels(causal, t, tk):
 
 
 # (T, Tk, D, Dv, causal, forward blocks (block_q, block_k, rows, chunk),
-#  backward blocks (block_q, block_k, dK/dV tile, dQ tile)): the walk at
-# sizes the interpreter affords, by explicit small blocks
+#  backward blocks (block_q, block_k, tile, second tile), a tile (query
+#  rows, keys)): the walk at sizes the interpreter affords, by explicit
+# small blocks.  The backward walks by one tile a call: the first with
+# the forward, the second, where it is another, in a test of its own
 _WALKS = {
     # one block of each axis: the walk is unrolled; 1, 2 and 4 chunks
     "unrolled-1-chunk": (16, 16, 16, 16, True, (64, 64, 16, 16),
@@ -130,26 +132,53 @@ _WALKS = {
                       (32, 32, (16, 16), (16, 16))),
     "narrow-values-ragged": (72, 72, 24, 8, True, (32, 32, 16, 8),
                              (32, 32, (8, 16), (16, 8))),
+    # heads of 64 and 128, and values wider than the keys
+    "head-64": (48, 48, 64, 64, True, (32, 32, 16, 16),
+                (32, 16, (16, 16), (16, 16))),
+    "head-128-wide-values": (32, 40, 128, 256, False, (32, 32, 16, 16),
+                             (16, 32, (16, 8), (8, 16))),
     "the-rule": (72, 72, 16, 16, True, None, None),
 }
+_SECOND_TILES = sorted(case for case, walk in _WALKS.items()
+                       if walk[6] and walk[6][2] != walk[6][3])
+
+
+def _walk_case(case, dtype):
+    """A case's inputs, the reference's output, log-sum-exp and VJP, and
+    the tolerance its dtype is held to."""
+    T, Tk, D, Dv, causal = _WALKS[case][:5]
+    rng = np.random.RandomState(len(case))
+    q, k, v, do = (jnp.asarray(rng.normal(size=(1, 2, t, d)), dtype)
+                   for t, d in ((T, D), (Tk, D), (Tk, Dv), (T, Dv)))
+    scale = D ** -0.5
+    ref, vjp = jax.vjp(
+        lambda q, k, v: _attention_fwd_ref(q, k, v, causal, scale), q, k, v)
+    _, ref_lse = _attention_fwd_ref(q, k, v, causal, scale, return_lse=True)
+    return ((q, k, v, do), (causal, scale), ref, ref_lse, vjp,
+            2e-5 if dtype == "float32" else 3e-2)
+
+
+def _check_backward(args, how, ref, ref_lse, vjp, tol, blocks):
+    q, k, v, do = args
+    grads = att._flash_bwd_pallas(q, k, v, ref, ref_lse, do, *how,
+                                  interpret=True, blocks=blocks)
+    for got, want in zip(grads, vjp(do)):
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=5 * tol, atol=5 * tol)
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("case", sorted(_WALKS))
 def test_flash_walk_matches_reference(case, dtype):
-    """The three kernels by explicit blocks (interpret mode): output,
+    """The two kernels by explicit blocks (interpret mode): output,
     log-sum-exp and all three gradients against the exact softmax and
     its VJP."""
-    T, Tk, D, Dv, causal, fwd_blocks, bwd_blocks = _WALKS[case]
-    rng = np.random.RandomState(len(case))
-    q, k, v, do = (jnp.asarray(rng.normal(size=(1, 2, t, d)), dtype)
-                   for t, d in ((T, D), (Tk, D), (Tk, Dv), (T, Dv)))
-    scale = D ** -0.5
-    tol = 2e-5 if dtype == "float32" else 3e-2
-    ref, vjp = jax.vjp(
-        lambda q, k, v: _attention_fwd_ref(q, k, v, causal, scale), q, k, v)
-    _, ref_lse = _attention_fwd_ref(q, k, v, causal, scale, return_lse=True)
-    out, lse = att._flash_fwd_pallas(q, k, v, causal, scale, interpret=True,
+    T, _, _, Dv, _, fwd_blocks, bwd_blocks = _WALKS[case]
+    args, how, ref, ref_lse, vjp, tol = _walk_case(case, dtype)
+    q, k, v, _ = args
+    out, lse = att._flash_fwd_pallas(q, k, v, *how, interpret=True,
                                      return_lse=True, blocks=fwd_blocks)
     assert out.dtype == q.dtype and out.shape == (1, 2, T, Dv)
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -157,13 +186,45 @@ def test_flash_walk_matches_reference(case, dtype):
                                rtol=tol, atol=tol)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
                                rtol=tol, atol=tol)
-    grads = att._flash_bwd_pallas(q, k, v, ref, ref_lse, do, causal, scale,
-                                  interpret=True, blocks=bwd_blocks)
-    for got, want in zip(grads, vjp(do)):
-        assert got.shape == want.shape and got.dtype == want.dtype
-        np.testing.assert_allclose(np.asarray(got, np.float32),
-                                   np.asarray(want, np.float32),
-                                   rtol=5 * tol, atol=5 * tol)
+    _check_backward(args, how, ref, ref_lse, vjp, tol,
+                    bwd_blocks and bwd_blocks[:3])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", _SECOND_TILES)
+def test_flash_backward_second_tile_matches_reference(case, dtype):
+    """The backward by a case's other tile, the sides the other way
+    round: all three gradients against the exact softmax's VJP."""
+    blocks = _WALKS[case][6]
+    _check_backward(*_walk_case(case, dtype), blocks[:2] + blocks[3:])
+
+
+# (B, H, T, Tk, causal, (block_q, block_k, tile)): grids of several
+# blocks an axis under several heads
+_CARRIES = {
+    "3x3-blocks-4-heads": (2, 2, 96, 96, True, (32, 32, (16, 16))),
+    "3x3-blocks-full": (1, 3, 96, 96, False, (32, 32, (16, 16))),
+    "2x4-blocks-mid-chunk": (1, 2, 64, 128, True, (32, 32, (16, 32))),
+    "4x2-blocks-ragged": (2, 1, 120, 56, False, (32, 32, (32, 16))),
+    "4x1-blocks": (1, 2, 128, 32, True, (32, 32, (16, 16))),
+    "1x4-blocks": (1, 2, 32, 128, False, (32, 32, (16, 16))),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CARRIES))
+def test_flash_backward_carries_its_sums_across_blocks(case):
+    """dQ's scratch is carried across a head's key blocks (and zeroed
+    again at the next head's first program), dK's and dV's across its
+    query blocks: a grid of several blocks an axis, several heads."""
+    B, H, T, Tk, causal, blocks = _CARRIES[case]
+    rng = np.random.RandomState(T + Tk)
+    q, k, v, do = (jnp.asarray(rng.normal(size=(B, H, t, 16)), "float32")
+                   for t in (T, Tk, Tk, T))
+    ref, vjp = jax.vjp(
+        lambda q, k, v: _attention_fwd_ref(q, k, v, causal, 0.25), q, k, v)
+    _, ref_lse = _attention_fwd_ref(q, k, v, causal, 0.25, return_lse=True)
+    _check_backward((q, k, v, do), (causal, 0.25), ref, ref_lse, vjp, 2e-5,
+                    blocks)
 
 
 @pytest.mark.parametrize("args,walked,masked,pairs", [
@@ -200,13 +261,11 @@ def test_walked_share_gauge_reads_what_the_walk_says(causal):
         q, k, v, causal, 0.25, interpret=True, return_lse=True,
         blocks=(32, 64, 16, 16))
     att._flash_bwd_pallas(q, k, v, out, lse, out, causal, 0.25,
-                          interpret=True,
-                          blocks=(32, 32, (32, 16), (16, 8)))
+                          interpret=True, blocks=(32, 32, (32, 16)))
     want = {"fwd": causal_walk(64, 64, 16, 16, causal),
-            "dkdv": causal_walk(64, 64, 16, 32, causal, True),
-            "dq": causal_walk(64, 64, 16, 8, causal)}
+            "bwd": causal_walk(64, 64, 16, 32, causal, True)}
     if causal:
-        assert want["fwd"][:2] == (10, 4) and want["dq"][0] == 20
+        assert want["fwd"][:2] == (10, 4) and want["bwd"][:2] == (6, 4)
     text = metrics.dump_metrics()
     for kernel, (walked, _, pairs) in want.items():
         gauge = att._M_WALKED.labels(kernel)

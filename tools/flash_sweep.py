@@ -1,13 +1,13 @@
-"""Sweep: device time of the three flash kernels over (shape, block, run,
+"""Sweep: device time of the two flash kernels over (shape, block, run,
 chunk), and of the einsum body beside the kernel at the 1024 crossover.
 
 What ``ops/attention.py``'s block rules rest on (PERF.md §6 holds the
 tables this printed on the attached v5e).  Every case runs ``--iters``
 times inside ONE profiler session, a marker op between cases; the times
 are the device durations of the trace's events, never the host clock:
-``fwd`` / ``dkdv`` / ``dq`` are the Pallas custom calls (told apart by
-their outputs), ``all`` is every device op of the case (the einsum body,
-or the backward's delta and lane-broadcast fusions too).
+``fwd`` / ``bwd`` are the Pallas custom calls (told apart by their
+outputs), ``all`` is every device op of the case (the einsum body, or
+the backward's delta fusion too).
 
     python tools/flash_sweep.py                  # the LM training shape
     python tools/flash_sweep.py --set serve      # the serving prefills, forward only
@@ -15,7 +15,8 @@ or the backward's delta and lane-broadcast fusions too).
 
 A block set is ``block_q,block_k,rows,keys`` (``ops/attention.py:
 _flash_fwd_pallas``: runs of ``rows`` query rows over chunks of ``keys``
-keys; the backward takes that tile for both its passes);
+keys; the backward takes that tile, runs of ``keys`` keys over chunks
+of ``rows`` query rows);
 ``rule`` is what the kernels choose by themselves.
 """
 
@@ -68,7 +69,7 @@ def _inputs(shape, seed=0):
 
 def _cases(shape, blocks, backward, einsum):
     """``[(label, jitted fn, args)]`` of one shape: forward with lse
-    (and the two backward passes) per block set, or the einsum body."""
+    (and the backward) per block set, or the einsum body."""
     q, k, v, do = _inputs(shape)
     scale = 1.0 / float(shape[3]) ** 0.5
     out = []
@@ -84,7 +85,7 @@ def _cases(shape, blocks, backward, einsum):
         if text != "rule":
             bq, bk, run, chunk = (int(x) for x in text.split(","))
             kw = {"blocks": (bq, bk, run, chunk)}
-            bkw = {"blocks": (bq, min(bk, 1024), (run, chunk), (run, chunk))}
+            bkw = {"blocks": (bq, min(bk, 1024), (run, chunk))}
         fwd = jax.jit(lambda q, k, v, kw=kw: att._flash_fwd_pallas(
             q, k, v, True, scale, return_lse=True, interpret=OFF_CHIP,
             **kw))
@@ -99,16 +100,14 @@ def _cases(shape, blocks, backward, einsum):
 
 
 def _kind(name):
-    """fwd / dkdv / dq for a Pallas custom call's event, by its outputs
-    (with lse: a bf16 and an f32; two bf16; one)."""
+    """fwd / bwd for a Pallas custom call's event, by its outputs (with
+    lse: a bf16 and an f32; dq, dk and dv: three bf16)."""
     head = re.match(r"%?[\w.\-]+ = ", name)
     shape, call, _ = name[head.end() if head else 0:].partition(
         " custom-call(")
     if not call:
         return None
-    if not shape.startswith("("):
-        return "dq"
-    return "fwd" if "f32[" in shape else "dkdv"
+    return "fwd" if "f32[" in shape else "bwd"
 
 
 def _device_events(logdir):
@@ -185,9 +184,9 @@ def main(argv=None):
               "crossover": CROSSOVER}[args.set]
     if args.shape:
         shapes = [tuple(int(x) for x in args.shape.split(","))]
-    print("%-28s %-22s %9s %9s %9s %9s  walked" % (
-        "shape (b,h,T,D,Dv) causal", "blocks", "fwd us", "dkdv us",
-        "dq us", "all us"))
+    print("%-28s %-22s %9s %9s %9s  walked" % (
+        "shape (b,h,T,D,Dv) causal", "blocks", "fwd us", "bwd us",
+        "all us"))
     for shape in shapes:
         cases = _cases(shape, blocks, backward=args.set == "train",
                        einsum=args.set == "crossover")
@@ -205,10 +204,10 @@ def main(argv=None):
                     min(chunk, shape[2]))
                 share = "%d/%d, %d masked" % (walked, pairs, masked)
             if row:
-                print("%-28s %-22s %9s %9s %9s %9.1f  %s" % (
+                print("%-28s %-22s %9s %9s %9.1f  %s" % (
                     ",".join(str(x) for x in shape), text,
                     *("%.1f" % row[k] if k in row else "-"
-                      for k in ("fwd", "dkdv", "dq")), row["all"], share),
+                      for k in ("fwd", "bwd")), row["all"], share),
                     flush=True)
     return 0
 
