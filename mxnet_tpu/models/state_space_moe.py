@@ -343,11 +343,17 @@ def _mamba_heads(params, p, conv, dt, dtype, cfg):
     n = conv.shape[0]
     inner, bc, _ = _sizes(cfg)
     groups = cfg["n_groups"]
-    xbc = jax.nn.silu(conv + params[p + "conv_bias"].astype(jnp.float32)
-                      ).astype(dtype)
-    x = xbc[:, :inner].reshape(n, cfg["mamba_num_heads"], -1)
-    b = xbc[:, inner:inner + bc].reshape(n, groups, -1)
-    c = xbc[:, inner + bc:].reshape(n, groups, -1)
+    bias = params[p + "conv_bias"].astype(jnp.float32)
+
+    def part(lo, hi):
+        # cut before the activation: each operand is then made where it
+        # is read from, and not cut out of a ``[N, channels]`` array
+        # (the scan's kernel takes its operands whole)
+        return jax.nn.silu(conv[:, lo:hi] + bias[lo:hi]).astype(dtype)
+
+    x = part(0, inner).reshape(n, cfg["mamba_num_heads"], -1)
+    b = part(inner, inner + bc).reshape(n, groups, -1)
+    c = part(inner + bc, inner + 2 * bc).reshape(n, groups, -1)
     dt = jax.nn.softplus(dt + params[p + "dt_bias"].astype(jnp.float32))
     return x, dt, -jnp.exp(params[p + "A_log"].astype(jnp.float32)), b, c, \
         params[p + "D"].astype(jnp.float32)
@@ -442,16 +448,23 @@ def _mamba_decode(params, p, h, pool, read, write, tail, cfg):
 
 
 # ----------------------------------------------------------------------
-# what the programs count: the expert layers' five, then one of a
+# what the programs count: the expert layers' five, then three of a
 # prefill's state-space layers (nothing in a decode step)
 
-#: the counter that follows :data:`~mxnet_tpu.parallel.moe.EXPERT_COUNTS`
+#: the counters that follow :data:`~mxnet_tpu.parallel.moe.EXPERT_COUNTS`
 #: in the programs' ``counts`` vector
-SSM_COUNTS = ("ssm_prefill_tokens_total",)
-_M_SSM = [_metrics.counter(
-    SSM_COUNTS[0], "Tokens prefills scanned through state-space layers: a "
-    "prompt's tokens times its state-space layers (the bucket's pad "
-    "positions pass and are not counted), by model", ["model"])]
+SSM_COUNTS = ("ssm_prefill_tokens_total", "ssm_prefill_chunks_run_total",
+              "ssm_prefill_chunks_skipped_total")
+_M_SSM = [_metrics.counter(name, text + ", by model", ["model"])
+          for name, text in zip(SSM_COUNTS, (
+              "Tokens prefills scanned through state-space layers: a "
+              "prompt's tokens times its state-space layers (the bucket's "
+              "pad positions pass and are not counted)",
+              "Chunks of the state-space prefill scan whose products ran, "
+              "over every state-space layer",
+              "Chunks of a bucket that lay wholly in its pad and ran no "
+              "product (the scan's kernel skips them; XLA's body runs "
+              "every chunk), over every state-space layer"))]
 
 
 def book(model, counts):
@@ -464,12 +477,25 @@ def book(model, counts):
         family.labels(model).inc(int(value))
 
 
-def _counts(cfg, expert_counts, scanned):
+def _counts(cfg, expert_counts, bucket, length):
+    """A call's ``counts``: the expert layers' sum and, of a prefill of
+    ``length`` tokens in a bucket of ``bucket`` (0 and 0: a decode
+    step), :data:`SSM_COUNTS`.  A stretch is whole chunks from the
+    bucket's start, so the chunks that hold a token are the first
+    ``ceil(length / chunk)``."""
     total = _lm._sum_counts(expert_counts)
     if total is None:           # a cut without an expert layer
         total = jnp.zeros(len(_moe.EXPERT_COUNTS), jnp.int32)
-    return jnp.concatenate([total, jnp.reshape(
-        scanned * cfg["layer_kinds"].count(MAMBA), (1,)).astype(jnp.int32)])
+    chunk = cfg["chunk_size"]
+    chunks = -(-bucket // chunk)
+    kernel = _ssm.scan_form(
+        cfg["mamba_num_heads"], cfg["mamba_head_dim"], cfg["n_groups"],
+        cfg["ssm_state_size"], chunk) == "kernel"
+    ran = (length + chunk - 1) // chunk if kernel else chunks
+    scan = jnp.stack([jnp.asarray(v, jnp.int32)
+                      for v in (length, ran, chunks - ran)])
+    return jnp.concatenate(
+        [total, scan * cfg["layer_kinds"].count(MAMBA)])
 
 
 # ----------------------------------------------------------------------
@@ -502,7 +528,7 @@ def forward(params, tokens, cfg, length=None):
             update, count = _experts(params, p, h, cfg, valid)
             counts.append(count)
         x = x + update
-    counts = _counts(cfg, counts, t if length is None else length)
+    counts = _counts(cfg, counts, t, t if length is None else length)
     return x, jnp.stack(k_rows), jnp.stack(v_rows), counts, \
         (jnp.stack(states), jnp.stack(tails))
 
@@ -576,7 +602,7 @@ def decode_step(params, tokens, positions, k_pages, v_pages, block_tables,
             counts.append(count)
         x = x + update
     return _lm._head(params, x, cfg), jnp.stack(k_rows), jnp.stack(v_rows), \
-        _counts(cfg, counts, 0), (pool_s, pool_tail)
+        _counts(cfg, counts, 0, 0), (pool_s, pool_tail)
 
 
 def lm_definition(cfg, dtype=jnp.bfloat16):

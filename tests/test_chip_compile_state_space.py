@@ -16,7 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from chip_compile_helpers import BF16, F32, _big_moves, _named_calls, _traffic
+from chip_compile_helpers import (BF16, F32, _big_moves, _holds,
+                                  _named_calls, _traffic)
 
 _DOCS = _traffic("serve-docs-closed64-17k.json")
 
@@ -92,19 +93,31 @@ def test_state_space_decode_step_updates_the_state_where_it_lies(topo,
     assert mem.argument_size_in_bytes > 12.4e9    # weights, pools, state
 
 
+# ``temp_size_in_bytes`` of the same three programs with XLA's body of
+# the scan (the parent of PR 47, compiled here for the same described
+# chip): the kernel's programs may hold no more
+_TEMPORARIES_BEFORE = {1024: 0.18e9, 6144: 0.76e9, 16384: 1.34e9}
+
+
 @pytest.mark.parametrize("bucket", [1024, 6144, 16384])
 def test_state_space_prefill_buckets_compile(topo, on_tpu, bucket):
     """The prefill at the smallest bucket, at the one that holds the
     mix's median and at the largest: the attention layer runs the flash
     kernel under its scope's name and holds no ``[32, T, T]`` score
-    matrix, the Mamba-2 layers the chunked scan under theirs, the routed
+    matrix; the Mamba-2 layers run the scan's kernel under theirs, one
+    custom call a layer (a bucket over 4,096 tokens runs its stretches
+    as iterations of one loop a layer), and nothing of XLA's body is
+    left: no ``[4 chunks, 128 heads, 128, 128]`` decays or weights in
+    either width, no carry of ``[8, 128, 1024]`` states through a loop
+    of its own; the routed
     experts two grouped kernels a run over the rows
     ``moe.grouped_kept_rows`` gives the widest row (the hidden
     activation's, 2688 wide over a latent of 1024) under the tiles
     ``moe.grouped_tiling`` gives them; the state ``[5, 8, 128, 1024]``
     and ``[5, 60, 512]`` and the cache rows ``[1, T, 256]`` go to the
-    pools, and the temporaries leave the 12.6 GB of weights, state and
-    pools their room under the chip's 15.75 GB."""
+    pools, and the temporaries are no more than with XLA's body and
+    leave the 12.6 GB of weights, state and pools their room under the
+    chip's 15.75 GB."""
     from mxnet_tpu.models import state_space_moe as sm
     from mxnet_tpu.parallel import moe
 
@@ -121,7 +134,12 @@ def test_state_space_prefill_buckets_compile(topo, on_tpu, bucket):
     assert text.count("%gqa_prefill_attention") >= 1
     assert "f32[32,%d,%d]" % (bucket, bucket) not in text
     assert "f32[1,32,%d,%d]" % (bucket, bucket) not in text
-    assert "ssm_prefill" in text
+    assert _named_calls(text, "ssm_prefill") == 5
+    assert not _holds(text, r"(f32|bf16)\[4,128,128,128\]")
+    assert not _holds(text, r"(f32|bf16)\[4,8,16,128,128\]")
+    stretch = sm._segment(bucket, cfg)
+    assert _holds(text, r"%%ssm_prefill[.\d]* = \(bf16\[%d,8192\]\S*, "
+                  r"f32\[8,128,1024\]" % stretch)
     pairs = bucket * 22
     rows = moe.grouped_kept_rows(pairs, 128, 512, 2688 * 2)
     assert rows == min(pairs // 2, 74880)
@@ -132,6 +150,8 @@ def test_state_space_prefill_buckets_compile(topo, on_tpu, bucket):
     assert tiles == {moe.grouped_tiling(rows, 1024, 2688),
                      moe.grouped_tiling(rows, 2688, 1024)}
     mem = compiled.memory_analysis()
-    print("bucket %d: temporaries %.2f GB, %d kept rows, tiles %s"
-          % (bucket, mem.temp_size_in_bytes / 1e9, rows, sorted(tiles)))
-    assert mem.temp_size_in_bytes < 1.9e9
+    print("bucket %d: temporaries %.2f GB (%.2f with XLA's body of the "
+          "scan), %d kept rows, tiles %s"
+          % (bucket, mem.temp_size_in_bytes / 1e9,
+             _TEMPORARIES_BEFORE[bucket] / 1e9, rows, sorted(tiles)))
+    assert mem.temp_size_in_bytes <= _TEMPORARIES_BEFORE[bucket]

@@ -50,7 +50,7 @@ def test_every_hot_path_kernel_is_registered():
     assert KERNELS == ["flash_prefill_attention", "gated_delta_decode",
                        "latent_decode_attention", "paged_decode_attention",
                        "paged_decode_gqa_attention", "sgd_mom_tree",
-                       "ssm_decode"]
+                       "ssm_decode", "ssm_prefill"]
     regs = fpar.parity_registrations()
     # the tree step is plain jax on every backend; the rest are Pallas
     assert [k for k in KERNELS if not regs[k].pallas] == ["sgd_mom_tree"]

@@ -174,6 +174,10 @@ def test_prefill_then_decode_through_state_and_cache_is_the_reference(
     assert _counter("kv_cache_layers", model=be.model) == 1
     # the prefill scanned its 5 tokens through 3 state layers
     assert _counter("ssm_prefill_tokens_total", model=be.model) == 15
+    # XLA's body runs every chunk of 8 of the bucket, pad or not
+    assert _counter("ssm_prefill_chunks_run_total", model=be.model) \
+        == 3 * -(-bucket // 8)
+    assert _counter("ssm_prefill_chunks_skipped_total", model=be.model) == 0
     assert _counter("moe_layer_steps_total", model=be.model) == 3 * 16
 
 
@@ -249,6 +253,145 @@ def test_pad_positions_leave_the_state_as_at_length(length):
     np.testing.assert_allclose(padded[1], alone[1], atol=1e-6, rtol=1e-6)
     np.testing.assert_allclose(padded[0][:length], alone[0], atol=1e-6,
                                rtol=1e-6)
+
+
+# the prefill scan's kernel under the interpreter, at the smallest whole
+# tiles: 2 groups of 2 heads of 64 (W = 128), a state of 128, chunks of
+# 128
+_KERNEL = dict(heads=4, p=64, groups=2, n=128)
+_OPERANDS = ("x", "dt", "a_rate", "b", "c", "d_skip")
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    monkeypatch.setattr(platform, "pallas_mode", lambda: "interpret")
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def _kernel_inputs(t, seed, dtype=jnp.float32):
+    """B and C a tenth as wide, so that a sum over a state of 128 stays
+    of size one and the float32 tolerance means what it means above."""
+    v = _scan_inputs(t, seed, **_KERNEL)
+    return dict(v, x=v["x"].astype(dtype), b=(0.1 * v["b"]).astype(dtype),
+                c=(0.1 * v["c"]).astype(dtype))
+
+
+def _kernel_state(seed=9):
+    return jnp.asarray(np.random.RandomState(seed).randn(2, 128, 128),
+                       jnp.float32)
+
+
+def _scan(v, **kw):
+    return jax.jit(lambda s, n: state_space.ssm_chunked(
+        *(v[k] for k in _OPERANDS), state=s, length=n, chunk=128))(
+        kw.get("state"), kw.get("length"))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("carried", [False, True], ids=["empty", "carried"])
+def test_scan_kernel_is_the_plain_scan_and_xlas_body(reference, interpreted,
+                                                     carried, dtype):
+    """300 tokens (two chunks and 44 of a third) through the kernel:
+    the outputs and the final state of the reference's scan a token and
+    of XLA's body, from an empty state and from a carried-in one, with
+    ``x``, ``B`` and ``C`` in float32 (every product of six passes) and
+    in bfloat16 (one pass and three; ``y`` is rounded to bfloat16 once,
+    so it is held to bfloat16's spacing there, the state to float32's
+    in both)."""
+    v = _kernel_inputs(300, 3, jnp.dtype(dtype))
+    start = _kernel_state() if carried else None
+    assert state_space.scan_form(4, 64, 2, 128, 128) == "kernel"
+    assert state_space.scan_form(4, 8, 2, 8, 8) == "xla"
+    assert str(jax.make_jaxpr(lambda: state_space.ssm_chunked(
+        *(v[k] for k in _OPERANDS), chunk=128))()).count("pallas_call") == 1
+    assert _counter("ssm_prefill_chunk_tokens", tokens="300",
+                    form="kernel") == 128
+    y, state = _scan(v, state=start)
+    assert y.dtype == v["x"].dtype and state.dtype == jnp.float32
+    f32 = {k: a.astype(jnp.float32) for k, a in v.items()}
+    want, want_state = jax.jit(lambda s: reference.selective_scan(
+        *(f32[k] for k in _OPERANDS), state=s))(
+        None if start is None else _as_reference_state(start, 4, 64))
+    xla, xla_state = jax.jit(lambda s: state_space._chunked(
+        *(v[k] for k in _OPERANDS), s, None, 128, 4))(start)
+    assert np.abs(want).max() > 1.0
+    tol = dict(atol=2e-5, rtol=1e-5)
+    y_tol = tol if dtype == "float32" else dict(atol=2e-5, rtol=2.0 ** -8)
+    np.testing.assert_allclose(y.astype(jnp.float32), want, **y_tol)
+    # two roundings of nearly the same sum may fall a spacing apart
+    np.testing.assert_allclose(
+        y.astype(jnp.float32), xla.astype(jnp.float32),
+        **(tol if dtype == "float32" else dict(atol=2e-5, rtol=2.0 ** -7)))
+    np.testing.assert_allclose(_as_reference_state(state, 4, 64), want_state,
+                               **tol)
+    np.testing.assert_allclose(state, xla_state, **tol)
+
+
+@pytest.mark.parametrize("length", [1, 200, 256, 384])
+def test_scan_kernel_passes_the_pad_and_skips_its_chunks(interpreted,
+                                                         length):
+    """A bucket of three chunks with ``length`` at its first token, in
+    the middle of a chunk, on a chunk's edge and at its end: the state
+    is the one a scan of the first ``length`` tokens gives, the outputs
+    before ``length`` are that scan's, and the chunks that lie wholly
+    behind it ran no product: their rows of ``y`` are ``D x``."""
+    v = _kernel_inputs(384, length)
+    start = _kernel_state(length)
+    y, state = _scan(v, state=start, length=length)
+    cut = {k: a[:length] if k in ("x", "dt", "b", "c") else a
+           for k, a in v.items()}
+    want, want_state = _scan(cut, state=start)
+    np.testing.assert_allclose(state, want_state, atol=1e-6, rtol=1e-6)
+    np.testing.assert_allclose(y[:length], want, atol=1e-6, rtol=1e-6)
+    skipped = -(-length // 128) * 128
+    np.testing.assert_array_equal(
+        y[skipped:], (v["d_skip"][:, None] * v["x"])[skipped:])
+
+
+def test_scan_kernel_in_two_stretches_is_one_stretch(interpreted):
+    """Two stretches, the second from the state the first hands on, are
+    the bucket scanned whole, wherever ``length`` falls."""
+    v = _kernel_inputs(384, 11)
+
+    def part(lo, hi):
+        return {k: a[lo:hi] if k in ("x", "dt", "b", "c") else a
+                for k, a in v.items()}
+
+    for length in (384, 300, 100):
+        whole, whole_state = _scan(v, state=_kernel_state(), length=length)
+        first, handed = _scan(part(0, 256), state=_kernel_state(),
+                              length=min(length, 256))
+        second, state = _scan(part(256, 384), state=handed,
+                              length=max(length - 256, 0))
+        np.testing.assert_allclose(state, whole_state, atol=1e-6, rtol=1e-6)
+        np.testing.assert_allclose(
+            jnp.concatenate([first, second])[:length], whole[:length],
+            atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("form", ["kernel", "xla"])
+def test_a_wholly_padded_chunk_is_booked_as_skipped(monkeypatch, form):
+    """A prefill's ``counts`` at the served sizes, a 1,024 bucket of 8
+    chunks with 300 tokens in it, over 5 state-space layers: the
+    kernel's 3 chunks that hold a token ran and 5 were skipped; XLA's
+    body runs all 8."""
+    monkeypatch.setattr(platform, "pallas_mode",
+                        lambda: "interpret" if form == "kernel" else None)
+    cfg = {"chunk_size": 128, "mamba_num_heads": 128, "mamba_head_dim": 64,
+           "n_groups": 8, "ssm_state_size": 128,
+           "layer_kinds": ("M", "E", "M", "*", "M", "M", "M")}
+    counts = jax.jit(lambda n: sm._counts(cfg, [], 1024, n))(300)
+    ran = 3 if form == "kernel" else 8
+    assert [int(c) for c in counts[-3:]] == [
+        5 * 300, 5 * ran, 5 * (8 - ran)]
+    assert [int(c) for c in sm._counts(cfg, [], 0, 0)[-3:]] == [0, 0, 0]
+    model = "ssm_book_" + form
+    sm.book(model, np.asarray(counts))
+    assert _counter("ssm_prefill_chunks_run_total", model=model) == 5 * ran
+    assert _counter("ssm_prefill_chunks_skipped_total", model=model) \
+        == 5 * (8 - ran)
 
 
 @pytest.mark.parametrize("interpret", [False, True], ids=["xla", "kernel"])

@@ -326,6 +326,38 @@ def test_readers_of_the_new_metrics(capsys):
         assert read(name, {"peaks": peaks}) is None, name
 
 
+def test_the_scan_kernel_has_a_share_of_its_own():
+    """``ssm_prefill_scan_share.nemotron`` (PR 47) reads the kernel by
+    its scope's name, as a traced run of the cell names it; the shape
+    patterns of ``ssm_share.nemotron`` do not hold it (its outputs are a
+    tuple), and a program whose scan is XLA's body reads nothing."""
+    from benchmark.spec import Spec
+
+    spec = Spec(ROOT)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = [m for m in json.load(f)["per_layer"]
+                 if m["name"] == "ssm_prefill_scan_share.nemotron"]
+    assert entry == [{
+        "name": "ssm_prefill_scan_share.nemotron", "unit": "%",
+        "better": "lower", "source": "device_trace",
+        "layer": "state-space scan", "moves": "serve_tokens_per_s",
+        "workloads": [CELL]}]
+
+    def read(metric, events):
+        doc = spec.metric_file(metric)
+        return spec.reader(doc["reader"])({"trace": _trace(events)},
+                                          doc.get("params", {}))
+
+    kernel = ("%ssm_prefill.12 = (bf16[4096,8192]{1,0:T(8,128)(2,1)}, "
+              "f32[8,128,1024]{2,1,0:T(8,128)}) custom-call(%bitcast.875, "
+              "%multiply_convert_fusion.9)")
+    events = [[kernel, 0, 700], ["%fusion.1 = f32[8,8] fusion(%p)", 700, 300]]
+    assert read("ssm_prefill_scan_share.nemotron", events) \
+        == pytest.approx(70.0)
+    assert read("ssm_share.nemotron", events) is None
+    assert read("ssm_prefill_scan_share.nemotron", events[1:]) is None
+
+
 # ----------------------------------------------------------------------
 # the controls of the limits
 
