@@ -74,7 +74,7 @@ from .lm import LMDefinition
 
 __all__ = ["lm_config", "lm_definition", "param_shapes", "init_params",
            "prefill", "decode_step", "full_logits", "state_rows", "book",
-           "SSM_COUNTS", "DECAY_RATE"]
+           "book_ssm", "ssm_counts", "SSM_COUNTS", "DECAY_RATE"]
 
 _PUBLISHED = (
     "hidden_size", "num_hidden_layers", "num_attention_heads",
@@ -326,11 +326,19 @@ def _attention_decode(params, p, h, k_pool, v_pool, tables, context_lens,
 def _mamba_inputs(params, p, h, cfg):
     """The state-space layer's projections of ``h [N, d]``: the output
     gate ``z [N, inner]``, what goes into the convolution ``[N,
-    channels]`` and the raw step ``[N, H]``, float32."""
+    channels]`` and the raw step ``[N, H]``, float32.  A family whose
+    projection is scaled row by row (``cfg["in_proj_scales"]``: float32
+    ``[inner + channels]`` for ``in_weight``'s rows and a factor for
+    ``dt_weight``'s) has the float32 products scaled before they are
+    rounded."""
     inner = _sizes(cfg)[0]
-    mixed = _lm._dot(h, params[p + "in_weight"])
-    dt = jnp.einsum("nc,fc->nf", h, params[p + "dt_weight"],
-                    preferred_element_type=jnp.float32)
+    mixed, dt = (jnp.einsum("nc,fc->nf", h, params[p + name],
+                            preferred_element_type=jnp.float32)
+                 for name in ("in_weight", "dt_weight"))
+    scales = cfg.get("in_proj_scales")
+    if scales is not None:
+        mixed, dt = mixed * scales[0], dt * scales[1]
+    mixed = mixed.astype(h.dtype)
     return mixed[:, :inner], mixed[:, inner:], dt
 
 
@@ -467,35 +475,45 @@ _M_SSM = [_metrics.counter(name, text + ", by model", ["model"])
               "every chunk), over every state-space layer"))]
 
 
+def book_ssm(model, counts):
+    """Add one call's :data:`SSM_COUNTS` to the counters."""
+    for family, value in zip(_M_SSM, counts):
+        family.labels(model).inc(int(value))
+
+
 def book(model, counts):
     """Add one call's ``counts`` to the counters: the expert layers'
     (:func:`~mxnet_tpu.parallel.moe.book_expert_counts`), then
     :data:`SSM_COUNTS`."""
     n = len(_moe.EXPERT_COUNTS)
     _moe.book_expert_counts(model, counts[:n])
-    for family, value in zip(_M_SSM, counts[n:]):
-        family.labels(model).inc(int(value))
+    book_ssm(model, counts[n:])
 
 
-def _counts(cfg, expert_counts, bucket, length):
-    """A call's ``counts``: the expert layers' sum and, of a prefill of
-    ``length`` tokens in a bucket of ``bucket`` (0 and 0: a decode
-    step), :data:`SSM_COUNTS`.  A stretch is whole chunks from the
-    bucket's start, so the chunks that hold a token are the first
-    ``ceil(length / chunk)``."""
-    total = _lm._sum_counts(expert_counts)
-    if total is None:           # a cut without an expert layer
-        total = jnp.zeros(len(_moe.EXPERT_COUNTS), jnp.int32)
+def ssm_counts(cfg, layers, bucket, length):
+    """:data:`SSM_COUNTS` of a prefill of ``length`` tokens in a bucket
+    of ``bucket`` (0 and 0: a decode step) through ``layers``
+    state-space layers.  A stretch is whole chunks from the bucket's
+    start, so the chunks that hold a token are the first ``ceil(length
+    / chunk)``."""
     chunk = cfg["chunk_size"]
     chunks = -(-bucket // chunk)
     kernel = _ssm.scan_form(
         cfg["mamba_num_heads"], cfg["mamba_head_dim"], cfg["n_groups"],
         cfg["ssm_state_size"], chunk) == "kernel"
     ran = (length + chunk - 1) // chunk if kernel else chunks
-    scan = jnp.stack([jnp.asarray(v, jnp.int32)
-                      for v in (length, ran, chunks - ran)])
-    return jnp.concatenate(
-        [total, scan * cfg["layer_kinds"].count(MAMBA)])
+    return layers * jnp.stack([jnp.asarray(v, jnp.int32)
+                               for v in (length, ran, chunks - ran)])
+
+
+def _counts(cfg, expert_counts, bucket, length):
+    """A call's ``counts``: the expert layers' sum, then
+    :func:`ssm_counts`."""
+    total = _lm._sum_counts(expert_counts)
+    if total is None:           # a cut without an expert layer
+        total = jnp.zeros(len(_moe.EXPERT_COUNTS), jnp.int32)
+    return jnp.concatenate([total, ssm_counts(
+        cfg, cfg["layer_kinds"].count(MAMBA), bucket, length)])
 
 
 # ----------------------------------------------------------------------

@@ -301,6 +301,13 @@ def _chunk_kernel(length_ref, x_ref, b_ref, c_ref, dt_ref, dt_across_ref,
             """The columns of ``v [rows, heads]`` of the heads that lie
             in 128 lanes from head ``first`` on, each over its lanes."""
             out = v[:, first:first + 1]
+            if together == 1:
+                # a head fills the lanes: its column goes over them
+                # here, by a select the compiler cannot fold away (a
+                # ``[1, 1]`` value spread over sublanes and lanes at
+                # once it refuses: "Broadcast in both sublanes and
+                # lanes")
+                return jnp.where(lane >= 0, out, 0.0)
             for k in range(1, together):
                 out = jnp.where(lane >= k * p,
                                 v[:, first + k:first + k + 1], out)
@@ -488,6 +495,9 @@ register_parity(
         # the served state (128 heads of 64 x 128 in 8 groups) in a
         # small pool
         (4, 128, 64, 8, 128, 9),
+        # the parallel hybrid's (32 heads of 128 x 256 in 2 groups):
+        # exactly KERNEL_STATE_BYTES a row
+        (3, 32, 128, 2, 256, 7),
     ))
 
 
@@ -525,4 +535,7 @@ register_parity(
         (300, 120, 8, 32, 1, 128, jnp.bfloat16),
         # the served layer (128 heads of 64 in 8 groups, a state of 128)
         (256, 256, 128, 64, 8, 128, jnp.bfloat16),
+        # the parallel hybrid's layer (32 heads of 128 in 2 groups, a
+        # state of 256): a head fills 128 lanes by itself
+        (256, 200, 32, 128, 2, 256, jnp.bfloat16),
     ))

@@ -106,6 +106,7 @@ from __future__ import annotations
 
 import collections as _collections
 import os
+import sys as _sys
 import threading
 import time
 import weakref as _weakref
@@ -200,6 +201,18 @@ class RecurrentStateHazard(MXNetError):
     step behind it was queued: the queued step has overwritten the
     version of the state this step read, so it cannot be run again in
     place.  The live sequences have to be re-prefilled or failed."""
+
+
+def _unheld_count():
+    """What ``sys.getrefcount`` says of an array that a list, the loop
+    over that list and the call itself refer to and nothing else: read
+    from the interpreter, not assumed (:meth:`LMBackend._host_logits`
+    asks it in a loop of the same form)."""
+    for array in [_np.empty(0)]:
+        return _sys.getrefcount(array)
+
+
+_UNHELD = _unheld_count()
 
 
 def _host_nbytes(arrays):
@@ -531,6 +544,9 @@ class LMBackend(Backend):
         # the block table the last decode call was handed, with what
         # was derived from it (:meth:`_on_device`)
         self._table = None
+        # the host arrays the last decode steps' logits were handed out
+        # in (:meth:`_host_logits`), at most two
+        self._handed = []
         self._ahead_used = _M_AHEAD_USED.labels(model)
         self._ahead_dropped = _M_AHEAD_DROPPED.labels(model)
         self._state_moved = _M_STATE_MOVED.labels(model)
@@ -603,7 +619,8 @@ class LMBackend(Backend):
 
     def _copy(self, phase, logits, counts, ids):
         """:meth:`_fetch`'s copies into ordinary memory, booked."""
-        logits = _np.array(logits)
+        logits = (self._host_logits(logits) if phase == "decode"
+                  else _np.array(logits))
         d2h = logits.nbytes
         if ids is not None:
             ids = _np.array(ids)
@@ -614,6 +631,30 @@ class LMBackend(Backend):
             self.definition.book(self.model, counts)
         self.moved(phase, d2h=d2h)
         return logits, ids
+
+    def _host_logits(self, logits):
+        """A decode step's ``logits`` in ordinary memory: in an array an
+        earlier step's were handed out in, if whoever got that array
+        has let it go, else in a new one.  A step's logits are
+        ``rows x vocabulary x 4`` bytes, and an array of megabytes made
+        and freed every step is mapped, faulted in page by page and
+        unmapped again in some steps and taken from the allocator's
+        heap in others: 33 MB (32 rows of 261,120) cost 3-4 ms or 30-42
+        ms a step on the v5e's host, 3.6 ms into an array that is kept
+        (PERF.md section 6, PR 48).  A caller that keeps the array, or
+        a view of it, keeps it for good: only an array that nothing but
+        this backend refers to is written again."""
+        src = _np.asarray(logits)
+        for out in self._handed:
+            # referred to by the list, by this loop and by the call that
+            # counts: nobody holds it or a view of it (a view's ``base``)
+            if (_sys.getrefcount(out) == _UNHELD and out.shape == src.shape
+                    and out.dtype == src.dtype):
+                _np.copyto(out, src)
+                return out
+        out = _np.array(src)
+        self._handed = self._handed[-1:] + [out]
+        return out
 
     @staticmethod
     def _copy_back(*outputs):
@@ -721,7 +762,10 @@ class LMBackend(Backend):
         [B, V], k_step [L, B, row width], v_step, cold)``: the logits a
         host copy, ``k_step``/``v_step`` device arrays.  The step's
         greedy ids (``argmax`` of those logits, made on the device) are
-        left in ``self.greedy_ids``, int32 ``[B]`` on the host.
+        left in ``self.greedy_ids``, int32 ``[B]`` on the host.  The
+        logits' array is the caller's for as long as it keeps it or a
+        view of it; one it has let go may be the array a later call's
+        logits come in (:meth:`_host_logits`).
 
         The program reads the pool as of before the step; **the write
         of ``k_step``/``v_step`` follows it inside this call**, into the

@@ -421,3 +421,25 @@ def test_a_subclass_with_the_four_argument_decode_sees_every_step(lm):
         for j in range(1, len(stream)):
             rows = served[len(prompt) - 1 + j, stream[j - 1]]
             assert any(int(row.argmax()) == stream[j] for row in rows)
+
+
+@pytest.mark.parametrize("kept", ["nothing", "the array", "a view"])
+def test_a_steps_logits_are_written_again_only_once_let_go(lm, kept):
+    """``LMBackend._host_logits``: a decode step's logits come in the
+    host array an earlier step's came in where the caller kept neither
+    that array nor a view of it (the benchmark's wrapper of a large
+    vocabulary keeps a copied part), and in a new one where it did:
+    what a caller holds is never written under it."""
+    be, args = _two_rows(lm, "handed_" + kept.replace(" ", "_"))
+    first = be.decode(*args)[0]
+    held = {"nothing": None, "the array": first, "a view": first[1]}[kept]
+    was, where = first.copy(), first.ctypes.data
+    del first
+    args[0], args[1], args[3] = (be.greedy_ids, args[1] + 1, args[3] + 1)
+    second = be.decode(*args)[0]
+    assert not np.array_equal(second, was)
+    assert (second.ctypes.data == where) == (kept == "nothing")
+    if kept == "the array":
+        np.testing.assert_array_equal(held, was)
+    if kept == "a view":
+        np.testing.assert_array_equal(held, was[1])

@@ -270,17 +270,23 @@ def interpreted(monkeypatch):
     jax.clear_caches()
 
 
-def _kernel_inputs(t, seed, dtype=jnp.float32):
+# the parallel hybrid's shape (models/parallel_hybrid.py): a head fills
+# 128 lanes by itself, the state is 256 a channel
+_KERNEL_WIDE = dict(heads=2, p=128, groups=2, n=256)
+
+
+def _kernel_inputs(t, seed, dtype=jnp.float32, shape=_KERNEL):
     """B and C a tenth as wide, so that a sum over a state of 128 stays
     of size one and the float32 tolerance means what it means above."""
-    v = _scan_inputs(t, seed, **_KERNEL)
+    v = _scan_inputs(t, seed, **shape)
     return dict(v, x=v["x"].astype(dtype), b=(0.1 * v["b"]).astype(dtype),
                 c=(0.1 * v["c"]).astype(dtype))
 
 
-def _kernel_state(seed=9):
-    return jnp.asarray(np.random.RandomState(seed).randn(2, 128, 128),
-                       jnp.float32)
+def _kernel_state(seed=9, shape=_KERNEL):
+    return jnp.asarray(np.random.RandomState(seed).randn(
+        *state_space.state_shape(shape["heads"], shape["p"],
+                                 shape["groups"], shape["n"])), jnp.float32)
 
 
 def _scan(v, **kw):
@@ -289,20 +295,27 @@ def _scan(v, **kw):
         kw.get("state"), kw.get("length"))
 
 
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("dtype,shape", [
+    ("float32", _KERNEL), ("bfloat16", _KERNEL), ("bfloat16", _KERNEL_WIDE)],
+    ids=["float32", "bfloat16", "bfloat16-a-head-a-tile"])
 @pytest.mark.parametrize("carried", [False, True], ids=["empty", "carried"])
 def test_scan_kernel_is_the_plain_scan_and_xlas_body(reference, interpreted,
-                                                     carried, dtype):
+                                                     carried, dtype, shape):
     """300 tokens (two chunks and 44 of a third) through the kernel:
     the outputs and the final state of the reference's scan a token and
     of XLA's body, from an empty state and from a carried-in one, with
     ``x``, ``B`` and ``C`` in float32 (every product of six passes) and
     in bfloat16 (one pass and three; ``y`` is rounded to bfloat16 once,
     so it is held to bfloat16's spacing there, the state to float32's
-    in both)."""
-    v = _kernel_inputs(300, 3, jnp.dtype(dtype))
-    start = _kernel_state() if carried else None
+    in both); at two heads of 64 channels to 128 lanes and at a head of
+    128 that fills them by itself over a state of 256 (PR 48: the
+    chip's compiler refused the head's decay spread over sublanes and
+    lanes at once)."""
+    heads, p = shape["heads"], shape["p"]
+    v = _kernel_inputs(300, 3, jnp.dtype(dtype), shape)
+    start = _kernel_state(shape=shape) if carried else None
     assert state_space.scan_form(4, 64, 2, 128, 128) == "kernel"
+    assert state_space.scan_form(32, 128, 2, 256, 128) == "kernel"
     assert state_space.scan_form(4, 8, 2, 8, 8) == "xla"
     assert str(jax.make_jaxpr(lambda: state_space.ssm_chunked(
         *(v[k] for k in _OPERANDS), chunk=128))()).count("pallas_call") == 1
@@ -313,7 +326,7 @@ def test_scan_kernel_is_the_plain_scan_and_xlas_body(reference, interpreted,
     f32 = {k: a.astype(jnp.float32) for k, a in v.items()}
     want, want_state = jax.jit(lambda s: reference.selective_scan(
         *(f32[k] for k in _OPERANDS), state=s))(
-        None if start is None else _as_reference_state(start, 4, 64))
+        None if start is None else _as_reference_state(start, heads, p))
     xla, xla_state = jax.jit(lambda s: state_space._chunked(
         *(v[k] for k in _OPERANDS), s, None, 128, 4))(start)
     assert np.abs(want).max() > 1.0
@@ -324,8 +337,8 @@ def test_scan_kernel_is_the_plain_scan_and_xlas_body(reference, interpreted,
     np.testing.assert_allclose(
         y.astype(jnp.float32), xla.astype(jnp.float32),
         **(tol if dtype == "float32" else dict(atol=2e-5, rtol=2.0 ** -7)))
-    np.testing.assert_allclose(_as_reference_state(state, 4, 64), want_state,
-                               **tol)
+    np.testing.assert_allclose(_as_reference_state(state, heads, p),
+                               want_state, **tol)
     np.testing.assert_allclose(state, xla_state, **tol)
 
 
