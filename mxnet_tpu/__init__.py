@@ -6,6 +6,12 @@ Import as ``import mxnet_tpu as mx`` — the namespace mirrors the reference's
 ``python/mxnet/__init__.py``.
 """
 
+# first and last statements: what an import of the package costs (JAX's
+# own, where nothing imported it before), booked with the start-up scopes
+import time as _time
+
+_T_IMPORT = _time.perf_counter()
+
 # Multi-process bootstrap MUST precede any XLA backend touch, so it runs
 # before everything else when the launcher env is present (parity: the
 # reference's MXInitPSEnv handshake with the dmlc tracker env,
@@ -93,3 +99,5 @@ symbol._init_module()
 
 # re-export common symbol constructors at top level like the reference
 from .symbol import Variable, Group  # noqa: E402
+
+compile_cache.book("package.import", _time.perf_counter() - _T_IMPORT)

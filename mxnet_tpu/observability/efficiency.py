@@ -248,30 +248,36 @@ def record_compile(cache, lower, steps=1):
     memory) → ``trainer_compile_cost_unsupported_total{cache}``.
     Never raises; constant-time guard when metrics are disabled.
     ``MXNET_TPU_COST_ANALYSIS=0`` skips entirely, ``=lowered`` skips
-    the AOT compile (cheaper, no memory footprint).
+    the AOT compile (cheaper, no memory footprint).  The lowering and
+    the AOT compile run under the start-up scope
+    ``trainer.cost_analysis`` (``program`` = ``cache``;
+    :mod:`mxnet_tpu.compile_cache`), which says what they cost.
     """
     if not _metrics.metrics_enabled():
         return
     mode = os.environ.get("MXNET_TPU_COST_ANALYSIS", "compiled").lower()
     if mode in ("0", "false", "off", "no"):
         return
+    from .. import compile_cache as _compile_cache
+
     fams = _cost_fams()
-    try:
-        lowered = lower()
-    except Exception:
-        fams["unsupported"].labels(cache).inc()
-        return
     cost = mem = None
-    if mode != "lowered":
+    with _compile_cache.scope("trainer.cost_analysis", cache):
         try:
-            compiled = lowered.compile()
-            cost = _first_cost(compiled.cost_analysis())
-            try:
-                mem = compiled.memory_analysis()
-            except Exception:
-                mem = None
+            lowered = lower()
         except Exception:
-            cost = None
+            fams["unsupported"].labels(cache).inc()
+            return
+        if mode != "lowered":
+            try:
+                compiled = lowered.compile()
+                cost = _first_cost(compiled.cost_analysis())
+                try:
+                    mem = compiled.memory_analysis()
+                except Exception:
+                    mem = None
+            except Exception:
+                cost = None
     if cost is None:
         try:
             cost = _first_cost(lowered.cost_analysis())

@@ -114,6 +114,7 @@ import weakref as _weakref
 import numpy as _np
 
 from .. import chaos
+from .. import compile_cache as _compile_cache
 from ..base import MXNetError
 from ..observability import memory as _memory
 from ..observability import metrics as _metrics
@@ -407,6 +408,12 @@ def with_greedy_ids(decode):
     return program
 
 
+def _program_label(key):
+    """A jit-cache key as a start-up scope's ``program``:
+    ``("prefill", 1024)`` is ``prefill:1024``."""
+    return "%s:%s" % (key[0], "x".join(str(n) for n in key[1:]))
+
+
 class _Table(_collections.namedtuple("_Table", "host device slots live")):
     """A decode batch's block table as :meth:`LMBackend.decode` was
     handed it (``host``, known again by identity), its copy on the
@@ -491,6 +498,7 @@ class LMBackend(Backend):
     stays on the parity contract.
     """
 
+    @_compile_cache.scope("backend.build")
     def __init__(self, params, cfg=None, block_size=None, num_blocks=None,
                  int8_head=False, model="lm", definition=None,
                  state_slots=None):
@@ -562,7 +570,10 @@ class LMBackend(Backend):
     def _jit(self, key, program, donate=()):
         """Shape-keyed jit cache of the definition's ``program``
         (``donate``: the arguments it may write where they lie);
-        returns (fn, cold)."""
+        returns (fn, cold).  A cold ``fn`` handed out outside
+        :meth:`GenerationScheduler.warmup` (no start-up scope is open)
+        runs, this once, under the start-up scope ``serve.cold``: a
+        compile that serving paid for is booked as one."""
         with self._jit_lock:
             fn = self._jits.get(key)
             cold = fn is None
@@ -571,6 +582,9 @@ class LMBackend(Backend):
 
                 fn = jax.jit(program, donate_argnums=donate)
                 self._jits[key] = fn
+                if not _compile_cache.is_open():
+                    fn = _compile_cache.scope(
+                        "serve.cold", _program_label(key))(fn)
         return fn, cold
 
     def moved(self, phase, h2d=0, d2h=0):
@@ -1026,8 +1040,10 @@ class GenerationScheduler(object):
                 ["model"]),
             "compiles": reg.counter(
                 "generation_compiles_total",
-                "Cold (compiling) prefill/decode shapes; flat after "
-                "warmup", ["model"]),
+                "Cold prefill/decode shapes: calls whose program was not "
+                "yet in the backend's jit cache, one a bucket however many "
+                "programs it compiled (compile_requests_total{scope} counts "
+                "those); flat after warmup", ["model"]),
             "errors": reg.counter(
                 "generation_dispatch_errors_total",
                 "Prefill/decode attempts that raised (chaos or backend "
@@ -1121,10 +1137,19 @@ class GenerationScheduler(object):
         generation never compiles.  The largest decode bucket, the one
         a full batch runs ahead in, also makes a queued step and is
         answered by it: the program fed the device's own ids.  Returns
-        cold count."""
+        the cold count, which ``generation_compiles_total`` takes: one a
+        bucket whose program was not yet in the backend's jit cache,
+        however many programs the bucket compiled (its pool write, the
+        queued step); every program is counted in
+        ``compile_requests_total{scope}``.  Each bucket runs under a
+        start-up scope (``warmup.prefill`` / ``warmup.decode``,
+        ``program`` ``prefill:<bucket>`` / ``decode:<bucket>``) and the
+        table of what each held is logged at the end
+        (:mod:`mxnet_tpu.compile_cache`)."""
         lane = self._lane(name)
         entry = lane.entry
         cold_n = 0
+        scopes = []
         with entry.dispatch_lock:
             backend = entry.backend
             cache = backend.cache
@@ -1133,22 +1158,31 @@ class GenerationScheduler(object):
             cache.allocate(sid, 3)
             try:
                 for t in self._prefill_buckets[name]:
-                    _, k, v, cold, *state = backend.prefill(
-                        _np.zeros(t, dtype=_np.int32), 1)
-                    cache.write_prefill(sid, k, v, 1, *state)
+                    with _compile_cache.scope(
+                            "warmup.prefill",
+                            _program_label(("prefill", t))) as sc:
+                        _, k, v, cold, *state = backend.prefill(
+                            _np.zeros(t, dtype=_np.int32), 1)
+                        cache.write_prefill(sid, k, v, 1, *state)
+                    scopes.append(sc)
                     cold_n += bool(cold)
                 for b in entry.buckets:
-                    tables = _np.stack(
-                        [cache.block_table(
-                            sid, backend.max_blocks_per_seq)] * b)
-                    step = [_np.zeros(b, _np.int32), _np.ones(b, _np.int32),
-                            tables, _np.full(b, 2, _np.int32)]
-                    backend.run_ahead = b == entry.buckets[-1]
-                    cold = backend.decode(*step)[3]
-                    if backend.run_ahead:
-                        backend.run_ahead = False
-                        backend.decode(backend.greedy_ids, step[1] + 1,
-                                       tables, step[3] + 1)
+                    with _compile_cache.scope(
+                            "warmup.decode",
+                            _program_label(("decode", b))) as sc:
+                        tables = _np.stack(
+                            [cache.block_table(
+                                sid, backend.max_blocks_per_seq)] * b)
+                        step = [_np.zeros(b, _np.int32),
+                                _np.ones(b, _np.int32), tables,
+                                _np.full(b, 2, _np.int32)]
+                        backend.run_ahead = b == entry.buckets[-1]
+                        cold = backend.decode(*step)[3]
+                        if backend.run_ahead:
+                            backend.run_ahead = False
+                            backend.decode(backend.greedy_ids, step[1] + 1,
+                                           tables, step[3] + 1)
+                    scopes.append(sc)
                     cold_n += bool(cold)
             finally:
                 backend.run_ahead = False
@@ -1156,6 +1190,7 @@ class GenerationScheduler(object):
                 cache.free(sid)
         if cold_n and _metrics.metrics_enabled():
             lane.m_compiles.inc(cold_n)
+        _compile_cache.log_table("warmup of %r" % name, scopes)
         return cold_n
 
     # -- admission ----------------------------------------------------
